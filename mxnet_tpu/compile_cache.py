@@ -19,11 +19,11 @@ Two tiers share this module:
   hits refresh recency).  Consumers: ``deploy.StableHLOModel.
   aot_program`` / ``serving.ModelRepository`` bucket programs.
 - **Training-side jit programs**: :func:`enable_jax_persistent_cache`
-  routes jax's OWN persistent compilation cache into a shared
-  directory and counts its hit/miss monitoring events — the bench
-  harness (``bench.py``) uses it so successive rounds stop paying the
-  full compile bill (BENCH r03/r05 hit the harness timeout largely on
-  recompilation).
+  turns on jax's OWN persistent compilation cache — where
+  ``JAX_COMPILATION_CACHE_DIR`` says, else at one fixed path inside the
+  checkout — and counts its hit/miss monitoring events; ``bench.py``
+  and ``chip_smoke.py`` call it first thing so a second run on the
+  same machine stops paying the full compile bill.
 
 Payload format: ``b"MXAOT1" + sha256(body) + body`` where ``body`` is
 the pickled ``(blob, in_tree, out_tree)`` triple from
@@ -57,14 +57,19 @@ _SUFFIX = ".bin"
 def topology_fingerprint():
     """Device-topology + runtime-version component of every cache key: a
     serialized executable only reloads onto the platform/device-kind/
-    count/process layout and jax/jaxlib pair it was compiled for."""
+    count/process layout and jax/jaxlib pair it was compiled for, and
+    only onto the very device it was compiled for (``dev=``: the
+    serialized form names its device by id)."""
     try:
         import jax
         import jaxlib
+
+        from .context import default_jax_device
         devs = jax.devices()
         kinds = ",".join(sorted({f"{d.platform}:{d.device_kind}"
                                  for d in devs}))
         return (f"{kinds}|n={len(devs)}|procs={jax.process_count()}"
+                f"|dev={default_jax_device().id}"
                 f"|jax={jax.__version__}|jaxlib={jaxlib.__version__}")
     except Exception:       # noqa: BLE001 — keyable even without a backend
         return "no-backend"
@@ -107,10 +112,17 @@ def _serialize_compiled(compiled) -> bytes:
 
 
 def _deserialize_compiled(body: bytes):
-    """Payload body -> loaded executable callable."""
+    """Payload body -> loaded executable callable, on the one device it
+    was compiled for (the cache key's ``dev=``).  Left to its default,
+    ``deserialize_and_load`` spreads the executable over EVERY device
+    of the backend, and a one-device program then refuses its
+    arguments wherever the process sees more than one device."""
     from jax.experimental.serialize_executable import deserialize_and_load
+
+    from .context import default_jax_device
     blob, in_tree, out_tree = pickle.loads(body)
-    return deserialize_and_load(blob, in_tree, out_tree)
+    return deserialize_and_load(blob, in_tree, out_tree,
+                                execution_devices=[default_jax_device()])
 
 
 def load_payload_file(path):
@@ -471,8 +483,12 @@ def aot_program(fn, avals, key, cache=None, shipped_path=None):
         prog = load_executable_file(shipped_path)
         if prog is not None:
             return prog, "disk"
+    # an already-jitted fn lowers as itself: wrapping it in a second
+    # jit would drop its donate_argnums (the decode programs donate the
+    # KV pools)
+    lower = fn.lower if hasattr(fn, "lower") else jax.jit(fn).lower
     try:
-        compiled = jax.jit(fn).lower(*avals).compile()
+        compiled = lower(*avals).compile()
     except Exception as e:
         raise MXNetError(f"aot_program: compile failed for key "
                          f"{key[:12]}…: {e}") from e
@@ -486,18 +502,29 @@ def aot_program(fn, avals, key, cache=None, shipped_path=None):
 
 
 # ----------------------------------------------- training-side (jax) cache
-def enable_jax_persistent_cache(cache_dir):
-    """Route jax's OWN persistent compilation cache (the training-side
-    ``jax.jit`` path — distinct from the serving executable store
-    above) into ``cache_dir``, with the size/time admission thresholds
-    zeroed so every program persists.  Returns a live ``{"hits": n,
-    "misses": n}`` dict updated from jax's compilation-cache monitoring
-    events — the bench harness reports it per phase."""
+# the one in-code home of jax's persistent cache: a fixed path inside
+# the checkout (the path is part of what a lookup keys on, so a
+# directory that moves between runs never hits)
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def enable_jax_persistent_cache():
+    """Turn on jax's OWN persistent compilation cache (the
+    training-side ``jax.jit`` path — distinct from the serving
+    executable store above), with the size/time admission thresholds
+    zeroed so every program persists.  Where the cache lives is decided
+    outside the program: when ``JAX_COMPILATION_CACHE_DIR`` is set, jax
+    has already read it and no directory is set here; otherwise it is
+    :data:`JAX_CACHE_DIR`.  Returns a live ``{"hits": n, "misses": n}``
+    dict updated from jax's compilation-cache monitoring events."""
     import jax
     from jax import monitoring
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(JAX_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     stats = {"hits": 0, "misses": 0}

@@ -37,7 +37,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError, get_env
@@ -468,7 +468,6 @@ class XLA(KVStore):
         fn = self._fn_cache.get(cache_key)
         if fn is None:
             mesh, _ = self._sharding(devices)
-            from .._jax_compat import shard_map
             body = shard_map(lambda x: lax.psum(x, "dev"), mesh=mesh,
                              in_specs=P("dev"), out_specs=P())
             fn = jax.jit(body,
@@ -486,7 +485,6 @@ class XLA(KVStore):
         fn = self._fn_cache.get(cache_key)
         if fn is None:
             mesh, _ = self._sharding(devices)
-            from .._jax_compat import shard_map
             if spec.stochastic:
                 def body(x, res, k):
                     rkey = jax.random.fold_in(k, lax.axis_index("dev"))

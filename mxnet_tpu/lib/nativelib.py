@@ -11,6 +11,7 @@ reports which path is active.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,21 +23,46 @@ from ..base import env_truthy
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "nativelib.cc")
 _SO = os.path.join(_DIR, "libmxnet_tpu_native.so")
+# sha256 of the source the cached .so was built from: the binary is a
+# build product that copies of the tree carry along (mtimes do not
+# survive a copy, and the ABI number does not move with every export),
+# so staleness is keyed on the source's CONTENT
+_STAMP = _SO + ".sha256"
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
+def _src_digest() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _stale() -> bool:
+    if not os.path.exists(_SO):
+        return True
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip() != _src_digest()
+    except OSError:
+        return True
+
+
 def _build() -> bool:
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-            "-o", _SO, _SRC]
+            "-o", tmp, _SRC]
     # libjpeg powers the threaded decode tier; hosts without it still
     # get the recordio/csv tier (decode falls back to Python/cv2)
     for cmd in (base + ["-ljpeg"], base + ["-DMXNATIVE_NO_JPEG"]):
         try:
             proc = subprocess.run(cmd, capture_output=True, timeout=120)
-            if proc.returncode == 0 and os.path.exists(_SO):
+            if proc.returncode == 0 and os.path.exists(tmp):
+                os.replace(tmp, _SO)
+                with open(tmp, "w") as f:
+                    f.write(_src_digest())
+                os.replace(tmp, _STAMP)
                 return True
         except (OSError, subprocess.TimeoutExpired):
             return False
@@ -52,9 +78,7 @@ def _load():
         # '0'/'' = off, like every other boolean knob
         if env_truthy("MXNET_TPU_DISABLE_NATIVE"):
             return None
-        stale = (not os.path.exists(_SO) or
-                 os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        if stale and not _build():
+        if _stale() and not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO)

@@ -29,28 +29,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
 
-__all__ = ["flash_attention", "pallas_available",
+__all__ = ["flash_attention",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_verify", "ragged_paged_verify_reference"]
 
 _NEG_INF = -1e30
-
-
-def pallas_available() -> bool:
-    """True when the Pallas kernels in this module can execute (compiled
-    on TPU, interpreted on CPU; both need the pltpu scratch/memory-space
-    constructors)."""
-    return _HAVE_PLTPU
 
 
 def _scratch(shape, dtype):
@@ -383,11 +370,6 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     causal=True.  Returns (B*H, Lq, D) in the query dtype.  Block sizes
     default to a per-(seqlen, head-dim) tuned table (_default_blocks).
     """
-    if not pallas_available():
-        from ..base import MXNetError
-        raise MXNetError(
-            "flash_attention requires jax.experimental.pallas.tpu "
-            "(check mx.runtime.Features()['PALLAS'])")
     BH, Lq, D = q.shape
     Lk = k.shape[1]
     if sm_scale is None:
@@ -472,17 +454,22 @@ def flash_selfatt_nomask(queries_keys_values, *, heads: int = 1,
 def _paged_fwd_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                       m_scr, l_scr, acc_scr, *, sm_scale, page_size,
                       n_pages):
-    """One (sequence, head, page) grid step of decode attention.
+    """One (sequence, page) grid step of decode attention, all heads.
 
     The page axis is innermost and sequential, so the online-softmax
-    statistics (m/l/acc scratch) carry across the pages of one
-    (sequence, head) exactly like the flash kernel's k axis.  Which
-    physical page backs grid step (b, h, p) is decided by the BlockSpec
+    statistics (m/l/acc scratch, one row per head) carry across the
+    pages of one sequence exactly like the flash kernel's k axis.  Which
+    physical page backs grid step (b, p) is decided by the BlockSpec
     index map reading the scalar-prefetched block table — the kernel
-    body never sees a page id, only its (page_size, D) tile.
+    body never sees a page id, only its (page_size, H, D) tile.
+
+    One query row per head leaves the MXU nothing to do, so the scores
+    are a VPU multiply + lane reduce in float32 with the head axis kept
+    on sublanes throughout (``keepdims``): no relayout and no per-head
+    slicing.
     """
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -497,23 +484,19 @@ def _paged_fwd_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # for an inactive slot, ctx == 0: output falls out as zeros)
     @pl.when(start < ctx)
     def _step():
-        q = q_ref[0]                            # (1, D)
-        k = k_ref[0, :, 0]                      # (page_size, D)
-        v = v_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (1, ps)
-        idx = start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+        q = q_ref[0].astype(jnp.float32)        # (H, D)
+        k = k_ref[0].astype(jnp.float32)        # (page_size, H, D)
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.sum(q[None] * k, axis=-1,
+                    keepdims=True) * sm_scale   # (ps, H, 1)
+        idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(idx < ctx, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_scr[:]                       # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         corr = jnp.exp(m_prev - m_new)
-        p_ = jnp.exp(s - m_new)                 # (1, ps)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p_, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p_.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        p_ = jnp.exp(s - m_new[None])           # (ps, H, 1)
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p_, axis=0)
+        acc_scr[:] = acc_scr[:] * corr + jnp.sum(p_ * v, axis=0)
         m_scr[:] = m_new
 
     @pl.when(p == n_pages - 1)
@@ -538,19 +521,15 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
       INCLUDING the token whose K/V was just written; 0 = inactive slot
       (output row is zeros).
 
-    The grid is (B, H, pages_per_seq) with pages innermost-sequential;
-    the block table rides scalar prefetch so the page indirection is an
-    index-map lookup, not in-kernel pointer math.  Returns (B, H, D) in
-    the query dtype.  Pure-jax twin:
-    :func:`ragged_paged_attention_reference` (CPU fallback + test
+    The grid is (B, pages_per_seq) with pages innermost-sequential;
+    every block takes ALL heads of one sequence or one page, so its last
+    two dimensions are the arrays' own (H, D) — the shape rule Mosaic
+    holds TPU blocks to.  The block table rides scalar prefetch so the
+    page indirection is an index-map lookup, not in-kernel pointer
+    math.  Returns (B, H, D) in the query dtype.  Pure-jax twin:
+    :func:`ragged_paged_attention_reference` (CPU serving path + test
     oracle).
     """
-    if not pallas_available():
-        from ..base import MXNetError
-        raise MXNetError(
-            "ragged_paged_attention requires jax.experimental.pallas.tpu "
-            "(check mx.runtime.Features()['PALLAS']); use "
-            "ragged_paged_attention_reference on other backends")
     B, H, D = q.shape
     n_pool, page_size, HK, DK = k_pages.shape
     if (HK, DK) != (H, D) or v_pages.shape != k_pages.shape:
@@ -567,18 +546,18 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     block_tables = block_tables.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
 
-    q_spec = pl.BlockSpec((1, 1, D), lambda b, h, p, bt, ln: (b, h, 0))
+    q_spec = pl.BlockSpec((1, H, D), lambda b, p, bt, ln: (b, 0, 0))
     kv_spec = pl.BlockSpec(
-        (1, page_size, 1, D),
-        lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0))
+        (1, page_size, H, D),
+        lambda b, p, bt, ln: (bt[b, p], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, n_pages),
+        grid=(B, n_pages),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
-        scratch_shapes=[_scratch((1, 1), jnp.float32),
-                        _scratch((1, 1), jnp.float32),
-                        _scratch((1, D), jnp.float32)],
+        scratch_shapes=[_scratch((H, 1), jnp.float32),
+                        _scratch((H, 1), jnp.float32),
+                        _scratch((H, D), jnp.float32)],
     )
     kernel = functools.partial(_paged_fwd_kernel,
                                sm_scale=float(sm_scale),
@@ -628,17 +607,20 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
 # ---------------------------------------------------------------------------
 def _paged_verify_kernel(bt_ref, start_ref, len_ref, q_ref, k_ref,
                          v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                         sm_scale, page_size, n_pages, width):
-    """One (sequence, head, page) grid step of windowed verify
-    attention.  Identical page-innermost online-softmax structure to
-    :func:`_paged_fwd_kernel`, but the query block is the whole (W, D)
-    window and the causal mask is per ROW: window row ``w`` (global
-    position ``start + w``) sees key ``j`` iff ``j <= start + w``.
-    Page 0 always holds valid keys for every valid row (all rows attend
-    from position 0), so a valid row's softmax statistics are finite
-    from its first processed block; rows past ``length`` accumulate
-    garbage the wrapper zeroes."""
+                         sm_scale, page_size, n_pages, block_w, heads):
+    """One (sequence, window tile, page) grid step of windowed verify
+    attention, all heads.  Identical page-innermost online-softmax
+    structure to :func:`_paged_fwd_kernel`, but the query block is a
+    (block_w, D) tile per head (an MXU matmul against the head's
+    (page_size, D) slice of the page, a static loop over heads) and the
+    causal mask is per ROW: window row ``w`` (global position
+    ``start + w``) sees key ``j`` iff ``j <= start + w``.  Page 0 always
+    holds valid keys for every valid row (all rows attend from position
+    0), so a valid row's softmax statistics are finite from its first
+    processed block; rows past ``length`` accumulate garbage the wrapper
+    zeroes."""
     b = pl.program_id(0)
+    w_start = pl.program_id(1) * block_w
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -650,38 +632,50 @@ def _paged_verify_kernel(bt_ref, start_ref, len_ref, q_ref, k_ref,
     start = start_ref[b]
     n_valid = len_ref[b]
     page_start = p * page_size
+    # rows of this tile that are valid end at tile_end (exclusive)
+    tile_end = jnp.minimum(n_valid, w_start + block_w)
 
-    # skip pages entirely past the last valid row's causal horizon
-    # (start + n_valid - 1); an inactive slot (n_valid == 0) skips all
-    @pl.when(page_start < start + n_valid)
+    # skip tiles past the valid rows (an inactive slot, n_valid == 0,
+    # skips all) and pages past the tile's last row's causal horizon
+    @pl.when(jnp.logical_and(w_start < n_valid,
+                             page_start < start + tile_end))
     def _step():
-        q = q_ref[0, :, 0]                      # (W, D)
-        k = k_ref[0, :, 0]                      # (page_size, D)
-        v = v_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (W, ps)
         idx = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (width, page_size), 1)
-        row = jax.lax.broadcasted_iota(
-            jnp.int32, (width, page_size), 0)
+            jnp.int32, (block_w, page_size), 1)
+        row = w_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_w, page_size), 0)
         mask = jnp.logical_and(idx <= start + row, row < n_valid)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p_ = jnp.exp(s - m_new)                 # (W, ps)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p_, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p_.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        for h in range(heads):
+            q = q_ref[0, h]                     # (block_w, D)
+            k = k_ref[0, :, h, :]               # (page_size, D)
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(mask, s, _NEG_INF)    # (block_w, ps)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p_ = jnp.exp(s - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p_, axis=1,
+                                                 keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p_.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(p == n_pages - 1)
     def _finish():
         l = l_scr[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+
+
+# window rows per verify grid step: at (H=12, D=64) float32 a 256-row
+# tile keeps q + out (double-buffered) + the accumulator near 4 MB of
+# VMEM; an untiled 512-row window overflows the 16 MB scoped limit
+_VERIFY_BLOCK_W = 256
 
 
 def ragged_paged_verify(q, k_pages, v_pages, block_tables, starts,
@@ -703,16 +697,13 @@ def ragged_paged_verify(q, k_pages, v_pages, block_tables, starts,
 
     Window row ``w`` attends causally over positions
     ``0 .. starts[b] + w`` — exactly prefill semantics when
-    ``starts == 0`` and decode semantics when ``W == 1``.  Returns
-    (B, W, H, D) in the query dtype; pure-jax twin:
-    :func:`ragged_paged_verify_reference`.
+    ``starts == 0`` and decode semantics when ``W == 1``.  The grid is
+    (B, window tiles, pages_per_seq); the window is tiled head-major
+    (the wrapper transposes q and the output, both small next to the
+    pool) so each head's query tile and each page block end in the
+    arrays' own last two dimensions.  Returns (B, W, H, D) in the query
+    dtype; pure-jax twin: :func:`ragged_paged_verify_reference`.
     """
-    if not pallas_available():
-        from ..base import MXNetError
-        raise MXNetError(
-            "ragged_paged_verify requires jax.experimental.pallas.tpu "
-            "(check mx.runtime.Features()['PALLAS']); use "
-            "ragged_paged_verify_reference on other backends")
     B, W, H, D = q.shape
     n_pool, page_size, HK, DK = k_pages.shape
     if (HK, DK) != (H, D) or v_pages.shape != k_pages.shape:
@@ -730,30 +721,36 @@ def ragged_paged_verify(q, k_pages, v_pages, block_tables, starts,
     starts = starts.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
 
-    q_spec = pl.BlockSpec((1, W, 1, D),
-                          lambda b, h, p, bt, st, ln: (b, 0, h, 0))
+    block_w = min(W, _VERIFY_BLOCK_W)
+    W_p = _ceil_to(W, block_w)
+    qt = q.transpose(0, 2, 1, 3)                            # (B, H, W, D)
+    if W_p != W:
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, W_p - W), (0, 0)))
+    q_spec = pl.BlockSpec((1, H, block_w, D),
+                          lambda b, w, p, bt, st, ln: (b, 0, w, 0))
     kv_spec = pl.BlockSpec(
-        (1, page_size, 1, D),
-        lambda b, h, p, bt, st, ln: (bt[b, p], 0, h, 0))
+        (1, page_size, H, D),
+        lambda b, w, p, bt, st, ln: (bt[b, p], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, n_pages),
+        grid=(B, W_p // block_w, n_pages),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
-        scratch_shapes=[_scratch((W, 1), jnp.float32),
-                        _scratch((W, 1), jnp.float32),
-                        _scratch((W, D), jnp.float32)],
+        scratch_shapes=[_scratch((H, block_w, 1), jnp.float32),
+                        _scratch((H, block_w, 1), jnp.float32),
+                        _scratch((H, block_w, D), jnp.float32)],
     )
     kernel = functools.partial(_paged_verify_kernel,
                                sm_scale=float(sm_scale),
                                page_size=page_size, n_pages=n_pages,
-                               width=W)
+                               block_w=block_w, heads=H)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, W, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, W_p, D), q.dtype),
         interpret=bool(interpret),
-    )(block_tables, starts, lengths, q, k_pages, v_pages)
+    )(block_tables, starts, lengths, qt, k_pages, v_pages)
+    out = out[:, :, :W].transpose(0, 2, 1, 3)               # (B, W, H, D)
     # defined semantics for padded rows (they accumulate garbage in the
     # kernel — their every score is masked, so the online max never
     # leaves the -inf floor and exp(s - m) degenerates to 1)
@@ -802,6 +799,6 @@ def ragged_paged_attention_auto(q, k_pages, v_pages, block_tables,
     lengths accept any numeric dtype (cast to int32)."""
     bt = block_tables.astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
-    if pallas_available() and jax.default_backend() == "tpu":
+    if jax.default_backend() == "tpu":
         return ragged_paged_attention(q, k_pages, v_pages, bt, lens)
     return ragged_paged_attention_reference(q, k_pages, v_pages, bt, lens)

@@ -27,8 +27,8 @@ axis names and, where possible, literal extents, so every
 ``PartitionSpec`` checks against the real axis set and dim
 divisibility; treat an ``out_specs`` entry of ``P()`` as a *claim*
 that every return path reduced the value (``psum``/``pmean``/...) —
-``shard_map_unchecked`` (_jax_compat) disables the runtime replication
-check, so the static one is the only net; and donate
+the compressed trainer's ``check_vma=False`` disables the runtime
+replication check, so the static one is the only net; and donate
 (``donate_argnums``) only buffers that flow to a matching output, then
 rebind the host name in the same statement (``params = step(params)``)
 — the old buffer is dead.

@@ -57,6 +57,12 @@ def initialize(coordinator_address: Optional[str] = None,
     With no arguments, configuration is read from the env protocol above —
     what ``tools/launch.py`` sets for each spawned worker.  Single-process
     use (no env, no args) is a no-op so scripts run unchanged standalone.
+
+    No devices are assigned here: each process takes whatever its
+    environment lets JAX see.  A chip belongs to one process at a time,
+    so several processes on ONE host are the CPU/gloo test path; on a
+    TPU host one process drives all of the host's chips, and this
+    bootstrap joins one such process per host.
     """
     import jax
     # whole check-and-init under the lock: two racing initialize()
@@ -98,20 +104,14 @@ def _initialize_locked(jax, coordinator_address, num_processes,
     # initialize XLA before jax.distributed.initialize.  Harmless on TPU:
     # the flag only affects CPU-client creation.
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    kwargs = dict(num_processes=int(num_processes),
-                  process_id=int(process_id),
-                  initialization_timeout=timeout_s)
-    # a crashing worker must EXIT, not block in the shutdown barrier —
-    # the launcher's failure detection relies on seeing the exit code
-    # promptly (§5.3 clean abort); older jax clients predate the knob
-    import inspect
-    try:
-        sig = inspect.signature(jax.distributed.initialize)
-        if "shutdown_timeout_seconds" in sig.parameters:
-            kwargs["shutdown_timeout_seconds"] = 15
-    except (TypeError, ValueError):     # builtins without a signature
-        pass
-    jax.distributed.initialize(coordinator_address, **kwargs)
+    # shutdown_timeout_seconds: a crashing worker must EXIT, not block
+    # in the shutdown barrier — the launcher's failure detection relies
+    # on seeing the exit code promptly (§5.3 clean abort)
+    jax.distributed.initialize(coordinator_address,
+                               num_processes=int(num_processes),
+                               process_id=int(process_id),
+                               initialization_timeout=timeout_s,
+                               shutdown_timeout_seconds=15)
     # mxlint: disable=lock-discipline (contract: sole caller is
     # initialize(), which holds _STATE_LOCK around this helper)
     _state["initialized"] = True

@@ -20,12 +20,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
-
-from .._jax_compat import shard_map, to_varying
 
 __all__ = ["pipeline_apply", "make_pipeline_mesh"]
 
@@ -56,9 +54,9 @@ def pipeline_apply(stage_fn, stage_params, micro_inputs, mesh: Mesh,
     T = n_micro + n_stages - 1
 
     def _varying(x):
-        # newer shard_map tracks varying-manual-axes: scan carries that
+        # shard_map tracks varying-manual-axes: scan carries that
         # BECOME pp-varying must start pp-varying
-        return to_varying(x, axis)
+        return lax.pcast(x, (axis,), to="varying")
 
     def per_device(params_stage, xs):
         # params_stage leaves: (1, ...) — this device's stage slice

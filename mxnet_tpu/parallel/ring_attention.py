@@ -12,9 +12,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .._jax_compat import shard_map, to_varying
 
 __all__ = ["ring_attention", "ring_self_attention"]
 
@@ -44,7 +43,8 @@ def _ring_attention_local(q, k, v, q_pos, k_pos, axis_name, causal, scale,
     acc0 = jnp.zeros((B, H, Lq, D), dtype=jnp.float32)
     # constants start axis-unvarying under shard_map's vma typing;
     # the loop carry becomes varying, so pre-cast the initial carry
-    m0, l0, acc0 = (to_varying(x, axis_name) for x in (m0, l0, acc0))
+    m0, l0, acc0 = (lax.pcast(x, (axis_name,), to="varying")
+                    for x in (m0, l0, acc0))
 
     def attend(m, l, acc, k, v, k_pos):
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k,

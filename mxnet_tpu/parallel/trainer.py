@@ -20,14 +20,13 @@ import time
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import faults as _faults
 from .. import perf_account as _pa
 from .. import quantize as qz
 from .. import runtime_metrics as _rm
-from .._jax_compat import shard_map_unchecked
 from ..base import MXNetError
 from . import optim as _optim
 from .functional import functionalize
@@ -223,7 +222,7 @@ class ShardedTrainer:
             loss = lax.pmean(loss, "dp")
             # out_specs claims aux replicated (P()): every branch must
             # reduce, or each device keeps its own value silently
-            # (shard_map_unchecked turns the runtime check off).  pmax
+            # (check_vma=False turns the runtime check off).  pmax
             # is dtype-preserving for the non-float stats — identity
             # when devices already agree, deterministic otherwise.
             aux = {n: (lax.pmean(v, "dp")
@@ -232,11 +231,15 @@ class ShardedTrainer:
                    for n, v in aux.items()}
             return synced, new_res, loss, aux
 
-        sync = shard_map_unchecked(
-            local_sync, mesh,
+        # check_vma=False: the quantized-collective bodies produce
+        # replicated outputs via a symmetric all_gather + local reduce,
+        # which shard_map's static checker cannot prove replicated
+        # (only psum-family results are)
+        sync = shard_map(
+            local_sync, mesh=mesh,
             in_specs=(P(), P("dp"), P())
             + (P("dp"),) * (n_inputs + self._n_labels),
-            out_specs=(P(), P("dp"), P(), P()))
+            out_specs=(P(), P("dp"), P(), P()), check_vma=False)
 
         def train_step(params, opt_state, residuals, key, *batch):
             synced, new_res, loss, aux = sync(params, residuals, key,

@@ -23,9 +23,10 @@ training half of that observability contract:
 - **Runtime MFU** (:func:`step_flops` / :func:`mfu`, promoted from
   ``bench.py``): exact per-step FLOPs from XLA's ``cost_analysis`` of
   the compiled step, divided by measured step time and the per-chip
-  peak (``MXNET_PEAK_TFLOPS`` or the device-kind default), published
-  as the ``train.mfu`` gauge.  Backends without cost analysis degrade
-  to a NaN-safe 0 with one warning.
+  peak (``MXNET_PEAK_TFLOPS`` or the :data:`PEAK_TFLOPS` entry of the
+  device kind), published as the ``train.mfu`` gauge.  Backends
+  without cost analysis degrade to a NaN-safe 0 with one warning; a
+  CPU has no peak and publishes no ``train.mfu``.
 - **Bottleneck verdict**: over a rolling window of steps, the largest
   non-compute phase names the bottleneck — ``input_bound``
   (data wait + h2d), ``comm_bound`` (collective), else
@@ -48,7 +49,7 @@ from collections import deque
 
 from . import runtime_metrics as _rm
 from . import tracing as _tr
-from .base import get_env
+from .base import MXNetError, get_env
 
 __all__ = [
     "PHASES", "VERDICTS", "StepAttribution",
@@ -111,24 +112,38 @@ def step_flops(trainer, batch):
         return None
 
 
+# Per-chip dense bf16 peak, TFLOP/s, keyed by the ``device_kind`` string
+# jax reports.  A kind enters this table when a chip run has reported it.
+PEAK_TFLOPS = {
+    # TPU v5e: 197 TFLOP/s bf16 (Google Cloud documentation, "TPU v5e");
+    # device_kind as printed by chip_smoke.py on the v5e (PR 21)
+    "TPU v5 lite": 197.0,
+}
+
+
 def detect_peak_tflops(devices=None):
     """Per-chip bf16 peak TFLOP/s for MFU: ``MXNET_PEAK_TFLOPS`` when
-    set (> 0), else the device-kind default (v5p 459, v5e/"lite" 197,
-    CPU 0.15 — the same table ``BENCH_PEAK_TFLOPS`` defaults from)."""
+    set (> 0), else the :data:`PEAK_TFLOPS` entry of the first device's
+    ``device_kind``.  A CPU has no peak (``None``: no MFU is computed
+    or published there); an accelerator the table does not know is an
+    error, never a default."""
     override = float(get_env("MXNET_PEAK_TFLOPS", typ=float) or 0.0)
     if override > 0:
         return override
     if devices is None:
-        try:
-            import jax
-            devices = jax.devices()
-        except Exception:                        # noqa: BLE001
-            return 0.15
-    on_tpu = any(d.platform != "cpu" for d in devices)
-    if not on_tpu:
-        return 0.15
-    kind = devices[0].device_kind.lower()
-    return 197.0 if ("lite" in kind or "v5e" in kind) else 459.0
+        import jax
+        devices = jax.devices()
+    dev = devices[0]
+    if dev.platform == "cpu":
+        return None
+    try:
+        return PEAK_TFLOPS[dev.device_kind]
+    except KeyError:
+        raise MXNetError(
+            f"perf_account: no peak TFLOP/s on record for device_kind "
+            f"{dev.device_kind!r} (platform {dev.platform!r}); set "
+            f"MXNET_PEAK_TFLOPS or add the kind, with its source, to "
+            f"perf_account.PEAK_TFLOPS") from None
 
 
 # ---------------------------------------------------------------------------
@@ -397,7 +412,8 @@ class StepAttribution:
                     h.seconds.get(p, 0.0), phase=p)
             tid = h.root.trace_id if h.root.sampled else None
             _rm.TRAINER_STEP_SECONDS.observe(dt, exemplar=tid)
-            _rm.TRAIN_MFU.set(self._mfu)
+            if self.peak_tflops:        # a CPU has no peak: no train.mfu
+                _rm.TRAIN_MFU.set(self._mfu)
             _rm.TRAIN_BOTTLENECK.set(_VERDICT_CODE[self._verdict])
 
     def _compute_verdict(self):
@@ -414,7 +430,7 @@ class StepAttribution:
         return "compute_bound"
 
     def _compute_mfu(self):
-        if not self.flops_per_step or self.peak_tflops <= 0:
+        if not self.flops_per_step or not self.peak_tflops:
             return 0.0
         wall = sum(dt for dt, _ in self._window)
         if wall <= 0:
@@ -456,7 +472,8 @@ class StepAttribution:
                 "phase_fraction":
                     {p: round(frac[p], 4) for p in PHASES},
                 "verdict": self._verdict,
-                "mfu": round(self._mfu, 4)}
+                "mfu": (round(self._mfu, 4) if self.peak_tflops
+                        else None)}
 
     def debug_state(self):
         """Incident-dump payload (rides supervisor/flight dumps)."""

@@ -16,14 +16,6 @@ class Feature:
         return f"[{'✔' if self.enabled else '✖'} {self.name}]"
 
 
-def _pallas_enabled() -> bool:
-    try:
-        from .ops.pallas_kernels import pallas_available
-        return pallas_available()
-    except Exception:
-        return False
-
-
 def _detect() -> Dict[str, bool]:
     import jax
     feats = {
@@ -37,7 +29,7 @@ def _detect() -> Dict[str, bool]:
         "DIST_KVSTORE": True,   # xla collectives backend
         "INT64_TENSOR_SIZE": True,
         "SIGNAL_HANDLER": True,
-        "PALLAS": _pallas_enabled(),
+        "PALLAS": True,         # compiled on TPU, interpreted on CPU
         "BF16": True,
         "INT8_QUANTIZATION": True,   # ops/quantization.py int8 MXU path
         "NATIVE_IO": False,     # flipped true when the C++ recordio lib loads

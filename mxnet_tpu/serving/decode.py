@@ -1544,31 +1544,30 @@ class PagedLMAdapter:
         kw = dict(num_heads=self.num_heads, page_size=geometry.page_size,
                   activation=self.lm._activation,
                   layer_norm_eps=self.lm._eps)
-        # donation lets XLA update the KV pools in place; the CPU
-        # backend cannot honor it and would warn on every program
-        cpu = jax.default_backend() == "cpu"
-        donate = (4, 5) if not cpu else ()
+        # donation lets XLA update the KV pools in place — on every
+        # backend, the CPU included, so the tests run the programs the
+        # chip runs: a pool array is dead once a program has taken it,
+        # and each protocol method below rebinds the pool (pool.swap)
+        # to the program's outputs before anything reads it again
         self._prefill_jit = jax.jit(
             functools.partial(paged_prefill, **kw),
-            donate_argnums=donate)
+            donate_argnums=(4, 5))
         self._decode_jit = jax.jit(
             functools.partial(paged_decode_step,
                               attention_impl=self.attention_impl, **kw),
-            donate_argnums=donate)
+            donate_argnums=(4, 5))
         # verify family (prefix-hit tails + speculative windows): the
         # pools sit at argument positions 5/6; the COW page copy is one
         # more (traced-scalar src/dst, so ONE program for every copy)
         self._verify_jit = jax.jit(
             functools.partial(paged_verify,
                               attention_impl=self.attention_impl, **kw),
-            donate_argnums=(5, 6) if not cpu else ())
+            donate_argnums=(5, 6))
         self._verify_batch_jit = jax.jit(
             functools.partial(paged_verify_batch,
                               attention_impl=self.attention_impl, **kw),
-            donate_argnums=(5, 6) if not cpu else ())
-        self._copy_jit = jax.jit(
-            copy_page_arrays,
-            donate_argnums=(0, 1) if not cpu else ())
+            donate_argnums=(5, 6))
+        self._copy_jit = jax.jit(copy_page_arrays, donate_argnums=(0, 1))
 
     def _cache(self):
         from .. import compile_cache as _cc

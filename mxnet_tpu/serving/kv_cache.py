@@ -599,8 +599,10 @@ class DeviceKVPool:
         # device_put COMMITS the arrays: compiled steps return committed
         # outputs, and a jit cache keys on placement — an uncommitted
         # initial pool would make the very first call of each program
-        # family compile twice (once for each placement)
-        dev = jax.devices()[0]
+        # family compile twice (once for each placement).  The device
+        # is the one the engine's programs run on.
+        from ..context import default_jax_device
+        dev = default_jax_device()
         self.k_pages = jax.device_put(jnp.zeros(shape, self.dtype), dev)
         self.v_pages = jax.device_put(jnp.zeros(shape, self.dtype), dev)
 

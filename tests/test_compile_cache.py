@@ -253,6 +253,59 @@ class TestExecutableTier:
         np.testing.assert_allclose(np.asarray(prog2(x)), x * 2 + 1)
 
 
+    def test_aot_program_keeps_a_jitted_fns_donation(self, cache):
+        """A fn that is already jitted lowers as itself: a second jit
+        around it would drop its donate_argnums, and the decode
+        programs' KV pools would be copied on every step."""
+        import jax
+        import jax.numpy as jnp
+
+        fn = jax.jit(lambda x: x + 1, donate_argnums=0)
+        aval = jax.ShapeDtypeStruct((4, 4), np.float32)
+        key = cc.cache_key("donating", 4, ["float32"], topology="t")
+        prog, src = cc.aot_program(fn, (aval,), key, cache)
+        assert src == "compile"
+        x = jnp.ones((4, 4), np.float32)
+        np.testing.assert_allclose(np.asarray(prog(x)), 2.0)
+        assert x.is_deleted()
+
+
+class TestJaxPersistentCache:
+    """enable_jax_persistent_cache: where jax's own cache lives is
+    decided outside the program (JAX_COMPILATION_CACHE_DIR), else it is
+    ONE fixed path inside the checkout."""
+
+    @pytest.fixture()
+    def config_updates(self, monkeypatch):
+        """Record jax.config.update calls WITHOUT applying them (this
+        process must not start persisting the suite's compiles)."""
+        import jax
+        calls = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: calls.__setitem__(k, v))
+        return calls
+
+    def test_env_dir_is_left_alone(self, monkeypatch, tmp_path,
+                                   config_updates):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        stats = cc.enable_jax_persistent_cache()
+        assert "jax_compilation_cache_dir" not in config_updates
+        assert stats == {"hits": 0, "misses": 0}
+
+    def test_default_is_the_fixed_in_checkout_path(self, monkeypatch,
+                                                   config_updates):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        cc.enable_jax_persistent_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert config_updates["jax_compilation_cache_dir"] \
+            == cc.JAX_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        # every program persists, however small or quick to compile
+        assert config_updates[
+            "jax_persistent_cache_min_compile_time_secs"] == 0
+        assert config_updates[
+            "jax_persistent_cache_min_entry_size_bytes"] == 0
+
+
 def _mlp(seed=7):
     mx.random.seed(seed)
     net = nn.HybridSequential()

@@ -302,15 +302,13 @@ def test_shuffling_collective_does_not_wash():
 
 def test_lambda_body_and_unchecked_variant():
     issues = run("""
-        from mxnet_tpu._jax_compat import shard_map_unchecked
-
         def f(mesh, x):
-            g = shard_map_unchecked(lambda v: v, mesh,
-                                    in_specs=(P("dp"),),
-                                    out_specs=P())
-            h = shard_map_unchecked(lambda v: lax.psum(v, "dp"), mesh,
-                                    in_specs=(P("dp"),),
-                                    out_specs=P())
+            g = shard_map(lambda v: v, mesh,
+                          in_specs=(P("dp"),),
+                          out_specs=P(), check_vma=False)
+            h = shard_map(lambda v: lax.psum(v, "dp"), mesh,
+                          in_specs=(P("dp"),),
+                          out_specs=P(), check_vma=False)
             return g(x), h(x)
     """, select=["replication-soundness"])
     assert ids(issues) == ["replication-soundness"]

@@ -17,6 +17,22 @@ pytestmark = pytest.mark.skipif(
 _MAGIC = struct.pack("<I", 0xCED7230A)
 
 
+def test_staleness_is_keyed_on_source_content(tmp_path, monkeypatch):
+    """The cached .so travels with copies of the tree (mtimes do not):
+    it is current exactly when its stamp holds the source's sha256."""
+    so = tmp_path / "lib.so"
+    so.write_bytes(b"not really a library")
+    monkeypatch.setattr(nativelib, "_SO", str(so))
+    monkeypatch.setattr(nativelib, "_STAMP", str(so) + ".sha256")
+    assert nativelib._stale()                   # no stamp
+    (tmp_path / "lib.so.sha256").write_text("0" * 64)
+    assert nativelib._stale()                   # built from other source
+    (tmp_path / "lib.so.sha256").write_text(nativelib._src_digest())
+    assert not nativelib._stale()
+    so.unlink()
+    assert nativelib._stale()                   # stamp without a binary
+
+
 class TestNativeRecordIO:
     def test_roundtrip_including_multipart(self, tmp_path):
         path = str(tmp_path / "t.rec")

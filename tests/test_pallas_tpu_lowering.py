@@ -1,0 +1,65 @@
+"""The Pallas kernels lower for the TPU — checked on the CPU, without
+compiling anything.
+
+``jax.export`` with ``platforms=["tpu"]`` runs the Pallas -> Mosaic
+lowering (``interpret=False``), which is where block shapes the TPU
+cannot tile are refused: the last two dimensions of every block must be
+divisible by (8, 128) or equal the array's own.  The interpreter the
+rest of the suite runs the kernels in never applies that rule, which is
+how the paged kernels shipped with blocks no TPU would take.  Whether
+Mosaic then COMPILES the module only a chip (``chip_smoke.py``) can say.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu.ops.pallas_kernels import (flash_attention,
+                                          ragged_paged_attention,
+                                          ragged_paged_verify)
+
+S = jax.ShapeDtypeStruct
+
+
+def _tpu_module_text(fn, *avals):
+    return jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+
+
+@pytest.mark.parametrize("L", [128, 512, 2048])
+@pytest.mark.parametrize("mode", ["fwd", "bwd", "windowed"])
+def test_flash_attention_lowers_for_tpu(L, mode):
+    a = S((16, L, 64), jnp.bfloat16)
+    kw = dict(causal=True, window=128) if mode == "windowed" else {}
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False, **kw)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    assert "tpu_custom_call" in _tpu_module_text(fn, a, a, a)
+
+
+# the shapes chip_smoke.py's serve leg runs: GPT-2-small heads over a
+# 513-page pool of 16-token pages, 8 slots, 64 pages per sequence; and
+# the wider H=16, D=128 head shape
+@pytest.mark.parametrize("H,D", [(12, 64), (16, 128)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernels_lower_for_tpu(H, D, dtype):
+    B, P, ps, n_pool = 8, 64, 16, 513
+    pool = S((n_pool, ps, H, D), dtype)
+    i32 = jnp.int32
+    text = _tpu_module_text(
+        functools.partial(ragged_paged_attention, interpret=False),
+        S((B, H, D), dtype), pool, pool, S((B, P), i32), S((B,), i32))
+    assert "tpu_custom_call" in text
+    # widths: one row, a speculation window, one full tile, several tiles
+    for W in (1, 8, 256, 1024):
+        text = _tpu_module_text(
+            functools.partial(ragged_paged_verify, interpret=False),
+            S((B, W, H, D), dtype), pool, pool, S((B, P), i32),
+            S((B,), i32), S((B,), i32))
+        assert "tpu_custom_call" in text, W

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from mxnet_tpu import perf_account as pa
+from mxnet_tpu.base import MXNetError
 from mxnet_tpu import runtime_metrics as rm
 from mxnet_tpu import tracing as tr
 
@@ -86,12 +87,17 @@ def test_detect_peak_env_override(monkeypatch):
     assert pa.detect_peak_tflops() == 123.5
     monkeypatch.delenv("MXNET_PEAK_TFLOPS")
     fake_cpu = [types.SimpleNamespace(platform="cpu", device_kind="cpu")]
-    assert pa.detect_peak_tflops(fake_cpu) == 0.15
+    assert pa.detect_peak_tflops(fake_cpu) is None   # a CPU has no peak
     v5e = [types.SimpleNamespace(platform="tpu",
                                  device_kind="TPU v5 lite")]
     assert pa.detect_peak_tflops(v5e) == 197.0
-    v5p = [types.SimpleNamespace(platform="tpu", device_kind="TPU v5p")]
-    assert pa.detect_peak_tflops(v5p) == 459.0
+    # an accelerator the table does not know is an error, not a default
+    unknown = [types.SimpleNamespace(platform="tpu",
+                                     device_kind="TPU v99")]
+    with pytest.raises(MXNetError, match="TPU v99"):
+        pa.detect_peak_tflops(unknown)
+    monkeypatch.setenv("MXNET_PEAK_TFLOPS", "10")
+    assert pa.detect_peak_tflops(unknown) == 10.0
 
 
 def test_step_flops_unavailable_returns_none():

@@ -192,9 +192,9 @@ def diagnose(metrics_smoke=False):
 
     _section("Training Performance")
     from mxnet_tpu import perf_account as _perf
-    print(f"peak tflops  : {_perf.detect_peak_tflops():g}  "
+    print(f"peak tflops  : {_perf.detect_peak_tflops()}  "
           f"(MXNET_PEAK_TFLOPS or the device-kind table; the "
-          f"train.mfu denominator)")
+          f"train.mfu denominator; None on a CPU)")
     verdict = _perf.current_verdict()
     if verdict is None:
         print("attribution  : (no attributed steps this process — with "

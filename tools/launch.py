@@ -19,6 +19,12 @@ Usage::
 
 Workers read the handshake via ``mxnet_tpu.parallel.dist.initialize()``
 (no arguments).
+
+Every local worker gets the SAME environment and no device assignment,
+so this local fan-out is the CPU/gloo test path (``JAX_PLATFORMS=cpu``).
+On a TPU host a chip belongs to one process at a time — only the first
+worker would get it — and one process drives all chips of a host: start
+one worker per host there.
 """
 from __future__ import annotations
 

@@ -3,9 +3,9 @@
 The sharding-annotated surface of this repo — ``PartitionSpec`` /
 ``NamedSharding`` / ``with_sharding_constraint`` / ``shard_map``
 in/out specs / buffer donation — is exactly the surface no pass
-validated before ISSUE-19, and PR 9's ``shard_map_unchecked`` shim
-deliberately turns off the one *runtime* guard (JAX's static
-replication check).  This module composes the PR-4 call graph with the
+validated before ISSUE-19, and the compressed trainer's
+``check_vma=False`` deliberately turns off the one *runtime* guard
+(JAX's static replication check).  This module composes the PR-4 call graph with the
 PR-5 symbolic Dim algebra into three reusable analyses:
 
 - **mesh resolution with extents** (:class:`MeshInfo`): the
@@ -25,12 +25,12 @@ PR-5 symbolic Dim algebra into three reusable analyses:
   shard walk over a shard_map body, tuple-aware and interprocedural
   (``qz.allreduce_mean`` returns ``(uniform, per-device)``), washing
   only at the uniform collectives (psum/pmean/pmax/pmin/all_gather) —
-  the static twin of the replication check ``shard_map_unchecked``
+  the static twin of the replication check ``check_vma=False``
   disables.
 
-``shard_map_unchecked`` is treated as a shard_map site everywhere:
-that is the whole point — the sites that opted out of the runtime
-check are the ones that need the static one most.
+A ``check_vma=False`` site is a shard_map site like any other: that
+is the whole point — the sites that opted out of the runtime check
+are the ones that need the static one most.
 """
 from __future__ import annotations
 
@@ -51,7 +51,7 @@ __all__ = [
     "any_shard", "chain_text",
 ]
 
-SHARD_MAP_NAMES = {"shard_map", "shmap", "shard_map_unchecked"}
+SHARD_MAP_NAMES = {"shard_map", "shmap"}
 _SPEC_NAMES = {"P", "PartitionSpec"}
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size"}
 _MAX_DEPTH = 4

@@ -46,7 +46,7 @@ from .. import mxshard
 _AXIS_ARG = {c: (0 if c == "axis_index" else 1) for c in COLLECTIVES}
 _CTRL = {"cond", "while_loop", "switch"}
 
-# shared with the SPMD passes (ISSUE-19): shard_map_unchecked is a
+# shared with the SPMD passes (ISSUE-19): a check_vma=False site is a
 # shard_map site too — it is exactly the variant whose bodies need the
 # static checks most, since the runtime replication check is off there
 _is_shard_map = mxshard.is_shard_map
@@ -450,7 +450,7 @@ class CollectiveSoundnessPass(LintPass):
         store (``synced[n] = m`` writes ``synced``) — never the index
         (``n`` is read, and tainting it made every ``if n in ...:``
         look per-device, a false positive surfaced when
-        shard_map_unchecked bodies joined the analysis)."""
+        check_vma=False bodies joined the analysis)."""
         if isinstance(target, ast.Name):
             yield target
         elif isinstance(target, (ast.Tuple, ast.List)):

@@ -2,8 +2,8 @@
 
 An ``out_specs`` entry of ``P()`` promises that every device returns
 the *same* value — JAX's shard_map enforces it with a runtime
-replication check, and PR 9's ``shard_map_unchecked`` compat shim
-deliberately turns that check off (``check_rep=False``) because the
+replication check, and the compressed trainer deliberately turns
+that check off (``check_vma=False``) because the
 quantized-allreduce bodies confuse it.  That makes a wrong ``P()``
 claim the worst bug shape in the parallel layer: no error, each device
 silently keeps its own shard and downstream math diverges per host.
@@ -37,7 +37,7 @@ class ReplicationSoundnessPass(LintPass):
     doc = ("a shard_map out_spec claiming replication (P()) on a "
            "return value that may still carry a per-device shard (no "
            "psum/pmean/all_gather on the path) — the silent "
-           "wrong-answer shape shard_map_unchecked stops checking "
+           "wrong-answer shape check_vma=False stops checking "
            "at runtime")
 
     def check_file(self, src):
@@ -103,7 +103,7 @@ class ReplicationSoundnessPass(LintPass):
                     f"but return value #{i} of {body_name} may still "
                     f"be a per-device shard — no "
                     f"psum/pmean/all_gather reduces it on every path. "
-                    f"shard_map_unchecked disables JAX's replication "
+                    f"check_vma=False disables JAX's replication "
                     f"check, so each device would silently keep its "
                     f"own different value; reduce the value, shard "
                     f"the out_spec, or suppress with the contract "

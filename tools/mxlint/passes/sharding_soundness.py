@@ -22,7 +22,7 @@ depending on the API.  All four are decidable from the AST here:
   unknown (quiet), ``12`` over extent-8 is provably wrong (flagged),
   ``16`` over extent-8 is provably fine.
 
-Checked sites: ``shard_map``/``shmap``/``shard_map_unchecked``
+Checked sites: ``shard_map``/``shmap``
 in_specs+out_specs (and the arrays at the site's application calls),
 ``NamedSharding(mesh, spec)``, and ``with_sharding_constraint(x,
 spec)``.  When the mesh is a runtime value, axis names are checked
