@@ -44,7 +44,8 @@ from . import engine, faults as _faults, runtime_metrics as _rm
 from .base import MXNetError, get_env
 
 __all__ = ["CompileCache", "cache_key", "topology_fingerprint",
-           "aot_program", "get_default", "enable_jax_persistent_cache"]
+           "aot_program", "get_default", "enable_jax_persistent_cache",
+           "backend_compiles"]
 
 _LOG = logging.getLogger("mxnet_tpu")
 
@@ -542,3 +543,31 @@ def enable_jax_persistent_cache():
 
     monitoring.register_event_listener(_listener)
     return stats
+
+
+_BACKEND_COMPILES = {"n": 0, "listening": False}
+_BACKEND_COMPILES_LOCK = engine.make_lock(
+    "compile_cache._BACKEND_COMPILES_LOCK")
+
+
+def _on_compile_duration(event, _secs, **_kw):
+    if event == "/jax/core/compile/backend_compile_duration":
+        with _BACKEND_COMPILES_LOCK:
+            _BACKEND_COMPILES["n"] += 1
+
+
+def backend_compiles():
+    """How many programs this process has handed to the backend so far
+    (compiled, or loaded from jax's persistent cache: either way a
+    program the caller did not have), counted from jax's
+    ``backend_compile_duration`` monitoring event.  The listener is
+    registered by the first call, so the count starts there: callers
+    compare two readings (``mx.train.step``'s ``compiles`` tag)."""
+    if not _BACKEND_COMPILES["listening"]:
+        with _BACKEND_COMPILES_LOCK:
+            if not _BACKEND_COMPILES["listening"]:
+                from jax import monitoring
+                monitoring.register_event_duration_secs_listener(
+                    _on_compile_duration)
+                _BACKEND_COMPILES["listening"] = True
+    return _BACKEND_COMPILES["n"]

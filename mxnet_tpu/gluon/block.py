@@ -248,7 +248,14 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        if _TRACING.get():
+            # inside a compiled program's trace the block's name is a
+            # scope: its ops read .../<block>/... in the HLO's op_name
+            # and in the profiler's trace of the device
+            with jax.named_scope(self._name):
+                out = self.forward(*args, **kwargs)
+        else:
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out

@@ -23,10 +23,12 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import compile_cache as _cc
 from .. import faults as _faults
 from .. import perf_account as _pa
 from .. import quantize as qz
 from .. import runtime_metrics as _rm
+from .. import tracing as _tr
 from ..base import MXNetError
 from . import optim as _optim
 from .functional import functionalize
@@ -78,6 +80,10 @@ class ShardedTrainer:
         # MXNET_RUNTIME_METRICS turns it on
         self.perf = _pa.StepAttribution()
         self._flops_noted = False
+        # the tag the mx.train.* phases of one step share; the compile
+        # count's listener starts before the step program is built
+        self._step_no = 0
+        _cc.backend_compiles()
         self.compression = qz.CompressionSpec.parse(compression)
         if self.compression is not None:
             if "dp" not in mesh.shape:
@@ -135,19 +141,29 @@ class ShardedTrainer:
         self.opt_state = jax.tree_util.tree_map(
             global_device_put, self.opt_state, opt_shardings)
 
+        # the program's name (XLA Modules reads jit_mx_train_step) and
+        # the scopes inside it (an op's op_name reads
+        # .../mx.fwd/<block>/..., its backward's transpose(jvp(mx.fwd)))
+        # are what the device metrics find the step and its parts by.
+        # jax leaves scope names out of the persistent cache's key: a
+        # program that differs from a cached one in scopes alone is
+        # served the cached executable without them (PERF.md section 7)
         if self.compression is None:
-            def train_step(params, opt_state, *batch):
+            def mx_train_step(params, opt_state, *batch):
                 inputs = batch[:self._n_inputs]
                 labels = batch[self._n_inputs:]
 
                 def loss_of(p):
-                    out, aux = apply_fn(p, *inputs)
-                    return loss_fn(out, *labels), aux
+                    with jax.named_scope("mx.fwd"):
+                        out, aux = apply_fn(p, *inputs)
+                    with jax.named_scope("mx.loss"):
+                        return loss_fn(out, *labels), aux
 
                 (loss, aux), grads = jax.value_and_grad(
                     loss_of, has_aux=True)(params)
-                new_params, new_state = opt_update(params, grads,
-                                                   opt_state, **opt_kw)
+                with jax.named_scope("mx.optim"):
+                    new_params, new_state = opt_update(
+                        params, grads, opt_state, **opt_kw)
                 # frozen params pass through untouched; aux states take
                 # the forward-captured update (BatchNorm moving stats),
                 # exactly like the eager/CachedOp paths
@@ -159,7 +175,7 @@ class ShardedTrainer:
                 return new_params, new_state, loss
 
             self._step = jax.jit(
-                train_step,
+                mx_train_step,
                 donate_argnums=(0, 1),
                 out_shardings=(self.param_shardings, opt_shardings,
                                repl))
@@ -200,8 +216,10 @@ class ShardedTrainer:
             labels = b[n_inputs:]
 
             def loss_of(p):
-                out, aux = apply_fn(p, *inputs)
-                return loss_fn(out, *labels), aux
+                with jax.named_scope("mx.fwd"):
+                    out, aux = apply_fn(p, *inputs)
+                with jax.named_scope("mx.loss"):
+                    return loss_fn(out, *labels), aux
 
             (loss, aux), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(p)
@@ -209,17 +227,18 @@ class ShardedTrainer:
             if spec.stochastic:
                 dkey = jax.random.fold_in(key, lax.axis_index("dp"))
             synced, new_res = {}, {}
-            for n, g in grads.items():
-                if n in comp_set:
-                    pkey = None if dkey is None else \
-                        jax.random.fold_in(dkey, comp_index[n])
-                    m, r = qz.allreduce_mean(g, res[n][0], spec, "dp",
-                                             key=pkey)
-                    synced[n] = m
-                    new_res[n] = r[None]
-                else:
-                    synced[n] = lax.pmean(g, "dp")
-            loss = lax.pmean(loss, "dp")
+            with jax.named_scope("mx.collective"):
+                for n, g in grads.items():
+                    if n in comp_set:
+                        pkey = None if dkey is None else \
+                            jax.random.fold_in(dkey, comp_index[n])
+                        m, r = qz.allreduce_mean(g, res[n][0], spec,
+                                                 "dp", key=pkey)
+                        synced[n] = m
+                        new_res[n] = r[None]
+                    else:
+                        synced[n] = lax.pmean(g, "dp")
+                loss = lax.pmean(loss, "dp")
             # out_specs claims aux replicated (P()): every branch must
             # reduce, or each device keeps its own value silently
             # (check_vma=False turns the runtime check off).  pmax
@@ -241,11 +260,12 @@ class ShardedTrainer:
             + (P("dp"),) * (n_inputs + self._n_labels),
             out_specs=(P(), P("dp"), P(), P()), check_vma=False)
 
-        def train_step(params, opt_state, residuals, key, *batch):
+        def mx_train_step(params, opt_state, residuals, key, *batch):
             synced, new_res, loss, aux = sync(params, residuals, key,
                                               *batch)
-            new_params, new_state = opt_update(params, synced,
-                                               opt_state, **opt_kw)
+            with jax.named_scope("mx.optim"):
+                new_params, new_state = opt_update(params, synced,
+                                                   opt_state, **opt_kw)
             new_params = {n: (v if n in trainable else params[n])
                           for n, v in new_params.items()}
             for n, v in aux.items():
@@ -254,7 +274,7 @@ class ShardedTrainer:
             return new_params, new_state, new_res, loss
 
         self._step = jax.jit(
-            train_step,
+            mx_train_step,
             donate_argnums=(0, 1, 2),
             out_shardings=(self.param_shardings, opt_shardings,
                            res_shardings, repl))
@@ -271,10 +291,11 @@ class ShardedTrainer:
     def shard_batch(self, *arrays):
         """Place host arrays batch-sharded over dp."""
         out = []
-        for a in arrays:
-            spec = P(*(["dp"] + [None] * (a.ndim - 1)))
-            out.append(global_device_put(
-                a, NamedSharding(self.mesh, spec)))
+        with _tr.phase("train.h2d", step=self._step_no):
+            for a in arrays:
+                spec = P(*(["dp"] + [None] * (a.ndim - 1)))
+                out.append(global_device_put(
+                    a, NamedSharding(self.mesh, spec)))
         return tuple(out)
 
     def step(self, *batch):
@@ -291,9 +312,22 @@ class ShardedTrainer:
         With tracing or runtime metrics on, the step runs ATTRIBUTED
         (:meth:`_step_attributed`): each phase is timed into a
         ``train.*`` span and the step completes synchronously so the
-        compute interval is real device time, not dispatch time."""
-        if self.perf.active:
-            return self._step_attributed(batch)
+        compute interval is real device time, not dispatch time.
+
+        Either way the step is a ``mx.train.step`` phase in the JAX
+        profiler's trace (:func:`~mxnet_tpu.tracing.phase`; a no-op
+        outside a profiler session, and nothing blocks for it), with
+        ``mx.train.h2d`` / ``mx.train.dispatch`` / ``mx.train.sync``
+        nested; ``compiles`` is the process's count of backend compiles
+        so far, so two steps' tags tell whether one compiled."""
+        self._step_no += 1
+        with _tr.phase("train.step", step=self._step_no,
+                       compiles=_cc.backend_compiles()):
+            if self.perf.active:
+                return self._step_attributed(batch)
+            return self._step_plain(batch)
+
+    def _step_plain(self, batch):
         batch = self.shard_batch(*[getattr(b, "_data", b) for b in batch])
         if self.watchdog.active:
             out = self.watchdog.watch(
@@ -367,21 +401,25 @@ class ShardedTrainer:
         # the fault site lives inside the watched call: a ``stall``
         # here is the wedged-collective shape the deadline must bound
         _faults.inject("train.step")
+        step = self._step_no
         if self.compression is None:
-            params, opt_state, loss = self._step(
-                self.params, self.opt_state, *batch)
+            with _tr.phase("train.dispatch", step=step):
+                params, opt_state, loss = self._step(
+                    self.params, self.opt_state, *batch)
             residuals = quant_step = None
         else:
             quant_step = self._quant_step + 1
             key = jax.random.PRNGKey(quant_step)
-            params, opt_state, residuals, loss = \
-                self._step(self.params, self.opt_state, self.residuals,
-                           key, *batch)
+            with _tr.phase("train.dispatch", step=step):
+                params, opt_state, residuals, loss = \
+                    self._step(self.params, self.opt_state,
+                               self.residuals, key, *batch)
         if sync:
             # the deadline must cover execution, not just dispatch —
             # async dispatch would "beat" any timeout while the wedged
             # collective hangs the NEXT host sync instead
-            jax.block_until_ready(loss)
+            with _tr.phase("train.sync", step=step):
+                jax.block_until_ready(loss)
         return params, opt_state, residuals, quant_step, loss
 
     def extra_state(self):

@@ -316,6 +316,9 @@ class DecodeEngine:
         self._started = False
         self._stopping = False
         self._thread = None
+        # the tag the mx.serve.* phases of one scheduler step share;
+        # written by the step loop's thread alone
+        self._step_no = 0
         self._stats = {"steps": 0, "admitted": 0, "evicted": 0,
                        "generated_tokens": 0, "peak_running": 0,
                        "shed": 0, "retries": 0, "quarantined": 0,
@@ -556,13 +559,17 @@ class DecodeEngine:
     def _loop(self):
         while True:
             with self._cond:
-                while not self._stopping and not self._waiting \
+                if not self._stopping and not self._waiting \
                         and not self._running:
-                    # mxlint: disable=deadline-soundness (contract:
-                    # idle park — no sequence is admitted, so there is
-                    # no deadline to consume; every submit/stop
-                    # notifies)
-                    self._cond.wait()
+                    with _tr.phase("serve.idle",
+                                   engine_step=self._step_no):
+                        while not self._stopping and not self._waiting \
+                                and not self._running:
+                            # mxlint: disable=deadline-soundness
+                            # (contract: idle park — no sequence is
+                            # admitted, so there is no deadline to
+                            # consume; every submit/stop notifies)
+                            self._cond.wait()
                 if self._stopping:
                     return
             try:
@@ -589,7 +596,16 @@ class DecodeEngine:
         Returns the number of tokens generated this step.  The step
         loop is the only mutator of the slot map and the allocator;
         ``submit``/``stats`` only touch the waiting queue and read
-        counters under the condition."""
+        counters under the condition.
+
+        Each part is a ``mx.serve.*`` phase in the JAX profiler's trace
+        (:func:`~mxnet_tpu.tracing.phase`; a no-op outside a profiler
+        session), tagged ``engine_step`` and the counts at its
+        boundary: ``admit``, ``prefill``, ``decode_step`` (``draft`` and
+        ``verify`` under speculation) and ``emit``."""
+        # mxlint: disable=lock-discipline (contract: the step loop's
+        # thread is the only one that writes or reads the counter)
+        self._step_no += 1
         admitted = self._admit()
         produced = 0
         for seq in admitted:
@@ -650,6 +666,18 @@ class DecodeEngine:
         reservation to the unmatched pages (the shared ones are
         aliased), and cache-only pages are LRU-evicted on demand when
         the free list cannot cover an admission."""
+        with _tr.phase("serve.admit", engine_step=self._step_no) as ph:
+            admitted = self._admit_pass()
+            now = time.monotonic()
+            waits = [now - seq.t_submit for seq in admitted]
+            ph.set_metadata(
+                admitted=len(admitted), waiting=len(self._waiting),
+                queue_wait_us_max=int(max(waits, default=0.0) * 1e6),
+                queue_wait_us_sum=int(sum(waits) * 1e6))
+        return admitted
+
+    def _admit_pass(self):
+        """The admission pass itself; returns the admitted sequences."""
         admitted, dropped, expired = [], [], []
         with self._cond:
             # prune cancelled AND deadline-expired entries ANYWHERE in
@@ -796,9 +824,11 @@ class DecodeEngine:
             return self._prefill_cached(seq)
         L = seq.prompt.size
         bucket = next_bucket(L, self.geometry.max_context)
-        with _tr.span("decode.prefill", parent=seq.trace,
-                      prompt_tokens=int(L), bucket=bucket,
-                      kv_pages=len(self.allocator.pages_of(seq.seq_id))):
+        with _tr.phase("serve.prefill", engine_step=self._step_no,
+                       bucket=bucket, tokens=int(L), prefix_hit_tokens=0), \
+            _tr.span("decode.prefill", parent=seq.trace,
+                     prompt_tokens=int(L), bucket=bucket,
+                     kv_pages=len(self.allocator.pages_of(seq.seq_id))):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :L] = seq.prompt
 
@@ -821,7 +851,9 @@ class DecodeEngine:
             seq.draft_ctx = L
             self._draft_prefill(seq, tokens, L)
             self._cache_insert(seq)
-            self._emit(seq, int(np.argmax(logits)))
+            with _tr.phase("serve.emit", engine_step=self._step_no,
+                           tokens=1):
+                self._emit(seq, int(np.argmax(logits)))
         self._maybe_evict(seq)
         return 1
 
@@ -840,11 +872,13 @@ class DecodeEngine:
         tail = seq.prompt[start:]
         length = int(tail.size)
         bucket = next_bucket(length, self.geometry.max_context)
-        with _tr.span("decode.prefill", parent=seq.trace,
-                      prompt_tokens=int(L), bucket=bucket,
-                      prefix_hit_tokens=int(m),
-                      cow=seq.cow is not None,
-                      kv_pages=len(self.allocator.pages_of(seq.seq_id))):
+        with _tr.phase("serve.prefill", engine_step=self._step_no,
+                       bucket=bucket, tokens=L, prefix_hit_tokens=int(m)), \
+            _tr.span("decode.prefill", parent=seq.trace,
+                     prompt_tokens=int(L), bucket=bucket,
+                     prefix_hit_tokens=int(m),
+                     cow=seq.cow is not None,
+                     kv_pages=len(self.allocator.pages_of(seq.seq_id))):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :length] = tail
             block_table = self.allocator.block_table(seq.seq_id)
@@ -895,7 +929,9 @@ class DecodeEngine:
                 except Exception as e:  # noqa: BLE001 — optimization
                     self._spec_fallback(seq, e, where="draft tail")
             self._cache_insert(seq)
-            self._emit(seq, int(np.argmax(logits[length - 1])))
+            with _tr.phase("serve.emit", engine_step=self._step_no,
+                           tokens=1):
+                self._emit(seq, int(np.argmax(logits[length - 1])))
         self._maybe_evict(seq)
         return 1
 
@@ -1051,27 +1087,36 @@ class DecodeEngine:
 
     def _plain_decode(self, running):
         """One non-speculative decode step for ``running`` (the
-        original bisection-aware path)."""
-        produced = 0
-        for seq, row, t0, t1, batch_n in self._decode_call(running):
-            # per-sequence decode-step spans (first step, then every
-            # Nth): ONE device call serves the whole batch, so each due
-            # sequence gets the shared interval with its own tags
-            if seq.trace is not None:
-                n_prior = len(seq.tokens)
-                if n_prior == 1 or n_prior % _STEP_SPAN_EVERY == 0:
-                    _tr.record_span(
-                        "decode.step", seq.trace, t0, t1,
-                        {"step": n_prior, "slot": seq.slot,
-                         "context_len": seq.context_len,
-                         "batch": batch_n,
-                         "kv_pages": len(self.allocator.pages_of(
-                             seq.seq_id))})
-            seq.context_len += 1
-            self._emit(seq, int(np.argmax(row)))
-            produced += 1
-            self._maybe_evict(seq)
-        return produced
+        original bisection-aware path).  The ``mx.serve.decode_step``
+        phase spans the host's framing of the call, its dispatch and
+        the wait for the logits; ``t0, t1`` (the device call alone)
+        stay the per-request spans' interval."""
+        with _tr.phase("serve.decode_step", engine_step=self._step_no,
+                       active=len(running), slots=self.max_batch,
+                       kv_pages_in_use=self.allocator.used_pages,
+                       kv_pages_total=self.geometry.usable_pages):
+            results = self._decode_call(running)
+        with _tr.phase("serve.emit", engine_step=self._step_no,
+                       tokens=len(results)):
+            for seq, row, t0, t1, batch_n in results:
+                # per-sequence decode-step spans (first step, then
+                # every Nth): ONE device call serves the whole batch, so
+                # each due sequence gets the shared interval with its
+                # own tags
+                if seq.trace is not None:
+                    n_prior = len(seq.tokens)
+                    if n_prior == 1 or n_prior % _STEP_SPAN_EVERY == 0:
+                        _tr.record_span(
+                            "decode.step", seq.trace, t0, t1,
+                            {"step": n_prior, "slot": seq.slot,
+                             "context_len": seq.context_len,
+                             "batch": batch_n,
+                             "kv_pages": len(self.allocator.pages_of(
+                                 seq.seq_id))})
+                seq.context_len += 1
+                self._emit(seq, int(np.argmax(row)))
+                self._maybe_evict(seq)
+        return len(results)
 
     def _spec_round(self, seqs):
         """One speculative round (docs/serving.md §9): the draft
@@ -1113,56 +1158,73 @@ class DecodeEngine:
                          "pos": s.draft_ctx, "proposals": [],
                          "steps": m + len(feed)})
         max_steps = max(p["steps"] for p in plan)
-        for st in range(max_steps):
-            tokens = np.zeros((B,), np.int32)
-            positions = np.zeros((B,), np.int32)
-            block_tables = np.zeros((B, P), np.int32)
-            active = [p for p in plan if st < p["steps"]]
-            for p in active:
-                slot = p["seq"].slot
-                tokens[slot] = p["cur"]
-                positions[slot] = p["pos"]
-                block_tables[slot] = tables[p["seq"].seq_id]
-            try:
-                logits = np.asarray(self.draft.decode_step(
-                    tokens, positions, block_tables))
-            except Exception as e:  # noqa: BLE001 — draft died
-                # proposals so far are unusable mid-round state; the
-                # round degrades to ONE plain target step (correct by
-                # construction) and the draft gets another chance next
-                # round — partially written draft K/V beyond draft_ctx
-                # is rolled back by never advancing the counter
-                _LOG.warning(
-                    "decode engine %s: draft step failed mid-round "
-                    "(%s); running this round without speculation",
-                    self.model_name, e)
-                with self._cond:
-                    self._stats["spec_fallbacks"] += len(seqs)
-                return self._plain_decode(seqs)
-            for p in active:
-                out = int(np.argmax(logits[p["seq"].slot]))
-                p["pos"] += 1
-                if p["feed"]:
-                    p["cur"] = p["feed"].pop(0)     # catch-up: discard
-                else:
-                    p["proposals"].append(out)
-                    p["cur"] = out
+        with _tr.phase("serve.draft", engine_step=self._step_no,
+                       active=len(plan), steps=max_steps):
+            for st in range(max_steps):
+                tokens = np.zeros((B,), np.int32)
+                positions = np.zeros((B,), np.int32)
+                block_tables = np.zeros((B, P), np.int32)
+                active = [p for p in plan if st < p["steps"]]
+                for p in active:
+                    slot = p["seq"].slot
+                    tokens[slot] = p["cur"]
+                    positions[slot] = p["pos"]
+                    block_tables[slot] = tables[p["seq"].seq_id]
+                try:
+                    logits = np.asarray(self.draft.decode_step(
+                        tokens, positions, block_tables))
+                except Exception as e:  # noqa: BLE001 — draft died
+                    # proposals so far are unusable mid-round state; the
+                    # round degrades to ONE plain target step (correct by
+                    # construction) and the draft gets another chance next
+                    # round — partially written draft K/V beyond draft_ctx
+                    # is rolled back by never advancing the counter
+                    _LOG.warning(
+                        "decode engine %s: draft step failed mid-round "
+                        "(%s); running this round without speculation",
+                        self.model_name, e)
+                    with self._cond:
+                        self._stats["spec_fallbacks"] += len(seqs)
+                    return self._plain_decode(seqs)
+                for p in active:
+                    out = int(np.argmax(logits[p["seq"].slot]))
+                    p["pos"] += 1
+                    if p["feed"]:
+                        p["cur"] = p["feed"].pop(0)     # catch-up: discard
+                    else:
+                        p["proposals"].append(out)
+                        p["cur"] = out
         W = next_bucket(k + 1, self.geometry.max_context)
-        if getattr(self.model, "verify_batch", None) is not None:
-            judged = self._verify_batched(plan, tables, W)
-        else:
-            judged = self._verify_each(plan, tables, W)
+        with _tr.phase("serve.verify", engine_step=self._step_no,
+                       proposed=sum(len(p["proposals"]) for p in plan)
+                       ) as ph:
+            if getattr(self.model, "verify_batch", None) is not None:
+                judged = self._verify_batched(plan, tables, W)
+            else:
+                judged = self._verify_each(plan, tables, W)
+            for p, logits, _t0, _t1 in judged:
+                # greedy-exact acceptance: row i of logits is the
+                # target's next-token distribution after consuming
+                # window[i]
+                proposals, accept = p["proposals"], 0
+                while accept < len(proposals) and proposals[accept] \
+                        == int(np.argmax(logits[accept])):
+                    accept += 1
+                p["accept"] = accept
+            ph.set_metadata(accepted=sum(p["accept"] for p, *_ in judged))
+        with _tr.phase("serve.emit", engine_step=self._step_no,
+                       tokens=sum(p["accept"] + 1 for p, *_ in judged)):
+            return self._emit_judged(judged)
+
+    def _emit_judged(self, judged):
+        """Commit one speculative round's verdicts: counters, the KV
+        rollback, the per-request spans, and the accepted tokens plus
+        the target's own next one."""
         produced = 0
         for p, logits, t0, t1 in judged:
             seq = p["seq"]
-            proposals = p["proposals"]
+            proposals, accept = p["proposals"], p["accept"]
             ctx = seq.context_len
-            # greedy-exact acceptance: row i of logits is the target's
-            # next-token distribution after consuming window[i]
-            accept = 0
-            while accept < len(proposals) \
-                    and proposals[accept] == int(np.argmax(logits[accept])):
-                accept += 1
             emits = proposals[:accept] + [int(np.argmax(logits[accept]))]
             with self._cond:
                 self._stats["spec_rounds"] += 1
@@ -1449,6 +1511,18 @@ class DecodeEngine:
 # ---------------------------------------------------------------------------
 # model adapters
 # ---------------------------------------------------------------------------
+def _named_program(name, fn, **static):
+    """``fn`` with ``static`` bound, under a name of its own.  jax names
+    a compiled program after its function (``jit_<name>``: the HLO
+    module, and the profiler's ``XLA Modules`` line, which is where a
+    trace's readers find the decode program), and a
+    ``functools.partial`` has no name (``jit__unknown``)."""
+    def program(*args):
+        return fn(*args, **static)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 class PagedLMAdapter:
     """Decode-model protocol over a
     :class:`~mxnet_tpu.models.transformer_blocks.TransformerDecoderLM`.
@@ -1511,8 +1585,6 @@ class PagedLMAdapter:
 
     # ------------------------------------------------------------- programs
     def setup(self, geometry):
-        import functools
-
         import jax
 
         from ..models.transformer_blocks import (paged_decode_step,
@@ -1550,24 +1622,26 @@ class PagedLMAdapter:
         # and each protocol method below rebinds the pool (pool.swap)
         # to the program's outputs before anything reads it again
         self._prefill_jit = jax.jit(
-            functools.partial(paged_prefill, **kw),
+            _named_program("mx_serve_prefill", paged_prefill, **kw),
             donate_argnums=(4, 5))
         self._decode_jit = jax.jit(
-            functools.partial(paged_decode_step,
-                              attention_impl=self.attention_impl, **kw),
+            _named_program("mx_serve_decode_step", paged_decode_step,
+                           attention_impl=self.attention_impl, **kw),
             donate_argnums=(4, 5))
         # verify family (prefix-hit tails + speculative windows): the
         # pools sit at argument positions 5/6; the COW page copy is one
         # more (traced-scalar src/dst, so ONE program for every copy)
         self._verify_jit = jax.jit(
-            functools.partial(paged_verify,
-                              attention_impl=self.attention_impl, **kw),
+            _named_program("mx_serve_verify", paged_verify,
+                           attention_impl=self.attention_impl, **kw),
             donate_argnums=(5, 6))
         self._verify_batch_jit = jax.jit(
-            functools.partial(paged_verify_batch,
-                              attention_impl=self.attention_impl, **kw),
+            _named_program("mx_serve_verify_batch", paged_verify_batch,
+                           attention_impl=self.attention_impl, **kw),
             donate_argnums=(5, 6))
-        self._copy_jit = jax.jit(copy_page_arrays, donate_argnums=(0, 1))
+        self._copy_jit = jax.jit(
+            _named_program("mx_serve_copy_pages", copy_page_arrays),
+            donate_argnums=(0, 1))
 
     def _cache(self):
         from .. import compile_cache as _cc
@@ -1577,13 +1651,16 @@ class PagedLMAdapter:
     def _fingerprint(self, kind, rows):
         """Architecture-level program identity for the compile-cache
         key.  Weights are program INPUTS, so two checkpoints of one
-        architecture share executables."""
+        architecture share executables.  The leading version goes up
+        whenever the programs' text changes without a dimension
+        changing (v2: they got their ``mx_serve_*`` names), or a store
+        written before would hand back the old executables."""
         import hashlib
 
         import jax
         g = self.geometry
         desc = "\x1f".join([
-            "mxnet_tpu.paged_lm/v1", kind, f"rows={rows}",
+            "mxnet_tpu.paged_lm/v2", kind, f"rows={rows}",
             f"layers={self.num_layers}", f"heads={self.num_heads}",
             f"units={self.lm.units}", f"vocab={self.vocab_size}",
             f"hidden={int(self.params['cells'][0]['f1_w'].shape[0])}",

@@ -46,13 +46,30 @@ decomposed into ``train.data.wait`` / ``train.h2d`` /
 Perfetto next to a serving one and a slow ``trainer.step.seconds`` p99
 resolves to its step trace through the same exemplar link.
 
-Overhead contract (mirrors ``runtime_metrics``): tracing is **off by
-default**; every instrumentation site either guards on the module-level
-``_ENABLED`` bool or goes through :func:`span`/:func:`trace`, which
-return a shared no-op singleton when the switch is off — one attribute
-load + branch (~ns) per site.  Enable with ``MXNET_TRACE=1`` or
-:func:`enable`.  Tracing never touches jax: with the switch in either
-position, zero additional XLA programs are compiled.
+- **Phases** (:func:`phase`): the one kind of span that lands in the
+  JAX profiler's own trace, beside the device's lines and on their
+  clock.  ``phase("train.step", step=n)`` is a
+  ``jax.profiler.TraceAnnotation("mx.train.step", step=n)``: it begins
+  and ends on one thread, nests by time, and carries the counts taken
+  at that boundary as tags.  **The profiler's session is its switch**:
+  with no ``jax.profiler.start_trace`` running it is a no-op in C++ and
+  costs its construction (half a microsecond); ``MXNET_TRACE`` is not
+  looked at.  ``ShardedTrainer`` and ``DecodeEngine`` wrap their phases
+  in it always, so one ``.xplane.pb`` shows which phase of the host
+  each idle gap of the device fell in (docs/observability.md lists the
+  ``mx.`` names and tags).  The request spans above export on the epoch
+  clock (:data:`CLOCK_ANCHOR`), which is the clock the profiler stamps
+  its session with, so a request trace and an ``.xplane.pb`` of one run
+  line up.
+
+Overhead contract (mirrors ``runtime_metrics``): request tracing is
+**off by default**; every instrumentation site either guards on the
+module-level ``_ENABLED`` bool or goes through :func:`span`/
+:func:`trace`, which return a shared no-op singleton when the switch is
+off — one attribute load + branch (~ns) per site.  Enable with
+``MXNET_TRACE=1`` or :func:`enable`.  Neither kind of span adds an XLA
+program: the request spans never touch jax, and :func:`phase` touches
+only its profiler.
 """
 from __future__ import annotations
 
@@ -66,13 +83,15 @@ import time
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional
 
+import jax
+
 from . import engine
 from .base import MXNetError, env_truthy, get_env
 
 __all__ = [
     "Span", "TraceContext", "Tracer", "TRACER",
     "enable", "disable", "enabled", "reset",
-    "trace", "span", "record_span", "tag",
+    "trace", "span", "record_span", "tag", "phase", "CLOCK_ANCHOR",
     "current_span", "current_context",
     "to_chrome_trace", "dump_chrome_trace", "dump_jsonl",
     "flight_record", "record_incident", "incident_paths",
@@ -95,6 +114,32 @@ _MAX_ACTIVE_TRACES = 256
 # collide in a merged dashboard
 _RUN_PREFIX = os.urandom(4).hex()
 _NEXT_ID = itertools.count(1)           # CPython: next() is atomic
+
+# (epoch ns, perf_counter ns) read together once: spans are timed on
+# perf_counter and exported on the epoch clock, the one the profiler
+# stamps a session with (``profile_start_time`` of its ``Task
+# Environment`` plane), so both kinds of trace share a time axis
+CLOCK_ANCHOR = (time.time_ns(), time.perf_counter_ns())
+
+
+def _epoch_s(t):
+    """A ``time.perf_counter`` reading as seconds since the epoch."""
+    return t + (CLOCK_ANCHOR[0] - CLOCK_ANCHOR[1]) * 1e-9
+
+
+def phase(name, **tags):
+    """A thread-nested phase of the program in the JAX profiler's own
+    trace: ``jax.profiler.TraceAnnotation("mx." + name, **tags)``, to be
+    used as a context manager around work that begins and ends on one
+    thread.  Names are ``<plane>.<phase>`` (``train.dispatch``,
+    ``serve.decode_step``); tags are the counts taken at that boundary,
+    among them the identifier the spans of one unit share (``step``,
+    ``engine_step``).  Counts known only at the end go in through the
+    annotation's ``set_metadata(**tags)`` before it closes.  The
+    profiler's session is the switch: outside one this is a no-op in
+    C++ that costs its construction, and no environment variable is
+    read."""
+    return jax.profiler.TraceAnnotation("mx." + name, **tags)
 
 
 def enable(sample=None):
@@ -495,8 +540,8 @@ def tag(key, value):
 def to_chrome_trace(traces) -> dict:
     """Render completed trace dicts as a chrome-trace JSON object
     (``chrome://tracing`` / Perfetto: ``ph:"X"`` complete events, ts in
-    microseconds, one row per span thread).  Accepts one trace dict or
-    a list of them."""
+    microseconds since the epoch, one row per span thread).  Accepts
+    one trace dict or a list of them."""
     if isinstance(traces, dict):
         traces = [traces]
     pid = os.getpid()
@@ -510,7 +555,7 @@ def to_chrome_trace(traces) -> dict:
                          "span_id": s["span_id"],
                          "parent_id": s["parent_id"]})
             events.append({"name": s["name"], "cat": tr["root"],
-                           "ph": "X", "ts": s["t0"] * 1e6,
+                           "ph": "X", "ts": _epoch_s(s["t0"]) * 1e6,
                            "dur": dur * 1e6, "pid": pid,
                            "tid": s["thread"], "args": args})
     return {"traceEvents": events, "displayTimeUnit": "ms"}
@@ -528,8 +573,8 @@ def dump_chrome_trace(path, traces=None) -> str:
 
 def dump_jsonl(path=None, traces=None) -> str:
     """One JSON object per span, one span per line (log-pipeline
-    friendly).  Returns the serialized text; also writes it when
-    ``path`` is given."""
+    friendly), ``t0``/``t1`` in seconds since the epoch.  Returns the
+    serialized text; also writes it when ``path`` is given."""
     if traces is None:
         traces = TRACER.traces()
     elif isinstance(traces, dict):
@@ -537,8 +582,9 @@ def dump_jsonl(path=None, traces=None) -> str:
     lines = []
     for tr in traces:
         for s in tr["spans"]:
-            rec = dict(s)
-            rec["root"] = tr["root"]
+            rec = dict(s, t0=_epoch_s(s["t0"]), root=tr["root"])
+            if s["t1"] is not None:
+                rec["t1"] = _epoch_s(s["t1"])
             lines.append(json.dumps(rec, sort_keys=True))
     text = "\n".join(lines) + ("\n" if lines else "")
     if path is not None:

@@ -823,3 +823,334 @@ class TestFlightRecorder:
                                path=str(tmp_path / "x.json"))
         rec = json.load(open(p))
         assert "debug_state failed" in rec["state"]["error"]
+
+
+# ---------------------------------------------------------------------------
+# phases on the profiler's clock (tracing.phase; ISSUE 31)
+# ---------------------------------------------------------------------------
+import contextlib
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+
+
+@contextlib.contextmanager
+def _session(trace_dir):
+    """One JAX profiler session, host annotations only."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _mx_events(trace_dir):
+    """(the session's ``mx.`` events by start, each with its tags and
+    the line it ran on; the session's start in epoch nanoseconds)."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events, began = [], None
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            began = int(dict(plane.stats)["profile_start_time"])
+        for n, line in enumerate(plane.lines):
+            events += [{"name": e.name, "t0": e.start_ns, "t1": e.end_ns,
+                        "tags": dict(e.stats), "line": (plane.name, n)}
+                       for e in line.events if e.name.startswith("mx.")]
+    return sorted(events, key=lambda e: e["t0"]), began
+
+
+def _inside(inner, outer):
+    return (outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"]
+            and inner["line"] == outer["line"])
+
+
+class TestPhase:
+    def test_silent_without_a_session_and_blind_to_the_switch(
+            self, tmp_path):
+        with tr.phase("test.before", n=1) as ph:    # no session: no-op
+            ph.set_metadata(m=2)
+        tr.disable()            # MXNET_TRACE is not phase()'s switch
+        with _session(tmp_path):
+            with tr.phase("test.inside", n=2):
+                pass
+        events, _began = _mx_events(tmp_path)
+        assert [e["name"] for e in events] == ["mx.test.inside"]
+        assert tr.TRACER.stats()["spans"] == 0      # no request span either
+
+    def test_on_the_profilers_epoch_clock_with_tags(self, tmp_path):
+        """ISSUE 31's premise: an event's ``start_ns`` counts from the
+        session's start, which the ``Task Environment`` plane gives in
+        epoch nanoseconds on ``time.time_ns()``'s clock; keyword tags
+        and ``set_metadata`` come back as the event's stats."""
+        with _session(tmp_path):
+            before = time.time_ns()
+            with tr.phase("train.step", step=7, compiles=3) as ph:
+                entered = time.time_ns()
+                ph.set_metadata(admitted=2)
+        (event,), began = _mx_events(tmp_path)
+        assert event["name"] == "mx.train.step"
+        assert event["tags"] == {"step": 7, "compiles": 3, "admitted": 2}
+        slack = 2_000_000       # the two clocks are read apart: 2 ms
+        assert before - slack <= began + event["t0"] <= entered + slack
+
+    @pytest.mark.parametrize("exporter", ["chrome", "jsonl"])
+    def test_request_spans_export_on_the_epoch_clock(self, exporter):
+        before = time.time()
+        with tr.trace("req"):
+            pass
+        after = time.time()
+        t = tr.TRACER.last(root="req")
+        if exporter == "chrome":
+            (ev,) = [e for e in tr.to_chrome_trace(t)["traceEvents"]
+                     if e.get("ph") == "X"]
+            t0, t1 = ev["ts"] * 1e-6, (ev["ts"] + ev["dur"]) * 1e-6
+        else:
+            (rec,) = [json.loads(l) for l in tr.dump_jsonl(traces=t)
+                      .splitlines()]
+            t0, t1 = rec["t0"], rec["t1"]
+        # CLOCK_ANCHOR pairs the two clocks once; they drift by less
+        # than this over a test run
+        assert before - 0.05 <= t0 <= t1 <= after + 0.05
+
+
+def _toy_trainer(**kw):
+    from mxnet_tpu import gluon, parallel
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(16, activation="relu", in_units=8),
+            gluon.nn.Dense(4, in_units=16))
+    net.initialize(mx.init.Xavier())
+    x = np.random.randn(8, 8).astype("float32")
+    y = np.random.randn(8, 4).astype("float32")
+    trainer = parallel.ShardedTrainer(
+        net, lambda out, y: jnp.mean((out - y) ** 2),
+        parallel.make_mesh(dp=kw.pop("dp", 1)), optimizer="adamw",
+        optimizer_params={"learning_rate": 1e-3},
+        example_inputs=(mx.nd.array(x),), n_labels=1, **kw)
+    return trainer, x, y
+
+
+@pytest.fixture(scope="module", params=["plain", "attributed"])
+def train_trace(request, tmp_path_factory):
+    """Four traced steps of a toy ``ShardedTrainer`` after two of
+    warm-up, down ``step()``'s plain path (both switches off) and down
+    ``_step_attributed`` (``MXNET_TRACE`` on)."""
+    tr.disable()
+    trainer, x, y = _toy_trainer()
+    if request.param == "attributed":
+        tr.enable(sample=1.0)
+    for _ in range(2):
+        trainer.step(x, y).block_until_ready()
+    trace_dir = tmp_path_factory.mktemp("train_" + request.param)
+    with _session(trace_dir):
+        losses = [trainer.step(x, y) for _ in range(4)]
+        jax.block_until_ready(losses)
+    tr.disable()
+    return request.param, _mx_events(trace_dir)[0]
+
+
+class TestTrainPhases:
+    def test_h2d_and_dispatch_nest_in_their_step(self, train_trace):
+        mode, events = train_trace
+        steps = [e for e in events if e["name"] == "mx.train.step"]
+        assert len(steps) == 4
+        for step in steps:
+            for name in ("mx.train.h2d", "mx.train.dispatch"):
+                (inner,) = [e for e in events if e["name"] == name
+                            and e["tags"]["step"] == step["tags"]["step"]]
+                assert _inside(inner, step), (name, inner, step)
+        # only a step that has to wait for the device says so
+        syncs = [e for e in events if e["name"] == "mx.train.sync"]
+        assert len(syncs) == (4 if mode == "attributed" else 0)
+
+    def test_step_tag_rises_by_one(self, train_trace):
+        _mode, events = train_trace
+        tags = [e["tags"]["step"] for e in events
+                if e["name"] == "mx.train.step"]
+        assert tags == list(range(tags[0], tags[0] + 4)) and tags[0] == 3
+
+    def test_compiles_tag_constant_after_warm_up(self, train_trace):
+        _mode, events = train_trace
+        compiles = {e["tags"]["compiles"] for e in events
+                    if e["name"] == "mx.train.step"}
+        assert len(compiles) == 1 and compiles.pop() >= 1
+
+
+@pytest.mark.parametrize("sync", [False, True])
+def test_dispatch_blocks_only_when_asked(sync, monkeypatch):
+    """The phases add no wait: ``_dispatch_step(sync=False)``, which
+    is ``step()``'s plain path, never calls ``block_until_ready``."""
+    tr.disable()
+    trainer, x, y = _toy_trainer()
+    batch = trainer.shard_batch(x, y)
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda a: calls.append(1) or real(a))
+    out = trainer._dispatch_step(batch, sync=sync)
+    monkeypatch.undo()
+    jax.block_until_ready(out[-1])
+    assert len(calls) == (1 if sync else 0)
+
+
+class ChainLM:
+    """Decode-model protocol in numpy whose every family agrees: the
+    next token is (last + 1) mod vocab in prefill, decode step and
+    verify window alike, so a draft of the same class is always
+    accepted."""
+
+    vocab_size = 32
+    max_context = 64
+
+    def _rows(self, tokens):
+        rows = np.zeros((len(tokens), self.vocab_size), np.float32)
+        rows[np.arange(len(tokens)),
+             (np.asarray(tokens) + 1) % self.vocab_size] = 1.0
+        return rows
+
+    def prefill(self, tokens, length, block_table):
+        return self._rows(tokens[0, int(length) - 1:int(length)])[0]
+
+    def decode_step(self, tokens, positions, block_tables):
+        return self._rows(tokens)
+
+    def verify(self, tokens, start, length, block_table):
+        return self._rows(tokens[0])
+
+
+@pytest.fixture(scope="module", params=["plain", "speculative"])
+def serve_trace(request, tmp_path_factory):
+    """A toy engine on its own thread, three requests over two slots,
+    traced from before the first submit to after the last result."""
+    tr.disable()
+    spec = request.param == "speculative"
+    # five tokens each: under speculation the first comes from the
+    # prefill, three from one round, and the last, one from the cap,
+    # from a plain decode step
+    cfg = _decode_cfg(decode_max_new_tokens=5, decode_pool_pages=33,
+                      **({"spec_k": 2} if spec else {}))
+    eng = DecodeEngine(ChainLM(), cfg, model_name="toy",
+                       draft=ChainLM() if spec else None, autostart=True)
+    trace_dir = tmp_path_factory.mktemp("serve_" + request.param)
+    try:
+        with _session(trace_dir):
+            seqs = [eng.submit([1, 2, 3 + i], max_new_tokens=5)
+                    for i in range(3)]
+            for s in seqs:
+                assert len(eng.result(s, timeout=60)) == 5
+    finally:
+        eng.stop()
+    return request.param, _mx_events(trace_dir)[0]
+
+
+class TestServePhases:
+    def test_phases_and_their_counts(self, serve_trace):
+        mode, events = serve_trace
+        by = {}
+        for e in events:
+            by.setdefault(e["name"], []).append(e)
+        want = {"mx.serve.admit", "mx.serve.prefill", "mx.serve.emit",
+                "mx.serve.decode_step"}
+        if mode == "speculative":
+            want |= {"mx.serve.draft", "mx.serve.verify"}
+        assert want <= set(by), sorted(by)
+        assert len({e["line"] for e in events}) == 1    # the engine's thread
+        assert sum(e["tags"]["admitted"] for e in by["mx.serve.admit"]) == 3
+        assert all(e["tags"]["queue_wait_us_max"]
+                   <= e["tags"]["queue_wait_us_sum"]
+                   for e in by["mx.serve.admit"])
+        assert [e["tags"]["tokens"] for e in by["mx.serve.prefill"]] \
+            == [3, 3, 3]
+        assert all(e["tags"]["prefix_hit_tokens"] == 0
+                   and e["tags"]["bucket"] >= 3
+                   for e in by["mx.serve.prefill"])
+        for e in by["mx.serve.decode_step"]:
+            t = e["tags"]
+            assert 1 <= t["active"] <= t["slots"] == 2, t
+            assert 1 <= t["kv_pages_in_use"] <= t["kv_pages_total"] == 32, t
+        assert sum(e["tags"]["tokens"] for e in by["mx.serve.emit"]) == 15
+        for e in by.get("mx.serve.verify", ()):
+            assert 0 <= e["tags"]["accepted"] <= e["tags"]["proposed"]
+
+    def test_one_steps_phases_share_engine_step_and_do_not_overlap(
+            self, serve_trace):
+        _mode, events = serve_trace
+        top = [e for e in events
+               if not any(_inside(e, o) for o in events if o is not e)]
+        steps = [e["tags"]["engine_step"] for e in top]
+        assert steps == sorted(steps)
+        for a, b in zip(top, top[1:]):
+            assert a["t1"] <= b["t0"], (a, b)
+        nested = [e for e in events if e not in top]
+        assert {e["name"] for e in nested} <= {"mx.serve.emit"}
+        for e in nested:        # the first token's emit, inside its prefill
+            (outer,) = [o for o in top if _inside(e, o)]
+            assert outer["name"] == "mx.serve.prefill"
+            assert outer["tags"]["engine_step"] == e["tags"]["engine_step"]
+
+
+def _serve_programs():
+    """{name: (jitted program, example arguments)} of a tiny paged LM,
+    as ``PagedLMAdapter``'s protocol methods call them."""
+    from mxnet_tpu.models.transformer_blocks import TransformerDecoderLM
+    from mxnet_tpu.serving.decode import PagedLMAdapter
+    from mxnet_tpu.serving.kv_cache import PageGeometry
+    lm = TransformerDecoderLM(13, units=8, hidden_size=16, num_layers=1,
+                              num_heads=2, max_length=16)
+    lm.initialize(mx.init.Xavier())
+    ad = PagedLMAdapter(lm)
+    ad.setup(PageGeometry(page_size=4, pool_pages=9, max_context=16,
+                          num_layers=1, num_heads=2, head_dim=4))
+    B, P, W = 2, 4, 4
+    i32 = lambda *shape: np.zeros(shape, np.int32)      # noqa: E731
+    pool = (ad.pool.k_pages, ad.pool.v_pages)
+    return {
+        "prefill": (ad._prefill_jit,
+                    (ad.params, i32(1, 8), np.int32(3), i32(P)) + pool),
+        "decode_step": (ad._decode_jit,
+                        (ad.params, i32(B), i32(B), i32(B, P)) + pool),
+        "verify": (ad._verify_jit, (ad.params, i32(1, W), np.int32(0),
+                                    np.int32(2), i32(P)) + pool),
+        "verify_batch": (ad._verify_batch_jit,
+                         (ad.params, i32(B, W), i32(B), i32(B),
+                          i32(B, P)) + pool),
+        "copy_pages": (ad._copy_jit, pool + (np.int32(1), np.int32(2))),
+    }
+
+
+@pytest.mark.parametrize("program", [
+    "train_step", "train_step_compressed", "prefill", "decode_step",
+    "verify", "verify_batch", "copy_pages"])
+def test_every_program_lowers_under_its_own_mx_name(program):
+    """What a trace's ``XLA Modules`` line reads is ``jit_<name>``: the
+    trainer's step and each of the engine's programs have a name of
+    their own, so a reader finds them by it (a ``functools.partial``
+    lowers as ``jit__unknown``)."""
+    if program.startswith("train_step"):
+        compressed = program.endswith("compressed")
+        trainer, x, y = _toy_trainer(
+            **({"compression": "int8", "dp": 8} if compressed else {}))
+        args = (trainer.params, trainer.opt_state)
+        if compressed:
+            args += (trainer.residuals, jax.random.PRNGKey(1))
+        fn, args = trainer._step, args + trainer.shard_batch(x, y)
+        want = "jit_mx_train_step"
+    else:
+        fn, args = _serve_programs()[program]
+        want = "jit_mx_serve_" + program
+    text = fn.lower(*args).as_text()
+    assert f"module @{want} " in text, text[:200]
+    if program == "train_step":
+        # the scopes the device metrics read, the block's name under them
+        debug = fn.lower(*args).as_text(debug_info=True)
+        for scope in ("jvp(mx.fwd)/dense", "transpose(jvp(mx.fwd))/dense",
+                      "jvp(mx.loss)", "mx.optim/"):
+            assert f"jit(mx_train_step)/{scope}" in debug, scope
+    if program == "train_step_compressed":
+        assert "mx.collective" in fn.lower(*args).as_text(debug_info=True)
