@@ -18,6 +18,8 @@ Layout contract (matches the reference ops):
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -200,9 +202,49 @@ def index_array(data, *, axes=None):
     return full[..., list(axes)].astype(jnp.int64)
 
 
+_SQRT_HALF = math.sqrt(0.5)
+_INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
+
+
+@jax.custom_vjp
+def _gelu_erf(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+def _gelu_erf_fwd(x):
+    # Under autodiff alone XLA keeps only x and expands erfc again in
+    # every fusion that reads gelu(x) or its derivative: in a
+    # transformer FFN three times a layer, twice on a matmul's operand
+    # side (PERF.md, PR 32).  Here the expansion runs once, where x is
+    # produced; the barrier keeps its two results one producer's, and
+    # the backward's float32 read of y keeps XLA from narrowing y for
+    # the matmuls that follow, which would split that producer again.
+    # The density is written from erfc's own argument, so that it
+    # shares the exponential of erfc's expansion.
+    with jax.named_scope("mx.act"):
+        z = -x * _SQRT_HALF
+        cdf = 0.5 * lax.erfc(z)
+        y = x * cdf
+        slope = cdf + x * (jnp.exp(-(z * z)) * _INV_SQRT_2PI)
+        y, rest = lax.optimization_barrier((y, slope - y))
+    return y, (y, rest)
+
+
+def _gelu_erf_bwd(res, g):
+    y, rest = res
+    return (g * (y + rest),)
+
+
+_gelu_erf.defvjp(_gelu_erf_fwd, _gelu_erf_bwd)
+
+
 @register("_contrib_gelu_erf", aliases=["gelu"])
 def gelu_erf(data):
-    return jax.nn.gelu(data, approximate=False)
+    """Exact GELU, ``x * Phi(x)``.  Not differentiated it is
+    ``jax.nn.gelu(x, approximate=False)``; differentiated, ``erfc`` is
+    evaluated once an element and ``dy/dx = Phi(x) + x * phi(x)`` is
+    saved, not recomputed."""
+    return _gelu_erf(data)
 
 
 @register("_contrib_gelu_tanh", aliases=["gelu_tanh"])

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .contrib import gelu_erf
 from .registry import register
 from .. import autograd
 
@@ -73,7 +74,7 @@ def LeakyReLU(data, gamma=None, *, act_type: str = "leaky",
         alpha, scale = 1.6732632423543772, 1.0507009873554805
         return scale * jnp.where(data >= 0, data, alpha * jnp.expm1(data))
     if act_type == "gelu":
-        return jax.nn.gelu(data, approximate=False)
+        return gelu_erf(data)
     if act_type == "rrelu":
         # inference behavior (fixed mean slope), like reference in test mode
         return jnp.where(data >= 0, data,
