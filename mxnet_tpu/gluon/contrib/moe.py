@@ -2,61 +2,92 @@
 axis).
 
 New TPU-first capability — upstream MXNet has no MoE (SURVEY.md §2.4:
-EP absent; flagged as new capability).  Wraps ``ops/moe.py``'s
-GShard-style dense-routing op: parameters are named so
-``parallel.MEGATRON_RULES`` shards the expert dim over ``ep`` (the
-dispatch/combine einsums then lower to ICI all-to-alls under GSPMD).
+EP absent; flagged as new capability).  Wraps ``ops/moe.py``'s dropless
+top-k layer.  The expert leaves carry the expert dimension first and
+are named so that ``parallel.MEGATRON_RULES`` shards it over ``ep``.
 
-    layer = MoEFFN(units=512, hidden_size=2048, num_experts=8)
-    out, aux_loss = layer(x)          # add aux_weight*aux_loss to loss
+    layer = MoEFFN(units=512, hidden_size=2048, num_experts=8,
+                   experts_per_token=2)
+    out = layer(x)
+
+A layer that holds one chip's share of the experts is told so: with
+``experts_held=16, first_expert=32`` of ``num_experts=64`` it routes
+over all 64, computes experts 32..47's part and leaves the rest out.
 """
 from __future__ import annotations
 
 from ...base import MXNetError
-from ..block import HybridBlock
+from ..block import HybridBlock, update_aux_state
 
 __all__ = ["MoEFFN"]
 
 
 class MoEFFN(HybridBlock):
-    """Switch/GShard top-1 MoE feed-forward block.
+    """Top-k dropless MoE feed-forward block (k=1 is Switch routing
+    without its capacity).
 
-    Inputs (..., units); returns (output (..., units), aux_loss ()).
-    Tokens routed past an expert's ``capacity_factor`` allowance are
-    dropped (carried by the caller's residual connection, per GShard).
+    Inputs (..., units); returns the output (..., units): for each token
+    the sum over its ``experts_per_token`` chosen experts that are held
+    here of ``w_e * expert_e(x)``, the weights renormalised over all
+    chosen.  ``gated`` experts are
+    ``w2 (act(w1_gate x) * (w1_up x))`` (SwiGLU with ``activation=
+    "silu"``), plain ones ``w2 act(w1 x)``; none has a bias.
+
+    ``rows_routed`` (n_held,) is a cumulative count, kept on the device
+    as float32 (exact to 2**24 a expert; read differences), of the
+    (token, expert) pairs routed to each held expert: auxiliary state
+    like BatchNorm's moving statistics, so a compiled train step
+    carries it and nothing reads it inside the step.
+
+    ``train_router=False`` freezes the router's weight (its gradient is
+    still taken; no optimizer moves it).  A layer that holds a share of
+    the experts and is trained alone needs it: only the held experts'
+    output reaches the loss, so a trained router learns to send every
+    token to them, which the whole layer's router, whose gradient sums
+    over all the shares, does not.
     """
 
     def __init__(self, units, hidden_size, num_experts,
-                 capacity_factor=1.25, activation="gelu",
+                 experts_per_token=1, experts_held=None, first_expert=0,
+                 activation="gelu", gated=False, recompute=False, train_router=True,
                  weight_initializer=None, **kwargs):
         super().__init__(**kwargs)
-        if num_experts < 1:
-            raise MXNetError("MoEFFN needs num_experts >= 1")
-        if activation not in ("relu", "gelu"):
+        held = num_experts if experts_held is None else int(experts_held)
+        if num_experts < 1 or not 1 <= experts_per_token <= num_experts:
+            raise MXNetError(
+                f"MoEFFN needs 1 <= experts_per_token <= num_experts, got "
+                f"{experts_per_token} of {num_experts}")
+        if held < 1 or first_expert < 0 or first_expert + held > num_experts:
+            raise MXNetError(
+                f"MoEFFN: experts {first_expert}..{first_expert + held - 1} "
+                f"held of {num_experts}")
+        if activation not in ("relu", "gelu", "silu"):
             raise MXNetError(
                 f"MoEFFN: unsupported activation {activation!r} "
-                f"(supported: 'relu', 'gelu')")
-        self._capacity_factor = float(capacity_factor)
-        self._activation = activation
+                f"(supported: 'relu', 'gelu', 'silu')")
+        self._kw = dict(experts_per_token=int(experts_per_token),
+                        first_expert=int(first_expert),
+                        activation=activation, gated=bool(gated),
+                        recompute=bool(recompute))
         with self.name_scope():
             self.gate_weight = self.params.get(
                 "gate_weight", shape=(units, num_experts),
-                init=weight_initializer)
+                init=weight_initializer,
+                grad_req="write" if train_router else "null")
             self.expert_w1 = self.params.get(
-                "expert_w1", shape=(num_experts, units, hidden_size),
+                "expert_w1",
+                shape=(held, units, (2 if gated else 1) * hidden_size),
                 init=weight_initializer)
-            self.expert_b1 = self.params.get(
-                "expert_b1", shape=(num_experts, hidden_size), init="zeros")
             self.expert_w2 = self.params.get(
-                "expert_w2", shape=(num_experts, hidden_size, units),
+                "expert_w2", shape=(held, hidden_size, units),
                 init=weight_initializer)
-            self.expert_b2 = self.params.get(
-                "expert_b2", shape=(num_experts, units), init="zeros")
+            self.rows_routed = self.params.get(
+                "rows_routed", shape=(held,), init="zeros",
+                grad_req="null")
 
-    def hybrid_forward(self, F, x, gate_weight, expert_w1, expert_b1,
-                       expert_w2, expert_b2):
-        out, aux = F.moe_ffn(x, gate_weight, expert_w1, expert_b1,
-                             expert_w2, expert_b2,
-                             capacity_factor=self._capacity_factor,
-                             activation=self._activation)
-        return out, aux
+    def hybrid_forward(self, F, x, gate_weight, expert_w1, expert_w2,
+                       rows_routed):
+        out, rows = F.moe_ffn(x, gate_weight, expert_w1, expert_w2,
+                              **self._kw)
+        update_aux_state(self.rows_routed, rows_routed + rows)
+        return out

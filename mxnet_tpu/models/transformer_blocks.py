@@ -21,6 +21,7 @@ from ..gluon.block import HybridBlock
 __all__ = ["PositionwiseFFN", "MultiHeadSelfAttention",
            "MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerDecoderLM",
+           "RMSNorm", "GatedFFN", "RotaryGroupedAttention", "DecoderCell",
            "paged_lm_params", "paged_prefill", "paged_decode_step",
            "paged_verify", "paged_verify_batch"]
 
@@ -244,6 +245,123 @@ class TransformerDecoderCell(HybridBlock):
 # ---------------------------------------------------------------------------
 # decoder-only LM + paged decode-mode forward (serving decode engine)
 # ---------------------------------------------------------------------------
+class RMSNorm(HybridBlock):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` over the last axis."""
+
+    def __init__(self, in_channels, epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self._eps = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init="ones")
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.rms_norm(x, gamma, eps=self._eps)
+
+
+class GatedFFN(HybridBlock):
+    """The bias-free gated feed-forward, ``W2 (act(Wg x) * (Wu x))``
+    (SwiGLU with ``activation="silu"``); ``ffn_1`` holds [gate | up]."""
+
+    def __init__(self, units, hidden_size, activation="silu", **kwargs):
+        super().__init__(**kwargs)
+        self._hidden, self._activation = hidden_size, activation
+        with self.name_scope():
+            self.ffn_1 = nn.Dense(2 * hidden_size, in_units=units,
+                                  use_bias=False, flatten=False,
+                                  prefix="ffn_1_")
+            self.ffn_2 = nn.Dense(units, in_units=hidden_size,
+                                  use_bias=False, flatten=False,
+                                  prefix="ffn_2_")
+
+    def hybrid_forward(self, F, x):
+        h = self.ffn_1(x)
+        gate = F.slice_axis(h, axis=-1, begin=0, end=self._hidden)
+        up = F.slice_axis(h, axis=-1, begin=self._hidden, end=None)
+        if self._activation == "silu":
+            gate = gate * F.sigmoid(gate)
+        else:
+            gate = F.Activation(gate, act_type=self._activation)
+        return self.ffn_2(gate * up)
+
+
+class RotaryGroupedAttention(HybridBlock):
+    """Causal self-attention over (B, L, C) with rotary positions and
+    ``num_kv_heads`` key/value heads shared by groups of query heads
+    (query head ``h`` reads key/value head ``h // (H // Hkv)``), no
+    bias, through the Pallas flash kernels (the interpreter on CPU).
+
+    ``window``: key ``s`` is visible to query ``t`` iff
+    ``t - window < s <= t``; None is plain causal.  ``rope``: keyword
+    arguments of the ``rope`` op (theta, the YaRN parameters).
+    ``kv_proj`` holds [k | v].  ``compute_dtype``: what q, k and v are
+    rounded to for the kernels (the MXU's fast path; the default
+    matmuls round float32 operands to it too); the output returns to
+    x's type.
+    """
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 window=None, rope=None, compute_dtype="bfloat16",
+                 **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise MXNetError(f"{num_kv_heads} key/value heads do not "
+                             f"group {num_heads} query heads")
+        if window is not None and int(window) < 1:
+            raise MXNetError(f"window must be >= 1, got {window}")
+        self._heads, self._kv_heads = num_heads, num_kv_heads
+        self._head_dim = head_dim
+        self._window = -1 if window is None else int(window)
+        self._rope = dict(rope or {})
+        self._compute_dtype = compute_dtype
+        with self.name_scope():
+            self.q_proj = nn.Dense(num_heads * head_dim, in_units=units,
+                                   use_bias=False, flatten=False,
+                                   prefix="q_proj_")
+            self.kv_proj = nn.Dense(2 * num_kv_heads * head_dim,
+                                    in_units=units, use_bias=False,
+                                    flatten=False, prefix="kv_proj_")
+            self.out_proj = nn.Dense(units, in_units=num_heads * head_dim,
+                                     use_bias=False, flatten=False,
+                                     prefix="out_proj_")
+
+    def hybrid_forward(self, F, x):
+        B, L, _ = x.shape
+        H, Hkv, D = self._heads, self._kv_heads, self._head_dim
+        q = self.q_proj(x).reshape((B, L, H, D))
+        kv = self.kv_proj(x)
+        k = F.slice_axis(kv, axis=-1, begin=0, end=Hkv * D)
+        v = F.slice_axis(kv, axis=-1, begin=Hkv * D, end=None)
+        q = F.rope(q, **self._rope)
+        k = F.rope(k.reshape((B, L, Hkv, D)), **self._rope)
+        v = v.reshape((B, L, Hkv, D))
+        out = F.flash_attention(
+            *(F.cast(a, dtype=self._compute_dtype) for a in (q, k, v)),
+            causal=True, window=self._window)
+        out = F.cast(out, dtype=str(x.dtype)).reshape((B, L, H * D))
+        return self.out_proj(out)
+
+
+class DecoderCell(HybridBlock):
+    """The present-day pre-norm decoder block over (B, L, C):
+    ``h = x + attention(norm(x))``, ``y = h + ffn(norm(h))``, RMSNorm
+    both.  ``attention`` and ``ffn`` are the blocks to run: what kind of
+    layer this is (window or full attention, dense or sparse
+    feed-forward) is how they were configured, not another class."""
+
+    def __init__(self, units, attention, ffn, rms_norm_eps=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.attn_norm = RMSNorm(units, rms_norm_eps, prefix="attn_norm_")
+            self.attention = attention
+            self.ffn_norm = RMSNorm(units, rms_norm_eps, prefix="ffn_norm_")
+            self.ffn = ffn
+
+    def hybrid_forward(self, F, x):
+        h = x + self.attention(self.attn_norm(x))
+        return h + self.ffn(self.ffn_norm(h))
+
+
 def _sinusoid_table(max_len, units):
     """Shared sinusoidal position table (also consumed by
     models/transformer.py — ONE copy of the formula)."""

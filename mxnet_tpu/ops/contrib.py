@@ -20,6 +20,8 @@ from __future__ import annotations
 
 import math
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -259,3 +261,64 @@ def smooth_l1(data, *, scalar: float = 1.0):
     absd = jnp.abs(data)
     return jnp.where(absd < 1.0 / s2, 0.5 * s2 * jnp.square(data),
                      absd - 0.5 / s2)
+
+
+# ---------------------------------------------------------------------------
+# the present-day decoder block's pieces: RMSNorm and rotary positions
+# ---------------------------------------------------------------------------
+@register("_contrib_rms_norm", num_inputs=2, aliases=["rms_norm"])
+def rms_norm(data, gamma, *, eps: float = 1e-6):
+    """``x * rsqrt(mean(x^2, -1) + eps) * gamma``, the statistics in
+    float32."""
+    x = data.astype(jnp.float32)
+    y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+def rope_inv_freq(head_dim, theta, yarn_factor=0.0, yarn_original_max=0,
+                  yarn_beta_fast=32.0, yarn_beta_slow=1.0):
+    """The rotary frequencies ``theta^(-2i/head_dim)``, i < head_dim/2
+    (float64).  With ``yarn_factor`` the static YaRN blend (Peng et al.
+    2023, as the ``transformers`` library computes it): each frequency
+    is interpolated (divided by the factor) or kept, by a linear ramp
+    between the dimensions that turn ``yarn_beta_fast`` and
+    ``yarn_beta_slow`` times over ``yarn_original_max`` positions."""
+    half = head_dim // 2
+    inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
+    if not yarn_factor:
+        return inv
+
+    def turns_at(rotations):
+        return (head_dim * math.log(yarn_original_max
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(yarn_beta_fast)), 0)
+    high = min(math.ceil(turns_at(yarn_beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return inv / yarn_factor * ramp + inv * (1.0 - ramp)
+
+
+@register("_contrib_rope", num_inputs=1, aliases=["rope"])
+def rope(data, *, theta: float = 10000.0, yarn_factor: float = 0.0,
+         yarn_original_max: int = 0, yarn_beta_fast: float = 32.0,
+         yarn_beta_slow: float = 1.0, attention_factor: float = 1.0):
+    """Rotary positions over the whole head dimension of (B, L, H, D):
+    position ``t`` of axis 1 rotates the pairs (i, i + D/2) by
+    ``t * inv_freq_i`` (the half-split layout of ``transformers``);
+    ``attention_factor`` scales cos and sin (YaRN)."""
+    with jax.named_scope("mx.rope"):
+        D = data.shape[-1]
+        inv = jnp.asarray(rope_inv_freq(
+            D, theta, yarn_factor, yarn_original_max, yarn_beta_fast,
+            yarn_beta_slow), jnp.float32)
+        angle = jnp.arange(data.shape[1], dtype=jnp.float32)[:, None] * inv
+        cos = (jnp.cos(angle) * attention_factor)[None, :, None, :]
+        sin = (jnp.sin(angle) * attention_factor)[None, :, None, :]
+        x = data.astype(jnp.float32)
+        a, b = x[..., :D // 2], x[..., D // 2:]
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+        return out.astype(data.dtype)

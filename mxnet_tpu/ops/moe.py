@@ -1,104 +1,174 @@
-"""Mixture-of-Experts operators (GShard-style dense routing).
+"""Mixture-of-experts operators: top-k routing over all experts,
+dropless grouped expert FFNs over the experts held here.
 
 New TPU-first capability — the reference has no MoE (SURVEY.md §2.4:
-EP is ABSENT upstream; flagged as new capability for the pod-scale
-north star).  Design follows the GShard/Switch dispatch pattern the TPU
-ecosystem standardized on: routing is expressed as dense one-hot
-einsums over a fixed expert ``capacity`` (never ragged gathers), so the
-whole layer is a handful of MXU matmuls that XLA shards cleanly — with
-the expert dimension partitioned over the mesh's ``ep`` axis, the
-dispatch/combine einsums lower to all-to-alls on ICI.
+EP is ABSENT upstream).  The layer is told which experts it holds (a
+contiguous range of the router's width: all of them on one chip, one
+chip's share under expert parallelism), routes every token over ALL
+experts, and computes its own experts' part of the result:
+
+- ``mx.moe.route``: softmax over all experts in float32 at "highest"
+  precision (a bfloat16 router flips near-ties), the k largest,
+  renormalised over all k chosen, held here or not;
+- ``mx.moe.dispatch``: the (token, expert) pairs sorted by held expert
+  (pairs whose expert is held elsewhere sort to the tail) and each
+  pair's token row gathered into that order;
+- ``mx.moe.experts``: two grouped products over the held experts
+  (``jax.lax.ragged_dot``: on the TPU a grouped-matmul kernel whose
+  tiles follow the group sizes, so the work follows the rows that are
+  routed here, not the worst case of k rows a token);
+- ``mx.moe.combine``: rows back in pair order, weighted, summed over
+  each token's k pairs.  A token none of whose experts is held gets
+  zero.
+
+No capacity and no dropped token: the pair buffer has S*k rows, the
+worst case.  No exchange: on one chip there is none, and nothing stands
+in for the absent chips.
 
 Ops:
-  ``moe_top1_dispatch`` — router: gate probs -> combine/dispatch tensors
-  ``moe_ffn``           — full MoE FFN block (router + expert MLPs)
+  ``moe_topk_route`` — router: tokens x router weight -> (weights, ids)
+  ``moe_ffn``        — the whole layer; also counts the rows it routed
+                       to each held expert
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .registry import register
 
-__all__ = ["moe_top1_dispatch", "moe_ffn"]
+__all__ = ["moe_topk_route", "moe_ffn"]
+
+_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+                "gelu": functools.partial(jax.nn.gelu, approximate=False)}
 
 
-def _top1_tensors(gates, capacity):
-    """gates (S, E) -> combine (S, E, C), dispatch bool (S, E, C),
-    aux_loss (Switch load-balancing loss)."""
-    S, E = gates.shape
-    expert = jnp.argmax(gates, axis=-1)                   # (S,)
-    onehot = jax.nn.one_hot(expert, E, dtype=gates.dtype)  # (S, E)
-    # position of each token within its expert's queue
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0        # (S, E)
-    keep = (pos >= 0) & (pos < capacity)
-    pos_cap = jnp.clip(pos, 0, capacity - 1).astype(jnp.int32)
-    pos_onehot = jax.nn.one_hot(pos_cap, capacity,
-                                dtype=gates.dtype)        # (S, E, C)
-    dispatch = pos_onehot * keep.astype(gates.dtype)[..., None]
-    gate_val = jnp.sum(gates * onehot, axis=-1, keepdims=True)  # (S, 1)
-    combine = dispatch * gate_val[..., None]
-    # Switch-transformer aux loss: E * sum_e (frac_tokens_e * mean_gate_e)
-    frac = onehot.mean(axis=0)
-    mean_gate = gates.mean(axis=0)
-    aux = E * jnp.sum(frac * mean_gate)
-    return combine, dispatch, aux
+@register("_contrib_moe_topk_route", num_inputs=2, num_outputs=2,
+          aliases=["moe_topk_route"])
+def moe_topk_route(x, gate_weight, *, experts_per_token: int = 1):
+    """Top-k router.  ``x`` (S, C), ``gate_weight`` (C, E).
 
-
-@register("_contrib_moe_top1_dispatch", num_outputs=3,
-          aliases=["moe_top1_dispatch"])
-def moe_top1_dispatch(gate_logits, *, capacity: int = 0,
-                      capacity_factor: float = 1.25):
-    """Top-1 (Switch) router. ``gate_logits``: (S, E).
-
-    Returns (combine (S,E,C), dispatch (S,E,C), aux_loss ()).  Tokens
-    beyond an expert's capacity are dropped (their combine weights are
-    zero — the residual connection carries them, as in GShard).
+    Returns (weights (S, k) float32, ids (S, k) int32): the k largest
+    of ``softmax(x @ gate_weight)`` a token, largest first (ties to the
+    lower id), divided by their sum.
     """
-    S, E = gate_logits.shape
-    cap = int(capacity) if capacity else \
-        max(1, int(capacity_factor * S / E))
-    gates = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    combine, dispatch, aux = _top1_tensors(gates, cap)
-    return (combine.astype(gate_logits.dtype),
-            dispatch.astype(gate_logits.dtype), aux)
+    with jax.named_scope("mx.moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         gate_weight.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, ids = lax.top_k(probs, int(experts_per_token))
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, ids.astype(jnp.int32)
 
 
-@register("_contrib_moe_ffn", num_inputs=6, num_outputs=2,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_of_tokens(x, order, inverse, held, k):
+    """Row ``order[i] // k`` of ``x`` for every sorted pair ``i``.  The
+    backward is a gather too (``order`` is a permutation of the pairs):
+    XLA's own transpose would be a scatter-add of S*k rows."""
+    return x[order // k]
+
+
+def _rows_of_tokens_fwd(x, order, inverse, held, k):
+    return x[order // k], (inverse, held)
+
+
+def _rows_of_tokens_bwd(k, res, g):
+    inverse, held = res
+    # the rows of pairs held elsewhere were never computed
+    pairs = jnp.where(held[:, None], g[inverse], 0)
+    return pairs.reshape(-1, k, g.shape[-1]).sum(1), None, None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(y, perm, inverse):
+    """``y[perm]`` for a permutation whose inverse is known: the
+    backward is ``g[inverse]``, a gather, not a scatter."""
+    return y[perm]
+
+
+_permute_rows.defvjp(lambda y, perm, inverse: (y[perm], (inverse,)),
+                     lambda res, g: (g[res[0]], None, None))
+
+
+def _experts_part(xs, weights, ids, w1, w2, first_expert, activation,
+                  gated):
+    """(the held experts' part of the layer's output (S, C), rows routed
+    to each held expert (n_held,) float32)."""
+    S, C = xs.shape
+    k = ids.shape[1]
+    n_held = w1.shape[0]
+    act = _ACTIVATIONS[activation]
+    with jax.named_scope("mx.moe.dispatch"):
+        local = ids.reshape(-1) - first_expert                 # (S*k,)
+        held = (local >= 0) & (local < n_held)
+        key = jnp.where(held, local, n_held)
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                        axis=0, dtype=jnp.int32)               # (n_held,)
+        rows = _rows_of_tokens(xs, order, inverse, held, k)    # (S*k, C)
+    with jax.named_scope("mx.moe.experts"):
+        h = lax.ragged_dot(rows, w1, sizes)
+        if gated:
+            gate, up = jnp.split(h, 2, axis=-1)
+            h = act(gate) * up
+        else:
+            h = act(h)
+        y = lax.ragged_dot(h, w2, sizes)                       # (S*k, C)
+    with jax.named_scope("mx.moe.combine"):
+        pairs = _permute_rows(y, inverse, order).reshape(S, k, C)
+        held = held.reshape(S, k)
+        # the tail of ``y`` belongs to no group: whatever it holds, a
+        # pair held elsewhere adds nothing
+        pairs = jnp.where(held[..., None], pairs, 0)
+        out = jnp.einsum("skc,sk->sc", pairs,
+                         jnp.where(held, weights, 0).astype(pairs.dtype))
+    return out, sizes.astype(jnp.float32)
+
+
+@register("_contrib_moe_ffn", num_inputs=4, num_outputs=2,
           aliases=["moe_ffn"])
-def moe_ffn(x, wg, w1, b1, w2, b2, *, capacity_factor: float = 1.25,
-            activation: str = "gelu"):
-    """Full MoE FFN: route -> expert MLPs -> combine.
+def moe_ffn(x, wg, w1, w2, *, experts_per_token: int = 1,
+            first_expert: int = 0, activation: str = "gelu",
+            gated: bool = False, recompute: bool = False):
+    """The expert layer: route over all experts, compute the held
+    experts' part.
 
-    x (B, L, C) or (S, C); wg (C, E); w1 (E, C, H); b1 (E, H);
-    w2 (E, H, C); b2 (E, C).  Returns (out with x's shape, aux_loss ())
-    — add ``aux_weight * aux_loss`` to the training loss to balance
-    expert load (Switch-transformer recipe).
+    x (B, L, C) or (S, C); wg (C, E), E the router's width; w1
+    (n_held, C, H), or (n_held, C, 2H) laid out [gate | up] when
+    ``gated``; w2 (n_held, H, C).  The layer holds experts
+    ``first_expert .. first_expert + n_held - 1``.  Returns (out with
+    x's shape, rows (n_held,) float32: the (token, expert) pairs this
+    call routed to each held expert).  ``recompute`` saves nothing of
+    dispatch, experts and combine for the backward pass and computes
+    them again there: their S*k-row buffers are most of a long
+    sequence's saved activations.
     """
-    orig_shape = x.shape
-    C = orig_shape[-1]
-    xs = x.reshape(-1, C)                                 # (S, C)
-    S = xs.shape[0]
-    E = w1.shape[0]
-    cap = max(1, int(capacity_factor * S / E))
-
-    if activation not in ("relu", "gelu"):
+    if activation not in _ACTIVATIONS:
         from ..base import MXNetError
         raise MXNetError(
             f"moe_ffn: unsupported activation {activation!r} "
-            f"(supported: 'relu', 'gelu')")
-    gates = jax.nn.softmax(
-        (xs.astype(jnp.float32) @ wg.astype(jnp.float32)), axis=-1)
-    combine, dispatch, aux = _top1_tensors(gates, cap)
-    combine = combine.astype(xs.dtype)
-    dispatch = dispatch.astype(xs.dtype)
-
-    expert_in = jnp.einsum("sec,sm->ecm", dispatch, xs)   # (E, cap, C)
-    h = jnp.einsum("ecm,emh->ech", expert_in, w1) + b1[:, None, :]
-    if activation == "relu":
-        h = jax.nn.relu(h)
-    else:
-        h = jax.nn.gelu(h)
-    expert_out = jnp.einsum("ech,ehm->ecm", h, w2) + b2[:, None, :]
-    out = jnp.einsum("sec,ecm->sm", combine, expert_out)  # (S, C)
-    return out.reshape(orig_shape), aux
+            f"(supported: {sorted(_ACTIVATIONS)})")
+    n_held, E = w1.shape[0], wg.shape[1]
+    if not 0 <= first_expert <= E - n_held:
+        from ..base import MXNetError
+        raise MXNetError(
+            f"moe_ffn: experts {first_expert}..{first_expert + n_held - 1} "
+            f"held, the router has {E}")
+    xs = x.reshape(-1, x.shape[-1])
+    weights, ids = moe_topk_route(xs, wg,
+                                  experts_per_token=experts_per_token)
+    part = functools.partial(_experts_part, first_expert=int(first_expert),
+                             activation=activation, gated=bool(gated))
+    if recompute:
+        part = jax.checkpoint(part)
+    out, rows = part(xs, weights.astype(xs.dtype), ids, w1, w2)
+    return out.reshape(x.shape), rows

@@ -177,17 +177,22 @@ def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    sm_scale, causal, block_q, block_k, nq, window=-1):
+                    sm_scale, causal, block_q, block_k, nq, window=-1,
+                    group=1):
+    # one key/value head a grid row; the innermost axis walks the q
+    # blocks of each of the ``group`` query heads that read it, so dK
+    # and dV are summed over the group in the scratch accumulators
     b = pl.program_id(0)
     ik = pl.program_id(1)
-    iq = pl.program_id(2)
+    step = pl.program_id(2)
+    iq = step % nq
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    kv_len = lens_ref[b]
+    kv_len = lens_ref[b * group]
     q_start = iq * block_q
     k_start = ik * block_k
     needed = k_start < kv_len
@@ -221,7 +226,7 @@ def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (bk, D)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -230,20 +235,43 @@ def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
-def _specs(block_q, block_k, D, Lq, Lk, order):
-    """BlockSpecs for (lens, q, k, v[, do, lse, delta]) given grid axis
-    order: 'qk' = (b, iq, ik), 'kq' = (b, ik, iq)."""
+def _specs(block_q, block_k, D, order, group=1, nq=1, nk=1,
+           causal=False, window=-1):
+    """BlockSpecs for (q, k, row statistics) given the grid axis order:
+    'qk' = (query head, iq, ik), 'kq' = (key/value head, ik, walk over
+    the group's query heads and their q blocks).  Query head ``h`` reads
+    key/value head ``h // group``: the index map does the grouping, so
+    K and V are never repeated in HBM.  Under a causal mask the index of
+    the operand that the innermost axis walks is clamped to the blocks
+    the kernel computes on: a skipped step then names the block already
+    in VMEM and fetches nothing."""
+    def k_range(i):             # k blocks a q block needs
+        if not causal:
+            return 0, nk - 1
+        hi = jnp.minimum((i * block_q + block_q - 1) // block_k, nk - 1)
+        lo = jnp.maximum(i * block_q - (window - 1), 0) // block_k \
+            if window > 0 else 0
+        return lo, hi
+
+    def q_range(i):             # q blocks a k block needs
+        if not causal:
+            return 0, nq - 1
+        lo = jnp.minimum((i * block_k) // block_q, nq - 1)
+        hi = jnp.minimum((i * block_k + block_k - 1 + window - 1)
+                         // block_q, nq - 1) if window > 0 else nq - 1
+        return lo, hi
+
     if order == "qk":
         qi = lambda b, i, j: (b, i, 0)          # noqa: E731
-        ki = lambda b, i, j: (b, j, 0)          # noqa: E731
-        rowi = lambda b, i, j: (b, i, 0)        # noqa: E731
+        ki = lambda b, i, j: (b // group,       # noqa: E731
+                              jnp.clip(j, *k_range(i)), 0)
     else:
-        qi = lambda b, i, j: (b, j, 0)          # noqa: E731
+        qi = lambda b, i, j: (b * group + j // nq,      # noqa: E731
+                              jnp.clip(j % nq, *q_range(i)), 0)
         ki = lambda b, i, j: (b, i, 0)          # noqa: E731
-        rowi = lambda b, i, j: (b, j, 0)        # noqa: E731
     q_spec = pl.BlockSpec((1, block_q, D), qi)
     k_spec = pl.BlockSpec((1, block_k, D), ki)
-    row_spec = pl.BlockSpec((1, block_q, 1), rowi)
+    row_spec = pl.BlockSpec((1, block_q, 1), qi)
     return q_spec, k_spec, row_spec
 
 
@@ -272,8 +300,10 @@ def _flash_fwd(q, k, v, lens, causal, sm_scale, block_q, block_k,
                interpret, window):
     BH, Lq, D = q.shape
     Lk = k.shape[1]
+    group = BH // k.shape[0]
     nq, nk = Lq // block_q, Lk // block_k
-    q_spec, k_spec, row_spec = _specs(block_q, block_k, D, Lq, Lk, "qk")
+    q_spec, k_spec, row_spec = _specs(block_q, block_k, D, "qk", group,
+                                      nq, nk, causal, window)
     lens_spec = _lens_spec()
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, block_q=block_q,
@@ -295,13 +325,15 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
                res, dout):
     q, k, v, lens, out, lse = res
     BH, Lq, D = q.shape
-    Lk = k.shape[1]
+    BHk, Lk = k.shape[:2]
+    group = BH // BHk
     nq, nk = Lq // block_q, Lk // block_k
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)                  # (BH, Lq, 1)
     lens_spec = _lens_spec()
 
-    q_spec, k_spec, row_spec = _specs(block_q, block_k, D, Lq, Lk, "qk")
+    q_spec, k_spec, row_spec = _specs(block_q, block_k, D, "qk", group,
+                                      nq, nk, causal, window)
     dq = _run(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
@@ -313,17 +345,18 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         [_scratch((block_q, D), jnp.float32)],
         (lens, q, k, v, dout, lse, delta), interpret)
 
-    q_spec2, k_spec2, row_spec2 = _specs(block_q, block_k, D, Lq, Lk,
-                                         "kq")
+    q_spec2, k_spec2, row_spec2 = _specs(block_q, block_k, D, "kq",
+                                         group, nq, nk, causal, window)
     dk, dv = _run(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
-                          block_k=block_k, nq=nq, window=window),
-        (BH, nk, nq),
+                          block_k=block_k, nq=nq, window=window,
+                          group=group),
+        (BHk, nk, group * nq),
         [lens_spec, q_spec2, k_spec2, k_spec2, q_spec2, row_spec2,
          row_spec2],
-        (jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
-         jax.ShapeDtypeStruct((BH, Lk, D), v.dtype)),
+        (jax.ShapeDtypeStruct((BHk, Lk, D), k.dtype),
+         jax.ShapeDtypeStruct((BHk, Lk, D), v.dtype)),
         (k_spec2, k_spec2),
         [_scratch((block_k, D), jnp.float32),
          _scratch((block_k, D), jnp.float32)],
@@ -362,6 +395,10 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
                     window=None):
     """Fused attention over (B*H, L, D) tensors.
 
+    ``k`` and ``v`` may have fewer heads, (B*Hkv, L, D) with Hkv
+    dividing H: query head ``h`` then reads key/value head
+    ``h // (H // Hkv)`` (grouped-query attention) through the kernels'
+    block index maps, dK and dV summed over each group's query heads.
     ``lengths``: optional int32 (B*H,) valid key lengths (padding mask).
     ``window``: optional causal sliding-window width — query q attends
     keys in [q-window+1, q] (Mistral/Longformer-style local attention);
@@ -372,6 +409,11 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     """
     BH, Lq, D = q.shape
     Lk = k.shape[1]
+    if BH % k.shape[0] or v.shape != k.shape:
+        from ..base import MXNetError
+        raise MXNetError(
+            f"flash_attention: {k.shape[0]} key/value heads (k {k.shape}, "
+            f"v {v.shape}) do not group {BH} query heads")
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(D))
     if interpret is None:
@@ -428,6 +470,29 @@ def flash_selfatt(queries_keys_values, valid_length, *, heads: int = 1,
                           window=None if window <= 0 else window)
     return out.reshape(B, heads, L, D).transpose(2, 0, 1, 3).reshape(
         L, B, heads * D)
+
+
+@register("_contrib_flash_attention", num_inputs=3,
+          aliases=["flash_attention"])
+def flash_attention_heads(q, k, v, *, causal: bool = False,
+                          window: int = -1):
+    """Flash attention over separate projections: q (B, L, H, D), k and
+    v (B, L, Hkv, D) with Hkv dividing H (query head ``h`` reads
+    key/value head ``h // (H // Hkv)``).  ``window > 0``: causal
+    sliding window of that width.  Returns (B, L, H, D) in q's dtype.
+    """
+    B, L, H, D = q.shape
+    Hkv = k.shape[2]
+    # the scope the device metrics find the kernels by, forward and
+    # (as transpose(jvp(...))) backward
+    with jax.named_scope("mx.attn.window" if window > 0
+                         else "mx.attn.full"):
+        out = flash_attention(
+            q.transpose(0, 2, 1, 3).reshape(B * H, L, D),
+            k.transpose(0, 2, 1, 3).reshape(B * Hkv, k.shape[1], D),
+            v.transpose(0, 2, 1, 3).reshape(B * Hkv, v.shape[1], D),
+            causal=causal, window=None if window <= 0 else window)
+        return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)
 
 
 @register("_contrib_flash_selfatt_nomask", num_inputs=1,

@@ -92,12 +92,14 @@ MEGATRON_RULES = ShardingRules([
     (r"(word_embed|tgt_embed|src_embed).*weight$", P(None, "tp")),
     (r"mlm_decoder_weight$", P("tp", None)),
     (r"mlm_decoder_bias$", P("tp")),
+    (r"lm_head_weight$", P("tp", None)),
     # MoE experts: dim 0 is the expert dim, sharded over the ep axis;
-    # the hidden dim additionally takes tp (GShard layout)
+    # the hidden dim additionally takes tp (GShard layout).  The count
+    # of rows routed to each held expert lies with its expert; the
+    # router (gate_weight) and the norms' gains are replicated
     (r"expert_w1$", P("ep", None, "tp")),
-    (r"expert_b1$", P("ep", "tp")),
     (r"expert_w2$", P("ep", "tp", None)),
-    (r"expert_b2$", P("ep", None)),
+    (r"rows_routed$", P("ep")),
 ], default=P())
 
 

@@ -58,13 +58,16 @@ class ShardedTrainer:
     loss_fn(outputs, *labels) -> scalar, written in jnp over raw arrays.
     Batch dims of inputs/labels are sharded over "dp"; params follow
     ``rules`` (default Megatron TP).  Donation gives in-place updates.
+    ``take_block_params=True`` frees the block's own parameter buffers
+    once copied (nothing is held twice); the block is unusable until
+    ``write_back()``.
     """
 
     def __init__(self, block, loss_fn, mesh: Mesh, optimizer="adamw",
                  optimizer_params=None, rules=MEGATRON_RULES,
                  example_inputs=(), n_labels=1, dtype=None,
                  compression=None, step_timeout_ms=None,
-                 slow_step_factor=None):
+                 slow_step_factor=None, take_block_params=False):
         if optimizer not in _OPTIMS:
             raise MXNetError(f"unknown optimizer {optimizer!r}; "
                              f"known: {sorted(_OPTIMS)}")
@@ -119,7 +122,16 @@ class ShardedTrainer:
                 return jnp.array(a, dtype=dtype, copy=True)
             return jnp.array(a, copy=True)
 
-        params = {n: _own(a) for n, a in params.items()}
+        # ``take_block_params``: each of the block's buffers is freed as
+        # soon as the trainer has its copy, so that no parameter is held
+        # twice (a model near the device's memory cannot afford the
+        # block's dead copy).  The block's parameters are then invalid
+        # until ``write_back()``.
+        for n in list(params):
+            theirs = params[n]
+            params[n] = _own(theirs)
+            if take_block_params:
+                theirs.delete()
         self.params, self.param_shardings = partition_params(
             params, mesh, rules)
         self.opt_state = opt_init(self.params)

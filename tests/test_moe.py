@@ -1,4 +1,5 @@
-"""MoE ops + gluon.contrib.MoEFFN + expert-parallel sharding."""
+"""ops/moe.py (top-k dropless routing, the held experts' share) +
+gluon.contrib.MoEFFN + expert-parallel sharding."""
 import numpy as np
 import pytest
 
@@ -8,102 +9,192 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import nd, autograd, gluon, parallel
 from mxnet_tpu.gluon.contrib import MoEFFN
+from mxnet_tpu.ops.moe import moe_ffn, moe_topk_route
+
+ACT = {"relu": lambda x: np.maximum(x, 0),
+       "silu": lambda x: x / (1 + np.exp(-x))}
 
 
-def test_top1_dispatch_routing():
-    from mxnet_tpu.ops.moe import moe_top1_dispatch
-    logits = jnp.asarray([[2.0, 0.0], [0.0, 3.0], [1.5, 0.1],
-                          [0.0, 2.5]], jnp.float32)      # S=4, E=2
-    combine, dispatch, aux = moe_top1_dispatch(logits, capacity=2)
-    d = np.asarray(dispatch)
-    # token 0, 2 -> expert 0 at positions 0, 1; token 1, 3 -> expert 1
-    assert d[0, 0, 0] == 1 and d[2, 0, 1] == 1
-    assert d[1, 1, 0] == 1 and d[3, 1, 1] == 1
-    # each token dispatched exactly once
-    np.testing.assert_allclose(d.sum(axis=(1, 2)), 1.0)
-    # combine carries the softmax gate of the chosen expert
-    gates = np.asarray(jax.nn.softmax(np.asarray(logits), axis=-1))
-    np.testing.assert_allclose(np.asarray(combine).sum(axis=(1, 2)),
-                               gates.max(axis=1), rtol=1e-6)
-    assert np.isfinite(float(aux))
+def dense_moe(x, wg, w1, w2, k, first=0, activation="silu", gated=True):
+    """Every held expert on every token, weighted, in float64 numpy."""
+    x, wg, w1, w2 = (np.asarray(a, np.float64) for a in (x, wg, w1, w2))
+    logits = x @ wg
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    ids = np.argsort(-p, axis=-1, kind="stable")[:, :k]
+    w = np.take_along_axis(p, ids, -1)
+    w = w / w.sum(-1, keepdims=True)
+    full = np.zeros_like(p)
+    np.put_along_axis(full, ids, w, -1)
+    out = np.zeros_like(x)
+    for e in range(w1.shape[0]):
+        h = x @ w1[e]
+        if gated:
+            gate, up = np.split(h, 2, -1)
+            h = ACT[activation](gate) * up
+        else:
+            h = ACT[activation](h)
+        out += full[:, first + e:first + e + 1] * (h @ w2[e])
+    return out, ids, w
 
 
-def test_top1_capacity_drop():
-    from mxnet_tpu.ops.moe import moe_top1_dispatch
-    # all four tokens prefer expert 0; capacity 2 drops the last two
-    logits = jnp.asarray([[5.0, 0.0]] * 4, jnp.float32)
-    combine, dispatch, aux = moe_top1_dispatch(logits, capacity=2)
-    d = np.asarray(dispatch)
-    np.testing.assert_allclose(d.sum(), 2.0)
-    np.testing.assert_allclose(d.sum(axis=(1, 2)), [1, 1, 0, 0])
+def _weights(seed, S=48, C=8, H=16, E=8, gated=True):
+    rng = np.random.RandomState(seed)
+    f = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32))  # noqa: E731
+    return (f(S, C), f(C, E), 0.3 * f(E, C, (2 if gated else 1) * H),
+            0.3 * f(E, H, C))
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_topk_route(k):
+    x, wg, _w1, _w2 = _weights(0)
+    with jax.default_matmul_precision("highest"):
+        w, ids = moe_topk_route(x, wg, experts_per_token=k)
+    _out, want_ids, want_w = dense_moe(x, wg, np.zeros((0, 8, 2)),
+                                       np.zeros((0, 1, 8)), k)
+    np.testing.assert_array_equal(np.asarray(ids), want_ids)
+    np.testing.assert_allclose(np.asarray(w), want_w, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, rtol=1e-6)
+    assert ids.dtype == jnp.int32 and w.dtype == jnp.float32
+
+
+def test_route_ties_go_to_the_lower_id():
+    x = jnp.ones((3, 4), jnp.float32)
+    w, ids = moe_topk_route(x, jnp.zeros((4, 6), jnp.float32),
+                            experts_per_token=2)
+    np.testing.assert_array_equal(np.asarray(ids), [[0, 1]] * 3)
+    np.testing.assert_allclose(np.asarray(w), 0.5, rtol=1e-6)
+
+
+@pytest.mark.parametrize("k,first,held,activation,gated", [
+    (1, 0, 8, "relu", False), (2, 0, 8, "silu", True),
+    (3, 2, 3, "silu", True), (8, 4, 4, "silu", True),
+    (2, 6, 2, "relu", False)])
+def test_moe_ffn_is_the_held_experts_weighted_sum(k, first, held,
+                                                  activation, gated):
+    x, wg, w1, w2 = _weights(1, gated=gated)
+    w1, w2 = w1[first:first + held], w2[first:first + held]
+    with jax.default_matmul_precision("highest"):
+        out, rows = jax.jit(lambda *a: moe_ffn(
+            *a, experts_per_token=k, first_expert=first,
+            activation=activation, gated=gated))(x, wg, w1, w2)
+    want, ids, _w = dense_moe(x, wg, w1, w2, k, first, activation, gated)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(
+        np.asarray(rows), [(ids == first + e).sum() for e in range(held)])
+
+
+def test_no_token_is_dropped_when_all_choose_the_same_experts():
+    # every token's 8 choices are experts 0..7 of 16: each of them gets
+    # all S rows (8 times the mean load), experts 8..15 none
+    S, C, H, E, k = 64, 8, 4, 16, 8
+    rng = np.random.RandomState(2)
+    x = jnp.asarray(np.abs(rng.randn(S, C)).astype(np.float32) + 0.1)
+    wg = np.zeros((C, E), np.float32)
+    wg[:, :8] = 1.0 + 0.01 * np.arange(8)
+    w1 = jnp.asarray(0.3 * rng.randn(E, C, 2 * H).astype(np.float32))
+    w2 = jnp.asarray(0.3 * rng.randn(E, H, C).astype(np.float32))
+    with jax.default_matmul_precision("highest"):
+        out, rows = moe_ffn(x, jnp.asarray(wg), w1, w2, experts_per_token=k,
+                            activation="silu", gated=True)
+    np.testing.assert_array_equal(np.asarray(rows), [S] * 8 + [0] * 8)
+    want, _ids, _w = dense_moe(x, wg, w1, w2, k)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-6)
+
+
+def test_a_token_none_of_whose_experts_is_held_gets_zero():
+    x, wg, w1, w2 = _weights(3)
+    _o, ids, _w = dense_moe(x, wg, w1, w2, 2)
+    out, rows = moe_ffn(x, wg, w1[6:], w2[6:], experts_per_token=2,
+                        first_expert=6, activation="silu", gated=True)
+    unheld = ~np.isin(ids, (6, 7)).any(-1)
+    assert unheld.sum() > 5 and (~unheld).sum() > 5
+    assert not np.asarray(out)[unheld].any()
+    assert np.abs(np.asarray(out)[~unheld]).min(0).max() > 0
+    assert float(rows.sum()) == np.isin(ids, (6, 7)).sum()
 
 
 def test_moe_ffn_single_expert_equals_mlp():
-    from mxnet_tpu.ops.moe import moe_ffn
     rng = np.random.RandomState(0)
     S, C, H = 8, 4, 16
     x = jnp.asarray(rng.randn(S, C).astype(np.float32))
-    wg = jnp.zeros((C, 1), jnp.float32)
     w1 = jnp.asarray(rng.randn(1, C, H).astype(np.float32))
-    b1 = jnp.zeros((1, H), jnp.float32)
     w2 = jnp.asarray(rng.randn(1, H, C).astype(np.float32))
-    b2 = jnp.zeros((1, C), jnp.float32)
-    out, aux = moe_ffn(x, wg, w1, b1, w2, b2, capacity_factor=2.0,
-                       activation="relu")
-    # E=1: softmax gate == 1, so this IS the plain MLP
+    out, rows = moe_ffn(x, jnp.zeros((C, 1), jnp.float32), w1, w2,
+                        activation="relu")
+    # E=1: the one weight is 1, so this IS the plain MLP
     ref = np.maximum(np.asarray(x) @ np.asarray(w1[0]), 0) @ \
         np.asarray(w2[0])
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4,
-                               atol=1e-5)
-    np.testing.assert_allclose(float(aux), 1.0, rtol=1e-5)  # E*1*1
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
+    assert float(rows[0]) == S
 
 
-def test_moe_ffn_under_jit_and_grad():
-    from mxnet_tpu.ops.moe import moe_ffn
-    rng = np.random.RandomState(1)
-    B, L, C, H, E = 2, 8, 4, 8, 4
-    x = jnp.asarray(rng.randn(B, L, C).astype(np.float32))
-    wg = jnp.asarray(rng.randn(C, E).astype(np.float32))
-    w1 = jnp.asarray(rng.randn(E, C, H).astype(np.float32) * 0.1)
-    b1 = jnp.zeros((E, H), jnp.float32)
-    w2 = jnp.asarray(rng.randn(E, H, C).astype(np.float32) * 0.1)
-    b2 = jnp.zeros((E, C), jnp.float32)
+@pytest.mark.parametrize("recompute", [False, True])
+def test_moe_ffn_gradients_match_the_dense_layer(recompute):
+    x, wg, w1, w2 = _weights(4)
+    first, held, k = 2, 4, 3
+    args = (x, wg, w1[first:first + held], w2[first:first + held])
 
-    @jax.jit
-    def loss(wg, w1, b1, w2, b2):
-        out, aux = moe_ffn(x, wg, w1, b1, w2, b2)
-        return (out ** 2).sum() + 0.01 * aux
+    def dense(x, wg, w1, w2):
+        p = jax.nn.softmax(x @ wg, -1)
+        ids = jnp.argsort(-p, -1)[:, :k]
+        w = jnp.take_along_axis(p, ids, -1)
+        w = w / w.sum(-1, keepdims=True)
+        full = jnp.zeros_like(p).at[jnp.arange(x.shape[0])[:, None],
+                                    ids].set(w)
+        gate, up = jnp.split(jnp.einsum("sc,ech->seh", x, w1), 2, -1)
+        a = jax.nn.silu(gate) * up * full[:, first:first + held, None]
+        return jnp.einsum("seh,ehc->sc", a, w2)
 
-    grads = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(wg, w1, b1, w2, b2)
-    for g in grads:
-        assert np.isfinite(np.asarray(g)).all()
-    # routing gradient reaches the gate through combine weights
-    assert np.abs(np.asarray(grads[0])).max() > 0
+    proj = jnp.asarray(np.random.RandomState(5).randn(*x.shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda *a: (moe_ffn(
+            *a, experts_per_token=k, first_expert=first, activation="silu",
+            gated=True, recompute=recompute)[0] * proj).sum(),
+            argnums=(0, 1, 2, 3)))(*args)
+        want = jax.grad(lambda *a: (dense(*a) * proj).sum(),
+                        argnums=(0, 1, 2, 3))(*args)
+    for g, w in zip(got, want):
+        assert np.abs(np.asarray(w)).max() > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+
+
+def test_moe_ffn_refuses_a_share_outside_the_router():
+    x, wg, w1, w2 = _weights(6)
+    with pytest.raises(mx.base.MXNetError):
+        moe_ffn(x, wg, w1[:4], w2[:4], first_expert=6)
+    with pytest.raises(mx.base.MXNetError):
+        moe_ffn(x, wg, w1, w2, activation="tanh")
+    with pytest.raises(mx.base.MXNetError):
+        MoEFFN(8, 16, 8, experts_held=4, first_expert=6)
 
 
 def test_gluon_moe_block_eager_hybrid_parity():
     mx.random.seed(0)
     layer = MoEFFN(units=8, hidden_size=16, num_experts=4,
-                   capacity_factor=2.0)
+                   experts_per_token=2, gated=True, activation="silu")
     layer.initialize(mx.init.Xavier())
     x = nd.array(np.random.RandomState(2).randn(2, 6, 8)
                  .astype(np.float32))
-    out_e, aux_e = layer(x)
+    out_e = layer(x)
+    rows_e = layer.rows_routed.data().asnumpy().copy()
     layer.hybridize()
-    out_h, aux_h = layer(x)
-    out_h2, _ = layer(x)
+    out_h = layer(x)
     np.testing.assert_allclose(out_e.asnumpy(), out_h.asnumpy(),
                                rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(float(aux_e.asscalar()),
-                               float(aux_h.asscalar()), rtol=1e-5)
+    # the cumulative count: 12 tokens x 2 experts a call, eager or traced
+    assert rows_e.sum() == 24
+    np.testing.assert_array_equal(layer.rows_routed.data().asnumpy(),
+                                  2 * rows_e)
 
 
-def test_moe_trains_with_gradient():
-    # tiny regression: MoE layer + residual learns a mapping; aux loss
-    # balances experts
+@pytest.mark.parametrize("k", [1, 2])
+def test_moe_trains_with_gradient(k):
+    # tiny regression: MoE layer + residual learns a mapping
     mx.random.seed(1)
     layer = MoEFFN(units=4, hidden_size=8, num_experts=2,
-                   capacity_factor=2.0, activation="relu")
+                   experts_per_token=k, activation="relu")
     layer.initialize(mx.init.Xavier())
     trainer = gluon.Trainer(layer.collect_params(), "adam",
                             {"learning_rate": 5e-3})
@@ -114,8 +205,7 @@ def test_moe_trains_with_gradient():
     for i in range(120):
         x, y = nd.array(X), nd.array(Y)
         with autograd.record():
-            out, aux = layer(x)
-            loss = ((out + x - y) ** 2).mean() + 0.01 * aux
+            loss = ((layer(x) + x - y) ** 2).mean()
         loss.backward()
         trainer.step(64)
         if i == 0:
@@ -126,7 +216,8 @@ def test_moe_trains_with_gradient():
 
 def test_expert_parallel_sharded_step():
     # dp=2 x ep=2 mesh on the virtual 8-device CPU backend: the expert
-    # dim must actually shard over ep, and one training step must run
+    # dim must actually shard over ep, and training steps must run and
+    # carry the count of routed rows in their state
     devices = jax.devices()[:4]
     mesh = parallel.make_mesh(dp=2, tp=1, sp=1, ep=2, devices=devices)
     assert mesh.shape["ep"] == 2
@@ -138,35 +229,33 @@ def test_expert_parallel_sharded_step():
             super().__init__(**kw)
             with self.name_scope():
                 self.moe = MoEFFN(units=8, hidden_size=16, num_experts=4,
-                                  capacity_factor=2.0)
+                                  experts_per_token=2, gated=True,
+                                  activation="silu")
 
         def hybrid_forward(self, F, x):
-            out, aux = self.moe(x)
-            return out + x, aux
+            return self.moe(x) + x
 
     net = Net()
     net.initialize(mx.init.Xavier())
-
-    def loss_fn(outputs, y):
-        out, aux = outputs
-        return ((out - y) ** 2).mean() + \
-            0.01 * aux.astype(jnp.float32)
 
     x = nd.array(np.random.RandomState(4).randn(8, 6, 8)
                  .astype(np.float32))
     y = nd.array(np.random.RandomState(5).randn(8, 6, 8)
                  .astype(np.float32))
     trainer = parallel.ShardedTrainer(
-        net, loss_fn, mesh, optimizer="adamw",
-        optimizer_params={"learning_rate": 1e-3},
+        net, lambda out, y: ((out - y) ** 2).mean(), mesh,
+        optimizer="adamw", optimizer_params={"learning_rate": 1e-3},
         example_inputs=(x,), n_labels=1)
-    loss = trainer.step(x, y)
+    for _ in range(2):
+        loss = trainer.step(x, y)
     assert np.isfinite(float(jax.device_get(loss)))
-    # the expert weights really live sharded over ep
-    w1 = [n for n in trainer.params if n.endswith("expert_w1")]
-    assert w1, list(trainer.params)[:8]
-    spec = trainer.params[w1[0]].sharding.spec
-    assert spec[0] == "ep", spec
+    # the expert weights and their counts really live sharded over ep
+    for leaf in ("expert_w1", "expert_w2", "rows_routed"):
+        name = [n for n in trainer.params if n.endswith(leaf)]
+        assert name, list(trainer.params)[:8]
+        assert trainer.params[name[0]].sharding.spec[0] == "ep", leaf
+    rows = np.asarray(trainer.params[name[0]])
+    assert rows.sum() == 2 * 48 * 2, rows     # steps x tokens x k
 
 
 def test_expert_rules_on_mesh_without_ep_axis():

@@ -729,6 +729,11 @@ ORACLES.update({
         _np_dense_selfatt(qkv, heads, vlen),
     "_contrib_flash_selfatt_nomask": lambda qkv, heads=1, **k:
         _np_dense_selfatt(qkv, heads, None),
+    "_contrib_flash_attention": lambda q, k, v, causal=False, window=-1:
+        _np_grouped_attention(q, k, v, causal, window),
+    "_contrib_rms_norm": lambda x, g, eps=1e-6:
+        x / np.sqrt((x * x).mean(-1, keepdims=True) + eps) * g,
+    "_contrib_rope": lambda x, **k: _np_rope(x, **k),
     # decode-path paged attention vs a per-sequence gather + dense
     # softmax (block-table indirection materialized in numpy)
     "_contrib_ragged_paged_attention": lambda q, kp, vp, bt, lens:
@@ -968,6 +973,56 @@ def _i8(rng, *shape):
 
 _MINMAX = lambda: [np.array([-1.0], np.float32), np.array([1.0], np.float32)]
 
+def _np_grouped_attention(q, k, v, causal, window):
+    """Dense softmax(QK^T)V with query head h reading key/value head
+    h // group, under the causal (and window) mask."""
+    B, L, H, D = q.shape
+    group = H // k.shape[2]
+    t = np.arange(L)
+    seen = np.ones((L, L), bool)
+    if causal:
+        seen &= t[None, :] <= t[:, None]
+    if window > 0:
+        seen &= t[None, :] > t[:, None] - window
+    out = np.zeros(q.shape, np.float64)
+    for h in range(H):
+        s = np.einsum("bqd,bkd->bqk", q[:, :, h], k[:, :, h // group]) \
+            / np.sqrt(D)
+        s = np.where(seen, s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[:, :, h] = np.einsum("bqk,bkd->bqd",
+                                 p / p.sum(-1, keepdims=True),
+                                 v[:, :, h // group])
+    return out
+
+
+def _np_rope(x, theta=10000.0, yarn_factor=0.0, yarn_original_max=0,
+             yarn_beta_fast=32.0, yarn_beta_slow=1.0, attention_factor=1.0):
+    """Rotary positions written out pair by pair (the YaRN blend as the
+    ``transformers`` library's ``_compute_yarn_parameters``)."""
+    import math
+    D = x.shape[-1]
+    out = np.zeros(x.shape, np.float64)
+    for i in range(D // 2):
+        f = theta ** (-2.0 * i / D)
+        if yarn_factor:
+            dim = lambda rot: D * math.log(        # noqa: E731
+                yarn_original_max / (rot * 2 * math.pi)) \
+                / (2 * math.log(theta))
+            low = max(math.floor(dim(yarn_beta_fast)), 0)
+            high = min(math.ceil(dim(yarn_beta_slow)), D - 1)
+            high += 0.001 * (low == high)
+            ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+            f = f / yarn_factor * ramp + f * (1.0 - ramp)
+        for t in range(x.shape[1]):
+            c = math.cos(t * f) * attention_factor
+            sn = math.sin(t * f) * attention_factor
+            a, b = x[:, t, :, i], x[:, t, :, i + D // 2]
+            out[:, t, :, i] = a * c - b * sn
+            out[:, t, :, i + D // 2] = b * c + a * sn
+    return out
+
+
 SPECS = {
     # ---------------- NN layers
     "Activation": dict(inputs=lambda r: [_f32(r, 2, 3)]),
@@ -1113,12 +1168,29 @@ SPECS = {
     "_contrib_interleaved_matmul_encdec_valatt": dict(
         inputs=lambda r: [_f32(r, 4, 2, 16), _pos(r, 4, 3, 4)],
         kwargs=dict(heads=2)),
+    # x, router (C, E), w1 (held, C, 2H) [gate | up], w2 (held, H, C):
+    # experts 1..2 of 3 held, 2 a token
     "_contrib_moe_ffn": dict(
-        inputs=lambda r: [_f32(r, 4, 3), _f32(r, 3, 2), _f32(r, 2, 3, 5),
-                          _f32(r, 2, 5), _f32(r, 2, 5, 3), _f32(r, 2, 3)],
+        inputs=lambda r: [_f32(r, 4, 3), _f32(r, 3, 3), _f32(r, 2, 3, 10),
+                          _f32(r, 2, 5, 3)],
+        kwargs=dict(experts_per_token=2, first_expert=1, activation="silu",
+                    gated=True),
         rtol=3e-2, atol=3e-3),
-    "_contrib_moe_top1_dispatch": dict(inputs=lambda r: [_f32(r, 4, 2)],
-                                       kwargs=dict(capacity=2)),
+    "_contrib_moe_topk_route": dict(
+        inputs=lambda r: [_f32(r, 4, 3), _f32(r, 3, 5)],
+        kwargs=dict(experts_per_token=2)),
+    "_contrib_rms_norm": dict(inputs=lambda r: [_f32(r, 2, 3, 8),
+                                                _pos(r, 8)]),
+    "_contrib_rope": dict(
+        inputs=lambda r: [_f32(r, 1, 5, 2, 8)],
+        kwargs=dict(theta=100.0, yarn_factor=4.0, yarn_original_max=64,
+                    attention_factor=1.1)),
+    # q (B, L, H, D), k and v (B, L, Hkv, D): 2 query heads a key/value
+    # head, window 3
+    "_contrib_flash_attention": dict(
+        inputs=lambda r: [_f32(r, 1, 8, 4, 8), _f32(r, 1, 8, 2, 8),
+                          _f32(r, 1, 8, 2, 8)],
+        kwargs=dict(causal=True, window=3), rtol=3e-2, atol=3e-3),
     "_contrib_multi_lars": dict(
         inputs=lambda r: [_pos(r, 3), _pos(r, 3), _pos(r, 3),
                           _pos(r, 3)]),
@@ -1387,7 +1459,7 @@ def test_forward(name):
 # sweep here plus the cheap analytic gradient parity in
 # tests/test_pallas.py::test_flash_grads_match_dense.
 SLOW_GRAD = {"_contrib_flash_selfatt", "_contrib_flash_selfatt_nomask",
-             "CTCLoss"}
+             "_contrib_flash_attention", "CTCLoss"}
 
 DIFF = [pytest.param(n, marks=pytest.mark.slow) if n in SLOW_GRAD else n
         for n in CANONICAL
@@ -1486,7 +1558,7 @@ def test_sweep_budget():
     allowed_no_oracle = {
         "BilinearResize2D", "Correlation", "MultiBoxDetection",
         "MultiBoxTarget", "_contrib_moe_ffn",
-        "_contrib_moe_top1_dispatch", "_contrib_quantized_act",
+        "_contrib_moe_topk_route", "_contrib_quantized_act",
         "_contrib_quantized_conv", "_contrib_quantized_fully_connected",
         "_contrib_quantized_pooling", "_linalg_gelqf", "_linalg_syevd",
         "_random_exponential", "_random_gamma",
