@@ -14,16 +14,22 @@ experts, and computes its own experts' part of the result:
   (pairs whose expert is held elsewhere sort to the tail) and each
   pair's token row gathered into that order;
 - ``mx.moe.experts``: two grouped products over the held experts
-  (``jax.lax.ragged_dot``: on the TPU a grouped-matmul kernel whose
-  tiles follow the group sizes, so the work follows the rows that are
-  routed here, not the worst case of k rows a token);
+  (``pallas_kernels.grouped_matmul``: where the shapes tile, a Pallas
+  grouped matmul whose row tiles follow the group sizes, so the work
+  follows the rows that are routed here, not the worst case of k rows a
+  token; rows and weights enter the MXU as bfloat16, results and
+  gradients are float32; other shapes take ``jax.lax.ragged_dot``);
 - ``mx.moe.combine``: rows back in pair order, weighted, summed over
   each token's k pairs.  A token none of whose experts is held gets
   zero.
 
 No capacity and no dropped token: the pair buffer has S*k rows, the
-worst case.  No exchange: on one chip there is none, and nothing stands
-in for the absent chips.
+worst case.  **The rows past the last held pair are undefined**: the
+kernels never visit them, so after the first product they hold whatever
+was in memory, NaN included, and the activation runs over that.  Every
+reader of those rows selects (``jnp.where(held, ...)``), never
+multiplies by a mask.  No exchange: on one chip there is none, and
+nothing stands in for the absent chips.
 
 Ops:
   ``moe_topk_route`` — router: tokens x router weight -> (weights, ids)
@@ -38,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .pallas_kernels import grouped_matmul
 from .registry import register
 
 __all__ = ["moe_topk_route", "moe_ffn"]
@@ -116,13 +123,13 @@ def _experts_part(xs, weights, ids, w1, w2, first_expert, activation,
                         axis=0, dtype=jnp.int32)               # (n_held,)
         rows = _rows_of_tokens(xs, order, inverse, held, k)    # (S*k, C)
     with jax.named_scope("mx.moe.experts"):
-        h = lax.ragged_dot(rows, w1, sizes)
+        h = grouped_matmul(rows, w1, sizes)
         if gated:
             gate, up = jnp.split(h, 2, axis=-1)
             h = act(gate) * up
         else:
             h = act(h)
-        y = lax.ragged_dot(h, w2, sizes)                       # (S*k, C)
+        y = grouped_matmul(h, w2, sizes)                       # (S*k, C)
     with jax.named_scope("mx.moe.combine"):
         pairs = _permute_rows(y, inverse, order).reshape(S, k, C)
         held = held.reshape(S, k)

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels: fused flash attention (forward + backward).
+"""Pallas TPU kernels: fused flash attention (forward + backward), the
+paged decode kernels, and the expert layer's grouped matmul.
 
 The reference's attention kernels (``src/operator/contrib/transformer.cc``,
 ``_contrib_interleaved_matmul_selfatt_*``) materialize the (L, L) score
@@ -33,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
 
-__all__ = ["flash_attention",
+__all__ = ["flash_attention", "grouped_matmul",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_verify", "ragged_paged_verify_reference"]
 
@@ -445,6 +446,278 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
                  block_k, bool(interpret),
                  -1 if window is None else int(window))
     return out[:, :Lq] if Lq_p != Lq else out
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul: the expert layer's products (ops/moe.py).  The schedule
+# is megablox's (jax.experimental.pallas.ops.tpu.megablox): row tiles
+# follow the group sizes through scalar prefetch, so the work follows the
+# rows that belong to a group and tiles past the last group are never
+# visited.
+
+# Picked on a v5e (PERF.md, PR 34).  A row tile is fetched whole; of its
+# 128-row blocks only those that hold a row of the group are multiplied,
+# so a group boundary costs 128 rows of work, not a tile.
+_GROUPED_ROW_TILE = 1024        # M is a multiple; the weights' gradient's
+_GROUPED_GMM_ROW_TILE = 512     # the products'; divides it
+_GROUPED_ROW_BLOCK = 128
+_GROUPED_VMEM_BYTES = 100 * 2 ** 20     # of a v5e core's 128 MiB
+_GROUPED_BLOCK_BYTES = 75 * 2 ** 20     # of it, the blocks' buffers
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _group_tiles(group_sizes, m, tm, visit_empty):
+    """The row tiles to visit, in order: for grid step ``i`` the group
+    ``gids[i]`` and the row tile ``tids[i]`` (a tile that holds a group
+    boundary is visited once for each group in it, consecutively);
+    ``offs[g] .. offs[g + 1]`` are group g's rows.  An empty group gets
+    one step if ``visit_empty`` (its output is still to be written).
+    Returns ((offs, gids, tids), number of steps).  jitted, like the
+    kernels' wrappers, so that a model's many calls share one trace
+    (tracing each anew cost the step's first call seconds)."""
+    G = group_sizes.shape[0]
+    ends = jnp.cumsum(group_sizes, dtype=jnp.int32)
+    first = (ends - group_sizes) // tm
+    count = jnp.where(group_sizes > 0, (ends + tm - 1) // tm - first,
+                      1 if visit_empty else 0).astype(jnp.int32)
+    until = jnp.cumsum(count)               # steps before the next group
+    step = jnp.arange(m // tm + G - 1, dtype=jnp.int32)     # the most
+    gids = jnp.minimum(jnp.sum(step[:, None] >= until[None, :], axis=1,
+                               dtype=jnp.int32), G - 1)
+    nth = step - (until - count)[gids]
+    tids = jnp.clip(first[gids] + nth, 0, m // tm - 1)
+    offs = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    return (offs, gids, tids), until[-1]
+
+
+def _live_blocks(offs_ref, g, row0, tm):
+    """(first, one past the last) of a row tile's 128-row blocks that
+    hold a row of group g; ``row0`` is the tile's first row."""
+    first = jnp.maximum(offs_ref[g] - row0, 0) // _GROUPED_ROW_BLOCK
+    last = pl.cdiv(jnp.minimum(offs_ref[g + 1] - row0, tm),
+                   _GROUPED_ROW_BLOCK)
+    return first, last
+
+
+def _block_rows(b):
+    return pl.ds(pl.multiple_of(b * _GROUPED_ROW_BLOCK, _GROUPED_ROW_BLOCK),
+                 _GROUPED_ROW_BLOCK)
+
+
+def _in_group(offs_ref, g, first_row, shape):
+    """Mask of ``shape``: the rows ``first_row ..`` that are group g's."""
+    row = first_row + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (row >= offs_ref[g]) & (row < offs_ref[g + 1])
+
+
+def _gmm_kernel(offs_ref, gids_ref, tids_ref, lhs_ref, rhs_ref, out_ref,
+                w_ref, *, transpose_rhs):
+    i = pl.program_id(1)
+    g = gids_ref[i]
+
+    # the group's weights enter the MXU as bfloat16: cast once a group,
+    # not once a row tile (the block stays in VMEM while the group lasts)
+    @pl.when((i == 0) | (gids_ref[jnp.maximum(i - 1, 0)] != g))
+    def _cast():
+        w_ref[...] = rhs_ref[...].astype(w_ref.dtype)
+
+    contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+    tm = lhs_ref.shape[0]
+    row0 = tids_ref[i] * tm
+
+    def block(b, _):
+        rows = _block_rows(b)
+        acc = jax.lax.dot_general(lhs_ref[rows, :], w_ref[...], contract,
+                                  preferred_element_type=jnp.float32)
+        # a tile on a group boundary is visited for each group in it:
+        # keep the other groups' rows (and whatever lies past the last
+        # group)
+        mine = _in_group(offs_ref, g, row0 + b * _GROUPED_ROW_BLOCK,
+                         acc.shape)
+        out_ref[rows, :] = jnp.where(mine, acc, out_ref[rows, :])
+
+    jax.lax.fori_loop(*_live_blocks(offs_ref, g, row0, tm), block, None)
+
+
+def _gmm_columns(K, N, rhs_itemsize):
+    """The widest column tile (a multiple of 128 dividing N) whose
+    blocks fit the VMEM budget with the whole of K: two buffers each of
+    weights, rows and output, and the bfloat16 weights.  None if even
+    128 columns do not fit."""
+    tm = _GROUPED_GMM_ROW_TILE
+    for tn in range(N, 0, -128):
+        need = (K * tn * (2 * rhs_itemsize + 2) + 2 * tm * K * 2
+                + 2 * tm * tn * 4)
+        if N % tn == 0 and need <= _GROUPED_BLOCK_BYTES:
+            return tn
+    return None
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
+    """``lhs[rows of g] @ rhs[g]`` (``rhs[g].T`` if ``transpose_rhs``)
+    for every group g: lhs (M, K) bfloat16, rhs (G, K, N) or (G, N, K),
+    result (M, N) float32, rows past the last group left as they were."""
+    M, K = lhs.shape
+    N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tm = _GROUPED_GMM_ROW_TILE
+    tn = _gmm_columns(K, N, rhs.dtype.itemsize)
+    meta, steps = _group_tiles(group_sizes, M, tm, False)
+    if transpose_rhs:
+        w_block = (None, tn, K)
+        w_index = lambda n, i, offs, gids, tids: (gids[i], n, 0)  # noqa: E731
+    else:
+        w_block = (None, K, tn)
+        w_index = lambda n, i, offs, gids, tids: (gids[i], 0, n)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(N // tn, steps),
+            in_specs=[
+                pl.BlockSpec((tm, K),
+                             lambda n, i, offs, gids, tids: (tids[i], 0)),
+                pl.BlockSpec(w_block, w_index)],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda n, i, offs, gids, tids: (tids[i], n)),
+            scratch_shapes=[_scratch(w_block[1:], jnp.bfloat16)]),
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=interpret,
+    )(*meta, lhs, rhs)
+
+
+def _tgmm_kernel(offs_ref, gids_ref, tids_ref, lhs_ref, rhs_ref, out_ref):
+    i = pl.program_id(0)
+    g = gids_ref[i]
+
+    @pl.when((i == 0) | (gids_ref[jnp.maximum(i - 1, 0)] != g))
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    tm = lhs_ref.shape[0]
+    row0 = tids_ref[i] * tm
+    contract = (((0,), (0,)), ((), ()))
+
+    def block(b, _):
+        rows = _block_rows(b)
+        r0 = row0 + b * _GROUPED_ROW_BLOCK
+        inside = ((r0 >= offs_ref[g])
+                  & (r0 + _GROUPED_ROW_BLOCK <= offs_ref[g + 1]))
+
+        @pl.when(inside)
+        def _whole():
+            out_ref[...] += jax.lax.dot_general(
+                lhs_ref[rows, :], rhs_ref[rows, :], contract,
+                preferred_element_type=jnp.float32)
+
+        # rows on a group boundary: the other groups' rows, and whatever
+        # lies past the last group (NaN included), are selected away
+        # from both operands
+        @pl.when(jnp.logical_not(inside))
+        def _boundary():
+            def mine(ref):
+                x = ref[rows, :]
+                return jnp.where(_in_group(offs_ref, g, r0, x.shape),
+                                 x.astype(jnp.float32), 0).astype(x.dtype)
+            out_ref[...] += jax.lax.dot_general(
+                mine(lhs_ref), mine(rhs_ref), contract,
+                preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(*_live_blocks(offs_ref, g, row0, tm), block, None)
+
+
+def _tgmm_fits(K, N):
+    """The weights' gradient keeps a group's whole (K, N) result in
+    VMEM, two buffers of it, beside two of each operand's row tile."""
+    return (2 * K * N * 4 + 2 * _GROUPED_ROW_TILE * (K + N) * 2
+            <= _GROUPED_BLOCK_BYTES)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _tgmm(lhs, rhs, group_sizes, interpret):
+    """``lhs[rows of g].T @ rhs[rows of g]`` for every group g: lhs
+    (M, K), rhs (M, N) bfloat16, result (G, K, N) float32, zero for a
+    group with no rows.  Rows past the last group are never read."""
+    (M, K), N = lhs.shape, rhs.shape[1]
+    G = group_sizes.shape[0]
+    tm = _GROUPED_ROW_TILE
+    meta, steps = _group_tiles(group_sizes, M, tm, True)
+    return pl.pallas_call(
+        _tgmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((tm, K),
+                             lambda i, offs, gids, tids: (tids[i], 0)),
+                pl.BlockSpec((tm, N),
+                             lambda i, offs, gids, tids: (tids[i], 0))],
+            out_specs=pl.BlockSpec(
+                (None, K, N), lambda i, offs, gids, tids: (gids[i], 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((G, K, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=interpret,
+    )(*meta, lhs, rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped(lhs, rhs, group_sizes, lhs_dtype, interpret):
+    return _grouped_fwd(lhs, rhs, group_sizes, lhs_dtype, interpret)[0]
+
+
+def _grouped_fwd(lhs, rhs, group_sizes, lhs_dtype, interpret):
+    lhs = lhs.astype(jnp.bfloat16)
+    # mxlint: disable=recompile-churn (interpret is a bool)
+    return (_gmm(lhs, rhs, group_sizes, False, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _grouped_bwd(lhs_dtype, interpret, res, g):
+    lhs, rhs, group_sizes = res
+    g = g.astype(jnp.bfloat16)
+    # mxlint: disable=recompile-churn (interpret is a bool)
+    d_lhs = _gmm(g, rhs, group_sizes, True, interpret)
+    # mxlint: disable=recompile-churn (interpret is a bool)
+    d_rhs = _tgmm(lhs, g, group_sizes, interpret)
+    return d_lhs.astype(lhs_dtype), d_rhs.astype(rhs.dtype), None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
+    """``lax.ragged_dot`` for the expert layer: ``lhs`` (M, K) rows
+    sorted by group, ``rhs`` (G, K, N), ``group_sizes`` (G,) int32 whose
+    sum is at most M; row i of the (M, N) float32 result is
+    ``lhs[i] @ rhs[g]`` for the group g that holds row i.
+
+    Where K and N are multiples of 128 and M of 1024 this is a Pallas
+    grouped matmul: operands enter the MXU as bfloat16 (the weights
+    cast a group at a time in VMEM), accumulation and result float32,
+    and so the two gradients (the rows' in ``lhs``'s dtype, the weights'
+    zero for a group with no rows).  **The rows past the last group are
+    not written**: they hold whatever was there, NaN included, and a
+    reader must select, never multiply by a mask.  Other shapes (and
+    weights that leave no room in VMEM) take ``lax.ragged_dot`` on the
+    operands as given, which zeroes those rows.
+    """
+    (M, K), N = lhs.shape, rhs.shape[2]
+    itemsize = rhs.dtype.itemsize
+    if (M % _GROUPED_ROW_TILE or K % 128 or N % 128
+            or _gmm_columns(K, N, itemsize) is None
+            or _gmm_columns(N, K, itemsize) is None
+            or not _tgmm_fits(K, N)):
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=jnp.float32)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _grouped(lhs, rhs, group_sizes.astype(jnp.int32),
+                    jnp.dtype(lhs.dtype), bool(interpret))
 
 
 # ---------------------------------------------------------------------------

@@ -129,35 +129,114 @@ def test_moe_ffn_single_expert_equals_mlp():
     assert float(rows[0]) == S
 
 
+def dense_layer(x, wg, w1, w2, k, first):
+    """Every held expert on every token, weighted: what ``moe_ffn``
+    (silu, gated) computes, differentiable."""
+    held = w1.shape[0]
+    p = jax.nn.softmax(x @ wg, -1)
+    ids = jnp.argsort(-p, -1)[:, :k]
+    w = jnp.take_along_axis(p, ids, -1)
+    w = w / w.sum(-1, keepdims=True)
+    full = jnp.zeros_like(p).at[jnp.arange(x.shape[0])[:, None],
+                                ids].set(w)
+    gate, up = jnp.split(jnp.einsum("sc,ech->seh", x, w1), 2, -1)
+    a = jax.nn.silu(gate) * up * full[:, first:first + held, None]
+    return jnp.einsum("seh,ehc->sc", a, w2)
+
+
+# (sizes, k, the router weight's scale, rtol, atol over the largest
+# element): the toy shape takes ``lax.ragged_dot``; S*k = 2048 rows of
+# C = 128 with H = 128 take the Pallas kernels, whose operands are
+# bfloat16
+SHAPES = {"ragged_dot": (dict(), 3, 1.0, 1e-4, 0.0),
+          "kernel": (dict(S=512, C=128, H=128), 4, 0.1, 2e-2, 2e-2)}
+
+
+def _takes_the_kernel(fn, *args):
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+def _assert_close(got, want, rtol, atol_of_max):
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    assert np.abs(want).max() > 0
+    np.testing.assert_allclose(
+        got, want, rtol=rtol, atol=1e-5 + atol_of_max * np.abs(want).max())
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
 @pytest.mark.parametrize("recompute", [False, True])
-def test_moe_ffn_gradients_match_the_dense_layer(recompute):
-    x, wg, w1, w2 = _weights(4)
-    first, held, k = 2, 4, 3
-    args = (x, wg, w1[first:first + held], w2[first:first + held])
-
-    def dense(x, wg, w1, w2):
-        p = jax.nn.softmax(x @ wg, -1)
-        ids = jnp.argsort(-p, -1)[:, :k]
-        w = jnp.take_along_axis(p, ids, -1)
-        w = w / w.sum(-1, keepdims=True)
-        full = jnp.zeros_like(p).at[jnp.arange(x.shape[0])[:, None],
-                                    ids].set(w)
-        gate, up = jnp.split(jnp.einsum("sc,ech->seh", x, w1), 2, -1)
-        a = jax.nn.silu(gate) * up * full[:, first:first + held, None]
-        return jnp.einsum("seh,ehc->sc", a, w2)
-
+def test_moe_ffn_gradients_match_the_dense_layer(recompute, shape):
+    sizes, k, scale, rtol, atol = SHAPES[shape]
+    x, wg, w1, w2 = _weights(4, **sizes)
+    first, held = 2, 4
+    args = (x, scale * wg, w1[first:first + held], w2[first:first + held])
     proj = jnp.asarray(np.random.RandomState(5).randn(*x.shape), jnp.float32)
+
+    def layer(*a):
+        return (moe_ffn(*a, experts_per_token=k, first_expert=first,
+                        activation="silu", gated=True,
+                        recompute=recompute)[0] * proj).sum()
+    assert _takes_the_kernel(layer, *args) == (shape == "kernel")
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda *a: (moe_ffn(
-            *a, experts_per_token=k, first_expert=first, activation="silu",
-            gated=True, recompute=recompute)[0] * proj).sum(),
-            argnums=(0, 1, 2, 3)))(*args)
-        want = jax.grad(lambda *a: (dense(*a) * proj).sum(),
+        got = jax.jit(jax.grad(layer, argnums=(0, 1, 2, 3)))(*args)
+        want = jax.grad(lambda *a: (dense_layer(*a, k, first) * proj).sum(),
                         argnums=(0, 1, 2, 3))(*args)
     for g, w in zip(got, want):
-        assert np.abs(np.asarray(w)).max() > 0
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
-                                   atol=1e-5)
+        _assert_close(g, w, rtol, atol)
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_the_rows_past_the_last_held_pair_may_hold_anything(
+        recompute, monkeypatch):
+    """The kernels never visit the rows past the last group: fill them
+    with NaN, in both products and in the rows' gradients, and the
+    layer's output and gradients are what they were."""
+    from mxnet_tpu.ops import moe
+    real, calls = moe.grouped_matmul, []
+
+    def poison(a, sizes):
+        row = jnp.arange(a.shape[0])[:, None]
+        return jnp.where(row < sizes.sum(), a, jnp.nan)
+
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, sizes):
+        return poison(real(lhs, rhs, sizes), sizes)
+
+    def fwd(lhs, rhs, sizes):
+        calls.append(lhs.shape)
+        out, vjp = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)
+        return poison(out, sizes), (vjp, sizes)
+
+    def bwd(res, g):
+        vjp, sizes = res
+        d_lhs, d_rhs = vjp(g)
+        return poison(d_lhs, sizes), d_rhs, None
+    poisoned.defvjp(fwd, bwd)
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+
+    x, wg, w1, w2 = _weights(7, S=512, C=128, H=128)
+    first, held, k = 5, 2, 4            # a quarter of the pairs held here
+    args = (x, 0.1 * wg, w1[first:first + held], w2[first:first + held])
+    proj = jnp.asarray(np.random.RandomState(8).randn(*x.shape), jnp.float32)
+
+    def layer(*a):
+        out, rows = moe_ffn(*a, experts_per_token=k, first_expert=first,
+                            activation="silu", gated=True,
+                            recompute=recompute)
+        return (out * proj).sum(), (out, rows)
+    assert _takes_the_kernel(lambda *a: layer(*a)[0], *args)
+    with jax.default_matmul_precision("highest"):
+        got, (out, rows) = jax.jit(jax.grad(
+            layer, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+        want = jax.grad(lambda *a: (dense_layer(*a, k, first) * proj).sum(),
+                        argnums=(0, 1, 2, 3))(*args)
+        want_out = dense_layer(*args, k, first)
+    assert len(calls) >= 2
+    assert 0 < float(rows.sum()) < 0.5 * x.shape[0] * k
+    _assert_close(out, want_out, 2e-2, 2e-2)
+    for g, w in zip(got, want):
+        _assert_close(g, w, 2e-2, 2e-2)
 
 
 def test_moe_ffn_refuses_a_share_outside_the_router():

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxnet_tpu.ops.pallas_kernels import (flash_attention,
+from mxnet_tpu.ops.pallas_kernels import (flash_attention, grouped_matmul,
                                           ragged_paged_attention,
                                           ragged_paged_verify)
 
@@ -63,3 +63,25 @@ def test_paged_kernels_lower_for_tpu(H, D, dtype):
             S((B, W, H, D), dtype), pool, pool, S((B, P), i32),
             S((B,), i32), S((B,), i32))
         assert "tpu_custom_call" in text, W
+
+
+# Mellum's two expert products (16 held experts, S*k = 65,536 pair rows)
+# and a small shape: the product, and with its gradients the rows' (the
+# weights transposed in the kernel) and the weights' (the rows
+# transposed in the kernel)
+@pytest.mark.parametrize("M,K,N,G", [(65536, 2304, 1792, 16),
+                                     (65536, 896, 2304, 16),
+                                     (2048, 128, 256, 3)])
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_grouped_matmul_lowers_for_tpu(M, K, N, G, mode):
+    def fwd(lhs, rhs, sizes):
+        return grouped_matmul(lhs, rhs, sizes, interpret=False)
+
+    def loss(lhs, rhs, sizes):
+        return (fwd(lhs, rhs, sizes) ** 2).sum()
+
+    fn = fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1))
+    text = _tpu_module_text(fn, S((M, K), jnp.float32),
+                            S((G, K, N), jnp.float32), S((G,), jnp.int32))
+    assert text.count("tpu_custom_call") >= (1 if mode == "fwd" else 3)
+    assert "ragged_dot" not in text
