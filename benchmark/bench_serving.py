@@ -1,8 +1,8 @@
 """Serving benchmark / smoke harness: export LeNet -> serve under
-concurrent load -> emit BENCH_*-style JSON.
+concurrent load -> emit one JSON result.
 
-Prints ONE JSON line (the bench.py contract: last stdout line is the
-authoritative result) with throughput, p50/p99 latency, batch occupancy,
+Prints ONE JSON line (the last stdout line is the authoritative
+result) with throughput, p50/p99 latency, batch occupancy,
 compiled-program count, cold-start-to-first-response, persistent
 compile-cache hit/miss counts, and shed count:
 

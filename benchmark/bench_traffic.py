@@ -14,10 +14,9 @@ servers:
 
 Both runs suffer the SAME chaos: one replica's heartbeat is stalled as
 the burst lands, so the set is down a replica exactly when it can least
-afford it.  The last stdout line is one JSON result (the bench.py
-contract) reporting SLO attainment, goodput, TTFT percentiles, the
-typed shed taxonomy per tier, and the autoscaler decision ledger side
-by side.
+afford it.  The last stdout line is one JSON result reporting SLO
+attainment, goodput, TTFT percentiles, the typed shed taxonomy per
+tier, and the autoscaler decision ledger side by side.
 
 ``--smoke`` (the CI tier, ci/runtime_functions.sh traffic_smoke)
 asserts the ISSUE-17 acceptance criteria:

@@ -224,20 +224,14 @@ training_smoke() {
     # injected kills, the corrupt payload detected by the integrity
     # manifest and never restored (verified-step fallback), and a
     # wedged fake collective raising TrainStepTimeoutError within the
-    # configured deadline instead of hanging the job.  Its traced
-    # phase is the ISSUE-16 acceptance gate: a ShardedTrainer step
-    # under MXNET_TRACE resolves the train.step span chain, the phase
-    # spans tile the root to within 10%, a bottleneck verdict is
-    # emitted, and the jit cache is unchanged vs untraced
+    # configured deadline instead of hanging the job.
     python benchmark/bench_train_resilience.py --smoke
     # the watchdog/supervisor/checkpoint suites double as race tests:
     # the deadline worker thread, the fault plan's trigger state, and
-    # the incident dumps cross the same locks the sanitizer guards;
-    # test_perf_account covers the attribution plane off-path contract
+    # the incident dumps cross the same locks the sanitizer guards
     MXNET_ENGINE_SANITIZE=1 python -m pytest \
         tests/test_faults_train.py tests/test_faults.py \
-        tests/test_checkpoint_sharded.py tests/test_perf_account.py \
-        -x -q
+        tests/test_checkpoint_sharded.py -x -q
 }
 
 traffic_smoke() {
@@ -260,9 +254,10 @@ traffic_smoke() {
 }
 
 bench_cpu() {
-    # tiny-config bench harness end-to-end (no TPU required): the full
-    # per-phase orchestrator, not just one child phase
-    BENCH_STEPS=2 python bench.py
+    # the benchmark's control flow end to end at toy sizes (no TPU
+    # required; prints no result: a CPU run measures nothing)
+    python3 -m perfbench.run --workload bert-large.pretrain_b32_l128 \
+        --seed 1 --seconds 4 --rehearsal --trace 0
 }
 
 if [ $# -lt 1 ] || ! declare -F "$1" > /dev/null; then

@@ -613,14 +613,6 @@ declare_env("MXNET_TRAIN_RESTART_BACKOFF_MS", 100,
             "jitter U[0.5, 1.0)).")
 declare_env("MXNET_TRAIN_RESTART_BACKOFF_MAX_MS", 5000,
             "Cap on one TrainingSupervisor restart backoff sleep.")
-declare_env("MXNET_PEAK_TFLOPS", 0.0,
-            "Per-chip peak TFLOP/s used as the train.mfu denominator "
-            "(perf_account.detect_peak_tflops).  0 (default) = look "
-            "the device_kind up in perf_account.PEAK_TFLOPS; a CPU "
-            "has no peak (no MFU is published) and an accelerator the "
-            "table does not know raises, so set this explicitly for "
-            "such hardware.  bench.py's BENCH_PEAK_TFLOPS overrides "
-            "this for benchmark runs.")
 declare_env("MXNET_SERVING_QUANT_REQUIRE_DIGEST", "1",
             "Serving admission of quantized artifacts "
             "(ModelRepository.load_artifact): 1 (default) rejects a "

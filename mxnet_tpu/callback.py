@@ -4,7 +4,6 @@ from __future__ import annotations
 import logging
 import time
 
-from . import perf_account as _pa
 from . import runtime_metrics as _rm
 
 __all__ = ["Speedometer", "do_checkpoint", "ProgressBar",
@@ -55,12 +54,6 @@ class Speedometer:
                 # up in Prometheus/TensorBoard exports without extra
                 # wiring (no-op while MXNET_RUNTIME_METRICS is off)
                 _rm.TRAINER_SAMPLES_PER_SEC.set(speed)
-                # step attribution, when any trainer published it:
-                # windowed MFU + the current bottleneck verdict ride
-                # the same log line as the throughput
-                verdict = _pa.current_verdict()
-                perf = ("" if verdict is None else
-                        f" mfu={_pa.current_mfu():.3f} verdict={verdict}")
                 if param.eval_metric is not None:
                     names, vals = param.eval_metric.get()
                     if not isinstance(names, list):
@@ -68,14 +61,13 @@ class Speedometer:
                     msg = " ".join(f"{n}={v:.6f}" for n, v in
                                    zip(names, vals))
                     logging.info("Epoch[%d] Batch [%d] Speed: %.2f "
-                                 "samples/sec %s%s", param.epoch, count,
-                                 speed, msg, perf)
+                                 "samples/sec %s", param.epoch, count,
+                                 speed, msg)
                     if self.auto_reset:
                         param.eval_metric.reset()
                 else:
                     logging.info("Epoch[%d] Batch [%d] Speed: %.2f "
-                                 "samples/sec%s", param.epoch, count,
-                                 speed, perf)
+                                 "samples/sec", param.epoch, count, speed)
                 self.tic = time.time()
         else:
             self.init = True

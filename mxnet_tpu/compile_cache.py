@@ -21,9 +21,10 @@ Two tiers share this module:
 - **Training-side jit programs**: :func:`enable_jax_persistent_cache`
   turns on jax's OWN persistent compilation cache — where
   ``JAX_COMPILATION_CACHE_DIR`` says, else at one fixed path inside the
-  checkout — and counts its hit/miss monitoring events; ``bench.py``
-  and ``chip_smoke.py`` call it first thing so a second run on the
-  same machine stops paying the full compile bill.
+  checkout — and counts its hit/miss monitoring events; the
+  benchmark (``perfbench``) and ``chip_smoke.py`` call it first thing
+  so a second run on the same machine stops paying the full compile
+  bill.
 
 Payload format: ``b"MXAOT1" + sha256(body) + body`` where ``body`` is
 the pickled ``(blob, in_tree, out_tree)`` triple from

@@ -16,7 +16,6 @@ import os
 import queue as _queue
 import struct
 import threading
-import time
 from collections import namedtuple
 
 import numpy as np
@@ -26,7 +25,6 @@ from .. import engine as _engine
 from .. import faults as _faults
 from .. import ndarray as nd
 from ..ndarray import NDArray
-from .. import perf_account as _pa
 from .. import recordio
 from .. import runtime_metrics as _rm
 from .. import tracing as _tr
@@ -83,20 +81,16 @@ class DataIter:
 
     def next(self) -> DataBatch:
         _faults.inject("train.data.next")
-        # data-wait attribution: the interval this consumer spent in
-        # next() becomes the following step's train.data.wait span
-        # (perf_account.note_data_wait) — only when observing
-        timed = _rm._ENABLED or _tr._ENABLED
-        t0 = time.perf_counter() if timed else 0.0
-        if self.iter_next():
-            if _rm._ENABLED:
-                _rm.IO_BATCHES.inc()
-            batch = DataBatch(data=self.getdata(), label=self.getlabel(),
-                              pad=self.getpad(), index=self.getindex())
-            if timed:
-                _pa.note_data_wait(t0, time.perf_counter())
-            return batch
-        raise StopIteration
+        # the interval this consumer spends in next() is the
+        # mx.train.data_wait phase of the profiler's trace
+        with _tr.phase("train.data_wait"):
+            if self.iter_next():
+                if _rm._ENABLED:
+                    _rm.IO_BATCHES.inc()
+                return DataBatch(data=self.getdata(),
+                                 label=self.getlabel(),
+                                 pad=self.getpad(), index=self.getindex())
+            raise StopIteration
 
     def __next__(self):
         return self.next()
@@ -240,33 +234,27 @@ class PrefetchingIter(DataIter):
 
     def next(self):
         _faults.inject("train.data.next")
-        # the consumer-visible wait is just the queue take — the
-        # producer thread's own timing never reaches a step (the
-        # data-wait channel is thread-local by design)
-        timed = _rm._ENABLED or _tr._ENABLED
-        t0 = time.perf_counter() if timed else 0.0
-        if self._done:
-            raise StopIteration
-        got = self._queue.get()
-        if _rm._ENABLED:
-            # depth AFTER this take: how far ahead the producer is
-            _rm.IO_PREFETCH_DEPTH.set(self._queue.qsize())
-        if got is None:
-            self._done = True  # producer exited; don't block on next call
-            raise StopIteration
-        if isinstance(got, Exception):
-            self._done = True
-            raise got
-        if len(self.iters) == 1:
-            batch = got[0]
-        else:
-            batch = DataBatch(
+        # the consumer-visible wait is just the queue take: the
+        # producer's own next() calls are phases on its thread
+        with _tr.phase("train.data_wait"):
+            if self._done:
+                raise StopIteration
+            got = self._queue.get()
+            if _rm._ENABLED:
+                # depth AFTER this take: how far ahead the producer is
+                _rm.IO_PREFETCH_DEPTH.set(self._queue.qsize())
+            if got is None:
+                self._done = True  # producer exited; don't block again
+                raise StopIteration
+            if isinstance(got, Exception):
+                self._done = True
+                raise got
+            if len(self.iters) == 1:
+                return got[0]
+            return DataBatch(
                 data=[d for b in got for d in b.data],
                 label=[l for b in got for l in (b.label or [])],
                 pad=got[0].pad)
-        if timed:
-            _pa.note_data_wait(t0, time.perf_counter())
-        return batch
 
     def iter_next(self):
         raise MXNetError("PrefetchingIter supports next() only")
@@ -473,19 +461,15 @@ class NDArrayIter(DataIter):
 
     def next(self):
         _faults.inject("train.data.next")
-        timed = _rm._ENABLED or _tr._ENABLED
-        t0 = time.perf_counter() if timed else 0.0
-        if not self.iter_next():
-            raise StopIteration
-        if _rm._ENABLED:
-            _rm.IO_BATCHES.inc()
-        batch = DataBatch(data=self.getdata(), label=self.getlabel(),
-                          pad=self.getpad(), index=None,
-                          provide_data=self.provide_data,
-                          provide_label=self.provide_label)
-        if timed:
-            _pa.note_data_wait(t0, time.perf_counter())
-        return batch
+        with _tr.phase("train.data_wait"):
+            if not self.iter_next():
+                raise StopIteration
+            if _rm._ENABLED:
+                _rm.IO_BATCHES.inc()
+            return DataBatch(data=self.getdata(), label=self.getlabel(),
+                             pad=self.getpad(), index=None,
+                             provide_data=self.provide_data,
+                             provide_label=self.provide_label)
 
 
 def _jpeg_dims(buf):
@@ -763,24 +747,21 @@ class ImageRecordIter(DataIter):
 
     def next(self):
         _faults.inject("train.data.next")
-        timed = _rm._ENABLED or _tr._ENABLED
-        t0 = time.perf_counter() if timed else 0.0
-        if self._done:
-            raise StopIteration
-        got = self._queue.get()
-        if _rm._ENABLED:
-            _rm.IO_PREFETCH_DEPTH.set(self._queue.qsize())
-        if got is None:
-            self._done = True
-            raise StopIteration
-        if isinstance(got, Exception):
-            self._done = True
-            raise got
-        if _rm._ENABLED:
-            _rm.IO_BATCHES.inc()
-        if timed:
-            _pa.note_data_wait(t0, time.perf_counter())
-        return got
+        with _tr.phase("train.data_wait"):
+            if self._done:
+                raise StopIteration
+            got = self._queue.get()
+            if _rm._ENABLED:
+                _rm.IO_PREFETCH_DEPTH.set(self._queue.qsize())
+            if got is None:
+                self._done = True
+                raise StopIteration
+            if isinstance(got, Exception):
+                self._done = True
+                raise got
+            if _rm._ENABLED:
+                _rm.IO_BATCHES.inc()
+            return got
 
     def iter_next(self):
         raise MXNetError(

@@ -63,7 +63,6 @@ import time
 from collections import deque
 
 from .. import engine as _engine
-from .. import perf_account as _pa
 from .. import runtime_metrics as _rm, tracing as _tr
 from ..base import MXNetError, entropy_rng, get_env
 
@@ -188,9 +187,6 @@ class StepWatchdog:
             out = run_with_deadline(fn, self.timeout_ms, self.site)
         except TrainStepTimeoutError:
             self.timeouts += 1
-            # lands on the enclosing train.step span when the step is
-            # attributed — the timeout shows up in the trace timeline
-            _tr.tag("watchdog_timeout_ms", self.timeout_ms)
             raise
         self._observe(time.perf_counter() - t0)
         return out
@@ -202,13 +198,11 @@ class StepWatchdog:
                 self.slow_steps += 1
                 if _rm._ENABLED:
                     _rm.TRAIN_SLOW_STEPS.inc()
-                _tr.tag("slow_step", round(dt, 6))
                 _tr.record_incident(
                     f"train.slow_step: {dt * 1e3:.1f}ms vs median "
                     f"{med * 1e3:.1f}ms",
                     {"site": self.site, "step_seconds": dt,
-                     "median_seconds": med, "factor": self.slow_factor,
-                     "verdict": _pa.current_verdict()})
+                     "median_seconds": med, "factor": self.slow_factor})
         self._times.append(dt)
 
     def debug_state(self):
@@ -481,7 +475,4 @@ class TrainingSupervisor:
         watchdog = getattr(self.trainer, "watchdog", None)
         if watchdog is not None:
             state["watchdog"] = watchdog.debug_state()
-        perf = getattr(self.trainer, "perf", None)
-        if perf is not None:
-            state["perf"] = perf.debug_state()
         return state

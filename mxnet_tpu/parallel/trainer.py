@@ -16,8 +16,6 @@ residuals ride the donated step state like the optimizer state does.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
@@ -25,7 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import compile_cache as _cc
 from .. import faults as _faults
-from .. import perf_account as _pa
 from .. import quantize as qz
 from .. import runtime_metrics as _rm
 from .. import tracing as _tr
@@ -36,6 +33,36 @@ from .sharding import MEGATRON_RULES, global_device_put, partition_params
 from .supervisor import StepWatchdog
 
 __all__ = ["ShardedTrainer"]
+
+
+def _loss_and_grads(apply_fn, loss_fn, params, inputs, labels):
+    """``((loss, aux), grads)`` of one forward and backward pass.  The
+    scopes here (an op's op_name reads .../mx.fwd/<block>/..., its
+    backward's transpose(jvp(mx.fwd))) are what the device metrics find
+    the step's parts by, so both step programs take them from this one
+    place.  jax leaves scope names out of the persistent cache's key: a
+    program that differs from a cached one in scopes alone is served
+    the cached executable without them (PERF.md section 7)."""
+    def loss_of(p):
+        with jax.named_scope("mx.fwd"):
+            out, aux = apply_fn(p, *inputs)
+        with jax.named_scope("mx.loss"):
+            return loss_fn(out, *labels), aux
+
+    return jax.value_and_grad(loss_of, has_aux=True)(params)
+
+
+def _keep_frozen(params, new_params, aux, trainable):
+    """Frozen params pass through untouched; aux states take the
+    forward-captured update (BatchNorm moving stats), exactly like the
+    eager/CachedOp paths."""
+    new_params = {n: (v if n in trainable else params[n])
+                  for n, v in new_params.items()}
+    for n, v in aux.items():
+        if n in new_params:
+            new_params[n] = v.astype(new_params[n].dtype)
+    return new_params
+
 
 def _sgd_shardings(ps, repl):
     return {"mom": dict(ps)}
@@ -78,11 +105,6 @@ class ShardedTrainer:
         # both off = step() dispatches directly, zero wrapper cost)
         self.watchdog = StepWatchdog(timeout_ms=step_timeout_ms,
                                      slow_factor=slow_step_factor)
-        # step-time attribution / MFU / bottleneck verdict — inert
-        # (one attribute load + branch in step()) until MXNET_TRACE or
-        # MXNET_RUNTIME_METRICS turns it on
-        self.perf = _pa.StepAttribution()
-        self._flops_noted = False
         # the tag the mx.train.* phases of one step share; the compile
         # count's listener starts before the step program is built
         self._step_no = 0
@@ -153,38 +175,18 @@ class ShardedTrainer:
         self.opt_state = jax.tree_util.tree_map(
             global_device_put, self.opt_state, opt_shardings)
 
-        # the program's name (XLA Modules reads jit_mx_train_step) and
-        # the scopes inside it (an op's op_name reads
-        # .../mx.fwd/<block>/..., its backward's transpose(jvp(mx.fwd)))
-        # are what the device metrics find the step and its parts by.
-        # jax leaves scope names out of the persistent cache's key: a
-        # program that differs from a cached one in scopes alone is
-        # served the cached executable without them (PERF.md section 7)
+        # the program's name (XLA Modules reads jit_mx_train_step) is
+        # what the device metrics find the step by
         if self.compression is None:
             def mx_train_step(params, opt_state, *batch):
-                inputs = batch[:self._n_inputs]
-                labels = batch[self._n_inputs:]
-
-                def loss_of(p):
-                    with jax.named_scope("mx.fwd"):
-                        out, aux = apply_fn(p, *inputs)
-                    with jax.named_scope("mx.loss"):
-                        return loss_fn(out, *labels), aux
-
-                (loss, aux), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(params)
+                (loss, aux), grads = _loss_and_grads(
+                    apply_fn, loss_fn, params,
+                    batch[:self._n_inputs], batch[self._n_inputs:])
                 with jax.named_scope("mx.optim"):
                     new_params, new_state = opt_update(
                         params, grads, opt_state, **opt_kw)
-                # frozen params pass through untouched; aux states take
-                # the forward-captured update (BatchNorm moving stats),
-                # exactly like the eager/CachedOp paths
-                new_params = {n: (v if n in trainable else params[n])
-                              for n, v in new_params.items()}
-                for n, v in aux.items():
-                    if n in new_params:
-                        new_params[n] = v.astype(new_params[n].dtype)
-                return new_params, new_state, loss
+                return (_keep_frozen(params, new_params, aux, trainable),
+                        new_state, loss)
 
             self._step = jax.jit(
                 mx_train_step,
@@ -224,17 +226,8 @@ class ShardedTrainer:
         self._quant_step = 0
 
         def local_sync(p, res, key, *b):
-            inputs = b[:n_inputs]
-            labels = b[n_inputs:]
-
-            def loss_of(p):
-                with jax.named_scope("mx.fwd"):
-                    out, aux = apply_fn(p, *inputs)
-                with jax.named_scope("mx.loss"):
-                    return loss_fn(out, *labels), aux
-
-            (loss, aux), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(p)
+            (loss, aux), grads = _loss_and_grads(
+                apply_fn, loss_fn, p, b[:n_inputs], b[n_inputs:])
             dkey = None
             if spec.stochastic:
                 dkey = jax.random.fold_in(key, lax.axis_index("dp"))
@@ -278,12 +271,8 @@ class ShardedTrainer:
             with jax.named_scope("mx.optim"):
                 new_params, new_state = opt_update(params, synced,
                                                    opt_state, **opt_kw)
-            new_params = {n: (v if n in trainable else params[n])
-                          for n, v in new_params.items()}
-            for n, v in aux.items():
-                if n in new_params:
-                    new_params[n] = v.astype(new_params[n].dtype)
-            return new_params, new_state, new_res, loss
+            return (_keep_frozen(params, new_params, aux, trainable),
+                    new_state, new_res, loss)
 
         self._step = jax.jit(
             mx_train_step,
@@ -321,79 +310,28 @@ class ShardedTrainer:
         fire ``train.slow_steps``.  ``faults.inject("train.step")`` is
         the chaos hook for the whole step.
 
-        With tracing or runtime metrics on, the step runs ATTRIBUTED
-        (:meth:`_step_attributed`): each phase is timed into a
-        ``train.*`` span and the step completes synchronously so the
-        compute interval is real device time, not dispatch time.
-
-        Either way the step is a ``mx.train.step`` phase in the JAX
-        profiler's trace (:func:`~mxnet_tpu.tracing.phase`; a no-op
-        outside a profiler session, and nothing blocks for it), with
-        ``mx.train.h2d`` / ``mx.train.dispatch`` / ``mx.train.sync``
-        nested; ``compiles`` is the process's count of backend compiles
-        so far, so two steps' tags tell whether one compiled."""
+        The step is a ``mx.train.step`` phase in the JAX profiler's
+        trace (:func:`~mxnet_tpu.tracing.phase`; a no-op outside a
+        profiler session, and nothing blocks for it), with
+        ``mx.train.h2d`` / ``mx.train.dispatch`` nested, and
+        ``mx.train.sync`` under a watchdog deadline only; ``compiles``
+        is the process's count of backend compiles so far, so two
+        steps' tags tell whether one compiled."""
         self._step_no += 1
         with _tr.phase("train.step", step=self._step_no,
                        compiles=_cc.backend_compiles()):
-            if self.perf.active:
-                return self._step_attributed(batch)
-            return self._step_plain(batch)
-
-    def _step_plain(self, batch):
-        batch = self.shard_batch(*[getattr(b, "_data", b) for b in batch])
-        if self.watchdog.active:
-            out = self.watchdog.watch(
-                lambda: self._dispatch_step(batch, sync=True))
-        else:
-            out = self._dispatch_step(batch, sync=False)
-        # commit on the CALLING thread only: after a watchdog timeout
-        # the abandoned worker may eventually finish, and its output
-        # must never clobber state the supervisor has since restored
-        # from a checkpoint (run_with_deadline discards it instead)
-        self.params, self.opt_state, residuals, quant_step, loss = out
-        if residuals is not None:
-            self.residuals = residuals
-        if quant_step is not None:
-            self._quant_step = quant_step
-            if _rm._ENABLED:
-                _rm.KV_WIRE_BYTES.inc(self.wire_bytes_per_step)
-        return loss
-
-    def _step_attributed(self, batch):
-        """The observed variant of :meth:`step`: same commit protocol,
-        but each phase lands in the ``train.step`` span tree and the
-        breakdown histograms (docs/observability.md).  Runs with
-        ``sync=True`` always — attribution needs the device interval,
-        so async dispatch pipelining is given up while observing.
-        ``train.collective``/``train.optimizer`` are zero-length
-        markers: XLA fuses both into the one compiled step program
-        measured as ``train.compute``."""
-        # per-step FLOPs once per trainer, metrics-gated: AOT
-        # lower().compile() — never enters the jit cache, so tracing
-        # alone adds zero XLA programs
-        if not self._flops_noted and _rm._ENABLED:
-            self._flops_noted = True
-            self.perf.note_flops(_pa.step_flops(self, batch))
-        h = self.perf.step_start()
-        with h:
-            t0 = time.perf_counter()
-            shardb = self.shard_batch(
+            batch = self.shard_batch(
                 *[getattr(b, "_data", b) for b in batch])
-            jax.block_until_ready(shardb)
-            t1 = time.perf_counter()
-            h.record("h2d", t0, t1)
             if self.watchdog.active:
                 out = self.watchdog.watch(
-                    lambda: self._dispatch_step(shardb, sync=True))
+                    lambda: self._dispatch_step(batch, sync=True))
             else:
-                out = self._dispatch_step(shardb, sync=True)
-            if self.compression is not None:
-                h.mark("collective", fused=True,
-                       wire_bytes=self.wire_bytes_per_step,
-                       logical_bytes=self.logical_bytes_per_step)
-            else:
-                h.mark("collective", fused=True)
-            h.mark("optimizer", fused=True)
+                out = self._dispatch_step(batch, sync=False)
+            # commit on the CALLING thread only: after a watchdog
+            # timeout the abandoned worker may eventually finish, and
+            # its output must never clobber state the supervisor has
+            # since restored from a checkpoint (run_with_deadline
+            # discards it instead)
             self.params, self.opt_state, residuals, quant_step, loss = out
             if residuals is not None:
                 self.residuals = residuals
@@ -401,10 +339,7 @@ class ShardedTrainer:
                 self._quant_step = quant_step
                 if _rm._ENABLED:
                     _rm.KV_WIRE_BYTES.inc(self.wire_bytes_per_step)
-            # compute closes LAST so the ~us of marker/commit work
-            # stays inside its interval and the phases tile the root
-            h.record("compute", t1, time.perf_counter())
-        return loss
+            return loss
 
     def _dispatch_step(self, batch, sync):
         """Pure with respect to trainer attributes — runs on the

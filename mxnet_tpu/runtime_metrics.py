@@ -746,28 +746,6 @@ TRAIN_SLOW_STEPS = counter(
     "Straggler steps: watched step time exceeded "
     "MXNET_TRAIN_SLOW_STEP_FACTOR x the rolling median (flight-"
     "recorder incident dumped per detection).")
-TRAIN_STEP_BREAKDOWN_SECONDS = histogram(
-    "train.step.breakdown.seconds",
-    "Per-phase decomposition of one attributed ShardedTrainer step "
-    "(perf_account.StepAttribution): data_wait (iterator next + host "
-    "staging), h2d (device transfer), compute (dispatch -> device "
-    "completion of the fused step program), collective and optimizer "
-    "(0s markers — both run fused inside the compute program; the "
-    "span tags carry wire-vs-logical bytes).  Phases tile the "
-    "train.step span interval.", labelnames=("phase",))
-TRAIN_MFU = gauge(
-    "train.mfu",
-    "Model FLOPs utilization over the attribution window: XLA "
-    "cost_analysis FLOPs of the compiled step / measured step time / "
-    "per-chip peak (MXNET_PEAK_TFLOPS or device-kind default).  0 "
-    "when the backend exposes no cost analysis.")
-TRAIN_BOTTLENECK = gauge(
-    "train.bottleneck",
-    "Windowed bottleneck verdict from the step breakdown: 0 "
-    "compute_bound, 1 input_bound (data_wait + h2d dominate), 2 "
-    "comm_bound (collective dominates).  A non-compute verdict "
-    "requires its phases to reach the StepAttribution threshold "
-    "(default 25%) of windowed wall time.")
 MEMORY_LIVE_BYTES = gauge(
     "memory.live_bytes",
     "Live accelerator bytes per device (host RSS fallback when the "

@@ -38,14 +38,6 @@ path — PAPERS.md).  This module is that plane:
   planes: ``observe(..., exemplar=trace_id)`` lets a Prometheus p99
   resolve to the exact trace that caused it.
 
-The TRAINING plane rides the same tracer: ``perf_account`` roots one
-``train.step`` trace per attributed ``ShardedTrainer`` step,
-decomposed into ``train.data.wait`` / ``train.h2d`` /
-``train.compute`` / ``train.collective`` / ``train.optimizer`` spans
-(docs/observability.md span taxonomy), so a training timeline opens in
-Perfetto next to a serving one and a slow ``trainer.step.seconds`` p99
-resolves to its step trace through the same exemplar link.
-
 - **Phases** (:func:`phase`): the one kind of span that lands in the
   JAX profiler's own trace, beside the device's lines and on their
   clock.  ``phase("train.step", step=n)`` is a
@@ -54,10 +46,11 @@ resolves to its step trace through the same exemplar link.
   at that boundary as tags.  **The profiler's session is its switch**:
   with no ``jax.profiler.start_trace`` running it is a no-op in C++ and
   costs its construction (half a microsecond); ``MXNET_TRACE`` is not
-  looked at.  ``ShardedTrainer`` and ``DecodeEngine`` wrap their phases
-  in it always, so one ``.xplane.pb`` shows which phase of the host
-  each idle gap of the device fell in (docs/observability.md lists the
-  ``mx.`` names and tags).  The request spans above export on the epoch
+  looked at.  ``ShardedTrainer``, the ``io`` iterators' ``next()`` and
+  ``DecodeEngine`` wrap their phases in it always, so one
+  ``.xplane.pb`` shows which phase of the host each idle gap of the
+  device fell in (docs/observability.md lists the ``mx.`` names and
+  tags).  The request spans above export on the epoch
   clock (:data:`CLOCK_ANCHOR`), which is the clock the profiler stamps
   its session with, so a request trace and an ``.xplane.pb`` of one run
   line up.

@@ -298,6 +298,22 @@ def test_diagnose_reports_mode_dead_rule(capsys):
     assert "DEAD RULE" in out and "honors mode" in out
 
 
+def test_diagnose_has_no_training_performance_section(capsys):
+    """``diagnose()`` imports no observatory: it runs to its end and
+    prints the sections its docstring lists, in order, and no
+    "Training Performance" (the runtime MFU and verdict are gone)."""
+    import re
+    import tools.diagnose as dg
+    dg.diagnose()
+    out = capsys.readouterr().out
+    assert re.findall(r"^-{10}(.+?)-{10}$", out, re.M) == [
+        "Platform Info", "Python Info", "Framework Info", "Environment",
+        "Compile Cache", "Concurrency Sanitizer", "Threads",
+        "Fault Injection", "Training Resilience", "Replica Serving",
+        "Traffic / Autoscaling / Admission",
+        "Tracing / Flight Recorder", "Runtime Metrics"]
+
+
 def test_declare_fault_site_validates():
     import pytest
     from mxnet_tpu import faults

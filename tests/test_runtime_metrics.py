@@ -1,6 +1,7 @@
 """Runtime metrics registry: primitives, exporters, and the
 instrumented hot layers (op dispatch, engine, io, kvstore, trainer)."""
 import json
+import re
 import threading
 
 import numpy as np
@@ -263,6 +264,40 @@ class TestInstrumentation:
         sp(p)                       # publishes the gauge
         assert rm.TRAINER_SAMPLES_PER_SEC.value() > 0
         assert "trainer_samples_per_sec" in rm.dump_prometheus()
+
+    def test_speedometer_line_is_upstreams(self, caplog):
+        """The log line is the reference Speedometer's, with and
+        without an ``eval_metric``: throughput, then the metric's
+        values, and nothing after them."""
+        import logging
+        from mxnet_tpu.callback import Speedometer
+
+        class _Metric:
+            def get(self):
+                return ["acc", "ce"], [0.5, 1.25]
+
+            def reset(self):
+                pass
+
+        class _Param:
+            epoch, nbatch, eval_metric = 3, 0, None
+
+        lines = []
+        for metric in (None, _Metric()):
+            sp = Speedometer(batch_size=32, frequent=1)
+            p = _Param()
+            p.eval_metric = metric
+            sp(p)                   # initializes the timer
+            p.nbatch = 7
+            caplog.clear()
+            with caplog.at_level(logging.INFO):
+                sp(p)
+            (record,) = caplog.records
+            lines.append(record.getMessage())
+        speed = r"Epoch\[3\] Batch \[7\] Speed: \d+\.\d\d samples/sec"
+        assert re.fullmatch(speed, lines[0]), lines[0]
+        assert re.fullmatch(speed + " acc=0.500000 ce=1.250000",
+                            lines[1]), lines[1]
 
     def test_after_train_step_all_acceptance_metrics_present(self):
         """ISSUE acceptance: one train step + one io batch yields

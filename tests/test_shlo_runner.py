@@ -6,7 +6,7 @@ Opt-in: needs a PJRT plugin .so and possibly the accelerator it talks
 to, so it only runs when MXNET_TEST_PJRT_PLUGIN is set (the
 `native_build` CI job does this where a plugin is available).  A TPU
 plugin runs on the real chip, which one process holds at a time — it
-must not race a live bench.
+must not race a live benchmark run.
 """
 import os
 import subprocess

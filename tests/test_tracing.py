@@ -866,9 +866,13 @@ def _mx_events(trace_dir):
     return sorted(events, key=lambda e: e["t0"]), began
 
 
+def _within(inner, outer):
+    """By time only: a watched call is on another thread's line."""
+    return outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"]
+
+
 def _inside(inner, outer):
-    return (outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"]
-            and inner["line"] == outer["line"])
+    return _within(inner, outer) and inner["line"] == outer["line"]
 
 
 class TestPhase:
@@ -936,28 +940,53 @@ def _toy_trainer(**kw):
     return trainer, x, y
 
 
-@pytest.fixture(scope="module", params=["plain", "attributed"])
-def train_trace(request, tmp_path_factory):
-    """Four traced steps of a toy ``ShardedTrainer`` after two of
-    warm-up, down ``step()``'s plain path (both switches off) and down
-    ``_step_attributed`` (``MXNET_TRACE`` on)."""
+@contextlib.contextmanager
+def _switches(trace, metrics):
+    """``MXNET_TRACE`` / ``MXNET_RUNTIME_METRICS`` as given, both off
+    again afterwards."""
     tr.disable()
-    trainer, x, y = _toy_trainer()
-    if request.param == "attributed":
+    if trace:
         tr.enable(sample=1.0)
+    if metrics:
+        rm.enable()
+    try:
+        yield
+    finally:
+        tr.disable()
+        rm.disable()
+        rm.reset()
+
+
+SWITCHES = {"trace": (True, False), "metrics": (False, True),
+            "both": (True, True)}
+
+
+def _traced_steps(trainer, x, y, trace_dir):
+    """The ``mx.`` events of four traced steps after two of warm-up."""
     for _ in range(2):
         trainer.step(x, y).block_until_ready()
-    trace_dir = tmp_path_factory.mktemp("train_" + request.param)
     with _session(trace_dir):
         losses = [trainer.step(x, y) for _ in range(4)]
         jax.block_until_ready(losses)
-    tr.disable()
-    return request.param, _mx_events(trace_dir)[0]
+    return _mx_events(trace_dir)[0]
+
+
+@pytest.fixture(scope="module", params=["plain", "observed"])
+def train_trace(request, tmp_path_factory):
+    """Four traced steps of a toy ``ShardedTrainer`` with both switches
+    off, and with ``MXNET_TRACE`` and ``MXNET_RUNTIME_METRICS`` on:
+    ``step()`` has one body and the switches change nothing in it."""
+    on = request.param == "observed"
+    with _switches(trace=on, metrics=on):
+        trainer, x, y = _toy_trainer()
+        return _traced_steps(
+            trainer, x, y,
+            tmp_path_factory.mktemp("train_" + request.param))
 
 
 class TestTrainPhases:
     def test_h2d_and_dispatch_nest_in_their_step(self, train_trace):
-        mode, events = train_trace
+        events = train_trace
         steps = [e for e in events if e["name"] == "mx.train.step"]
         assert len(steps) == 4
         for step in steps:
@@ -965,38 +994,198 @@ class TestTrainPhases:
                 (inner,) = [e for e in events if e["name"] == name
                             and e["tags"]["step"] == step["tags"]["step"]]
                 assert _inside(inner, step), (name, inner, step)
-        # only a step that has to wait for the device says so
-        syncs = [e for e in events if e["name"] == "mx.train.sync"]
-        assert len(syncs) == (4 if mode == "attributed" else 0)
+        # no deadline: no step waits for the device, observed or not
+        assert not [e for e in events if e["name"] == "mx.train.sync"]
 
     def test_step_tag_rises_by_one(self, train_trace):
-        _mode, events = train_trace
+        events = train_trace
         tags = [e["tags"]["step"] for e in events
                 if e["name"] == "mx.train.step"]
         assert tags == list(range(tags[0], tags[0] + 4)) and tags[0] == 3
 
     def test_compiles_tag_constant_after_warm_up(self, train_trace):
-        _mode, events = train_trace
+        events = train_trace
         compiles = {e["tags"]["compiles"] for e in events
                     if e["name"] == "mx.train.step"}
         assert len(compiles) == 1 and compiles.pop() >= 1
 
 
-@pytest.mark.parametrize("sync", [False, True])
-def test_dispatch_blocks_only_when_asked(sync, monkeypatch):
-    """The phases add no wait: ``_dispatch_step(sync=False)``, which
-    is ``step()``'s plain path, never calls ``block_until_ready``."""
+@pytest.fixture(scope="module")
+def deadline_trace(tmp_path_factory):
+    """Four traced steps of a toy trainer under a watchdog deadline:
+    the watched call runs to device completion on the deadline's
+    thread."""
     tr.disable()
-    trainer, x, y = _toy_trainer()
-    batch = trainer.shard_batch(x, y)
+    trainer, x, y = _toy_trainer(step_timeout_ms=60000)
+    return _traced_steps(trainer, x, y,
+                         tmp_path_factory.mktemp("train_deadline"))
+
+
+class TestTrainPhasesUnderDeadline:
+    def test_one_sync_a_step(self, deadline_trace):
+        steps = [e["tags"]["step"] for e in deadline_trace
+                 if e["name"] == "mx.train.step"]
+        syncs = [e["tags"]["step"] for e in deadline_trace
+                 if e["name"] == "mx.train.sync"]
+        assert len(steps) == 4 and syncs == steps
+
+    def test_sync_and_dispatch_fall_inside_their_step_by_time(
+            self, deadline_trace):
+        by_step = {e["tags"]["step"]: e for e in deadline_trace
+                   if e["name"] == "mx.train.step"}
+        inner = [e for e in deadline_trace
+                 if e["name"] in ("mx.train.sync", "mx.train.dispatch")]
+        assert len(inner) == 8
+        for e in inner:
+            step = by_step[e["tags"]["step"]]
+            assert _within(e, step), (e, step)
+            assert e["line"] != step["line"]    # the deadline's thread
+        for step_no in by_step:
+            dispatch, sync = [e for e in inner
+                              if e["tags"]["step"] == step_no]
+            assert (dispatch["name"], sync["name"]) == \
+                ("mx.train.dispatch", "mx.train.sync")
+            assert dispatch["t1"] <= sync["t0"]
+
+    def test_h2d_stays_on_the_callers_line(self, deadline_trace):
+        for step in (e for e in deadline_trace
+                     if e["name"] == "mx.train.step"):
+            (h2d,) = [e for e in deadline_trace
+                      if e["name"] == "mx.train.h2d"
+                      and e["tags"]["step"] == step["tags"]["step"]]
+            assert _inside(h2d, step), (h2d, step)
+
+
+@contextlib.contextmanager
+def _counting_block_until_ready(monkeypatch):
     calls = []
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda a: calls.append(1) or real(a))
-    out = trainer._dispatch_step(batch, sync=sync)
-    monkeypatch.undo()
+    try:
+        yield calls
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("sync", [False, True])
+def test_dispatch_blocks_only_when_asked(sync, monkeypatch):
+    """The phases add no wait: ``_dispatch_step(sync=False)``, which
+    is what ``step()`` calls without a deadline, never calls
+    ``block_until_ready``."""
+    tr.disable()
+    trainer, x, y = _toy_trainer()
+    batch = trainer.shard_batch(x, y)
+    with _counting_block_until_ready(monkeypatch) as calls:
+        out = trainer._dispatch_step(batch, sync=sync)
     jax.block_until_ready(out[-1])
     assert len(calls) == (1 if sync else 0)
+
+
+@pytest.mark.parametrize("switches", SWITCHES)
+def test_step_never_waits_for_observation(switches, monkeypatch):
+    """``MXNET_TRACE`` / ``MXNET_RUNTIME_METRICS`` do not change how a
+    step is dispatched: ``step()`` returns with the step enqueued.
+    Under a deadline it waits exactly once, whatever the switches."""
+    with _switches(*SWITCHES[switches]):
+        trainer, x, y = _toy_trainer()
+        watched, _, _ = _toy_trainer(step_timeout_ms=60000)
+        with _counting_block_until_ready(monkeypatch) as calls:
+            losses = [trainer.step(x, y) for _ in range(3)]
+            assert calls == []
+            watched.step(x, y)
+            assert calls == [1]
+        jax.block_until_ready(losses)
+
+
+@pytest.mark.parametrize("switches", SWITCHES)
+def test_observed_steps_add_no_programs(switches):
+    """Observation compiles nothing: one entry in the step's jit cache
+    and a constant count of backend compiles over four observed steps
+    (an ahead-of-time compile, to ask XLA for the step's FLOPs, would
+    count)."""
+    from mxnet_tpu import compile_cache
+    tr.disable()
+    trainer, x, y = _toy_trainer()
+    trainer.step(x, y).block_until_ready()      # the one compile
+    with _switches(*SWITCHES[switches]):
+        before = compile_cache.backend_compiles()
+        for _ in range(4):
+            trainer.step(x, y).block_until_ready()
+        assert compile_cache.backend_compiles() == before
+    assert trainer._step._cache_size() == 1
+
+
+class _CountingIter(mx.io.DataIter):
+    """The base class's own ``next()``: three batches of ones."""
+
+    def __init__(self):
+        super().__init__(batch_size=4)
+        self._left = 3
+
+    def iter_next(self):
+        self._left -= 1
+        return self._left >= 0
+
+    def getdata(self):
+        return [mx.nd.ones((4, 2))]
+
+    def getlabel(self):
+        return [mx.nd.zeros((4,))]
+
+    def getpad(self):
+        return 0
+
+    def getindex(self):
+        return None
+
+
+def _make_iter(kind, tmp_path):
+    """An iterator of three batches of four rows."""
+    data = np.arange(24, dtype=np.float32).reshape(12, 2)
+    if kind == "DataIter":
+        return _CountingIter()
+    if kind == "NDArrayIter":
+        return mx.io.NDArrayIter(data, np.zeros(12, np.float32),
+                                 batch_size=4)
+    if kind == "PrefetchingIter":
+        return mx.io.PrefetchingIter(mx.io.NDArrayIter(
+            data, np.zeros(12, np.float32), batch_size=4))
+    from test_io import _write_image_rec
+    prefix, _labels = _write_image_rec(tmp_path)
+    return mx.io.ImageRecordIter(
+        path_imgrec=prefix + ".rec", path_imgidx=prefix + ".idx",
+        data_shape=(3, 32, 32), batch_size=4, shuffle=False)
+
+
+@pytest.mark.parametrize("kind", ["DataIter", "NDArrayIter",
+                                  "PrefetchingIter", "ImageRecordIter"])
+def test_data_wait_phase(kind, tmp_path, monkeypatch, metrics):
+    """What the train loop waits for its batch is the
+    ``mx.train.data_wait`` phase: one event a ``next()`` on the thread
+    that called it inside a profiler session; outside one no clock is
+    read, whatever ``MXNET_TRACE`` / ``MXNET_RUNTIME_METRICS`` say
+    (both are on here)."""
+    it = _make_iter(kind, tmp_path)
+    me, reads = threading.get_ident(), []
+    real = time.perf_counter
+    monkeypatch.setattr(
+        time, "perf_counter",
+        lambda: (reads.append(1) if threading.get_ident() == me
+                 else None) or real())
+    first = it.next()
+    monkeypatch.undo()
+    assert reads == [] and first.data[0].shape[0] == 4
+    with _session(tmp_path / "trace"):
+        with tr.phase("test.caller"):
+            got = [it.next(), it.next()]
+    events, _began = _mx_events(tmp_path / "trace")
+    (caller,) = [e for e in events if e["name"] == "mx.test.caller"]
+    waits = [e for e in events if e["name"] == "mx.train.data_wait"
+             and e["line"] == caller["line"]]
+    assert len(waits) == len(got) == 2
+    assert all(_inside(w, caller) for w in waits)
+    assert waits[0]["t1"] <= waits[1]["t0"]
 
 
 class ChainLM:

@@ -290,7 +290,7 @@ class TestSummarize:
 
 # ------------------------------------------------- replay determinism
 class TestReplayDeterminism:
-    """ISSUE-18: the BENCH_r07 burst trace replayed twice against
+    """ISSUE-18: the seed-0 burst trace replayed twice against
     identical deterministic twins must score byte-identically —
     the regression that keeps ambient entropy out of the
     generate -> replay -> summarize chain (mxlint

@@ -19,13 +19,7 @@ then asserts the span chains (admission -> queue wait -> batch/execute
 on the predict side; admission -> queue wait -> prefill -> decode
 steps -> evict on the generate side), the p99 exemplar link, and that
 the flight-recorder dump is non-empty and parses as chrome trace.
-It then drives the TRAINING chain the same way: a resnet50-shaped
-fake trainer (input-bound: data wait dominates) through
-``perf_account.StepAttribution``, asserting the ``train.step`` span
-tree resolves, the phase spans tile the root to within 10%, the
-bottleneck verdict comes out ``input_bound``, and the
-``trainer.step.seconds`` p99 exemplar resolves to a trace.  This is
-the CI gate for docs/observability.md's tracing section
+This is the CI gate for docs/observability.md's tracing section
 (ci/runtime_functions.sh serving_smoke).
 
 ``--flight-dump [PATH]`` writes the in-process flight-recorder
@@ -189,22 +183,6 @@ def diagnose(metrics_smoke=False):
               f"(+ {_trm.TRAIN_STEP_TIMEOUTS.value():g} step "
               f"timeout(s), {_trm.TRAIN_SLOW_STEPS.value():g} slow "
               f"step(s) this process)")
-
-    _section("Training Performance")
-    from mxnet_tpu import perf_account as _perf
-    print(f"peak tflops  : {_perf.detect_peak_tflops()}  "
-          f"(MXNET_PEAK_TFLOPS or the device-kind table; the "
-          f"train.mfu denominator; None on a CPU)")
-    verdict = _perf.current_verdict()
-    if verdict is None:
-        print("attribution  : (no attributed steps this process — with "
-              "MXNET_TRACE/MXNET_RUNTIME_METRICS on, ShardedTrainer "
-              "steps publish train.step.breakdown.seconds + the "
-              "train.bottleneck verdict; docs/perf_playbook.md)")
-    else:
-        print(f"verdict      : {verdict}  (train.bottleneck, rolling "
-              f"window)")
-        print(f"mfu          : {_perf.current_mfu():.4f}  (train.mfu)")
 
     _section("Replica Serving")
     n_rep = get_env("MXNET_SERVING_REPLICAS", typ=int)
@@ -406,46 +384,6 @@ def trace_smoke():
         0.99, model="echo")
     assert ex == pt["trace_id"], (ex, pt["trace_id"])
 
-    # training chain: the resnet50-shaped input-bound case (data wait
-    # >> compute) through the same StepAttribution the ShardedTrainer
-    # uses — fake phases, zero compiles
-    import time as _time
-
-    from mxnet_tpu import perf_account as perf
-    att = perf.StepAttribution(peak_tflops=1.0)
-    att.note_flops(1e9)
-    for _ in range(4):
-        t0 = _time.perf_counter()
-        _time.sleep(0.012)              # starved input pipeline
-        perf.note_data_wait(t0, _time.perf_counter())
-        h = att.step_start()
-        with h:
-            with h.phase("h2d"):
-                _time.sleep(0.002)
-            with h.phase("compute"):
-                _time.sleep(0.006)
-            h.mark("collective", fused=True)
-            h.mark("optimizer", fused=True)
-    tt = tracing.TRACER.last(root="train.step")
-    assert tt is not None, tracing.TRACER.stats()
-    need = {"train.step", "train.data.wait", "train.h2d",
-            "train.compute", "train.collective", "train.optimizer"}
-    names = {s["name"] for s in tt["spans"]}
-    assert need <= names, (sorted(need - names), sorted(names))
-    ids = {s["span_id"] for s in tt["spans"]}
-    for s in tt["spans"]:
-        assert s["trace_id"] == tt["trace_id"], s
-        assert s["parent_id"] is None or s["parent_id"] in ids, s
-    root = next(s for s in tt["spans"] if s["name"] == "train.step")
-    span_sum = sum(s["t1"] - s["t0"] for s in tt["spans"]
-                   if s["name"] != "train.step")
-    dur = root["t1"] - root["t0"]
-    assert abs(span_sum - dur) <= 0.10 * dur, (span_sum, dur)
-    assert att.verdict() == "input_bound", att.summary()
-    assert rm.TRAIN_BOTTLENECK.value() == 1.0, rm.TRAIN_BOTTLENECK
-    tex = rm.TRAINER_STEP_SECONDS.exemplar_for_quantile(0.99)
-    assert tracing.TRACER.find(tex) is not None, tex
-
     # flight-recorder dump: non-empty and parsable (the CI criterion)
     with tempfile.TemporaryDirectory() as tmp:
         fpath = os.path.join(tmp, "flight.json")
@@ -457,14 +395,13 @@ def trace_smoke():
         assert rec["traces"], "flight-recorder dump is empty"
         assert rec["state"]["repository"]["lm"]["current"] == 1
         cpath = tracing.dump_chrome_trace(
-            os.path.join(tmp, "trace.json"), [pt, gt, tt])
+            os.path.join(tmp, "trace.json"), [pt, gt])
         with open(cpath) as f:
             events = json.load(f)["traceEvents"]
         assert len(events) > 8, "chrome-trace dump is empty"
 
     print(f"trace smoke: OK ({len(pt['spans'])} predict span(s), "
-          f"{len(gt['spans'])} generate span(s), {len(tt['spans'])} "
-          f"train span(s), verdict={att.verdict()}, flight recorder "
+          f"{len(gt['spans'])} generate span(s), flight recorder "
           f"parsed)")
 
 
