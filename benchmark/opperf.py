@@ -135,14 +135,13 @@ def _op_specs(large=False):
             [nd.array(rng.rand(4, 3, 4096).astype(np.float32)),
              nd.array(rng.randn(4, 4096 * 4).astype(np.float32) * 0.1),
              nd.array(rng.rand(1, 4096, 4).astype(np.float32))], {})),
-        # MoE (GShard dense routing)
+        # MoE (top-1 dropless, every expert held; no biases since PR 33)
         "moe_ffn": ("moe", lambda nd, rng: (
             [nd.array(rng.rand(8, 128, 512).astype(np.float32)),
              nd.array(rng.randn(512, 8).astype(np.float32)),
              nd.array(rng.randn(8, 512, 1024).astype(np.float32) * 0.05),
-             nd.zeros((8, 1024)),
-             nd.array(rng.randn(8, 1024, 512).astype(np.float32) * 0.05),
-             nd.zeros((8, 512))], {})),
+             nd.array(rng.randn(8, 1024, 512).astype(np.float32) * 0.05)],
+            {})),
     }
     return specs
 

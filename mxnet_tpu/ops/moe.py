@@ -11,25 +11,39 @@ experts, and computes its own experts' part of the result:
   precision (a bfloat16 router flips near-ties), the k largest,
   renormalised over all k chosen, held here or not;
 - ``mx.moe.dispatch``: the (token, expert) pairs sorted by held expert
-  (pairs whose expert is held elsewhere sort to the tail) and each
-  pair's token row gathered into that order;
+  (pairs whose expert is held elsewhere sort to the tail) and each held
+  pair's token row written into that order
+  (``pallas_kernels.rows_of_tokens``, the pair-side mover);
 - ``mx.moe.experts``: two grouped products over the held experts
-  (``pallas_kernels.grouped_matmul``: where the shapes tile, a Pallas
-  grouped matmul whose row tiles follow the group sizes, so the work
-  follows the rows that are routed here, not the worst case of k rows a
-  token; rows and weights enter the MXU as bfloat16, results and
-  gradients are float32; other shapes take ``jax.lax.ragged_dot``);
-- ``mx.moe.combine``: rows back in pair order, weighted, summed over
-  each token's k pairs.  A token none of whose experts is held gets
-  zero.
+  (``pallas_kernels.grouped_matmul``) with the activation between them
+  (``pallas_kernels.expert_activation``);
+- ``mx.moe.combine``: each token's held pairs' rows, weighted, summed
+  in float32 (``pallas_kernels.tokens_of_rows``, the token-side mover).
+  A token none of whose experts is held gets zero.
 
-No capacity and no dropped token: the pair buffer has S*k rows, the
-worst case.  **The rows past the last held pair are undefined**: the
-kernels never visit them, so after the first product they hold whatever
-was in memory, NaN included, and the activation runs over that.  Every
-reader of those rows selects (``jnp.where(held, ...)``), never
-multiplies by a mask.  No exchange: on one chip there is none, and
-nothing stands in for the absent chips.
+No capacity and no dropped token: the pair buffers have S*k rows, the
+worst case, and **every pass visits only the rows below the last held
+pair** (``sum(sizes)``; a quarter of the buffer where a chip holds 16
+experts of 64).  Where the shapes tile (``pallas_kernels.grouped_tiles``,
+the products' predicate: C and H multiples of 128, S*k of the row tile)
+each pass is a Pallas kernel whose grid ends at the last live tile, and
+**the rows past it are undefined** in every pair buffer, forward and
+backward: the gathered rows, both products, the activation, and the
+gradients of all four hold whatever was in memory there, NaN included.
+A reader fetches held pairs' rows by index or stops at the last live
+tile; none multiplies by a mask.  Rows and activations enter the MXU as
+bfloat16, written so by the pass before; what leaves a product is
+float32, and so is the sum over a token's pairs.  Other shapes (the
+unit tests' widths of 8-16) take ``lax.ragged_dot`` and the ``jnp``
+gathers the movers replaced, inside the same functions, over all S*k
+rows, in the operands' dtype.
+
+The backward pass is written out (``_backward``), mover for mover the
+forward's transpose: the token-side mover with unit weights is the
+gather's gradient, the pair-side mover with a scale is the weighted
+sum's, and a weight's gradient is one row-wise product on the pair
+side.  No exchange: on one chip there is none, and nothing stands in
+for the absent chips.
 
 Ops:
   ``moe_topk_route`` — router: tokens x router weight -> (weights, ids)
@@ -44,7 +58,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_kernels import grouped_matmul
+from .pallas_kernels import (expert_activation, grouped_matmul,
+                             grouped_matmul_grads, grouped_tiles,
+                             rows_of_tokens, tokens_of_rows)
 from .registry import register
 
 __all__ = ["moe_topk_route", "moe_ffn"]
@@ -72,73 +88,89 @@ def moe_topk_route(x, gate_weight, *, experts_per_token: int = 1):
     return weights, ids.astype(jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_tokens(x, order, inverse, held, k):
-    """Row ``order[i] // k`` of ``x`` for every sorted pair ``i``.  The
-    backward is a gather too (``order`` is a permutation of the pairs):
-    XLA's own transpose would be a scatter-add of S*k rows."""
-    return x[order // k]
-
-
-def _rows_of_tokens_fwd(x, order, inverse, held, k):
-    return x[order // k], (inverse, held)
-
-
-def _rows_of_tokens_bwd(k, res, g):
-    inverse, held = res
-    # the rows of pairs held elsewhere were never computed
-    pairs = jnp.where(held[:, None], g[inverse], 0)
-    return pairs.reshape(-1, k, g.shape[-1]).sum(1), None, None, None
-
-
-_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
-
-
-@jax.custom_vjp
-def _permute_rows(y, perm, inverse):
-    """``y[perm]`` for a permutation whose inverse is known: the
-    backward is ``g[inverse]``, a gather, not a scatter."""
-    return y[perm]
-
-
-_permute_rows.defvjp(lambda y, perm, inverse: (y[perm], (inverse,)),
-                     lambda res, g: (g[res[0]], None, None))
-
-
-def _experts_part(xs, weights, ids, w1, w2, first_expert, activation,
-                  gated):
-    """(the held experts' part of the layer's output (S, C), rows routed
-    to each held expert (n_held,) float32)."""
+def _forward(xs, weights, ids, w1, w2, first_expert, activation, gated):
+    """((the held experts' part of the layer's output (S, C), rows routed
+    to each held expert (n_held,) float32), what the backward pass
+    needs)."""
     S, C = xs.shape
     k = ids.shape[1]
     n_held = w1.shape[0]
-    act = _ACTIVATIONS[activation]
+    # what a kernel reads is written as bfloat16 by the pass before it
+    to_w1 = _operand_dtype(xs, S * k, w1)
+    to_w2 = _operand_dtype(xs, S * k, w2)
     with jax.named_scope("mx.moe.dispatch"):
         local = ids.reshape(-1) - first_expert                 # (S*k,)
-        held = (local >= 0) & (local < n_held)
-        key = jnp.where(held, local, n_held)
-        order = jnp.argsort(key, stable=True)
-        inverse = jnp.argsort(order)
-        sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
-                        axis=0, dtype=jnp.int32)               # (n_held,)
-        rows = _rows_of_tokens(xs, order, inverse, held, k)    # (S*k, C)
+        group = jnp.where((local >= 0) & (local < n_held), local, n_held)
+        # a sort carries what a gather of S*k numbers would fetch, at a
+        # tenth of its cost: each pair's weight goes along
+        pair = lax.iota(jnp.int32, S * k)
+        _, order, scale = lax.sort((group, pair, weights.reshape(-1)),
+                                   num_keys=1, is_stable=True)
+        inverse = lax.sort((order, pair), num_keys=1)[1]
+        sizes = jnp.sum(group[None, :] == jnp.arange(n_held)[:, None],
+                        axis=1, dtype=jnp.int32)               # (n_held,)
+        live = jnp.sum(sizes)
+        tok = order // k
+        rows = rows_of_tokens(xs, tok, live, dtype=to_w1)      # (S*k, C)
     with jax.named_scope("mx.moe.experts"):
         h = grouped_matmul(rows, w1, sizes)
-        if gated:
-            gate, up = jnp.split(h, 2, axis=-1)
-            h = act(gate) * up
-        else:
-            h = act(h)
-        y = grouped_matmul(h, w2, sizes)                       # (S*k, C)
+        a = expert_activation(h, live, _ACTIVATIONS[activation], gated,
+                              dtype=to_w2)
+        y = grouped_matmul(a, w2, sizes)                       # (S*k, C)
     with jax.named_scope("mx.moe.combine"):
-        pairs = _permute_rows(y, inverse, order).reshape(S, k, C)
-        held = held.reshape(S, k)
-        # the tail of ``y`` belongs to no group: whatever it holds, a
-        # pair held elsewhere adds nothing
-        pairs = jnp.where(held[..., None], pairs, 0)
-        out = jnp.einsum("skc,sk->sc", pairs,
-                         jnp.where(held, weights, 0).astype(pairs.dtype))
-    return out, sizes.astype(jnp.float32)
+        pairs = (inverse.reshape(S, k), group.reshape(S, k), sizes)
+        out = tokens_of_rows(y, weights, *pairs)
+    return ((out.astype(xs.dtype), sizes.astype(jnp.float32)),
+            (scale, w1, w2, tok, order, pairs, rows, h, a, y))
+
+
+def _operand_dtype(xs, M, w):
+    """bfloat16 for the rows of a grouped product that takes the kernels
+    (they enter the MXU so), ``xs``'s for ``lax.ragged_dot``."""
+    tiles = grouped_tiles(M, w.shape[1], w.shape[2], w.dtype.itemsize)
+    return jnp.dtype(jnp.bfloat16 if tiles else xs.dtype)
+
+
+def _backward(first_expert, activation, gated, res, cotangents):
+    """The forward pass transposed, mover for mover: each pair buffer is
+    written once, in the dtype its reader wants, below the last held
+    pair."""
+    scale, w1, w2, tok, order, pairs, rows, h, a, y = res
+    inverse, group, sizes = pairs
+    g = cotangents[0]
+    live = jnp.sum(sizes)
+    with jax.named_scope("mx.moe.combine"):
+        dy, dscale = rows_of_tokens(g.astype(jnp.float32), tok, live,
+                                    scale=scale, dot=y, dtype=a.dtype)
+        # back in pair order; past the last held pair it holds anything
+        d_weights = lax.sort((order, dscale), num_keys=1)[1].reshape(
+            group.shape)
+        d_weights = jnp.where(group < sizes.shape[0], d_weights,
+                              0).astype(scale.dtype)
+    with jax.named_scope("mx.moe.experts"):
+        da, d_w2 = grouped_matmul_grads(a, w2, sizes, dy)
+        dh = expert_activation(h, live, _ACTIVATIONS[activation], gated,
+                               g=da, dtype=rows.dtype)
+        drows, d_w1 = grouped_matmul_grads(rows, w1, sizes, dh)
+    with jax.named_scope("mx.moe.dispatch"):
+        d_xs = tokens_of_rows(drows, jnp.ones(group.shape, scale.dtype),
+                              *pairs)
+    return d_xs.astype(g.dtype), d_weights, None, d_w1, d_w2
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _experts_part(xs, weights, ids, w1, w2, first_expert, activation,
+                  gated):
+    """(the held experts' part of the layer's output (S, C), rows routed
+    to each held expert (n_held,) float32).  One forward and one
+    backward body, written out: the pair buffers change dtype between a
+    pass and its transpose (bfloat16 into a kernel, float32 out of it),
+    which autodiff's one dtype a value cannot say."""
+    return _forward(xs, weights, ids, w1, w2, first_expert, activation,
+                    gated)[0]
+
+
+_experts_part.defvjp(_forward, _backward)
 
 
 @register("_contrib_moe_ffn", num_inputs=4, num_outputs=2,
@@ -154,10 +186,14 @@ def moe_ffn(x, wg, w1, w2, *, experts_per_token: int = 1,
     ``gated``; w2 (n_held, H, C).  The layer holds experts
     ``first_expert .. first_expert + n_held - 1``.  Returns (out with
     x's shape, rows (n_held,) float32: the (token, expert) pairs this
-    call routed to each held expert).  ``recompute`` saves nothing of
-    dispatch, experts and combine for the backward pass and computes
-    them again there: their S*k-row buffers are most of a long
-    sequence's saved activations.
+    call routed to each held expert; over S*k, the share of the pair
+    buffers that the layer's passes visit).  Where C and H are multiples
+    of 128 and S*k of 1024 every pass is a Pallas kernel over the held
+    pairs' rows only (the module's docstring says which rows are
+    undefined where); other shapes compute all S*k rows in ``jnp``.
+    ``recompute`` saves nothing of dispatch, experts and combine for
+    the backward pass and computes them again there: their S*k-row
+    buffers are most of a long sequence's saved activations.
     """
     if activation not in _ACTIVATIONS:
         from ..base import MXNetError
@@ -173,8 +209,9 @@ def moe_ffn(x, wg, w1, w2, *, experts_per_token: int = 1,
     xs = x.reshape(-1, x.shape[-1])
     weights, ids = moe_topk_route(xs, wg,
                                   experts_per_token=experts_per_token)
-    part = functools.partial(_experts_part, first_expert=int(first_expert),
-                             activation=activation, gated=bool(gated))
+    def part(xs, weights, ids, w1, w2):
+        return _experts_part(xs, weights, ids, w1, w2, int(first_expert),
+                             activation, bool(gated))
     if recompute:
         part = jax.checkpoint(part)
     out, rows = part(xs, weights.astype(xs.dtype), ids, w1, w2)

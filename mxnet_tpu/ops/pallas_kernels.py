@@ -34,7 +34,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
 
-__all__ = ["flash_attention", "grouped_matmul",
+__all__ = ["flash_attention", "grouped_matmul", "grouped_matmul_grads",
+           "grouped_tiles", "rows_of_tokens", "tokens_of_rows",
+           "expert_activation",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_verify", "ragged_paged_verify_reference"]
 
@@ -665,6 +667,48 @@ def _tgmm(lhs, rhs, group_sizes, interpret):
     )(*meta, lhs, rhs)
 
 
+def _interpret(interpret):
+    """The kernels run in the interpreter where there is only a CPU."""
+    return (jax.default_backend() == "cpu" if interpret is None
+            else bool(interpret))
+
+
+def grouped_tiles(M, K, N, itemsize=4):
+    """Whether an (M, K) x (G, K, N) grouped product takes the Pallas
+    kernels: K and N multiples of 128, M of the row tile, and weights of
+    ``itemsize`` bytes an element that leave room in VMEM.  The one
+    predicate of the expert layer (ops/moe.py): its movers and its
+    activation follow what their own operands show of it."""
+    return not (M % _GROUPED_ROW_TILE or K % 128 or N % 128
+                or _gmm_columns(K, N, itemsize) is None
+                or _gmm_columns(N, K, itemsize) is None
+                or not _tgmm_fits(K, N))
+
+
+def grouped_matmul_grads(lhs, rhs, group_sizes, g, interpret=None):
+    """The two gradients of ``grouped_matmul(lhs, rhs, group_sizes)``
+    for the result's cotangent ``g``: (the rows' (M, K) float32, the
+    weights' in ``rhs``'s dtype, zero for a group with no rows).  Where
+    the shapes tile, ``lhs`` and ``g`` enter the kernels as bfloat16
+    (hand them over as bfloat16 and nothing is cast) and the rows past
+    the last group are neither read nor written."""
+    (M, K), N = lhs.shape, rhs.shape[2]
+    if not grouped_tiles(M, K, N, rhs.dtype.itemsize):
+        _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(
+            a, b, group_sizes, preferred_element_type=jnp.float32),
+            lhs, rhs)
+        d_lhs, d_rhs = vjp(g.astype(jnp.float32))
+        return d_lhs.astype(jnp.float32), d_rhs
+    interpret = _interpret(interpret)
+    group_sizes = group_sizes.astype(jnp.int32)
+    g = g.astype(jnp.bfloat16)
+    # mxlint: disable=recompile-churn (interpret is a bool)
+    d_lhs = _gmm(g, rhs, group_sizes, True, interpret)
+    # mxlint: disable=recompile-churn (interpret is a bool)
+    d_rhs = _tgmm(lhs.astype(jnp.bfloat16), g, group_sizes, interpret)
+    return d_lhs, d_rhs.astype(rhs.dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _grouped(lhs, rhs, group_sizes, lhs_dtype, interpret):
     return _grouped_fwd(lhs, rhs, group_sizes, lhs_dtype, interpret)[0]
@@ -679,12 +723,8 @@ def _grouped_fwd(lhs, rhs, group_sizes, lhs_dtype, interpret):
 
 def _grouped_bwd(lhs_dtype, interpret, res, g):
     lhs, rhs, group_sizes = res
-    g = g.astype(jnp.bfloat16)
-    # mxlint: disable=recompile-churn (interpret is a bool)
-    d_lhs = _gmm(g, rhs, group_sizes, True, interpret)
-    # mxlint: disable=recompile-churn (interpret is a bool)
-    d_rhs = _tgmm(lhs, g, group_sizes, interpret)
-    return d_lhs.astype(lhs_dtype), d_rhs.astype(rhs.dtype), None
+    d_lhs, d_rhs = grouped_matmul_grads(lhs, rhs, group_sizes, g, interpret)
+    return d_lhs.astype(lhs_dtype), d_rhs, None
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
@@ -696,28 +736,381 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
     sum is at most M; row i of the (M, N) float32 result is
     ``lhs[i] @ rhs[g]`` for the group g that holds row i.
 
-    Where K and N are multiples of 128 and M of 1024 this is a Pallas
-    grouped matmul: operands enter the MXU as bfloat16 (the weights
-    cast a group at a time in VMEM), accumulation and result float32,
-    and so the two gradients (the rows' in ``lhs``'s dtype, the weights'
-    zero for a group with no rows).  **The rows past the last group are
-    not written**: they hold whatever was there, NaN included, and a
-    reader must select, never multiply by a mask.  Other shapes (and
-    weights that leave no room in VMEM) take ``lax.ragged_dot`` on the
-    operands as given, which zeroes those rows.
+    Where K and N are multiples of 128 and M of 1024 (``grouped_tiles``)
+    this is a Pallas grouped matmul: operands enter the MXU as bfloat16
+    (the weights cast a group at a time in VMEM), accumulation and
+    result float32, and so the two gradients (the rows' in ``lhs``'s
+    dtype, the weights' zero for a group with no rows).  **The rows past
+    the last group are not written**: they hold whatever was there, NaN
+    included, and a reader must select, never multiply by a mask.  Other
+    shapes (and weights that leave no room in VMEM) take
+    ``lax.ragged_dot`` on the operands as given, which zeroes those rows.
     """
     (M, K), N = lhs.shape, rhs.shape[2]
-    itemsize = rhs.dtype.itemsize
-    if (M % _GROUPED_ROW_TILE or K % 128 or N % 128
-            or _gmm_columns(K, N, itemsize) is None
-            or _gmm_columns(N, K, itemsize) is None
-            or not _tgmm_fits(K, N)):
+    if not grouped_tiles(M, K, N, rhs.dtype.itemsize):
         return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                                   preferred_element_type=jnp.float32)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     return _grouped(lhs, rhs, group_sizes.astype(jnp.int32),
-                    jnp.dtype(lhs.dtype), bool(interpret))
+                    jnp.dtype(lhs.dtype), _interpret(interpret))
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's movers and activation (ops/moe.py): everything
+# around the grouped products, over the rows below the last held pair
+# only.  XLA's gather costs its output rows, and the pair buffer has the
+# worst case of them; these follow the group sizes, as the products do.
+# Mosaic slices an array in HBM by whole (8, 128) tiles only, so no DMA
+# can fetch one row of 2304 floats: the pair-side mover keeps the token
+# rows in VMEM and copies rows there, and the token-side mover fetches
+# runs of whole 8-row slabs (a token tile's pairs in one group are
+# consecutive pair rows, because the sort is stable).
+
+_MOVER_ROWS = 128               # pair rows, or tokens, a grid step moves
+_ACT_ROWS = 512                 # pair rows a step of the activation
+_MOVER_SOURCE_BYTES = 80 * 2 ** 20      # the token rows, whole in VMEM
+_MOVER_BUFFER_BYTES = 40 * 2 ** 20      # the slabs' two landing buffers
+_SLAB = 8                       # rows of a float32 tile
+# Mosaic lowers these (no erfc there: the exact gelu stays in jnp)
+_KERNEL_ACTIVATIONS = (jax.nn.relu, jax.nn.silu)
+
+
+def _tile_index(i, *_prefetched):
+    """Block i of the rows, all of the columns."""
+    return i, 0
+
+
+def _rows_kernel(tok_ref, x_ref, *refs, scaled, dotted):
+    refs = list(refs)
+    scale_ref = refs.pop(0) if scaled else None
+    z_ref = refs.pop(0) if dotted else None
+    out_ref = refs.pop(0)
+    dot_ref = refs.pop(0) if dotted else None
+    buf, = refs
+    tm = out_ref.shape[0]
+    row0 = pl.program_id(0) * tm
+
+    def slab(b, _):
+        for r in range(_SLAB):          # unrolled: the loop is all scalar
+            r = b * _SLAB + r
+            buf[pl.ds(r, 1), :] = x_ref[pl.ds(tok_ref[row0 + r], 1), :]
+    jax.lax.fori_loop(0, tm // _SLAB, slab, None)
+    rows = buf[...]
+    if dotted:
+        dot_ref[...] = jnp.sum(rows * z_ref[...], axis=1, keepdims=True)
+    if scaled:
+        rows = rows * scale_ref[...]
+    out_ref[...] = rows.astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _rows(x, tok, live, scale, dot, dtype, interpret):
+    C, M, tm = x.shape[1], tok.shape[0], _MOVER_ROWS
+    index = _tile_index
+    operands = [x] + [a for a in (scale, dot) if a is not None]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)]
+    out_specs = [pl.BlockSpec((tm, C), index)]
+    out_shape = [jax.ShapeDtypeStruct((M, C), dtype)]
+    if scale is not None:
+        in_specs.append(pl.BlockSpec((tm, 1), index))
+    if dot is not None:
+        in_specs.append(pl.BlockSpec((tm, C), index))
+        out_specs.append(pl.BlockSpec((tm, 1), index))
+        out_shape.append(jax.ShapeDtypeStruct((M, 1), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, scaled=scale is not None,
+                          dotted=dot is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(live, tm),),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[_scratch((tm, C), x.dtype)]),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=interpret,
+    )(tok, *operands)
+
+
+def rows_of_tokens(x, tok, live, scale=None, dot=None, dtype=None,
+                   interpret=None):
+    """The pair-side mover: row i of the (M, C) result is ``x[tok[i]]``
+    (times ``scale[i]`` if given) as ``dtype`` (default ``x``'s), for
+    the ``live`` first pairs.  ``x`` (S, C) float32, ``tok`` (M,) int32,
+    ``live`` an int32 scalar, ``scale`` (M,) float32.  With ``dot``
+    (M, C) also returns (M,) float32, ``<dot[i], x[tok[i]]>``: a
+    weight's gradient, on the pair side.
+
+    Where C is a multiple of 128, M of the grouped products' row tile
+    and ``x`` fits in VMEM this is a Pallas kernel that copies rows out
+    of VMEM and stops at the last live tile: **the rows past it are not
+    written** (the products' contract) and ``dot`` is not read there.
+    Other shapes take the ``jnp`` gather over all M rows."""
+    dtype = jnp.dtype(dtype or x.dtype)
+    M, (S, C) = tok.shape[0], x.shape
+    if (M % _GROUPED_ROW_TILE or C % 128 or S % _SLAB
+            or x.dtype != jnp.float32
+            or S * C * 4 > _MOVER_SOURCE_BYTES):
+        rows = x[tok]
+        dots = None if dot is None else jnp.sum(rows * dot, axis=1)
+        if scale is not None:
+            rows = rows * scale[:, None]
+        return rows.astype(dtype) if dot is None else (
+            rows.astype(dtype), dots)
+    # mxlint: disable=recompile-churn (a dtype and a bool)
+    out = _rows(x, tok.astype(jnp.int32), live.astype(jnp.int32),
+                None if scale is None else scale.reshape(M, 1), dot, dtype,
+                _interpret(interpret))
+    return out[0] if dot is None else (out[0], out[1].reshape(M))
+
+
+def _token_tile(S, k, G, C):
+    """(tokens a grid step sums: the largest of 128, 64 .. 8 that
+    divides S; the most 8-row slabs its pairs can lie in: a run in each
+    group, each run with a ragged slab at either end), or None where
+    two landing buffers of that many slabs do not fit."""
+    for ts in (128, 64, 32, 16, 8):
+        cap = _ceil_to(ts * k // _SLAB + 2 * G,
+                       _GROUPED_ROW_BLOCK // _SLAB)
+        if S % ts == 0 and 2 * cap * _SLAB * C * 4 <= _MOVER_BUFFER_BYTES:
+            return ts, cap
+    return None
+
+
+def _sum_before(a, axis):
+    """The exclusive prefix sum along a short axis as one masked
+    reduction (``jnp.cumsum`` is a scan of many small steps on the
+    TPU, and these tables are a few hundred numbers)."""
+    n = a.shape[axis]
+    a = jnp.moveaxis(a, axis, -1)
+    before = jnp.arange(n)[:, None] < jnp.arange(n)[None, :]
+    out = jnp.sum(jnp.where(before, a[..., :, None], 0), axis=-2)
+    return jnp.moveaxis(out, -1, axis)
+
+
+def _token_runs(inverse, group, group_sizes, ts, cap):
+    """Where a tile of ``ts`` tokens finds its pairs' rows: pairs are
+    sorted by group and, inside a group, by token, so the tile's pairs
+    in group g are one run of rows.  Returns ((the slabs to fetch, tile
+    by tile, ``cap`` a tile; how many of them; the one among them that
+    the last row of all ends inside, -1 if none does; the number of
+    rows in a group), for each pair the row of its tile's landing buffer
+    that will hold it, -1 for a pair in no group)."""
+    S, k = inverse.shape
+    G = group_sizes.shape[0]
+    tiles = S // ts
+    i32 = jnp.int32
+    # groups lead: the long axis stays minor, where the lanes are
+    of_group = (group.reshape(1, tiles, ts * k)
+                == jnp.arange(G, dtype=i32).reshape(G, 1, 1))
+    count = jnp.sum(of_group, axis=2, dtype=i32)            # (G, tiles)
+    first = (_sum_before(group_sizes, 0)[:, None]
+             + _sum_before(count, 1))
+    slab0 = first // _SLAB
+    n = jnp.where(count > 0, (first + count - 1) // _SLAB - slab0 + 1, 0)
+    base = _sum_before(n, 0)                                # (G, tiles)
+    until = base + n
+    q = jnp.arange(cap, dtype=i32)
+    g_of_q = jnp.minimum(jnp.sum(q >= until[:, :, None], axis=0, dtype=i32),
+                         G - 1)                             # (tiles, cap)
+    mine = g_of_q[None] == jnp.arange(G, dtype=i32).reshape(G, 1, 1)
+    slabs = q + jnp.sum(jnp.where(mine, (slab0 - base)[:, :, None], 0),
+                        axis=0)
+    live = jnp.sum(group_sizes)
+    ragged = (n > 0) & (first + count == live) & (live % _SLAB > 0)
+    tail = jnp.max(jnp.where(ragged, until - 1, -1), axis=0)
+    shift = (base - slab0) * _SLAB
+    vrow = inverse.reshape(tiles, ts * k) + jnp.sum(
+        jnp.where(of_group, shift[:, :, None], 0), axis=0)
+    vrow = jnp.where(group.reshape(tiles, ts * k) < G, vrow, -1)
+    return ((slabs.reshape(-1), until[-1], tail, live.reshape(1)),
+            vrow.reshape(S, k))
+
+
+def _tokens_kernel(slab_ref, n_ref, tail_ref, live_ref, z_hbm, vrow_ref,
+                   w_ref, out_ref, buf, sem, *, cap):
+    ts, k = w_ref.shape
+    i = pl.program_id(0)
+    block = _GROUPED_ROW_BLOCK
+
+    def slab(step, q):
+        at = lambda a: pl.ds(pl.multiple_of(a * _SLAB, _SLAB), _SLAB)  # noqa: E731,E501
+        return pltpu.make_async_copy(
+            z_hbm.at[at(slab_ref[step * cap + q])],
+            buf.at[step % 2, at(q)], sem.at[step % 2])
+
+    def fetch(step):
+        jax.lax.fori_loop(0, n_ref[step],
+                          lambda q, _: slab(step, q).start(), None)
+
+    # two landing buffers: the next tile's slabs are on their way while
+    # this one is summed.  Zero them first: a block of 128 rows is
+    # multiplied whole, and what no slab has landed on must be finite.
+    @pl.when(i == 0)
+    def _first():
+        buf[...] = jnp.zeros_like(buf)
+        fetch(i)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        fetch(i + 1)
+
+    jax.lax.fori_loop(0, n_ref[i], lambda q, _: slab(i, q).wait(), None)
+    slot = i % 2
+
+    # so must the rows past the last of all be, in the slab it ends in
+    @pl.when(tail_ref[i] >= 0)
+    def _tail():
+        rows = pl.ds(pl.multiple_of(tail_ref[i] * _SLAB, _SLAB), _SLAB)
+        x = buf[slot, rows, :]
+        row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        buf[slot, rows, :] = jnp.where(row < live_ref[0] % _SLAB, x, 0)
+
+    out_ref[...] = jnp.zeros_like(out_ref)
+    vrow, w = vrow_ref[...], w_ref[...]
+
+    def rows(b, _):
+        # the MXU moves the rows: (tokens x rows) weights, one a pair,
+        # times the rows, float32 throughout
+        col = b * block + jax.lax.broadcasted_iota(jnp.int32, (ts, block), 1)
+        weights = jnp.zeros((ts, block), jnp.float32)
+        for j in range(k):
+            weights = weights + jnp.where(vrow[:, j:j + 1] == col,
+                                          w[:, j:j + 1], 0.0)
+        out_ref[...] += jax.lax.dot_general(
+            weights, buf[slot, _block_rows(b), :], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    jax.lax.fori_loop(0, pl.cdiv(n_ref[i] * _SLAB, block), rows, None)
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _tokens(z, w, inverse, group, group_sizes, interpret):
+    (S, k), C = inverse.shape, z.shape[1]
+    ts, cap = _token_tile(S, k, group_sizes.shape[0], C)
+    index = _tile_index
+    meta, vrow = _token_runs(inverse, group, group_sizes, ts, cap)
+    return pl.pallas_call(
+        functools.partial(_tokens_kernel, cap=cap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(S // ts,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((ts, k), index),
+                      pl.BlockSpec((ts, k), index)],
+            out_specs=pl.BlockSpec((ts, C), index),
+            scratch_shapes=[_scratch((2, cap * _SLAB, C), z.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((S, C), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=interpret,
+    )(*meta, z, vrow, w)
+
+
+def tokens_of_rows(z, w, inverse, group, group_sizes, interpret=None):
+    """The token-side mover, ``rows_of_tokens``' transpose: token s of
+    the (S, C) float32 result is the float32 sum over its k pairs j of
+    ``w[s, j] * z[inverse[s, j]]`` for the pairs that are in a group,
+    and of nothing for the others (whatever ``z`` holds there).
+    ``z`` (M, C) float32, the pairs' rows sorted by group and inside a
+    group by pair (s, j); ``w`` (S, k) float32; ``inverse`` (S, k) int32
+    the row of each pair; ``group`` (S, k) int32 its group, G for a pair
+    in none; ``group_sizes`` (G,).
+
+    Where the shapes tile (``rows_of_tokens``) a Pallas kernel fetches
+    only the 8-row slabs that hold a pair in a group and the MXU sums
+    those pairs' rows at float32 precision (in its own order, not slot
+    order); other shapes gather all M rows, select and sum in ``jnp``."""
+    (S, k), C = inverse.shape, z.shape[1]
+    G = group_sizes.shape[0]
+    if ((S * k) % _GROUPED_ROW_TILE or C % 128 or z.dtype != jnp.float32
+            or _token_tile(S, k, G, C) is None):
+        held = group < G
+        pairs = jnp.where(held[..., None], z[inverse], 0)
+        return jnp.einsum("skc,sk->sc", pairs,
+                          jnp.where(held, w, 0).astype(pairs.dtype))
+    # mxlint: disable=recompile-churn (interpret is a bool)
+    return _tokens(z, w.astype(jnp.float32), inverse.astype(jnp.int32),
+                   group.astype(jnp.int32), group_sizes.astype(jnp.int32),
+                   _interpret(interpret))
+
+
+def _halves(h, gated):
+    H = h.shape[1] // 2
+    return (h[:, :H], h[:, H:]) if gated else (h, None)
+
+
+def _activated(h, act, gated):
+    gate, up = _halves(h, gated)
+    return act(gate) * up if gated else act(gate)
+
+
+def _act_kernel(h_ref, out_ref, *, act, gated):
+    out_ref[...] = _activated(h_ref[...], act, gated).astype(out_ref.dtype)
+
+
+def _act_grad_kernel(h_ref, g_ref, out_ref, *, act, gated):
+    gate, up = _halves(h_ref[...], gated)
+    g = g_ref[...]
+    a, vjp = jax.vjp(act, gate)
+    H = gate.shape[1]
+    out_ref[:, :H] = vjp(g * up if gated else g)[0].astype(out_ref.dtype)
+    if gated:
+        out_ref[:, H:] = (g * a).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _act(h, g, live, act, gated, dtype, interpret):
+    M, N = h.shape
+    H = N // 2 if gated else N
+    ta = _ACT_ROWS
+    index = _tile_index
+    kernel, operands, width = _act_kernel, [h], H
+    in_specs = [pl.BlockSpec((ta, N), index)]
+    if g is not None:
+        kernel, operands, width = _act_grad_kernel, [h, g], N
+        in_specs.append(pl.BlockSpec((ta, H), index))
+    return pl.pallas_call(
+        functools.partial(kernel, act=act, gated=gated),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=(pl.cdiv(live, ta),),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((ta, width), index)),
+        out_shape=jax.ShapeDtypeStruct((M, width), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=interpret,
+    )(*operands)
+
+
+def expert_activation(h, live, act, gated, g=None, dtype=None,
+                      interpret=None):
+    """The expert FFN's activation over the ``live`` first rows of the
+    first product ``h`` (M, N) float32: ``act(h)``, or ``act(gate) * up``
+    of ``h = [gate | up]`` if ``gated``, as ``dtype`` (default ``h``'s).
+    With ``g``, the cotangent of that result, its gradient in ``h``
+    instead.
+
+    Where the halves are multiples of 128 columns and M of the grouped
+    products' row tile a Pallas kernel stops at the last live tile and
+    **the rows past it are not written**; other shapes, and the exact
+    gelu, compute all M rows in ``jnp``."""
+    dtype = jnp.dtype(dtype or h.dtype)
+    M, N = h.shape
+    if (M % _GROUPED_ROW_TILE or (N // 2 if gated else N) % 128
+            or act not in _KERNEL_ACTIVATIONS):
+        fn = functools.partial(_activated, act=act, gated=gated)
+        if g is None:
+            return fn(h).astype(dtype)
+        return jax.vjp(fn, h)[1](g.astype(h.dtype))[0].astype(dtype)
+    # mxlint: disable=recompile-churn (one of _KERNEL_ACTIVATIONS, bools, a
+    # dtype)
+    return _act(h, g, live.astype(jnp.int32), act, bool(gated),
+                dtype, _interpret(interpret))
 
 
 # ---------------------------------------------------------------------------

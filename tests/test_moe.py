@@ -186,34 +186,85 @@ def test_moe_ffn_gradients_match_the_dense_layer(recompute, shape):
         _assert_close(g, w, rtol, atol)
 
 
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_the_layers_predicate_is_the_products(shape):
+    """Where the grouped products take the Pallas kernels so does every
+    pass around them, forward and backward: nothing but a kernel writes
+    a buffer of S*k rows of features, and ``ops/moe.py`` gathers only
+    numbers (a pair's weight).  Where they take ``lax.ragged_dot`` the
+    layer runs no kernel at all."""
+    sizes, k, scale, _rtol, _atol = SHAPES[shape]
+    x, wg, w1, w2 = _weights(4, **sizes)
+    args = (x, scale * wg, w1[2:6], w2[2:6])
+
+    def layer(*a):
+        return moe_ffn(*a, experts_per_token=k, first_expert=2,
+                       activation="silu", gated=True)[0].sum()
+    eqns = list(_equations(jax.make_jaxpr(
+        jax.grad(layer, argnums=(0, 1, 2, 3)))(*args).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    pair_rows = x.shape[0] * k
+    wide = [e.primitive.name for e in eqns for v in e.outvars
+            if getattr(v.aval, "ndim", 0) == 2
+            and v.aval.shape[0] == pair_rows and v.aval.shape[1] > 1
+            and e.primitive.name not in ("pallas_call", "pjit", "jit",
+                                         "custom_vjp_call",
+                                         "custom_vjp_call_jaxpr")]
+    if shape == "kernel":
+        # dispatch, product, activation, product, combine; combine's
+        # transpose, two gradients a product, the activation's, dispatch's
+        assert names.count("pallas_call") == 5 + 7
+        assert "ragged_dot_general" not in names
+        assert wide == []
+    else:
+        assert "pallas_call" not in names
+        assert names.count("ragged_dot_general") >= 2
+
+
 @pytest.mark.parametrize("recompute", [False, True])
 def test_the_rows_past_the_last_held_pair_may_hold_anything(
         recompute, monkeypatch):
-    """The kernels never visit the rows past the last group: fill them
-    with NaN, in both products and in the rows' gradients, and the
-    layer's output and gradients are what they were."""
+    """No pass visits the rows past the last held pair: fill them with
+    NaN in every pair buffer, forward and backward (the gathered rows,
+    both products, the activation, the gradients of all of them and the
+    weights' gradient taken on the pair side), and the layer's output
+    and gradients are what they were."""
     from mxnet_tpu.ops import moe
-    real, calls = moe.grouped_matmul, []
+    calls = []
 
-    def poison(a, sizes):
-        row = jnp.arange(a.shape[0])[:, None]
-        return jnp.where(row < sizes.sum(), a, jnp.nan)
+    def poison(a, live):
+        row = jnp.arange(a.shape[0]).reshape((-1,) + (1,) * (a.ndim - 1))
+        return jnp.where(row < live, a, jnp.nan)
 
-    @jax.custom_vjp
-    def poisoned(lhs, rhs, sizes):
-        return poison(real(lhs, rhs, sizes), sizes)
+    def poisoned(name, live_of, outputs=None):
+        """``moe.<name>`` with the pair buffers among its results
+        poisoned; ``live_of`` reads the number of live rows off its
+        arguments."""
+        real = getattr(moe, name)
 
-    def fwd(lhs, rhs, sizes):
-        calls.append(lhs.shape)
-        out, vjp = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)
-        return poison(out, sizes), (vjp, sizes)
-
-    def bwd(res, g):
-        vjp, sizes = res
-        d_lhs, d_rhs = vjp(g)
-        return poison(d_lhs, sizes), d_rhs, None
-    poisoned.defvjp(fwd, bwd)
-    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+        def fn(*args, **kw):
+            out, live = real(*args, **kw), live_of(*args, **kw)
+            calls.append(name)
+            if not isinstance(out, tuple):
+                return poison(out, live)
+            return tuple(poison(o, live) if outputs is None or i in outputs
+                         else o for i, o in enumerate(out))
+        monkeypatch.setattr(moe, name, fn)
+    poisoned("rows_of_tokens", lambda x, tok, live, **kw: live)
+    poisoned("grouped_matmul", lambda lhs, rhs, sizes: sizes.sum())
+    poisoned("expert_activation", lambda h, live, *a, **kw: live)
+    poisoned("grouped_matmul_grads",
+             lambda lhs, rhs, sizes, g: sizes.sum(), outputs=(0,))
 
     x, wg, w1, w2 = _weights(7, S=512, C=128, H=128)
     first, held, k = 5, 2, 4            # a quarter of the pairs held here
@@ -232,7 +283,8 @@ def test_the_rows_past_the_last_held_pair_may_hold_anything(
         want = jax.grad(lambda *a: (dense_layer(*a, k, first) * proj).sum(),
                         argnums=(0, 1, 2, 3))(*args)
         want_out = dense_layer(*args, k, first)
-    assert len(calls) >= 2
+    assert set(calls) == {"rows_of_tokens", "grouped_matmul",
+                          "expert_activation", "grouped_matmul_grads"}
     assert 0 < float(rows.sum()) < 0.5 * x.shape[0] * k
     _assert_close(out, want_out, 2e-2, 2e-2)
     for g, w in zip(got, want):

@@ -15,9 +15,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxnet_tpu.ops.pallas_kernels import (flash_attention, grouped_matmul,
+from mxnet_tpu.ops.pallas_kernels import (expert_activation,
+                                          flash_attention, grouped_matmul,
                                           ragged_paged_attention,
-                                          ragged_paged_verify)
+                                          ragged_paged_verify,
+                                          rows_of_tokens, tokens_of_rows)
 
 S = jax.ShapeDtypeStruct
 
@@ -85,3 +87,48 @@ def test_grouped_matmul_lowers_for_tpu(M, K, N, G, mode):
                             S((G, K, N), jnp.float32), S((G,), jnp.int32))
     assert text.count("tpu_custom_call") >= (1 if mode == "fwd" else 3)
     assert "ragged_dot" not in text
+
+
+# the expert layer's movers and activation at Mellum's shapes (8192
+# tokens of 2304, 8 pairs a token, 16 groups, first product 1792 wide)
+# and a small one, each in the forms the forward and the backward pass
+# call: the interpreter never applies Mosaic's block-shape rule.  (That
+# Mosaic slices an array in HBM by whole (8, 128) tiles only, which
+# shaped both movers, shows at its compile, not here: PERF.md section 7,
+# recipe 2.)
+@pytest.mark.parametrize("s,k,c,h,g", [(8192, 8, 2304, 896, 16),
+                                       (512, 4, 128, 128, 3)])
+@pytest.mark.parametrize("mover", ["rows", "rows_scaled_and_dotted",
+                                   "tokens", "activation",
+                                   "activation_gradient"])
+def test_expert_movers_lower_for_tpu(mover, s, k, c, h, g):
+    m = s * k
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    fn, avals = {
+        "rows": (
+            lambda x, tok, live: rows_of_tokens(
+                x, tok, live, dtype=bf16, interpret=False),
+            (S((s, c), f32), S((m,), i32), S((), i32))),
+        "rows_scaled_and_dotted": (
+            lambda x, tok, live, scale, dot: rows_of_tokens(
+                x, tok, live, scale=scale, dot=dot, dtype=bf16,
+                interpret=False),
+            (S((s, c), f32), S((m,), i32), S((), i32), S((m,), f32),
+             S((m, c), f32))),
+        "tokens": (
+            functools.partial(tokens_of_rows, interpret=False),
+            (S((m, c), f32), S((s, k), f32), S((s, k), i32), S((s, k), i32),
+             S((g,), i32))),
+        "activation": (
+            lambda hh, live: expert_activation(
+                hh, live, jax.nn.silu, True, dtype=bf16, interpret=False),
+            (S((m, 2 * h), f32), S((), i32))),
+        "activation_gradient": (
+            lambda hh, da, live: expert_activation(
+                hh, live, jax.nn.silu, True, g=da, dtype=bf16,
+                interpret=False),
+            (S((m, 2 * h), f32), S((m, h), f32), S((), i32))),
+    }[mover]
+    text = _tpu_module_text(fn, *avals)
+    assert text.count("tpu_custom_call") == 1
+    assert "gather" not in text.replace("all-gather", "")
