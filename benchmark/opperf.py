@@ -142,6 +142,29 @@ def _op_specs(large=False):
              nd.array(rng.randn(8, 512, 1024).astype(np.float32) * 0.05),
              nd.array(rng.randn(8, 1024, 512).astype(np.float32) * 0.05)],
             {})),
+        # the router told to score by sigmoid, choose by score + bias,
+        # renormalise and scale (Nemotron-3's: 6 of 128)
+        "moe_topk_route": ("moe", lambda nd, rng: (
+            [nd.array(rng.rand(8 * 128, 512).astype(np.float32)),
+             nd.array(rng.randn(512, 128).astype(np.float32)),
+             nd.array(rng.randn(128).astype(np.float32) * 0.01)],
+            {"experts_per_token": 6, "scoring": "sigmoid", "scale": 2.5})),
+        # state-space (Mamba-2: 16 heads of 64 over 2 groups, state 128)
+        "ssm_conv": ("ssm", lambda nd, rng: (
+            [nd.array(rng.randn(2, 1024, 1536).astype(np.float32)),
+             nd.array(rng.rand(1536, 4).astype(np.float32) - 0.5),
+             nd.array(rng.rand(1536).astype(np.float32) - 0.5)], {})),
+        "ssm_scan": ("ssm", lambda nd, rng: (
+            [nd.array(rng.randn(2, 1024, 16, 64).astype(np.float32)),
+             nd.array(rng.randn(2, 1024, 16).astype(np.float32)),
+             nd.array(np.log(rng.uniform(1, 16, 16)).astype(np.float32)),
+             nd.array(rng.randn(2, 1024, 2, 128).astype(np.float32)),
+             nd.array(rng.randn(2, 1024, 2, 128).astype(np.float32)),
+             nd.ones((16,)), nd.zeros((16,))], {"chunk": 128})),
+        "ssm_gate_norm": ("ssm", lambda nd, rng: (
+            [nd.array(rng.randn(2, 1024, 1024).astype(np.float32)),
+             nd.array(rng.randn(2, 1024, 1024).astype(np.float32)),
+             nd.ones((1024,))], {"groups": 2})),
     }
     return specs
 

@@ -33,6 +33,15 @@ class MoEFFN(HybridBlock):
     ``w2 (act(w1_gate x) * (w1_up x))`` (SwiGLU with ``activation=
     "silu"``), plain ones ``w2 act(w1 x)``; none has a bias.
 
+    ``scoring``: how the router scores (``ops.moe.moe_topk_route``):
+    "softmax", or "sigmoid" with ``route_bias`` (num_experts,), added
+    to the scores to choose and not to weigh, and ``route_scale`` on the
+    renormalised weights.  The bias is no weight: no optimizer moves it
+    (its published update rule, from the experts' load, is a training
+    loop's, not this layer's).  ``shared_hidden_size`` > 0 adds one
+    shared expert of that width and of the routed experts' kind, which
+    every token passes.
+
     ``rows_routed`` (n_held,) is a cumulative count, kept on the device
     as float32 (exact to 2**24 a expert; read differences), of the
     (token, expert) pairs routed to each held expert: auxiliary state
@@ -50,6 +59,7 @@ class MoEFFN(HybridBlock):
     def __init__(self, units, hidden_size, num_experts,
                  experts_per_token=1, experts_held=None, first_expert=0,
                  activation="gelu", gated=False, recompute=False, train_router=True,
+                 scoring="softmax", route_scale=1.0, shared_hidden_size=0,
                  weight_initializer=None, **kwargs):
         super().__init__(**kwargs)
         held = num_experts if experts_held is None else int(experts_held)
@@ -61,14 +71,21 @@ class MoEFFN(HybridBlock):
             raise MXNetError(
                 f"MoEFFN: experts {first_expert}..{first_expert + held - 1} "
                 f"held of {num_experts}")
-        if activation not in ("relu", "gelu", "silu"):
+        if activation not in ("relu", "relu2", "gelu", "silu"):
             raise MXNetError(
                 f"MoEFFN: unsupported activation {activation!r} "
-                f"(supported: 'relu', 'gelu', 'silu')")
+                f"(supported: 'relu', 'relu2', 'gelu', 'silu')")
+        if scoring not in ("softmax", "sigmoid"):
+            raise MXNetError(
+                f"MoEFFN: unsupported scoring {scoring!r} "
+                f"(supported: 'softmax', 'sigmoid')")
         self._kw = dict(experts_per_token=int(experts_per_token),
                         first_expert=int(first_expert),
                         activation=activation, gated=bool(gated),
-                        recompute=bool(recompute))
+                        recompute=bool(recompute), scoring=scoring,
+                        route_scale=float(route_scale),
+                        shared_expert=bool(shared_hidden_size))
+        fan = 2 if gated else 1
         with self.name_scope():
             self.gate_weight = self.params.get(
                 "gate_weight", shape=(units, num_experts),
@@ -76,7 +93,7 @@ class MoEFFN(HybridBlock):
                 grad_req="write" if train_router else "null")
             self.expert_w1 = self.params.get(
                 "expert_w1",
-                shape=(held, units, (2 if gated else 1) * hidden_size),
+                shape=(held, units, fan * hidden_size),
                 init=weight_initializer)
             self.expert_w2 = self.params.get(
                 "expert_w2", shape=(held, hidden_size, units),
@@ -84,10 +101,24 @@ class MoEFFN(HybridBlock):
             self.rows_routed = self.params.get(
                 "rows_routed", shape=(held,), init="zeros",
                 grad_req="null")
+            if scoring == "sigmoid":
+                self.route_bias = self.params.get(
+                    "route_bias", shape=(num_experts,), init="zeros",
+                    grad_req="null")
+            if shared_hidden_size:
+                self.shared_w1 = self.params.get(
+                    "shared_w1", shape=(units, fan * shared_hidden_size),
+                    init=weight_initializer)
+                self.shared_w2 = self.params.get(
+                    "shared_w2", shape=(shared_hidden_size, units),
+                    init=weight_initializer)
 
     def hybrid_forward(self, F, x, gate_weight, expert_w1, expert_w2,
-                       rows_routed):
-        out, rows = F.moe_ffn(x, gate_weight, expert_w1, expert_w2,
+                       rows_routed, route_bias=None, shared_w1=None,
+                       shared_w2=None):
+        more = [a for a in (route_bias, shared_w1, shared_w2)
+                if a is not None]
+        out, rows = F.moe_ffn(x, gate_weight, expert_w1, expert_w2, *more,
                               **self._kw)
         update_aux_state(self.rows_routed, rows_routed + rows)
         return out

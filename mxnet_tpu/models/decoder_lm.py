@@ -1,6 +1,8 @@
 """Present-day decoder-only language models for training: RMSNorm,
 rotary positions, grouped key/value heads, window and full causal
-layers through the flash kernels, dense or sparse gated feed-forwards.
+layers through the flash kernels, dense or sparse feed-forwards, and
+hybrid models whose layers are one mixer each (Mamba-2, attention
+without rotary, an expert layer with a shared expert).
 One ``DecoderCell`` class; ``layer_types`` says what each layer is.
 
 The serving side (the paged forwards of ``transformer_blocks``) has
@@ -12,8 +14,8 @@ from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.contrib.moe import MoEFFN
-from .transformer_blocks import (DecoderCell, GatedFFN, RMSNorm,
-                                 RotaryGroupedAttention)
+from .transformer_blocks import (DecoderCell, GatedFFN, Mamba2Mixer,
+                                 RMSNorm, RotaryGroupedAttention)
 
 __all__ = ["DecoderLM", "get_decoder_lm"]
 
@@ -21,17 +23,26 @@ __all__ = ["DecoderLM", "get_decoder_lm"]
 class DecoderLM(HybridBlock):
     """``lm(tokens (B, L)) -> logits (B, L, vocab_size)``.
 
-    ``layer_types``: one of "sliding_attention" / "full_attention" a
-    layer; ``rope``: the ``rope`` op's keyword arguments for each of the
-    two.  ``num_experts`` > 0 makes every feed-forward sparse:
-    ``experts_per_token`` of ``num_experts`` experts of width
-    ``expert_hidden_size``, of which this model holds ``experts_held``
+    ``layer_types``, a layer each.  "sliding_attention" /
+    "full_attention": attention, then a feed-forward, each under its
+    norm; ``rope``: the ``rope`` op's keyword arguments for each of the
+    two.  "mamba2" / "attention" / "moe": ONE mixer under one norm, a
+    hybrid model's layer: a Mamba-2 mixer of the sizes in ``mamba``
+    (``Mamba2Mixer``'s keyword arguments), causal attention (rotated
+    only if ``rope`` has an entry "attention": such a model's Mamba
+    layers carry position), or the expert layer.
+
+    ``num_experts`` > 0 makes every feed-forward, and every "moe"
+    layer, sparse: ``experts_per_token`` of ``num_experts`` experts of
+    width ``expert_hidden_size`` (``expert_activation``, gated or not
+    by ``expert_gated``), of which this model holds ``experts_held``
     from ``first_expert`` on (all by default; one chip's share under
-    expert parallelism).  ``vocab_size`` is the number of rows of the
-    embedding and the head held here.  ``recompute_experts``: see
-    ``ops.moe.moe_ffn``; ``train_router``: see ``MoEFFN``;
-    ``attention_dtype``: what the flash kernels
-    compute in (``RotaryGroupedAttention``).
+    expert parallelism); ``router``: ``MoEFFN``'s ``scoring`` and
+    ``route_scale``; ``shared_expert_hidden_size`` > 0 gives each
+    expert layer a shared expert.  ``vocab_size`` is the number of rows
+    of the embedding and the head held here.  ``recompute_experts``:
+    see ``ops.moe.moe_ffn``; ``train_router``: see ``MoEFFN``; ``attention_dtype``: what the
+    flash kernels compute in (``RotaryGroupedAttention``).
     """
 
     def __init__(self, vocab_size, units, layer_types, num_heads,
@@ -39,39 +50,56 @@ class DecoderLM(HybridBlock):
                  hidden_size=0, num_experts=0, experts_per_token=1,
                  expert_hidden_size=0, experts_held=None, first_expert=0,
                  rms_norm_eps=1e-6, recompute_experts=False,
-                 train_router=True, attention_dtype="bfloat16", **kwargs):
+                 train_router=True, attention_dtype="bfloat16",
+                 expert_activation="silu", expert_gated=True, router=None,
+                 shared_expert_hidden_size=0, mamba=None, **kwargs):
         super().__init__(**kwargs)
         rope = rope or {}
         self.vocab_size, self.units = int(vocab_size), int(units)
+
+        def attention(kind, prefix):
+            # Mellum's two kinds always rotate (an absent entry: the
+            # op's defaults); the hybrid's rotates only if told how
+            return RotaryGroupedAttention(
+                units, num_heads, num_kv_heads, head_dim,
+                window=window if kind == "sliding_attention" else None,
+                rope=rope.get(kind, None if kind == "attention" else {}),
+                compute_dtype=attention_dtype, prefix=prefix + "attention_")
+
+        def experts(prefix):
+            return MoEFFN(
+                units, expert_hidden_size, num_experts,
+                experts_per_token=experts_per_token,
+                experts_held=experts_held, first_expert=first_expert,
+                activation=expert_activation, gated=expert_gated,
+                recompute=recompute_experts, train_router=train_router,
+                shared_hidden_size=shared_expert_hidden_size,
+                prefix=prefix + "moe_", **(router or {}))
+
         with self.name_scope():
             self.word_embed = nn.Embedding(vocab_size, units,
                                            prefix="word_embed_")
             self.cells = nn.HybridSequential(prefix="")
             for i, kind in enumerate(layer_types):
-                if kind not in ("sliding_attention", "full_attention"):
-                    raise MXNetError(f"layer_types[{i}]: {kind!r}")
-                sliding = kind == "sliding_attention"
                 with self.cells.name_scope():
                     prefix = f"layer{i}_"
-                    attention = RotaryGroupedAttention(
-                        units, num_heads, num_kv_heads, head_dim,
-                        window=window if sliding else None,
-                        rope=rope.get(kind), compute_dtype=attention_dtype,
-                        prefix=prefix + "attention_")
-                    if num_experts:
-                        ffn = MoEFFN(
-                            units, expert_hidden_size, num_experts,
-                            experts_per_token=experts_per_token,
-                            experts_held=experts_held,
-                            first_expert=first_expert, activation="silu",
-                            gated=True, recompute=recompute_experts,
-                            train_router=train_router,
-                            prefix=prefix + "moe_")
+                    if kind in ("sliding_attention", "full_attention"):
+                        blocks = (attention(kind, prefix),
+                                  experts(prefix) if num_experts else
+                                  GatedFFN(units, hidden_size,
+                                           prefix=prefix + "ffn_"))
+                    elif kind == "attention":
+                        blocks = (attention(kind, prefix),)
+                    elif kind == "moe":
+                        blocks = (experts(prefix),)
+                    elif kind == "mamba2":
+                        blocks = (Mamba2Mixer(
+                            units, prefix=prefix + "mamba_", **mamba),)
                     else:
-                        ffn = GatedFFN(units, hidden_size,
-                                       prefix=prefix + "ffn_")
-                    self.cells.add(DecoderCell(units, attention, ffn,
-                                               rms_norm_eps, prefix=prefix))
+                        raise MXNetError(f"layer_types[{i}]: {kind!r}")
+                    self.cells.add(DecoderCell(
+                        units, *blocks, rms_norm_eps=rms_norm_eps,
+                        prefix=prefix))
             self.final_norm = RMSNorm(units, rms_norm_eps,
                                       prefix="final_norm_")
             self.lm_head = nn.Dense(vocab_size, in_units=units,
@@ -99,6 +127,21 @@ _DECODER_CONFIGS = {
                   theta=500000.0, yarn_factor=16.0, yarn_original_max=8192,
                   yarn_beta_fast=32.0, yarn_beta_slow=1.0,
                   attention_factor=1.2772588722239782)}),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B-BF16, config.json (model_type
+    # nemotron_h): a layer is one mixer, its kind a letter of
+    # hybrid_override_pattern
+    "nemotron_3_nano_30b_a3b": dict(
+        vocab_size=131072, units=2688,
+        layer_types=tuple(
+            {"M": "mamba2", "E": "moe", "*": "attention"}[c] for c in
+            "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"),
+        num_heads=32, num_kv_heads=2, head_dim=128, rms_norm_eps=1e-5,
+        mamba=dict(num_heads=64, head_dim=64, state_size=128, n_groups=8,
+                   conv_kernel=4, chunk=128, norm_eps=1e-5),
+        num_experts=128, experts_per_token=6, expert_hidden_size=1856,
+        expert_activation="relu2", expert_gated=False,
+        router=dict(scoring="sigmoid", route_scale=2.5),
+        shared_expert_hidden_size=3712),
 }
 
 

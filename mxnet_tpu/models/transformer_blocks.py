@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import numpy as np
 
 from ..base import MXNetError
@@ -21,7 +22,8 @@ from ..gluon.block import HybridBlock
 __all__ = ["PositionwiseFFN", "MultiHeadSelfAttention",
            "MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerDecoderLM",
-           "RMSNorm", "GatedFFN", "RotaryGroupedAttention", "DecoderCell",
+           "RMSNorm", "GatedFFN", "RotaryGroupedAttention", "Mamba2Mixer",
+           "DecoderCell",
            "paged_lm_params", "paged_prefill", "paged_decode_step",
            "paged_verify", "paged_verify_batch"]
 
@@ -293,7 +295,9 @@ class RotaryGroupedAttention(HybridBlock):
 
     ``window``: key ``s`` is visible to query ``t`` iff
     ``t - window < s <= t``; None is plain causal.  ``rope``: keyword
-    arguments of the ``rope`` op (theta, the YaRN parameters).
+    arguments of the ``rope`` op (theta, the YaRN parameters; an empty
+    dict rotates at the op's defaults); None leaves q and k as they are
+    (a model whose other layers carry position).
     ``kv_proj`` holds [k | v].  ``compute_dtype``: what q, k and v are
     rounded to for the kernels (the MXU's fast path; the default
     matmuls round float32 operands to it too); the output returns to
@@ -312,7 +316,7 @@ class RotaryGroupedAttention(HybridBlock):
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim = head_dim
         self._window = -1 if window is None else int(window)
-        self._rope = dict(rope or {})
+        self._rope = None if rope is None else dict(rope)
         self._compute_dtype = compute_dtype
         with self.name_scope():
             self.q_proj = nn.Dense(num_heads * head_dim, in_units=units,
@@ -332,8 +336,9 @@ class RotaryGroupedAttention(HybridBlock):
         kv = self.kv_proj(x)
         k = F.slice_axis(kv, axis=-1, begin=0, end=Hkv * D)
         v = F.slice_axis(kv, axis=-1, begin=Hkv * D, end=None)
-        q = F.rope(q, **self._rope)
-        k = F.rope(k.reshape((B, L, Hkv, D)), **self._rope)
+        k = k.reshape((B, L, Hkv, D))
+        if self._rope is not None:
+            q, k = F.rope(q, **self._rope), F.rope(k, **self._rope)
         v = v.reshape((B, L, Hkv, D))
         out = F.flash_attention(
             *(F.cast(a, dtype=self._compute_dtype) for a in (q, k, v)),
@@ -342,22 +347,85 @@ class RotaryGroupedAttention(HybridBlock):
         return self.out_proj(out)
 
 
-class DecoderCell(HybridBlock):
-    """The present-day pre-norm decoder block over (B, L, C):
-    ``h = x + attention(norm(x))``, ``y = h + ffn(norm(h))``, RMSNorm
-    both.  ``attention`` and ``ffn`` are the blocks to run: what kind of
-    layer this is (window or full attention, dense or sparse
-    feed-forward) is how they were configured, not another class."""
+class Mamba2Mixer(HybridBlock):
+    """The Mamba-2 mixer over (B, L, C) (Dao and Gu 2024; ``ops/ssm.py``
+    has the equations): ``in_proj`` gives [z | x B C | dt] of widths
+    ``inner | inner + 2 * n_groups * state_size | num_heads`` with
+    ``inner = num_heads * head_dim``; x, B and C pass the causal
+    depthwise convolution of ``conv_kernel`` taps and silu; the
+    selective scan runs in chunks of ``chunk``; its result is gated by
+    ``silu(z)``, RMS-normed over each of ``n_groups`` groups of
+    channels, and ``out_proj`` returns to C.  No bias but the
+    convolution's.
+    """
 
-    def __init__(self, units, attention, ffn, rms_norm_eps=1e-6, **kwargs):
+    def __init__(self, units, num_heads, head_dim, state_size, n_groups,
+                 conv_kernel=4, chunk=128, norm_eps=1e-5, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % n_groups:
+            raise MXNetError(f"{n_groups} groups of B and C do not divide "
+                             f"{num_heads} heads")
+        inner = num_heads * head_dim
+        conv_dim = inner + 2 * n_groups * state_size
+        self._sizes = (num_heads, head_dim, n_groups, state_size)
+        self._chunk, self._eps = int(chunk), norm_eps
+        with self.name_scope():
+            self.in_proj = nn.Dense(inner + conv_dim + num_heads,
+                                    in_units=units, use_bias=False,
+                                    flatten=False, prefix="in_proj_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(conv_dim, conv_kernel))
+            self.conv_bias = self.params.get(
+                "conv_bias", shape=(conv_dim,), init="zeros")
+            self.dt_bias = self.params.get(
+                "dt_bias", shape=(num_heads,), init="zeros")
+            self.A_log = self.params.get(
+                "A_log", shape=(num_heads,), init="zeros")
+            self.D = self.params.get("D", shape=(num_heads,), init="ones")
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(inner,), init="ones")
+            self.out_proj = nn.Dense(units, in_units=inner, use_bias=False,
+                                     flatten=False, prefix="out_proj_")
+
+    def hybrid_forward(self, F, x, conv_weight, conv_bias, dt_bias, A_log,
+                       D, norm_gamma):
+        H, P, G, N = self._sizes
+        with jax.named_scope("mx.ssm.in_proj"):
+            h = self.in_proj(x)
+        y = F.ssm_mixer(h, conv_weight, conv_bias, dt_bias, A_log, D,
+                        norm_gamma, num_heads=H, head_dim=P, n_groups=G,
+                        state_size=N, chunk=self._chunk, eps=self._eps)
+        with jax.named_scope("mx.ssm.out_proj"):
+            return self.out_proj(y)
+
+
+class DecoderCell(HybridBlock):
+    """The present-day pre-norm decoder block over (B, L, C), RMSNorm
+    throughout.  With ``ffn``: ``h = x + mixer(norm(x))``,
+    ``y = h + ffn(norm(h))`` (``mixer`` an attention block).  Without:
+    one mixer alone under one norm, ``y = x + mixer(norm(x))`` (a hybrid
+    model's layer: a state-space mixer, an attention block or an expert
+    layer).  What kind of layer this is (window or full attention,
+    Mamba-2, dense or sparse feed-forward) is how the blocks handed in
+    were configured, not another class."""
+
+    def __init__(self, units, mixer, ffn=None, rms_norm_eps=1e-6, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
-            self.attn_norm = RMSNorm(units, rms_norm_eps, prefix="attn_norm_")
-            self.attention = attention
-            self.ffn_norm = RMSNorm(units, rms_norm_eps, prefix="ffn_norm_")
+            if ffn is None:
+                self.norm = RMSNorm(units, rms_norm_eps, prefix="norm_")
+                self.mixer = mixer
+            else:
+                self.attn_norm = RMSNorm(units, rms_norm_eps,
+                                         prefix="attn_norm_")
+                self.attention = mixer
+                self.ffn_norm = RMSNorm(units, rms_norm_eps,
+                                        prefix="ffn_norm_")
             self.ffn = ffn
 
     def hybrid_forward(self, F, x):
+        if self.ffn is None:
+            return x + self.mixer(self.norm(x))
         h = x + self.attention(self.attn_norm(x))
         return h + self.ffn(self.ffn_norm(h))
 

@@ -7,9 +7,11 @@ contiguous range of the router's width: all of them on one chip, one
 chip's share under expert parallelism), routes every token over ALL
 experts, and computes its own experts' part of the result:
 
-- ``mx.moe.route``: softmax over all experts in float32 at "highest"
+- ``mx.moe.route``: scores over all experts in float32 at "highest"
   precision (a bfloat16 router flips near-ties), the k largest,
-  renormalised over all k chosen, held here or not;
+  renormalised over all k chosen, held here or not.  The scores are
+  what the layer is told: the softmax, or sigmoids chosen by score plus
+  a bias that is no part of the weights, and scaled;
 - ``mx.moe.dispatch``: the (token, expert) pairs sorted by held expert
   (pairs whose expert is held elsewhere sort to the tail) and each held
   pair's token row written into that order
@@ -19,13 +21,16 @@ experts, and computes its own experts' part of the result:
   (``pallas_kernels.expert_activation``);
 - ``mx.moe.combine``: each token's held pairs' rows, weighted, summed
   in float32 (``pallas_kernels.tokens_of_rows``, the token-side mover).
-  A token none of whose experts is held gets zero.
+  A token none of whose experts is held gets zero;
+- ``mx.moe.shared``: a shared expert, where the layer has one: the same
+  feed-forward for every token, dense, added after combine (every chip
+  computes it alike; a sum over the shares counts it once).
 
 No capacity and no dropped token: the pair buffers have S*k rows, the
 worst case, and **every pass visits only the rows below the last held
 pair** (``sum(sizes)``; a quarter of the buffer where a chip holds 16
 experts of 64).  Where the shapes tile (``pallas_kernels.grouped_tiles``,
-the products' predicate: C and H multiples of 128, S*k of the row tile)
+the products' predicate: C and H multiples of 64, S*k of the row tile)
 each pass is a Pallas kernel whose grid ends at the last live tile, and
 **the rows past it are undefined** in every pair buffer, forward and
 backward: the gathered rows, both products, the activation, and the
@@ -59,32 +64,56 @@ import jax.numpy as jnp
 from jax import lax
 
 from .pallas_kernels import (expert_activation, grouped_matmul,
-                             grouped_matmul_grads, grouped_tiles,
+                             grouped_matmul_grads, grouped_tiles, relu2,
                              rows_of_tokens, tokens_of_rows)
 from .registry import register
 
 __all__ = ["moe_topk_route", "moe_ffn"]
 
-_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+_ACTIVATIONS = {"relu": jax.nn.relu, "relu2": relu2, "silu": jax.nn.silu,
                 "gelu": functools.partial(jax.nn.gelu, approximate=False)}
 
 
-@register("_contrib_moe_topk_route", num_inputs=2, num_outputs=2,
-          aliases=["moe_topk_route"])
-def moe_topk_route(x, gate_weight, *, experts_per_token: int = 1):
+@register("_contrib_moe_topk_route",
+          num_inputs=lambda kw: 3 if kw.get("scoring") == "sigmoid" else 2,
+          num_outputs=2, aliases=["moe_topk_route"])
+def moe_topk_route(x, gate_weight, choice_bias=None, *,
+                   experts_per_token: int = 1, scoring: str = "softmax",
+                   scale: float = 1.0):
     """Top-k router.  ``x`` (S, C), ``gate_weight`` (C, E).
 
-    Returns (weights (S, k) float32, ids (S, k) int32): the k largest
-    of ``softmax(x @ gate_weight)`` a token, largest first (ties to the
-    lower id), divided by their sum.
+    Returns (weights (S, k) float32, ids (S, k) int32).  ``scoring``
+    "softmax": the k largest of ``softmax(x @ gate_weight)`` a token,
+    largest first (ties to the lower id), divided by their sum.
+    "sigmoid" (DeepSeek-V3's router, Nemotron-3's): scores
+    ``sigmoid(x @ gate_weight)``; the k largest of ``score +
+    choice_bias`` ((E,), the load-balancing bias: it chooses and does
+    not weigh) are chosen; the weights are the scores at those ids,
+    divided by (their sum + 1e-20), times ``scale``.
     """
     with jax.named_scope("mx.moe.route"):
         logits = jnp.dot(x.astype(jnp.float32),
                          gate_weight.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, ids = lax.top_k(probs, int(experts_per_token))
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        k = int(experts_per_token)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+            weights, ids = lax.top_k(probs, k)
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            choice = scores if choice_bias is None else (
+                scores + choice_bias.astype(jnp.float32))
+            ids = lax.top_k(choice, k)[1]
+            weights = jnp.take_along_axis(scores, ids, axis=-1)
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        else:
+            from ..base import MXNetError
+            raise MXNetError(f"moe_topk_route: unsupported scoring "
+                             f"{scoring!r} (supported: 'softmax', 'sigmoid')")
+        if scale != 1.0:
+            weights = weights * scale
     return weights, ids.astype(jnp.int32)
 
 
@@ -173,24 +202,37 @@ def _experts_part(xs, weights, ids, w1, w2, first_expert, activation,
 _experts_part.defvjp(_forward, _backward)
 
 
-@register("_contrib_moe_ffn", num_inputs=4, num_outputs=2,
+def _n_inputs(kw):
+    return (4 + (kw.get("scoring") == "sigmoid")
+            + 2 * bool(kw.get("shared_expert")))
+
+
+@register("_contrib_moe_ffn", num_inputs=_n_inputs, num_outputs=2,
           aliases=["moe_ffn"])
-def moe_ffn(x, wg, w1, w2, *, experts_per_token: int = 1,
+def moe_ffn(x, wg, w1, w2, *more, experts_per_token: int = 1,
             first_expert: int = 0, activation: str = "gelu",
-            gated: bool = False, recompute: bool = False):
+            gated: bool = False, recompute: bool = False,
+            scoring: str = "softmax", route_scale: float = 1.0,
+            shared_expert: bool = False):
     """The expert layer: route over all experts, compute the held
     experts' part.
 
     x (B, L, C) or (S, C); wg (C, E), E the router's width; w1
     (n_held, C, H), or (n_held, C, 2H) laid out [gate | up] when
     ``gated``; w2 (n_held, H, C).  The layer holds experts
-    ``first_expert .. first_expert + n_held - 1``.  Returns (out with
-    x's shape, rows (n_held,) float32: the (token, expert) pairs this
-    call routed to each held expert; over S*k, the share of the pair
-    buffers that the layer's passes visit).  Where C and H are multiples
-    of 128 and S*k of 1024 every pass is a Pallas kernel over the held
-    pairs' rows only (the module's docstring says which rows are
-    undefined where); other shapes compute all S*k rows in ``jnp``.
+    ``first_expert .. first_expert + n_held - 1``.  ``scoring`` and
+    ``route_scale``: ``moe_topk_route``'s ``scoring`` and ``scale``;
+    with "sigmoid" the next input is the router's choice bias (E,).
+    With ``shared_expert`` the last two inputs are its matrices (C, Hs)
+    (or (C, 2Hs), [gate | up]) and (Hs, C): one more expert of the same
+    kind that every token passes, added to the held experts' part.
+    Returns (out with x's shape, rows (n_held,) float32: the (token,
+    expert) pairs this call routed to each held expert; over S*k, the
+    share of the pair buffers that the layer's passes visit).  Where
+    the shapes tile (``pallas_kernels.grouped_tiles``) every pass is a
+    Pallas kernel over the held pairs' rows only (the module's
+    docstring says which rows are undefined where); other shapes
+    compute all S*k rows in ``jnp``.
     ``recompute`` saves nothing of dispatch, experts and combine for
     the backward pass and computes them again there: their S*k-row
     buffers are most of a long sequence's saved activations.
@@ -206,13 +248,26 @@ def moe_ffn(x, wg, w1, w2, *, experts_per_token: int = 1,
         raise MXNetError(
             f"moe_ffn: experts {first_expert}..{first_expert + n_held - 1} "
             f"held, the router has {E}")
+    more = list(more)
+    choice_bias = more.pop(0) if scoring == "sigmoid" else None
     xs = x.reshape(-1, x.shape[-1])
-    weights, ids = moe_topk_route(xs, wg,
-                                  experts_per_token=experts_per_token)
+    weights, ids = moe_topk_route(xs, wg, choice_bias=choice_bias,
+                                  experts_per_token=experts_per_token,
+                                  scoring=scoring, scale=route_scale)
     def part(xs, weights, ids, w1, w2):
         return _experts_part(xs, weights, ids, w1, w2, int(first_expert),
                              activation, bool(gated))
     if recompute:
         part = jax.checkpoint(part)
     out, rows = part(xs, weights.astype(xs.dtype), ids, w1, w2)
+    if shared_expert:
+        with jax.named_scope("mx.moe.shared"):
+            s1, s2 = more
+            h = jnp.dot(xs, s1.astype(xs.dtype))
+            if gated:
+                gate, up = jnp.split(h, 2, axis=-1)
+                h = _ACTIVATIONS[activation](gate) * up
+            else:
+                h = _ACTIVATIONS[activation](h)
+            out = out + jnp.dot(h, s2.astype(xs.dtype))
     return out.reshape(x.shape), rows

@@ -463,6 +463,7 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
 _GROUPED_ROW_TILE = 1024        # M is a multiple; the weights' gradient's
 _GROUPED_GMM_ROW_TILE = 512     # the products'; divides it
 _GROUPED_ROW_BLOCK = 128
+_GROUPED_WIDTH = 64             # K and N are multiples
 _GROUPED_VMEM_BYTES = 100 * 2 ** 20     # of a v5e core's 128 MiB
 _GROUPED_BLOCK_BYTES = 75 * 2 ** 20     # of it, the blocks' buffers
 
@@ -542,15 +543,16 @@ def _gmm_kernel(offs_ref, gids_ref, tids_ref, lhs_ref, rhs_ref, out_ref,
 
 
 def _gmm_columns(K, N, rhs_itemsize):
-    """The widest column tile (a multiple of 128 dividing N) whose
-    blocks fit the VMEM budget with the whole of K: two buffers each of
-    weights, rows and output, and the bfloat16 weights.  None if even
-    128 columns do not fit."""
+    """The widest column tile (all of N, or a multiple of 128 dividing
+    it: what Mosaic takes as a block's last dimension) whose blocks fit
+    the VMEM budget with the whole of K: two buffers each of weights,
+    rows and output, and the bfloat16 weights.  None if none fits."""
     tm = _GROUPED_GMM_ROW_TILE
     for tn in range(N, 0, -128):
         need = (K * tn * (2 * rhs_itemsize + 2) + 2 * tm * K * 2
                 + 2 * tm * tn * 4)
-        if N % tn == 0 and need <= _GROUPED_BLOCK_BYTES:
+        if (N % tn == 0 and (tn == N or tn % 128 == 0)
+                and need <= _GROUPED_BLOCK_BYTES):
             return tn
     return None
 
@@ -675,11 +677,15 @@ def _interpret(interpret):
 
 def grouped_tiles(M, K, N, itemsize=4):
     """Whether an (M, K) x (G, K, N) grouped product takes the Pallas
-    kernels: K and N multiples of 128, M of the row tile, and weights of
-    ``itemsize`` bytes an element that leave room in VMEM.  The one
-    predicate of the expert layer (ops/moe.py): its movers and its
-    activation follow what their own operands show of it."""
-    return not (M % _GROUPED_ROW_TILE or K % 128 or N % 128
+    kernels: K and N multiples of 64 (Mosaic compiles and the chip
+    multiplies a width of 1856 = 14.5 x 128 whole, as a block's full
+    dimension: PERF.md, PR 37; below 64 nothing was tried), M of the row
+    tile, and weights of ``itemsize`` bytes an element that leave room
+    in VMEM.  The one predicate of the expert layer (ops/moe.py): its
+    movers and its activation follow what their own operands show of
+    it."""
+    return not (M % _GROUPED_ROW_TILE or K % _GROUPED_WIDTH
+                or N % _GROUPED_WIDTH
                 or _gmm_columns(K, N, itemsize) is None
                 or _gmm_columns(N, K, itemsize) is None
                 or not _tgmm_fits(K, N))
@@ -736,7 +742,7 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
     sum is at most M; row i of the (M, N) float32 result is
     ``lhs[i] @ rhs[g]`` for the group g that holds row i.
 
-    Where K and N are multiples of 128 and M of 1024 (``grouped_tiles``)
+    Where K and N are multiples of 64 and M of 1024 (``grouped_tiles``)
     this is a Pallas grouped matmul: operands enter the MXU as bfloat16
     (the weights cast a group at a time in VMEM), accumulation and
     result float32, and so the two gradients (the rows' in ``lhs``'s
@@ -767,11 +773,16 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
 
 _MOVER_ROWS = 128               # pair rows, or tokens, a grid step moves
 _ACT_ROWS = 512                 # pair rows a step of the activation
-_MOVER_SOURCE_BYTES = 80 * 2 ** 20      # the token rows, whole in VMEM
+_MOVER_SOURCE_BYTES = 84 * 2 ** 20      # the token rows, whole in VMEM
 _MOVER_BUFFER_BYTES = 40 * 2 ** 20      # the slabs' two landing buffers
 _SLAB = 8                       # rows of a float32 tile
+def relu2(x):
+    """``relu(x)^2`` (So et al. 2021, Primer)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 # Mosaic lowers these (no erfc there: the exact gelu stays in jnp)
-_KERNEL_ACTIVATIONS = (jax.nn.relu, jax.nn.silu)
+_KERNEL_ACTIVATIONS = (jax.nn.relu, relu2, jax.nn.silu)
 
 
 def _tile_index(i, *_prefetched):
@@ -1101,6 +1112,9 @@ def expert_activation(h, live, act, gated, g=None, dtype=None,
     gelu, compute all M rows in ``jnp``."""
     dtype = jnp.dtype(dtype or h.dtype)
     M, N = h.shape
+    # (a width of 14.5 x 128 compiles, and on the chip the kernel then
+    # takes 1.8 ms over 3,072 live rows where XLA takes 0.8 ms over all
+    # 49,152: PERF.md, PR 37; so such a width stays in jnp)
     if (M % _GROUPED_ROW_TILE or (N // 2 if gated else N) % 128
             or act not in _KERNEL_ACTIVATIONS):
         fn = functools.partial(_activated, act=act, gated=gated)

@@ -86,8 +86,9 @@ class ShardedTrainer:
     Batch dims of inputs/labels are sharded over "dp"; params follow
     ``rules`` (default Megatron TP).  Donation gives in-place updates.
     ``take_block_params=True`` frees the block's own parameter buffers
-    once copied (nothing is held twice); the block is unusable until
-    ``write_back()``.
+    once copied, and its gradient buffers (which the step never writes:
+    as large as the parameters again), so nothing is held twice; the
+    block is unusable until ``write_back()``.
     """
 
     def __init__(self, block, loss_fn, mesh: Mesh, optimizer="adamw",
@@ -154,6 +155,10 @@ class ShardedTrainer:
             params[n] = _own(theirs)
             if take_block_params:
                 theirs.delete()
+        if take_block_params:
+            for p in block.collect_params().values():
+                for g in p._grad.values():
+                    g._data.delete()
         self.params, self.param_shardings = partition_params(
             params, mesh, rules)
         self.opt_state = opt_init(self.params)
@@ -391,3 +396,5 @@ class ShardedTrainer:
                     self.params[name],
                     arr._data.sharding if hasattr(arr._data, "sharding")
                     else None).astype(arr._data.dtype))
+                if any(g._data.is_deleted() for g in p._grad.values()):
+                    p._init_grad()      # taken with the parameters

@@ -220,11 +220,18 @@ def test_trainer_takes_the_blocks_parameters():
         n_labels=1, take_block_params=True)
     theirs = [p.data()._data for p in lm.collect_params().values()]
     assert all(a.is_deleted() for a in theirs)       # nothing held twice
+    grads = [g._data for p in lm.collect_params().values()
+             for g in p._grad.values()]
+    assert grads and all(g.is_deleted() for g in grads)   # nor its gradient
     first = float(trainer.step(tokens, tokens[:, 1:]))
     for _ in range(3):
         last = float(trainer.step(tokens, tokens[:, 1:]))
     assert np.isfinite(last) and last < first
     trainer.write_back()                             # the block lives again
     assert np.isfinite(lm(nd.array(tokens)).asnumpy()).all()
+    with mx.autograd.record():
+        out = lm(nd.array(tokens)).sum()
+    out.backward()
+    assert float(np.abs(lm.lm_head.weight.grad().asnumpy()).max()) > 0
     counters = [n for n in trainer.params if n.endswith("rows_routed")]
     assert float(trainer.params[counters[0]].sum()) == 4 * 64 * 2

@@ -408,3 +408,152 @@ def test_make_mesh_ep_backcompat():
                               devices=jax.devices()[:8])
     assert mesh.shape["ep"] == 1
     assert mesh.shape["dp"] == 2
+
+
+# ------------------------------------------- the router told to score by sigmoid
+def sigmoid_layer(x, wg, bias, w1, w2, s1, s2, k, first, scale):
+    """Sigmoid scores, the k largest of score + bias, the scores at
+    those ids renormalised and scaled; relu^2 experts, not gated; plus
+    the shared expert: what ``moe_ffn`` computes when told so,
+    differentiable."""
+    held = w1.shape[0]
+    s = jax.nn.sigmoid(x @ wg)
+    ids = jnp.argsort(-(s + bias), -1)[:, :k]
+    w = jnp.take_along_axis(s, ids, -1)
+    w = scale * w / (w.sum(-1, keepdims=True) + 1e-20)
+    full = jnp.zeros_like(s).at[jnp.arange(x.shape[0])[:, None], ids].set(w)
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))            # noqa: E731
+    a = relu2(jnp.einsum("sc,ech->seh", x, w1)) \
+        * full[:, first:first + held, None]
+    return jnp.einsum("seh,ehc->sc", a, w2) + relu2(x @ s1) @ s2
+
+
+def test_sigmoid_route_chooses_by_the_bias_and_weighs_without_it():
+    """Expert 3 scores lowest and is chosen first because the bias says
+    so; its weight is still its own (small) score's share, so the order
+    of the choice is not the order of the weights."""
+    x = jnp.eye(4, dtype=jnp.float32)[:1]                   # one token
+    wg = jnp.asarray([[2.0, 1.0, 0.0, -1.0, -3.0]] + [[0.0] * 5] * 3)
+    bias = jnp.asarray([0.0, 0.0, 0.0, 1.0, 0.0])
+    scores = np.asarray(jax.nn.sigmoid(wg[0]))
+    w, ids = moe_topk_route(x, wg, bias, experts_per_token=3,
+                            scoring="sigmoid", scale=2.5)
+    assert np.asarray(ids).tolist() == [[3, 0, 1]]
+    want = scores[[3, 0, 1]] / scores[[3, 0, 1]].sum() * 2.5
+    np.testing.assert_allclose(np.asarray(w)[0], want, rtol=1e-6)
+    assert np.argsort(-np.asarray(w)[0]).tolist() == [1, 2, 0]
+    np.testing.assert_allclose(np.asarray(w).sum(), 2.5, rtol=1e-6)
+    # without the bias the choice follows the scores
+    _w, plain = moe_topk_route(x, wg, None, experts_per_token=3,
+                               scoring="sigmoid")
+    assert np.asarray(plain).tolist() == [[0, 1, 2]]
+    # ties still go to the lower id, and softmax is what it was
+    _w, tied = moe_topk_route(x, jnp.zeros((4, 5)), jnp.zeros((5,)),
+                              experts_per_token=2, scoring="sigmoid")
+    assert np.asarray(tied).tolist() == [[0, 1]]
+    with pytest.raises(mx.base.MXNetError, match="scoring"):
+        moe_topk_route(x, wg, experts_per_token=1, scoring="tanh")
+
+
+@pytest.mark.parametrize("shape", ["ragged_dot", "kernel", "kernel_64"])
+@pytest.mark.parametrize("recompute", [False, True])
+def test_sigmoid_relu2_shared_layer_matches_the_dense_layer(recompute, shape):
+    """The layer Nemotron-3 tells it to be, output and gradients; at a
+    width that is a multiple of 64 and not of 128 ("kernel_64": H = 192,
+    as 1856 = 29 x 64) the products and the movers still take the
+    Pallas kernels."""
+    sizes, k, scale, rtol, atol = {
+        **SHAPES, "kernel_64": (dict(S=512, C=128, H=192), 4, 0.1, 2e-2,
+                                2e-2)}[shape]
+    x, wg, w1, w2 = _weights(11, gated=False, **sizes)
+    rng = np.random.RandomState(12)
+    C, H = w1.shape[1], w1.shape[2]
+    bias = jnp.asarray(rng.randn(wg.shape[1]).astype(np.float32))
+    s1 = jnp.asarray(0.3 * rng.randn(C, 2 * H).astype(np.float32))
+    s2 = jnp.asarray(0.3 * rng.randn(2 * H, C).astype(np.float32))
+    first, held = 2, 4
+    args = (x, scale * wg, w1[first:first + held], w2[first:first + held],
+            bias, s1, s2)
+    proj = jnp.asarray(rng.randn(*x.shape), jnp.float32)
+
+    def layer(*a):
+        out, rows = moe_ffn(*a, experts_per_token=k, first_expert=first,
+                            activation="relu2", gated=False,
+                            recompute=recompute, scoring="sigmoid",
+                            route_scale=2.5, shared_expert=True)
+        return (out * proj).sum(), (out, rows)
+
+    def dense(x, wg, w1, w2, bias, s1, s2):
+        return sigmoid_layer(x, wg, bias, w1, w2, s1, s2, k, first, 2.5)
+    assert _takes_the_kernel(lambda *a: layer(*a)[0], *args) \
+        == (shape != "ragged_dot")
+    wrt = (0, 1, 2, 3, 5, 6)
+    with jax.default_matmul_precision("highest"):
+        got, (out, rows) = jax.jit(jax.grad(layer, argnums=wrt,
+                                            has_aux=True))(*args)
+        want = jax.grad(lambda *a: (dense(*a) * proj).sum(),
+                        argnums=wrt)(*args)
+        want_out = dense(*args)
+        d_bias = jax.grad(lambda *a: layer(*a)[0], argnums=4)(*args)
+    _assert_close(out, want_out, rtol, atol)
+    for g, w in zip(got, want):
+        _assert_close(g, w, rtol, atol)
+    assert float(jnp.abs(d_bias).max()) == 0.0     # it chooses, no more
+    assert 0 < float(rows.sum()) < x.shape[0] * k
+
+
+def test_relu2_joins_the_kernel_activations():
+    from mxnet_tpu.ops.pallas_kernels import expert_activation, relu2
+    h = jnp.asarray(np.random.RandomState(0).randn(1024, 256), jnp.float32)
+    g = jnp.asarray(np.random.RandomState(1).randn(1024, 256), jnp.float32)
+    live = jnp.int32(700)
+    assert _takes_the_kernel(lambda h: expert_activation(
+        h, live, relu2, False), h)
+    want = np.maximum(np.asarray(h), 0) ** 2
+    want_grad = 2 * np.maximum(np.asarray(h), 0) * np.asarray(g)
+    a = expert_activation(h, live, relu2, False)
+    da = expert_activation(h, live, relu2, False, g=g)
+    np.testing.assert_allclose(np.asarray(a)[:700], want[:700], rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(da)[:700], want_grad[:700],
+                               rtol=1e-6)
+    # a width of 1.5 x 128 (as 1856 = 14.5 x 128): the products take the
+    # kernels, the activation stays in jnp over all rows (the chip's
+    # kernel is slower there than XLA: PERF.md, PR 37)
+    assert not _takes_the_kernel(lambda h: expert_activation(
+        h, live, relu2, False), h[:, :192])
+    np.testing.assert_allclose(
+        np.asarray(expert_activation(h[:, :192], live, relu2, False,
+                                     g=g[:, :192])), want_grad[:, :192],
+        rtol=1e-6)
+
+
+def test_gluon_moe_block_with_sigmoid_router_and_shared_expert():
+    mx.random.seed(5)
+    layer = MoEFFN(units=8, hidden_size=16, num_experts=8,
+                   experts_per_token=3, experts_held=4, first_expert=2,
+                   activation="relu2", scoring="sigmoid", route_scale=2.5,
+                   shared_hidden_size=12)
+    layer.initialize(mx.init.Normal(0.5))
+    params = {n.split("_", 1)[1]: p for n, p in
+              layer.collect_params().items()}
+    assert params["route_bias"].shape == (8,)
+    assert params["route_bias"].grad_req == "null"      # no weight
+    assert params["shared_w1"].shape == (8, 12)         # not gated
+    assert params["shared_w2"].shape == (12, 8)
+    bias = np.random.RandomState(3).randn(8).astype(np.float32)
+    params["route_bias"].set_data(nd.array(bias))
+    x = np.random.RandomState(2).randn(2, 6, 8).astype(np.float32)
+    leaves = {k: p.data()._data for k, p in params.items()}
+    with jax.default_matmul_precision("highest"):
+        out = layer(nd.array(x)).asnumpy()
+        want = sigmoid_layer(
+            jnp.asarray(x.reshape(12, 8)), leaves["gate_weight"],
+            leaves["route_bias"], leaves["expert_w1"], leaves["expert_w2"],
+            leaves["shared_w1"], leaves["shared_w2"], 3, 2, 2.5)
+    np.testing.assert_allclose(out.reshape(12, 8), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    layer.hybridize()
+    np.testing.assert_allclose(layer(nd.array(x)).asnumpy(), out, rtol=1e-5,
+                               atol=1e-6)
+    with pytest.raises(mx.base.MXNetError, match="scoring"):
+        MoEFFN(8, 16, 8, scoring="tanh")

@@ -734,6 +734,13 @@ ORACLES.update({
     "_contrib_rms_norm": lambda x, g, eps=1e-6:
         x / np.sqrt((x * x).mean(-1, keepdims=True) + eps) * g,
     "_contrib_rope": lambda x, **k: _np_rope(x, **k),
+    # the Mamba-2 mixer's pieces: the convolution's K-term sum, the
+    # recurrence one position at a time, the gate before the group norm
+    "_contrib_ssm_conv": lambda x, w, b: _np_ssm_conv(x, w, b),
+    "_contrib_ssm_scan": lambda *a, chunk=128: _np_ssm_scan(*a),
+    "_contrib_ssm_gate_norm": lambda y, z, g, groups=1, eps=1e-5:
+        _np_ssm_gate_norm(y, z, g, groups, eps),
+    "_contrib_ssm_mixer": lambda *a, **k: _np_ssm_mixer(*a, **k),
     # decode-path paged attention vs a per-sequence gather + dense
     # softmax (block-table indirection materialized in numpy)
     "_contrib_ragged_paged_attention": lambda q, kp, vp, bt, lens:
@@ -996,6 +1003,53 @@ def _np_grouped_attention(q, k, v, causal, window):
     return out
 
 
+def _np_silu(x):
+    return x / (1.0 + np.exp(-x))
+
+
+def _np_ssm_conv(x, w, b):
+    K, L = w.shape[1], x.shape[1]
+    xp = np.concatenate([np.zeros_like(x[:, :K - 1]), x], axis=1)
+    return _np_silu(b + sum(w[:, j] * xp[:, j:j + L] for j in range(K)))
+
+
+def _np_ssm_scan(x, dt, A_log, B, C, D, dt_bias):
+    """h_t = exp(delta_t A) h_{t-1} + delta_t x_t B_t^T, y_t = h_t C_t +
+    D x_t, one position at a time."""
+    b, L, H, P = x.shape
+    R = H // B.shape[2]
+    delta = np.log1p(np.exp(dt + dt_bias))
+    A = -np.exp(A_log)
+    Bh, Ch = np.repeat(B, R, axis=2), np.repeat(C, R, axis=2)
+    h = np.zeros((b, H, P, B.shape[-1]))
+    y = np.zeros(x.shape)
+    for t in range(L):
+        h = (np.exp(delta[:, t] * A)[..., None, None] * h
+             + (delta[:, t, :, None] * x[:, t])[..., None]
+             * Bh[:, t, :, None, :])
+        y[:, t] = np.einsum("bhpn,bhn->bhp", h, Ch[:, t])
+    return y + D[:, None] * x
+
+
+def _np_ssm_gate_norm(y, z, g, groups=1, eps=1e-5):
+    v = (y * _np_silu(z)).reshape(y.shape[:-1] + (groups, -1))
+    v = v / np.sqrt((v * v).mean(-1, keepdims=True) + eps)
+    return v.reshape(y.shape) * g
+
+
+def _np_ssm_mixer(data, cw, cb, dtb, A_log, D, g, num_heads, head_dim,
+                  n_groups, state_size, chunk=128, eps=1e-5):
+    H, P, G, N = num_heads, head_dim, n_groups, state_size
+    inner = H * P
+    b, L, W = data.shape
+    z, xbc, dt = data[..., :inner], data[..., inner:W - H], data[..., W - H:]
+    xbc = _np_ssm_conv(xbc, cw, cb)
+    y = _np_ssm_scan(xbc[..., :inner].reshape(b, L, H, P), dt, A_log,
+                     xbc[..., inner:inner + G * N].reshape(b, L, G, N),
+                     xbc[..., inner + G * N:].reshape(b, L, G, N), D, dtb)
+    return _np_ssm_gate_norm(y.reshape(b, L, inner), z, g, G, eps)
+
+
 def _np_rope(x, theta=10000.0, yarn_factor=0.0, yarn_original_max=0,
              yarn_beta_fast=32.0, yarn_beta_slow=1.0, attention_factor=1.0):
     """Rotary positions written out pair by pair (the YaRN blend as the
@@ -1176,9 +1230,32 @@ SPECS = {
         kwargs=dict(experts_per_token=2, first_expert=1, activation="silu",
                     gated=True),
         rtol=3e-2, atol=3e-3),
+    # x, router (C, E), the choice bias (E,): sigmoid scores, 2 of 5
+    # chosen by score + bias, weighted by score, renormalised, scaled
     "_contrib_moe_topk_route": dict(
-        inputs=lambda r: [_f32(r, 4, 3), _f32(r, 3, 5)],
-        kwargs=dict(experts_per_token=2)),
+        inputs=lambda r: [_f32(r, 4, 3), _f32(r, 3, 5), _f32(r, 5)],
+        kwargs=dict(experts_per_token=2, scoring="sigmoid", scale=2.5)),
+    # (b, L, C), taps (C, 4), bias (C,)
+    "_contrib_ssm_conv": dict(
+        inputs=lambda r: [_f32(r, 2, 6, 5), _f32(r, 5, 4), _f32(r, 5)]),
+    # x (b, L, H, P), dt (b, L, H), A_log (H,), B and C (b, L, G, N),
+    # D and dt_bias (H,): 4 heads over 2 groups, 7 positions in chunks
+    # of 3
+    "_contrib_ssm_scan": dict(
+        inputs=lambda r: [_f32(r, 1, 7, 4, 3), _f32(r, 1, 7, 4), _f32(r, 4),
+                          _f32(r, 1, 7, 2, 5), _f32(r, 1, 7, 2, 5),
+                          _f32(r, 4), _f32(r, 4)],
+        kwargs=dict(chunk=3)),
+    "_contrib_ssm_gate_norm": dict(
+        inputs=lambda r: [_f32(r, 2, 3, 8), _f32(r, 2, 3, 8), _pos(r, 8)],
+        kwargs=dict(groups=2)),
+    # [z | x B C | dt] of widths 6 | 6 + 2 * 4 | 2: 2 heads of 3, one
+    # group, state 4
+    "_contrib_ssm_mixer": dict(
+        inputs=lambda r: [_f32(r, 1, 7, 22), _f32(r, 14, 4), _f32(r, 14),
+                          _f32(r, 2), _f32(r, 2), _f32(r, 2), _pos(r, 6)],
+        kwargs=dict(num_heads=2, head_dim=3, n_groups=1, state_size=4,
+                    chunk=3)),
     "_contrib_rms_norm": dict(inputs=lambda r: [_f32(r, 2, 3, 8),
                                                 _pos(r, 8)]),
     "_contrib_rope": dict(

@@ -1,0 +1,312 @@
+"""The state-space operators (``ops/ssm.py``), the Mamba-2 mixer block
+and the hybrid layer kinds of ``DecoderLM`` on the CPU at small sizes:
+the chunked scan against the recurrence it stands for, its gradient by
+finite differences, the convolution's first positions, the gate before
+the norm, attention without rotary, and the published sizes."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import models, nd
+from mxnet_tpu.models import transformer_blocks as tb
+from mxnet_tpu.models.decoder_lm import _DECODER_CONFIGS
+from mxnet_tpu.ops.ssm import ssm_conv, ssm_gate_norm, ssm_mixer, ssm_scan
+
+H, P, G, N = 4, 8, 2, 16
+NAMES = ("x", "dt", "A_log", "B", "C", "D", "dt_bias")
+
+
+def _operands(L, seed=0, b=2):
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    return (jax.random.normal(k[0], (b, L, H, P)),
+            jax.random.normal(k[1], (b, L, H)),
+            jnp.log(jax.random.uniform(k[2], (H,), minval=1.0, maxval=16.0)),
+            jax.random.normal(k[3], (b, L, G, N)),
+            jax.random.normal(k[4], (b, L, G, N)),
+            1.0 + 0.1 * jax.random.normal(k[5], (H,)),
+            jax.random.normal(k[6], (H,)))
+
+
+def recurrence(x, dt, A_log, B, C, D, dt_bias):
+    """One position at a time: the definition."""
+    R = x.shape[2] // B.shape[2]
+    delta, A = jax.nn.softplus(dt + dt_bias), -jnp.exp(A_log)
+    Bh, Ch = jnp.repeat(B, R, axis=2), jnp.repeat(C, R, axis=2)
+
+    def step(h, at):
+        xt, dl, Bt, Ct = at
+        h = (jnp.exp(dl * A)[..., None, None] * h
+             + (dl[..., None] * xt)[..., None] * Bt[:, :, None, :])
+        return h, jnp.einsum("bhpn,bhn->bhp", h, Ct)
+
+    h0 = jnp.zeros(x.shape[:1] + x.shape[2:] + B.shape[-1:])
+    _, y = jax.lax.scan(step, h0, tuple(jnp.moveaxis(a, 1, 0)
+                                        for a in (x, delta, Bh, Ch)))
+    return jnp.moveaxis(y, 0, 1) + D[:, None] * x
+
+
+@pytest.mark.parametrize("L,chunk", [(75, 16), (10, 16), (64, 16), (128, 128),
+                                     (33, 1)],
+                         ids=["ragged_last_chunk", "below_one_chunk",
+                              "whole_chunks", "one_chunk", "chunk_of_one"])
+def test_chunked_scan_is_the_recurrence(L, chunk):
+    ops = _operands(L)
+    with jax.default_matmul_precision("highest"):
+        got, want = ssm_scan(*ops, chunk=chunk), recurrence(*ops)
+    assert got.shape == want.shape == (2, L, H, P)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5 * float(
+                                   jnp.abs(want).max()))
+
+
+def test_scan_survives_decays_that_underflow_a_quotient():
+    """delta A of about -1,000 a position: exp of a chunk's running sum
+    is 0 in float32, a quotient of two such is 0/0; differences of the
+    sum are exact."""
+    x, dt, A_log, B, C, D, dt_bias = _operands(48)
+    ops = (x, dt + 8.0, A_log + 3.0, B, C, D, dt_bias)
+    cum = jnp.cumsum(jax.nn.softplus(ops[1] + dt_bias) * -jnp.exp(ops[2]), 1)
+    assert float(jnp.exp(cum[:, 15]).max()) == 0.0      # a chunk of 16
+    with jax.default_matmul_precision("highest"):
+        got, want = ssm_scan(*ops, chunk=16), recurrence(*ops)
+        grads = jax.grad(lambda *a: ssm_scan(*a, chunk=16).sum(),
+                         argnums=tuple(range(7)))(*ops)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+
+
+@pytest.mark.parametrize("leaf", NAMES)
+def test_scan_gradient_by_finite_differences(leaf):
+    """One leaf of each kind: the directional derivative along a random
+    direction, central differences in float64 against autodiff through
+    the chunked form."""
+    jax.config.update("jax_enable_x64", True)
+    try:
+        ops = [a.astype(jnp.float64) for a in _operands(40, seed=3, b=1)]
+        i = NAMES.index(leaf)
+        u = jax.random.normal(jax.random.PRNGKey(9), ops[i].shape,
+                              jnp.float64)
+        w = jax.random.normal(jax.random.PRNGKey(10), (1, 40, H, P),
+                              jnp.float64)
+
+        def f(a):
+            return (ssm_scan(*ops[:i], a, *ops[i + 1:], chunk=16)
+                    .astype(jnp.float64) * w).sum()
+
+        with jax.default_matmul_precision("highest"):
+            auto = float((jax.grad(f)(ops[i]) * u).sum())
+            eps = 1e-3
+            numeric = float(f(ops[i] + eps * u) - f(ops[i] - eps * u)) \
+                / (2 * eps)
+    finally:
+        jax.config.update("jax_enable_x64", False)
+    # the scan itself computes in float32: 1e-3 of the derivative is its
+    # rounding under a step of 1e-3
+    assert abs(auto - numeric) <= 2e-3 * max(abs(numeric), 1.0), (auto,
+                                                                  numeric)
+
+
+def test_scan_refuses_groups_that_do_not_divide_the_heads():
+    x, dt, A_log, B, C, D, dt_bias = _operands(8)
+    with pytest.raises(mx.base.MXNetError, match="3 groups"):
+        ssm_scan(x, dt, A_log, jnp.zeros((2, 8, 3, N)),
+                 jnp.zeros((2, 8, 3, N)), D, dt_bias)
+
+
+def test_convolution_first_positions_by_hand():
+    """``out[t] = silu(b + sum_j w[j] x[t - 3 + j])``, zeros before the
+    row: position 0 sees only its own input through the LAST tap."""
+    x = jnp.arange(1.0, 11.0).reshape(1, 5, 2)        # x[t] = (2t+1, 2t+2)
+    w = jnp.asarray([[1.0, 10.0, 100.0, 1000.0], [2.0, 0.0, 0.0, -1.0]])
+    b = jnp.asarray([0.5, -0.5])
+    sums = np.asarray([
+        [0.5 + 1000 * 1, -0.5 - 2],
+        [0.5 + 100 * 1 + 1000 * 3, -0.5 - 4],
+        [0.5 + 10 * 1 + 100 * 3 + 1000 * 5, -0.5 - 6],
+        [0.5 + 1 + 30 + 500 + 7000, -0.5 + 2 * 2 - 8]])
+    out = np.asarray(ssm_conv(x, w, b))[0]
+    np.testing.assert_allclose(out[:4], sums / (1 + np.exp(-sums)),
+                               rtol=1e-6)
+
+
+def test_gate_comes_before_the_group_norm():
+    y = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 32))
+    z = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 32))
+    gamma = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (32,))
+    v = (y * jax.nn.silu(z)).reshape(2, 5, 4, 8)
+    want = (v / jnp.sqrt(jnp.mean(v * v, -1, keepdims=True) + 1e-5)
+            ).reshape(2, 5, 32) * gamma
+    got = ssm_gate_norm(y, z, gamma, groups=4, eps=1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+    w = y.reshape(2, 5, 4, 8)
+    other = (w / jnp.sqrt(jnp.mean(w * w, -1, keepdims=True) + 1e-5)
+             ).reshape(2, 5, 32) * gamma * jax.nn.silu(z)
+    assert float(jnp.abs(got - other).max()) > 0.1
+
+
+def test_mixer_op_is_its_three_parts():
+    inner, cw = H * P, H * P + 2 * G * N
+    k = jax.random.split(jax.random.PRNGKey(4), 4)
+    data = jax.random.normal(k[0], (2, 24, inner + cw + H))
+    leaves = (0.5 * jax.random.normal(k[1], (cw, 4)),
+              0.1 * jax.random.normal(k[2], (cw,)), jnp.zeros((H,)),
+              jnp.zeros((H,)), jnp.ones((H,)),
+              1.0 + 0.1 * jax.random.normal(k[3], (inner,)))
+    kw = dict(num_heads=H, head_dim=P, n_groups=G, state_size=N, chunk=8)
+
+    def by_hand(data, cw_, cb, dtb, A_log, D, gamma):
+        z, xbc, dt = (data[..., :inner], data[..., inner:inner + cw],
+                      data[..., inner + cw:])
+        xbc = ssm_conv(xbc, cw_, cb)
+        x, B, C = (xbc[..., :inner], xbc[..., inner:inner + G * N],
+                   xbc[..., inner + G * N:])
+        y = recurrence(x.reshape(2, 24, H, P), dt, A_log,
+                       B.reshape(2, 24, G, N), C.reshape(2, 24, G, N), D,
+                       dtb)
+        return ssm_gate_norm(y.reshape(2, 24, inner), z, gamma, groups=G)
+
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(
+            lambda *a: ssm_mixer(*a, **kw).sum(),
+            argnums=(0, 1, 5))(data, *leaves)
+        want, want_g = jax.value_and_grad(
+            lambda *a: by_hand(*a).sum(), argnums=(0, 1, 5))(data, *leaves)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
+                                   atol=1e-4 * float(jnp.abs(b).max()))
+
+
+def test_mamba2_mixer_block_shapes_and_causality():
+    mx.random.seed(3)
+    mixer = tb.Mamba2Mixer(32, num_heads=8, head_dim=8, state_size=16,
+                           n_groups=2, conv_kernel=4, chunk=8)
+    mixer.initialize(mx.init.Normal(0.3))
+    shapes = {n.split("_", 1)[1]: p.shape
+              for n, p in mixer.collect_params().items()}
+    assert shapes == {
+        "in_proj_weight": (64 + 64 + 2 * 2 * 16 + 8, 32),
+        "conv_weight": (128, 4), "conv_bias": (128,), "dt_bias": (8,),
+        "A_log": (8,), "D": (8,), "norm_gamma": (64,),
+        "out_proj_weight": (32, 64)}
+    x = np.random.RandomState(0).randn(1, 40, 32).astype(np.float32)
+    other = x.copy()
+    other[0, 17] += 1.0
+    a, b = mixer(nd.array(x)).asnumpy(), mixer(nd.array(other)).asnumpy()
+    moved = np.abs(a - b).max(-1)[0] > 1e-7
+    assert a.shape == (1, 40, 32)
+    assert not moved[:17].any() and moved[17:30].all()   # the state carries
+    with pytest.raises(mx.base.MXNetError):
+        tb.Mamba2Mixer(32, num_heads=8, head_dim=8, state_size=16, n_groups=3)
+
+
+def test_attention_without_rope_leaves_q_and_k_bit_for_bit():
+    """``rope=None``: the flash kernel gets the projections themselves;
+    an empty dict still rotates at the op's defaults (Mellum's two kinds
+    pass theirs explicitly)."""
+    mx.random.seed(4)
+    kw = dict(num_heads=4, num_kv_heads=2, head_dim=8,
+              compute_dtype="float32")
+    plain = tb.RotaryGroupedAttention(32, rope=None, **kw)
+    plain.initialize(mx.init.Normal(0.3))
+    x = np.random.RandomState(1).randn(2, 16, 32).astype(np.float32)
+    xs = nd.array(x)
+    q = plain.q_proj(xs).reshape((2, 16, 4, 8))
+    kv = plain.kv_proj(xs)
+    k = nd.slice_axis(kv, axis=-1, begin=0, end=16).reshape((2, 16, 2, 8))
+    v = nd.slice_axis(kv, axis=-1, begin=16, end=None).reshape((2, 16, 2, 8))
+    want = plain.out_proj(nd.flash_attention(q, k, v, causal=True, window=-1)
+                          .reshape((2, 16, 32))).asnumpy()
+    got = plain(xs).asnumpy()
+    np.testing.assert_array_equal(got, want)
+    # no position signal at all: the last position's output does not
+    # change when two earlier positions swap places
+    swapped = x.copy()
+    swapped[:, [2, 9]] = swapped[:, [9, 2]]
+    np.testing.assert_allclose(plain(nd.array(swapped)).asnumpy()[:, -1],
+                               got[:, -1], rtol=1e-5, atol=1e-6)
+    plain._rope = {}            # the same weights, rotated at the defaults
+    assert np.abs(plain(xs).asnumpy() - got).max() > 1e-3
+
+
+def test_nemotron3_nano_published_sizes():
+    c = _DECODER_CONFIGS["nemotron_3_nano_30b_a3b"]
+    kinds = c["layer_types"]
+    assert len(kinds) == 52
+    assert [kinds.count(k) for k in ("mamba2", "moe", "attention")] \
+        == [23, 23, 6]
+    assert "".join({"mamba2": "M", "moe": "E", "attention": "*"}[k]
+                   for k in kinds[:9]) == "MEMEM*EME"
+    C, m = c["units"], c["mamba"]
+    inner = m["num_heads"] * m["head_dim"]
+    conv = inner + 2 * m["n_groups"] * m["state_size"]
+    assert (C, inner, conv, inner + conv + m["num_heads"]) \
+        == (2688, 4096, 6144, 10304)
+    mamba = (C * (inner + conv + m["num_heads"]) + inner * C + conv * 5
+             + inner + 3 * m["num_heads"])
+    attn = C * 2 * (32 * 128 + 2 * 128)
+    expert = 2 * C * c["expert_hidden_size"]
+    moe = (C * 128 + 128 + 2 * C * c["shared_expert_hidden_size"]
+           + 128 * expert)
+    assert [round(x / 1e6, 2) for x in (mamba, attn, expert)] \
+        == [38.74, 23.4, 9.98]
+    total = (23 * mamba + 6 * attn + 23 * moe + 53 * C
+             + 2 * c["vocab_size"] * C)
+    assert round(total / 1e9, 1) == 31.6
+    active = total - 23 * (128 - 6) * expert
+    assert round(active / 1e9, 1) == 3.6       # with both vocabulary tables
+    assert c["router"] == dict(scoring="sigmoid", route_scale=2.5)
+    assert (c["expert_activation"], c["expert_gated"]) == ("relu2", False)
+    assert "rope" not in c
+
+
+SMALL = dict(vocab_size=96, units=32, num_heads=4, num_kv_heads=2, head_dim=8,
+             mamba=dict(num_heads=8, head_dim=8, state_size=16, n_groups=2,
+                        conv_kernel=4, chunk=8),
+             num_experts=8, experts_per_token=2, expert_hidden_size=16,
+             shared_expert_hidden_size=24, attention_dtype="float32")
+
+
+def test_hybrid_layers_are_one_mixer_each_of_the_one_cell_class():
+    lm = models.get_decoder_lm("nemotron_3_nano_30b_a3b", num_layers=9,
+                               experts_held=4, first_expert=4, **SMALL)
+    assert {type(c) for c in lm.cells} == {tb.DecoderCell}
+    assert [type(c.mixer).__name__ for c in lm.cells] == [
+        "Mamba2Mixer", "MoEFFN", "Mamba2Mixer", "MoEFFN", "Mamba2Mixer",
+        "RotaryGroupedAttention", "MoEFFN", "Mamba2Mixer", "MoEFFN"]
+    assert all(c.ffn is None for c in lm.cells)
+    assert lm.cells[5].mixer._rope is None and lm.cells[5].mixer._window == -1
+    shapes = {n.split("_", 1)[1]: (p.shape, p.grad_req)
+              for n, p in lm.collect_params().items()}
+    assert shapes["layer1_moe_gate_weight"] == ((32, 8), "write")
+    assert shapes["layer1_moe_route_bias"] == ((8,), "null")
+    assert shapes["layer1_moe_expert_w1"] == ((4, 32, 16), "write")  # not gated
+    assert shapes["layer1_moe_shared_w1"] == ((32, 24), "write")
+    assert shapes["layer1_moe_shared_w2"] == ((24, 32), "write")
+    assert shapes["layer0_norm_gamma"] == ((32,), "write")
+    assert not any("attn_norm" in n or "ffn_norm" in n for n in shapes)
+    with pytest.raises(mx.base.MXNetError, match="layer_types"):
+        models.get_decoder_lm("nemotron_3_nano_30b_a3b",
+                              layer_types=("mamba",), **SMALL)
+
+
+def test_hybrid_lm_is_causal_and_its_state_reaches_the_rows_end():
+    """Changing token t changes the logits from t on, and (through the
+    Mamba layers' state and the full attention) all the way."""
+    mx.random.seed(6)
+    lm = models.get_decoder_lm("nemotron_3_nano_30b_a3b", num_layers=9,
+                               **SMALL)
+    lm.initialize(mx.init.Normal(0.3))
+    tokens = np.random.RandomState(5).randint(0, 96, (1, 48)).astype(np.int32)
+    other = tokens.copy()
+    other[0, 20] = (other[0, 20] + 1) % 96
+    a, b = lm(nd.array(tokens)).asnumpy(), lm(nd.array(other)).asnumpy()
+    moved = np.abs(a - b).max(-1)[0] > 1e-6
+    assert a.shape == (1, 48, 96)
+    assert not moved[:20].any() and moved[20:].all()
