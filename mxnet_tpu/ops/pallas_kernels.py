@@ -36,7 +36,7 @@ from .registry import register
 
 __all__ = ["flash_attention", "grouped_matmul", "grouped_matmul_grads",
            "grouped_tiles", "rows_of_tokens", "tokens_of_rows",
-           "expert_activation",
+           "expert_activation", "ssm_scan_chunks", "ssm_scan_tiles",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_verify", "ragged_paged_verify_reference"]
 
@@ -1125,6 +1125,361 @@ def expert_activation(h, live, act, gated, g=None, dtype=None,
     # dtype)
     return _act(h, g, live.astype(jnp.int32), act, bool(gated),
                 dtype, _interpret(interpret))
+
+
+# ---------------------------------------------------------------------------
+# the selective scan of a Mamba-2 mixer (ops/ssm.py), chunk by chunk.  A
+# grid step is one (row, group of B and C, chunk); the chunk axis is last
+# and sequential, and the group's state, (N, R x P) float32 for its R
+# heads of P channels, stays in VMEM scratch from one chunk to the next.
+# x, B and C are read where the mixer's convolution left them, side by
+# side in one (L, H x P + 2 G x N) array: x in column blocks of R x P a
+# group, B and C in blocks of N behind them, nothing sliced out or laid
+# out anew in HBM; y is written as (L, H x P) the same way;
+# a chunk's (Q, Q) decay matrix a head is made, multiplied into the
+# group's C B^T and consumed by the MXU without leaving VMEM.  The MXU's
+# operands are bfloat16 exactly where XLA's default precision rounds the
+# operands of ops/ssm._chunked_scan's einsums; decays, sums and the state
+# are float32.
+#
+# The per-head vectors come from jnp (they are 1/P of x): ``cols``
+# (b, G, L, 4 R) holds, a position down a column, [delta | a | exp(a) |
+# exp(a_last - a)] with ``a`` the running sum of ``delta A`` inside the
+# chunk, and ``a_rows`` (b, G, R, L) holds ``a`` a position along a row: a
+# decay matrix needs both.
+
+_SSM_LANES = 128                # Q, N and R x P are multiples
+_SSM_VMEM_BYTES = 64 * 2 ** 20
+_SSM_BLOCK_BYTES = 40 * 2 ** 20         # of it, blocks and temporaries
+
+
+def ssm_scan_tiles(Q, G, R, P, N):
+    """Whether a scan with chunks of Q positions and G groups of R heads
+    of P channels over a state of N takes the Pallas kernels: Q, N and
+    R x P multiples of 128, P a multiple of 128 or a power of two below
+    it (a 128-lane block then holds whole heads), at least two heads a
+    group (with one, Mosaic is asked to broadcast one element along
+    both axes and does not), x's columns a whole number of blocks of N
+    (B's and C's lie behind them in one array), and a chunk's blocks
+    and temporaries inside the VMEM budget."""
+    W = R * P
+    lanes = _SSM_LANES
+    heads_fit = R > 1 and (P % lanes == 0 or (P >= 8 and lanes % P == 0))
+    # the backward kernel: some twenty (Q, W) and six (N, W) float32
+    # arrays, blocks twice, and a head's (Q, Q) matrices
+    need = 4 * (20 * Q * W + 6 * N * W + 8 * Q * Q + 8 * Q * N)
+    return not (Q % lanes or N % lanes or W % lanes or G * W % N
+                or not heads_fit or need > _SSM_BLOCK_BYTES)
+
+
+def _ssm_col(cols_ref, k, r):
+    """Vector k (0 delta, 1 a, 2 exp(a), 3 exp(a_last - a)) of head r:
+    (Q, 1)."""
+    i = k * (cols_ref.shape[1] // 4) + r
+    return cols_ref[:, i:i + 1]
+
+
+def _ssm_widen(piece, R, P):
+    """(rows, R x P) from ``piece(r)`` (rows, 1), head r's value on each
+    of its P columns."""
+    rows = piece(0).shape[0]
+    lanes = _SSM_LANES
+    if P % lanes == 0:
+        return jnp.concatenate(
+            [jnp.broadcast_to(piece(r), (rows, P)) for r in range(R)], axis=1)
+    per = lanes // P
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+    blocks = []
+    for first in range(0, R, per):
+        block = jnp.broadcast_to(piece(first), (rows, lanes))
+        for k in range(1, per):
+            block = jnp.where(
+                lane >= k * P,
+                jnp.broadcast_to(piece(first + k), (rows, lanes)), block)
+        blocks.append(block)
+    return jnp.concatenate(blocks, axis=1)
+
+
+def _ssm_head(v, r, P):
+    """Head r's columns of ``v`` (rows, R x P) on whole 128-lane blocks:
+    (the columns themselves, or their block with its other heads zeroed;
+    the slice of columns that stands for)."""
+    lanes = _SSM_LANES
+    if P % lanes == 0:
+        cols = slice(r * P, (r + 1) * P)
+        return v[:, cols], cols
+    first = r * P // lanes * lanes
+    cols = slice(first, first + lanes)
+    block = v[:, cols]
+    lane = first + jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    mine = (lane >= r * P) & (lane < (r + 1) * P)
+    return jnp.where(mine, block, jnp.zeros_like(block)), cols
+
+
+def _ssm_head_sum(v, r, P):
+    """The sum over head r's columns of ``v`` (rows, R x P) float32:
+    (rows, 1)."""
+    return jnp.sum(_ssm_head(v, r, P)[0], axis=1, keepdims=True)
+
+
+def _ssm_decay(cols_ref, a_rows_ref, r, seen):
+    """Head r's (Q, Q) decay: position t reads s <= t through
+    ``exp(a_t - a_s)``, a difference of running sums and never a
+    quotient of two exponentials."""
+    return jnp.exp(jnp.where(
+        seen, _ssm_col(cols_ref, 1, r) - a_rows_ref[r:r + 1, :], -jnp.inf))
+
+
+def _ssm_seen(Q):
+    return (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+
+
+_NT = (((1,), (1,)), ((), ()))          # a @ b.T
+_TN = (((0,), (0,)), ((), ()))          # a.T @ b
+
+
+def _mxu(a, b, contract=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, contract,
+                               preferred_element_type=jnp.float32)
+
+
+def _ssm_fwd_kernel(x_ref, b_ref, *refs, P, states):
+    """One chunk of one group: y, or with ``states`` (the backward
+    pass's sweep: no C, no D, no y) the state that enters the chunk."""
+    if states:
+        cols_ref, out_ref, h_ref = refs
+    else:
+        c_ref, cols_ref, a_rows_ref, d_ref, out_ref, h_ref = refs
+    Q, R = cols_ref.shape[0], cols_ref.shape[1] // 4
+    bf = jnp.bfloat16
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+    def wide(k):
+        return _ssm_widen(lambda r: _ssm_col(cols_ref, k, r), R, P)
+
+    x = x_ref[...]
+    xd = x * wide(0)
+    B = b_ref[...].astype(bf)
+    h, exp_a = h_ref[...], wide(2)
+    if states:
+        out_ref[...] = h
+    else:
+        C, xd_b = c_ref[...].astype(bf), xd.astype(bf)
+        scores = _mxu(C, B, _NT)
+        # what the state carried in gives, and D x
+        out_ref[...] = _mxu(C, h.astype(bf)) * exp_a + d_ref[...] * x
+        seen = _ssm_seen(Q)
+        for r in range(R):
+            masked = (scores * _ssm_decay(cols_ref, a_rows_ref, r, seen)
+                      ).astype(bf)
+            mine, cols = _ssm_head(xd_b, r, P)
+            out_ref[:, cols] += _mxu(masked, mine)
+    # exp(a) of the chunk's last position is what the chunk leaves of the
+    # state that entered it; then what it adds by its end
+    h_ref[...] = (h * exp_a[Q - 1:Q]
+                  + _mxu(B, (xd * wide(3)).astype(bf), _TN))
+
+
+def _ssm_bwd_kernel(x_ref, b_ref, c_ref, cols_ref, a_rows_ref, d_ref, h_ref,
+                    dy_ref, dx_ref, db_ref, dc_ref, dcols_ref, da_rows_ref,
+                    dd_ref, dh_ref, *, P):
+    """The same chunk transposed; the chunks come last to first, and
+    ``dh_ref`` carries the gradient of the state that LEAVES the chunk.
+    ``h_ref`` is the state that entered it (the forward sweep's)."""
+    R, Q = a_rows_ref.shape
+    bf = jnp.bfloat16
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dh_ref[...] = jnp.zeros_like(dh_ref)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    def wide(k):
+        return _ssm_widen(lambda r: _ssm_col(cols_ref, k, r), R, P)
+
+    x, dy, h, dh = x_ref[...], dy_ref[...], h_ref[...], dh_ref[...]
+    delta, exp_a, to_end = wide(0), wide(2), wide(3)
+    xd = x * delta
+    B, C = b_ref[...].astype(bf), c_ref[...].astype(bf)
+    xd_b, dy_b, h_b, dh_b = (v.astype(bf) for v in (xd, dy, h, dh))
+    dy_decayed = (dy * exp_a).astype(bf)
+    d_added = _mxu(B, dh_b)                 # of (xd * to_end), (Q, W)
+    d_exp_a = dy * _mxu(C, h_b)             # summed over a head's columns
+    d_to_end = d_added * xd                 # the same
+    dd_ref[...] += jnp.sum(dy * x, axis=0, keepdims=True)
+    dx_ref[...] = d_added * to_end          # d xd, the heads' parts to come
+    scores = _mxu(C, B, _NT)
+    seen = _ssm_seen(Q)
+    d_scores = jnp.zeros((Q, Q), jnp.float32)
+    for r in range(R):
+        decay = _ssm_decay(cols_ref, a_rows_ref, r, seen)
+        masked = scores * decay
+        mine, cols = _ssm_head(dy_b, r, P)
+        d_masked = _mxu(mine, xd_b[:, cols], _NT)
+        dx_ref[:, cols] += _mxu(masked.astype(bf), mine, _TN)
+        d_scores += d_masked * decay
+        d_log = d_masked * masked           # of a_t - a_s
+        dcols_ref[:, R + r:R + r + 1] = jnp.sum(d_log, axis=1, keepdims=True)
+        da_rows_ref[r:r + 1, :] = -jnp.sum(d_log, axis=0, keepdims=True)
+    d_scores = d_scores.astype(bf)
+    dc_ref[...] = _mxu(d_scores, B) + _mxu(dy_decayed, h_b, _NT)
+    db_ref[...] = (_mxu(d_scores, C, _TN)
+                   + _mxu((xd * to_end).astype(bf), dh_b, _NT))
+    d_xd = dx_ref[...]
+    dx_ref[...] = d_xd * delta + dy * d_ref[...]
+    d_delta = d_xd * x
+    # of exp(a) at the chunk's last position, through the state
+    d_whole = jnp.sum(dh * h, axis=0, keepdims=True)
+    last = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
+    for r in range(R):
+        dcols_ref[:, r:r + 1] = _ssm_head_sum(d_delta, r, P)
+        dcols_ref[:, 2 * R + r:2 * R + r + 1] = (
+            _ssm_head_sum(d_exp_a, r, P)
+            + jnp.where(last, _ssm_head_sum(d_whole, r, P), 0.0))
+        dcols_ref[:, 3 * R + r:3 * R + r + 1] = _ssm_head_sum(d_to_end, r, P)
+    dh_ref[...] = dh * exp_a[Q - 1:Q] + _mxu(C, dy_decayed, _TN)
+
+
+def _ssm_specs(Q, G, R, P, N, nc, reverse):
+    """Block specs on the grid (row, group, chunk), by what they fetch:
+    x's, B's and C's columns of ``xbc`` (b, L, [x | B | C]) (``x`` also
+    y's, dy's and dx's of a (b, L, G R P) array), ``cols``, ``a_rows``,
+    D, and the states (b, G, chunks, N, R P); ``reverse`` walks the
+    chunks last to first."""
+    chunk = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
+    W = R * P
+    first_b = G * W // N        # B's first block of N columns, then C's
+
+    def columns(width, first):
+        return pl.BlockSpec((None, Q, width),
+                            lambda i, g, c: (i, chunk(c), first + g))
+
+    return dict(
+        x=columns(W, 0), b=columns(N, first_b), c=columns(N, first_b + G),
+        cols=pl.BlockSpec((None, None, Q, 4 * R),
+                          lambda i, g, c: (i, g, chunk(c), 0)),
+        a_rows=pl.BlockSpec((None, None, R, Q),
+                            lambda i, g, c: (i, g, 0, chunk(c))),
+        d=pl.BlockSpec((1, W), lambda i, g, c: (0, g)),
+        states=pl.BlockSpec((None, None, None, N, W),
+                            lambda i, g, c: (i, g, chunk(c), 0, 0)))
+
+
+_SSM_PARAMS = dict(
+    compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_SSM_VMEM_BYTES))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _ssm_fwd(xbc, cols, a_rows, D, N, Q, states, interpret):
+    """y (b, L, G R P), or with ``states`` the state entering each chunk
+    (b, G, L // Q, N, R P); float32."""
+    b, L, _ = xbc.shape
+    G, R = a_rows.shape[1:3]
+    W, nc = D.shape[1] // G, L // Q
+    at = _ssm_specs(Q, G, R, W // R, N, nc, False)
+    if states:
+        operands = dict(x=xbc, b=xbc, cols=cols)
+        out, out_shape = "states", (b, G, nc, N, W)
+    else:
+        operands = dict(x=xbc, b=xbc, c=xbc, cols=cols, a_rows=a_rows, d=D)
+        out, out_shape = "x", (b, L, G * W)
+    return pl.pallas_call(
+        functools.partial(_ssm_fwd_kernel, P=W // R, states=states),
+        grid=(b, G, nc), in_specs=[at[k] for k in operands],
+        out_specs=at[out],
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        scratch_shapes=[_scratch((N, W), jnp.float32)],
+        interpret=interpret, **_SSM_PARAMS)(*operands.values())
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _ssm_bwd(xbc, cols, a_rows, D, entering, dy, N, Q, interpret):
+    """The gradients of ``_ssm_fwd``'s y in ``xbc``, ``cols``,
+    ``a_rows`` and D (a row of the batch each: (b, 1, G R P))."""
+    b, L, _ = xbc.shape
+    G, R = a_rows.shape[1:3]
+    W, nc = D.shape[1] // G, L // Q
+    at = _ssm_specs(Q, G, R, W // R, N, nc, True)
+    f32 = jnp.float32
+    # dx is written into an array of xbc's shape, where x's columns lie;
+    # dB and dC are arrays of their own, (b, L, G N), and take their
+    # places in it afterwards (in place: a third of a concatenate's bytes)
+    narrow = pl.BlockSpec((None, Q, N), at["x"].index_map)
+    d_xbc, dB, dC, *rest = pl.pallas_call(
+        functools.partial(_ssm_bwd_kernel, P=W // R),
+        grid=(b, G, nc),
+        in_specs=[at[k] for k in ("x", "b", "c", "cols", "a_rows", "d",
+                                  "states", "x")],
+        out_specs=[at["x"], narrow, narrow, at["cols"], at["a_rows"],
+                   pl.BlockSpec((None, 1, W), lambda i, g, c: (i, 0, g))],
+        out_shape=[jax.ShapeDtypeStruct(shape, f32) for shape in (
+            xbc.shape, (b, L, G * N), (b, L, G * N), cols.shape,
+            a_rows.shape, (b, 1, G * W))],
+        scratch_shapes=[_scratch((N, W), f32)],
+        interpret=interpret, **_SSM_PARAMS)(
+            xbc, xbc, xbc, cols, a_rows, D, entering, dy)
+    d_xbc = jax.lax.dynamic_update_slice(d_xbc, dB, (0, 0, G * W))
+    d_xbc = jax.lax.dynamic_update_slice(d_xbc, dC, (0, 0, G * W + G * N))
+    return (d_xbc, *rest)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _ssm_chunks(xbc, cols, a_rows, D, N, Q, interpret):
+    return _ssm_chunks_fwd(xbc, cols, a_rows, D, N, Q, interpret)[0]
+
+
+def _ssm_chunks_fwd(xbc, cols, a_rows, D, N, Q, interpret):
+    operands = (xbc, cols, a_rows, D)
+    # mxlint: disable=recompile-churn (two sizes and bools)
+    return _ssm_fwd(*operands, N, Q, False, interpret), operands
+
+
+def _ssm_chunks_bwd(N, Q, interpret, operands, dy):
+    # the backward's operations carry the scope's name too (a transposed
+    # custom_vjp opens none): train.ssm_device_ms reads them by it
+    with jax.named_scope("mx.ssm.scan"):
+        # the states entering the chunks live inside this layer's
+        # backward only: a sweep of the state alone writes them
+        # mxlint: disable=recompile-churn (two sizes and bools)
+        entering = _ssm_fwd(*operands, N, Q, True, interpret)
+        # mxlint: disable=recompile-churn (two sizes and a bool)
+        *grads, dD = _ssm_bwd(*operands, entering, dy.astype(jnp.float32),
+                              N, Q, interpret)
+        return (*grads, jnp.sum(dD, axis=0))
+
+
+_ssm_chunks.defvjp(_ssm_chunks_fwd, _ssm_chunks_bwd)
+
+
+def ssm_scan_chunks(xbc, delta, A, D, N, Q, interpret=None):
+    """The chunked selective scan as Pallas kernels, for shapes that
+    ``ssm_scan_tiles`` takes.  ``xbc`` (b, L, G R P + 2 G N) is
+    [x | B | C] side by side as a Mamba-2 mixer's convolution leaves
+    them (the kernels read their columns where they lie; nothing is
+    sliced out), delta (b, G, R, L) (positions last: the per-head
+    vectors stay dense that way), A and D (G, R); float32, L a multiple
+    of Q.  Returns ``y + D x`` (b, L, G R P) float32, what
+    ``ops/ssm._chunked_scan`` returns plus the ``D x`` term; its
+    gradients come from a second kernel that walks the chunks backward
+    (ops/ssm.py has the mathematics)."""
+    b, G, R, L = delta.shape
+    P = (xbc.shape[2] - 2 * G * N) // (G * R)
+    # a: the running sum of delta A inside the chunk, a position's log
+    # decay since the chunk began
+    a = jnp.cumsum((delta * A[..., None]).reshape(b, G, R, L // Q, Q),
+                   axis=-1)
+    vectors = (delta, a, jnp.exp(a), jnp.exp(a[..., -1:] - a))
+    cols = jnp.concatenate([v.reshape(b, G, R, L) for v in vectors], axis=2)
+    return _ssm_chunks(xbc, cols.transpose(0, 1, 3, 2),
+                       a.reshape(b, G, R, L),
+                       jnp.repeat(D.reshape(1, G * R), P, axis=1), N, Q,
+                       _interpret(interpret))
 
 
 # ---------------------------------------------------------------------------

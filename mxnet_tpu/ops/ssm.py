@@ -17,9 +17,32 @@
 Decays are ``exp`` of differences of a cumulative sum of ``delta A``
 (never a quotient of two exponentials, which underflows to 0/0 where a
 chunk's decay passes float32's range); decays, state and sums are
-float32, the products take the backend's default precision.  The scan's
-backward pass is autodiff's under ``jax.checkpoint``: its inputs are
-saved, the (chunks, heads, chunk, chunk) decay matrices are not.
+float32, the products take the backend's default precision.
+
+Which code runs the scan is decided from the shapes alone
+(``pallas_kernels.ssm_scan_tiles``).  Where chunk and state size are
+multiples of 128, a group's heads fill whole 128-lane blocks (R x P a
+multiple of 128 with P a power of two up to 128 or a multiple of it,
+two heads a group or more), x's width is a multiple of the state size
+and a chunk's blocks fit VMEM, it is a pair
+of Pallas kernels behind one ``custom_vjp``
+(``pallas_kernels.ssm_scan_chunks``): a grid over (row, group, chunk)
+with the group's state in VMEM from chunk to chunk, x, B and C read
+where the mixer's convolution left them (their columns of its one
+(L, H P + 2 G N) result: ``ssm_mixer`` slices nothing out, ``ssm_scan``,
+which is handed the three apart, packs them first) and y written as
+(L, H P), a head's (chunk, chunk) decay matrix made and consumed in
+VMEM, the MXU's operands rounded to bfloat16 where the TPU's default
+precision rounds the einsums' below.  Between forward and
+backward it keeps its inputs only: the backward pass first sweeps the
+states that enter the chunks (state only, no y) and then walks the
+chunks last to first with the state's gradient in VMEM, giving the
+gradients in x, B, C and the per-head vectors (delta, the running log
+decay and its two exponentials); ``softplus``, the running sum, ``A_log``,
+``dt_bias`` and ``D`` are ``jnp`` around it, and autodiff's.  Every other
+shape runs ``_chunked_scan`` below, in ``jnp``, with autodiff's backward
+pass under ``jax.checkpoint``: its inputs are saved, the (chunks, heads,
+chunk, chunk) decay matrices are not.
 
 Ops:
   ``ssm_conv``      — (B, L, C), weight (C, K), bias (C,) -> (B, L, C)
@@ -34,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .pallas_kernels import ssm_scan_chunks, ssm_scan_tiles
 from .registry import register
 
 __all__ = ["ssm_conv", "ssm_scan", "ssm_gate_norm", "ssm_mixer"]
@@ -89,6 +113,41 @@ def _chunked_scan(x, delta, A, B, C, Q):
     return y.reshape(b, L, G, R, P)
 
 
+def _heads_a_group(H, G):
+    if H % G:
+        from ..base import MXNetError
+        raise MXNetError(f"ssm_scan: {G} groups of B and C do not "
+                         f"divide {H} heads")
+    return H // G
+
+
+def _whole_chunks(a, Q, axis=1):
+    """``a`` with ``axis`` filled with zeros up to a multiple of Q."""
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, -a.shape[axis] % Q)
+    return jnp.pad(a, widths) if widths[axis][1] else a
+
+
+def _kernel_scan(xbc, dt, A_log, D, dt_bias, H, G, N, Q):
+    """``ssm_scan`` through the Pallas kernels, for shapes that
+    ``ssm_scan_tiles`` takes: ``xbc`` (b, L, .) is [x | B | C] side by
+    side, as the mixer's convolution leaves them.  Returns y
+    (b, L, H P) float32."""
+    with jax.named_scope("mx.ssm.scan"):
+        f32 = jnp.float32
+        b, L, _ = xbc.shape
+        # positions last, so that the per-head vectors stay dense
+        # ((L, H) arrays pad every row of 64 to 128 lanes, (L, G, R)
+        # ones every 8)
+        delta = jax.nn.softplus(jnp.swapaxes(dt.astype(f32), 1, 2)
+                                + dt_bias.astype(f32)[:, None])
+        return ssm_scan_chunks(
+            _whole_chunks(xbc.astype(f32), Q),
+            _whole_chunks(delta.reshape(b, G, H // G, L), Q, 3),
+            -jnp.exp(A_log.astype(f32)).reshape(G, H // G),
+            D.astype(f32).reshape(G, H // G), N, Q)[:, :L]
+
+
 @register("_contrib_ssm_scan", num_inputs=7, aliases=["ssm_scan"])
 def ssm_scan(x, dt, A_log, B, C, D, dt_bias, *, chunk: int = 128):
     """The selective scan of a Mamba-2 mixer.  x (b, L, H, P), dt
@@ -97,27 +156,23 @@ def ssm_scan(x, dt, A_log, B, C, D, dt_bias, *, chunk: int = 128):
     (b, L, H, P) in x's dtype; the module's docstring has the
     recurrence.  Any L: the last chunk is filled with positions that
     neither decay nor add to the state."""
+    b, L, H, P = x.shape
+    G, N = B.shape[2:]
+    R, Q = _heads_a_group(H, G), int(chunk)
+    f32 = jnp.float32
+    if ssm_scan_tiles(Q, G, R, P, N):
+        xbc = jnp.concatenate([a.astype(f32).reshape(b, L, -1)
+                               for a in (x, B, C)], axis=-1)
+        y = _kernel_scan(xbc, dt, A_log, D, dt_bias, H, G, N, Q)
+        return y.reshape(b, L, H, P).astype(x.dtype)
     with jax.named_scope("mx.ssm.scan"):
-        b, L, H, P = x.shape
-        G = B.shape[2]
-        if H % G:
-            from ..base import MXNetError
-            raise MXNetError(f"ssm_scan: {G} groups of B and C do not "
-                             f"divide {H} heads")
-        R, Q = H // G, int(chunk)
-        f32 = jnp.float32
         xf = x.astype(f32).reshape(b, L, G, R, P)
         delta = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-        delta = delta.reshape(b, L, G, R)
         A = -jnp.exp(A_log.astype(f32)).reshape(G, R)
-        pad = -L % Q
-        operands = (xf, delta, B.astype(f32), C.astype(f32))
-        if pad:
-            operands = tuple(
-                jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                for a in operands)
+        xp, delta, Bp, Cp = (_whole_chunks(a, Q) for a in (
+            xf, delta.reshape(b, L, G, R), B.astype(f32), C.astype(f32)))
         y = jax.checkpoint(_chunked_scan, static_argnums=(5,))(
-            operands[0], operands[1], A, operands[2], operands[3], Q)
+            xp, delta, A, Bp, Cp, Q)
         y = y[:, :L] + D.astype(f32).reshape(G, R, 1) * xf
         return y.reshape(b, L, H, P).astype(x.dtype)
 
@@ -151,7 +206,13 @@ def ssm_mixer(data, conv_weight, conv_bias, dt_bias, A_log, D, gamma, *,
     b, L, _ = data.shape
     z, xbc, dt = jnp.split(data, (inner, data.shape[-1] - H), axis=-1)
     xbc = ssm_conv(xbc, conv_weight, conv_bias)
-    x, B, C = jnp.split(xbc, (inner, inner + G * N), axis=-1)
-    y = ssm_scan(x.reshape(b, L, H, P), dt, A_log, B.reshape(b, L, G, N),
-                 C.reshape(b, L, G, N), D, dt_bias, chunk=chunk)
-    return ssm_gate_norm(y.reshape(b, L, inner), z, gamma, groups=G, eps=eps)
+    if ssm_scan_tiles(int(chunk), G, _heads_a_group(H, G), P, N):
+        # the kernels read x, B and C where the convolution left them
+        y = _kernel_scan(xbc, dt, A_log, D, dt_bias, H, G, N,
+                         int(chunk)).astype(xbc.dtype)
+    else:
+        x, B, C = jnp.split(xbc, (inner, inner + G * N), axis=-1)
+        y = ssm_scan(x.reshape(b, L, H, P), dt, A_log,
+                     B.reshape(b, L, G, N), C.reshape(b, L, G, N), D,
+                     dt_bias, chunk=chunk).reshape(b, L, inner)
+    return ssm_gate_norm(y, z, gamma, groups=G, eps=eps)

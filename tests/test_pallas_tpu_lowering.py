@@ -19,7 +19,8 @@ from mxnet_tpu.ops.pallas_kernels import (expert_activation,
                                           flash_attention, grouped_matmul,
                                           ragged_paged_attention,
                                           ragged_paged_verify,
-                                          rows_of_tokens, tokens_of_rows)
+                                          rows_of_tokens, ssm_scan_chunks,
+                                          tokens_of_rows)
 
 S = jax.ShapeDtypeStruct
 
@@ -142,3 +143,27 @@ def test_expert_movers_lower_for_tpu(mover, s, k, c, h, g, gated):
     kernel = not (mover.startswith("activation") and h % 128)
     assert text.count("tpu_custom_call") == int(kernel)
     assert "gather" not in text.replace("all-gather", "")
+
+
+# The selective scan at Nemotron-3-Nano's mixer (one 8192-token row, 8
+# groups of 8 heads of 64, state 128, chunks of 128) and at the other
+# head widths ``ssm_scan_tiles`` takes: the forward kernel; with the
+# gradients also the sweep of the entering states and the backward
+# kernel.  (That Mosaic compiles them shows at its compile: PERF.md
+# section 7, recipe 2.)
+@pytest.mark.parametrize("b,L,G,R,P,N,Q", [(1, 8192, 8, 8, 64, 128, 128),
+                                           (2, 512, 2, 4, 32, 128, 128),
+                                           (2, 512, 2, 2, 128, 256, 256)])
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_selective_scan_lowers_for_tpu(b, L, G, R, P, N, Q, mode):
+    f32 = jnp.float32
+    avals = (S((b, L, G * R * P + 2 * G * N), f32), S((b, G, R, L), f32),
+             S((G, R), f32), S((G, R), f32))
+
+    def fwd(*a):
+        return ssm_scan_chunks(*a, N, Q, interpret=False)
+
+    fn = fwd if mode == "fwd" else jax.value_and_grad(
+        lambda *a: fwd(*a).sum(), argnums=tuple(range(4)))
+    text = _tpu_module_text(fn, *avals)
+    assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)

@@ -1,8 +1,11 @@
 """The state-space operators (``ops/ssm.py``), the Mamba-2 mixer block
 and the hybrid layer kinds of ``DecoderLM`` on the CPU at small sizes:
 the chunked scan against the recurrence it stands for, its gradient by
-finite differences, the convolution's first positions, the gate before
-the norm, attention without rotary, and the published sizes."""
+finite differences, the Pallas scan (in the interpreter) against both,
+the convolution's first positions, the gate before the norm, attention
+without rotary, and the published sizes."""
+from unittest import mock
+
 import numpy as np
 import pytest
 
@@ -13,13 +16,14 @@ import mxnet_tpu as mx
 from mxnet_tpu import models, nd
 from mxnet_tpu.models import transformer_blocks as tb
 from mxnet_tpu.models.decoder_lm import _DECODER_CONFIGS
+from mxnet_tpu.ops import pallas_kernels, ssm
 from mxnet_tpu.ops.ssm import ssm_conv, ssm_gate_norm, ssm_mixer, ssm_scan
 
 H, P, G, N = 4, 8, 2, 16
 NAMES = ("x", "dt", "A_log", "B", "C", "D", "dt_bias")
 
 
-def _operands(L, seed=0, b=2):
+def _operands(L, seed=0, b=2, H=H, P=P, G=G, N=N):
     k = jax.random.split(jax.random.PRNGKey(seed), 7)
     return (jax.random.normal(k[0], (b, L, H, P)),
             jax.random.normal(k[1], (b, L, H)),
@@ -111,6 +115,192 @@ def test_scan_gradient_by_finite_differences(leaf):
                                                                   numeric)
 
 
+# ---- the Pallas scan (ops/pallas_kernels.ssm_scan_chunks), which
+# ``ssm_scan`` takes where chunk and state are multiples of 128 and a
+# group's heads fill whole 128-lane blocks; in the interpreter here.
+# Two groups of two heads of 64 over a state of 128, chunks of 128.
+KERNEL = dict(H=4, P=64, G=2, N=128)
+# The kernels round what enters the MXU to bfloat16 (8 bits of mantissa,
+# 2^-9 of an operand at most, 2^-8 of a product of two) where the CPU's
+# einsums of ``_chunked_scan`` and the recurrence keep float32.  A value
+# passes three such products in a row (C B^T, the masked product or the
+# state's, and C against the state), a gradient up to five, and a
+# head's leaf (A_log, dt_bias) is a sum over every position in which
+# terms of both signs cancel, so its norm is small beside its terms'
+# errors.  2^-6 of the NORM of what is compared bounds them with room:
+# the values read 0.0033, the gradients 0.0008 to 0.0064 (dt_bias).
+KERNEL_TOL = 2.0 ** -6
+
+
+def _kernel_operands(L, seed=0, b=2):
+    """delta of about 0.007 against A of -1 to -16 and D of 0.1: the
+    state outlives a chunk of 128 for the slower heads, as a trained
+    model's does, and ``D x`` does not drown what it gives, so the carry
+    weighs in every comparison."""
+    x, dt, A_log, B, C, D, dt_bias = _operands(L, seed=seed, b=b, **KERNEL)
+    return x, 0.5 * dt - 5.0, A_log, 0.3 * B, 0.3 * C, 0.1 * D, 0.3 * dt_bias
+
+
+def _chunked(*ops, chunk=128):
+    """``ssm_scan`` on its ``jnp`` path whatever the shape."""
+    with mock.patch.object(ssm, "ssm_scan_tiles", lambda *shape: False):
+        return ssm_scan(*ops, chunk=chunk)
+
+
+def _gap(got, want):
+    return float(jnp.linalg.norm((got - want).ravel())
+                 / jnp.linalg.norm(want.ravel()))
+
+
+def _has_kernels(fn, *ops):
+    return str(jax.make_jaxpr(fn)(*ops)).count("pallas_call")
+
+
+@pytest.mark.parametrize("oracle", ["recurrence", "chunked_scan"])
+@pytest.mark.parametrize("L", [384, 300], ids=["whole_chunks", "ragged"])
+def test_kernel_scan_values(L, oracle):
+    ops = _kernel_operands(L)
+    assert _has_kernels(ssm_scan, *ops) == 1
+    got = ssm_scan(*ops)
+    with jax.default_matmul_precision("highest"):
+        want = (recurrence if oracle == "recurrence" else _chunked)(*ops)
+    assert got.shape == want.shape == (2, L, 4, 64)
+    assert _gap(got, want) < KERNEL_TOL
+    # ``D x`` is most of y: what the state and the chunk give, alone
+    skip = ops[5][:, None] * ops[0]
+    assert _gap(got - skip, want - skip) < KERNEL_TOL
+
+
+@pytest.fixture(scope="module")
+def kernel_gradients():
+    """Every leaf's gradient of one weighted sum of y, L = 300 (two
+    whole chunks and a ragged one): through the kernels, through
+    autodiff of ``_chunked_scan`` and of the recurrence."""
+    ops = _kernel_operands(300, seed=2)
+    w = jax.random.normal(jax.random.PRNGKey(7), ops[0].shape)
+
+    def grads(fn):
+        return jax.grad(lambda *a: (fn(*a) * w).sum(),
+                        argnums=tuple(range(7)))
+
+    assert _has_kernels(grads(ssm_scan), *ops) == 3
+    got = grads(ssm_scan)(*ops)
+    with jax.default_matmul_precision("highest"):
+        return got, {"recurrence": grads(recurrence)(*ops),
+                     "chunked_scan": grads(_chunked)(*ops)}
+
+
+@pytest.mark.parametrize("oracle", ["recurrence", "chunked_scan"])
+@pytest.mark.parametrize("leaf", NAMES)
+def test_kernel_scan_gradient(kernel_gradients, leaf, oracle):
+    got, want = kernel_gradients
+    i = NAMES.index(leaf)
+    assert got[i].shape == want[oracle][i].shape
+    assert _gap(got[i], want[oracle][i]) < KERNEL_TOL, leaf
+
+
+def _loses_the_carry(kernel):
+    """The kernel with its carried state (its last operand, the VMEM
+    scratch) emptied before every chunk."""
+    def faulty(*refs, **kw):
+        refs[-1][...] = jnp.zeros_like(refs[-1])
+        return kernel(*refs, **kw)
+    return faulty
+
+
+@pytest.mark.parametrize("which", ["_ssm_fwd_kernel", "_ssm_bwd_kernel"])
+def test_kernel_that_loses_the_carry_fails_the_comparison(which):
+    """The fault the kernels could hide: a chunk that starts from an
+    empty state (forward, and the sweep that recomputes the states for
+    the backward pass), or a chunk whose state's gradient never reaches
+    the chunk before (backward).  The comparison of the tests above must
+    read it at 8 times its limit or more (it reads 0.16 to 0.25, sound
+    kernels 0.0064 at most), in y and in every leaf the state reaches
+    the loss through (forward: C reads the state; backward: B and delta
+    write it)."""
+    ops = _kernel_operands(384, seed=4, b=1)
+    w = jax.random.normal(jax.random.PRNGKey(8), ops[0].shape)
+    skip = ops[5][:, None] * ops[0]
+
+    def run(fn):
+        y, vjp = jax.vjp(fn, *ops)
+        return dict(zip(("y",) + NAMES, (y - skip,) + vjp(w)))
+
+    def gaps(got):
+        return {name: _gap(got[name], want[name]) for name in got}
+
+    with jax.default_matmul_precision("highest"):
+        want = run(_chunked)
+    jitted = (pallas_kernels._ssm_fwd, pallas_kernels._ssm_bwd)
+    try:
+        for f in jitted:
+            f.clear_cache()
+        with mock.patch.object(pallas_kernels, which, _loses_the_carry(
+                getattr(pallas_kernels, which))):
+            faulty = gaps(run(ssm_scan))
+    finally:
+        for f in jitted:        # the faulty traces must not outlive this
+            f.clear_cache()
+    sound = gaps(run(ssm_scan))
+    assert max(sound.values()) < KERNEL_TOL, sound
+    read_by = (("y", "C", "A_log", "dt_bias") if which == "_ssm_fwd_kernel"
+               else ("B", "dt", "dt_bias"))
+    for name in read_by:
+        assert faulty[name] > 8 * KERNEL_TOL, faulty
+    if which == "_ssm_bwd_kernel":
+        assert faulty["y"] == sound["y"]        # the forward pass is whole
+
+
+def test_kernel_scan_survives_decays_that_underflow_a_quotient():
+    """``test_scan_survives_decays_that_underflow_a_quotient`` at a
+    shape the kernels take: exp of the running sum is 0 long before a
+    chunk of 128 ends."""
+    x, dt, A_log, B, C, D, dt_bias = _operands(300, seed=5, **KERNEL)
+    ops = (x, dt + 8.0, A_log + 3.0, 0.3 * B, 0.3 * C, D, dt_bias)
+    cum = jnp.cumsum(jax.nn.softplus(ops[1] + dt_bias) * -jnp.exp(ops[2]), 1)
+    assert float(jnp.exp(cum[:, 15]).max()) == 0.0
+    assert _has_kernels(ssm_scan, *ops) == 1
+    got = ssm_scan(*ops)
+    grads = jax.grad(lambda *a: ssm_scan(*a).sum(),
+                     argnums=tuple(range(7)))(*ops)
+    with jax.default_matmul_precision("highest"):
+        want = recurrence(*ops)
+    assert bool(jnp.isfinite(got).all())
+    assert _gap(got, want) < KERNEL_TOL
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+
+
+# Nemotron-3-Nano's mixer (the benchmark's cell): 64 heads of 64 in 8
+# groups over a state of 128, chunks of 128; and shapes that stay on
+# ``_chunked_scan``: this file's small ones, a chunk that is no
+# multiple of 128, a state of 64, heads of 48, one head a group
+@pytest.mark.parametrize("H,P,G,N,chunk,kernels", [
+    (64, 64, 8, 128, 128, True), (4, 64, 2, 128, 256, True),
+    (8, 32, 2, 128, 128, True), (4, 128, 2, 256, 128, True),
+    (4, 8, 2, 16, 16, False), (4, 8, 2, 16, 128, False),
+    (4, 64, 2, 128, 64, False), (4, 64, 2, 64, 128, False),
+    (8, 48, 1, 128, 128, False), (2, 128, 2, 128, 128, False)],
+    ids=["cell_widths", "chunk_256", "heads_of_32", "heads_of_128",
+         "small", "small_one_chunk", "chunk_64", "state_64", "heads_of_48",
+         "one_head_a_group"])
+def test_which_shapes_take_the_kernels(H, P, G, N, chunk, kernels):
+    """The choice is static, made from shapes at trace time: read it
+    from the jaxpr.  One call forward; forward, the sweep of the states
+    and the backward kernel under ``grad``."""
+    ops = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+           for a in _operands(300, b=1, H=H, P=P, G=G, N=N)]
+
+    def fwd(*a):
+        return ssm_scan(*a, chunk=chunk)
+
+    def bwd(*a):
+        return jax.grad(lambda *b: fwd(*b).sum(), argnums=(0, 1, 2))(*a)
+
+    assert _has_kernels(fwd, *ops) == (1 if kernels else 0)
+    assert _has_kernels(bwd, *ops) == (3 if kernels else 0)
+    assert pallas_kernels.ssm_scan_tiles(chunk, G, H // G, P, N) is kernels
+
+
 def test_scan_refuses_groups_that_do_not_divide_the_heads():
     x, dt, A_log, B, C, D, dt_bias = _operands(8)
     with pytest.raises(mx.base.MXNetError, match="3 groups"):
@@ -181,6 +371,47 @@ def test_mixer_op_is_its_three_parts():
     for a, b in zip(got_g, want_g):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
                                    atol=1e-4 * float(jnp.abs(b).max()))
+
+
+def test_mixer_hands_the_kernels_the_convolutions_output_whole():
+    """At widths the kernels take, ``ssm_mixer`` slices no x, B or C out
+    of what the convolution wrote (the kernels fetch their columns of
+    it), and gives what its three parts give by hand, bit for bit."""
+    H_, P_, G_, N_ = KERNEL["H"], KERNEL["P"], KERNEL["G"], KERNEL["N"]
+    inner, cw = H_ * P_, H_ * P_ + 2 * G_ * N_
+    k = jax.random.split(jax.random.PRNGKey(11), 4)
+    data = jax.random.normal(k[0], (2, 300, inner + cw + H_))
+    leaves = (0.5 * jax.random.normal(k[1], (cw, 4)),
+              0.1 * jax.random.normal(k[2], (cw,)), jnp.zeros((H_,)) - 4.0,
+              jnp.zeros((H_,)), jnp.ones((H_,)),
+              1.0 + 0.1 * jax.random.normal(k[3], (inner,)))
+    kw = dict(num_heads=H_, head_dim=P_, n_groups=G_, state_size=N_)
+
+    def by_hand(data, cw_, cb, dtb, A_log, D, gamma):
+        z, xbc, dt = jnp.split(data, (inner, inner + cw), axis=-1)
+        x, B, C = jnp.split(ssm_conv(xbc, cw_, cb), (inner, inner + G_ * N_),
+                            axis=-1)
+        y = ssm_scan(x.reshape(2, 300, H_, P_), dt, A_log,
+                     B.reshape(2, 300, G_, N_), C.reshape(2, 300, G_, N_),
+                     D, dtb)
+        return ssm_gate_norm(y.reshape(2, 300, inner), z, gamma, groups=G_)
+
+    def both(fn):
+        return jax.value_and_grad(lambda *a: fn(*a).sum(),
+                                  argnums=(0, 1, 3, 4))(data, *leaves)
+
+    jaxpr = str(jax.make_jaxpr(lambda *a: ssm_mixer(*a, **kw))(data, *leaves))
+    assert jaxpr.count("pallas_call") == 1
+    # one concatenate, of the per-head vectors; by hand a second one
+    # packs x, B and C again
+    assert jaxpr.count("concatenate") == 1
+    assert str(jax.make_jaxpr(by_hand)(data, *leaves)).count(
+        "concatenate") == 2
+    (got, got_g), (want, want_g) = both(lambda *a: ssm_mixer(*a, **kw)), \
+        both(by_hand)
+    assert float(got) == float(want)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_mamba2_mixer_block_shapes_and_causality():
