@@ -154,6 +154,10 @@ def _op_specs(large=False):
             [nd.array(rng.randn(2, 1024, 1536).astype(np.float32)),
              nd.array(rng.rand(1536, 4).astype(np.float32) - 0.5),
              nd.array(rng.rand(1536).astype(np.float32) - 0.5)], {})),
+        # the gated short convolution (LFM2: [B | C | u] of 512, 3 taps)
+        "gated_short_conv": ("ssm", lambda nd, rng: (
+            [nd.array(rng.randn(2, 1024, 1536).astype(np.float32)),
+             nd.array(rng.rand(512, 3).astype(np.float32) - 0.5)], {})),
         "ssm_scan": ("ssm", lambda nd, rng: (
             [nd.array(rng.randn(2, 1024, 16, 64).astype(np.float32)),
              nd.array(rng.randn(2, 1024, 16).astype(np.float32)),

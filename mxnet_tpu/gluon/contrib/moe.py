@@ -35,8 +35,9 @@ class MoEFFN(HybridBlock):
 
     ``scoring``: how the router scores (``ops.moe.moe_topk_route``):
     "softmax", or "sigmoid" with ``route_bias`` (num_experts,), added
-    to the scores to choose and not to weigh, and ``route_scale`` on the
-    renormalised weights.  The bias is no weight: no optimizer moves it
+    to the scores to choose and not to weigh, ``route_eps`` beside the
+    chosen scores' sum, and ``route_scale`` on the renormalised
+    weights.  The bias is no weight: no optimizer moves it
     (its published update rule, from the experts' load, is a training
     loop's, not this layer's).  ``shared_hidden_size`` > 0 adds one
     shared expert of that width and of the routed experts' kind, which
@@ -59,7 +60,8 @@ class MoEFFN(HybridBlock):
     def __init__(self, units, hidden_size, num_experts,
                  experts_per_token=1, experts_held=None, first_expert=0,
                  activation="gelu", gated=False, recompute=False, train_router=True,
-                 scoring="softmax", route_scale=1.0, shared_hidden_size=0,
+                 scoring="softmax", route_scale=1.0, route_eps=1e-20,
+                 shared_hidden_size=0,
                  weight_initializer=None, **kwargs):
         super().__init__(**kwargs)
         held = num_experts if experts_held is None else int(experts_held)
@@ -84,6 +86,7 @@ class MoEFFN(HybridBlock):
                         activation=activation, gated=bool(gated),
                         recompute=bool(recompute), scoring=scoring,
                         route_scale=float(route_scale),
+                        route_eps=float(route_eps),
                         shared_expert=bool(shared_hidden_size))
         fan = 2 if gated else 1
         with self.name_scope():

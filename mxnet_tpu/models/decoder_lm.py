@@ -1,8 +1,9 @@
 """Present-day decoder-only language models for training: RMSNorm,
 rotary positions, grouped key/value heads, window and full causal
-layers through the flash kernels, dense or sparse feed-forwards, and
-hybrid models whose layers are one mixer each (Mamba-2, attention
-without rotary, an expert layer with a shared expert).
+layers through the flash kernels or gated short convolutions, each
+before a dense or sparse feed-forward, and hybrid models whose layers
+are one mixer each (Mamba-2, attention without rotary, an expert layer
+with a shared expert).
 One ``DecoderCell`` class; ``layer_types`` says what each layer is.
 
 The serving side (the paged forwards of ``transformer_blocks``) has
@@ -15,7 +16,8 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.contrib.moe import MoEFFN
 from .transformer_blocks import (DecoderCell, GatedFFN, Mamba2Mixer,
-                                 RMSNorm, RotaryGroupedAttention)
+                                 RMSNorm, RotaryGroupedAttention,
+                                 ShortConvMixer)
 
 __all__ = ["DecoderLM", "get_decoder_lm"]
 
@@ -24,23 +26,30 @@ class DecoderLM(HybridBlock):
     """``lm(tokens (B, L)) -> logits (B, L, vocab_size)``.
 
     ``layer_types``, a layer each.  "sliding_attention" /
-    "full_attention": attention, then a feed-forward, each under its
-    norm; ``rope``: the ``rope`` op's keyword arguments for each of the
-    two.  "mamba2" / "attention" / "moe": ONE mixer under one norm, a
+    "full_attention" / "conv": attention, or a gated short convolution
+    of ``conv_kernel`` taps (``ShortConvMixer``), then a feed-forward,
+    each under its norm; ``rope``: the ``rope`` op's keyword arguments
+    for each of the two attentions; ``qk_norm_eps``:
+    ``RotaryGroupedAttention``'s.  "mamba2" / "attention" / "moe": ONE
+    mixer under one norm, a
     hybrid model's layer: a Mamba-2 mixer of the sizes in ``mamba``
     (``Mamba2Mixer``'s keyword arguments), causal attention (rotated
     only if ``rope`` has an entry "attention": such a model's Mamba
     layers carry position), or the expert layer.
 
-    ``num_experts`` > 0 makes every feed-forward, and every "moe"
-    layer, sparse: ``experts_per_token`` of ``num_experts`` experts of
+    ``num_experts`` > 0 makes every feed-forward but those of the first
+    ``dense_ffn_layers`` layers (``GatedFFN(hidden_size)``, a model's
+    leading dense layers), and every "moe" layer, sparse: ``experts_per_token`` of ``num_experts`` experts of
     width ``expert_hidden_size`` (``expert_activation``, gated or not
     by ``expert_gated``), of which this model holds ``experts_held``
     from ``first_expert`` on (all by default; one chip's share under
-    expert parallelism); ``router``: ``MoEFFN``'s ``scoring`` and
-    ``route_scale``; ``shared_expert_hidden_size`` > 0 gives each
-    expert layer a shared expert.  ``vocab_size`` is the number of rows
-    of the embedding and the head held here.  ``recompute_experts``:
+    expert parallelism); ``router``: ``MoEFFN``'s ``scoring``,
+    ``route_scale`` and ``route_eps``; ``shared_expert_hidden_size`` > 0
+    gives each expert layer a shared expert.  ``vocab_size`` is the
+    number of rows of the embedding and the head held here;
+    ``tie_embeddings``: the head reads the embedding's rows, one
+    parameter whose gradient is the sum of both uses.
+    ``recompute_experts``:
     see ``ops.moe.moe_ffn``; ``train_router``: see ``MoEFFN``; ``attention_dtype``: what the
     flash kernels compute in (``RotaryGroupedAttention``).
     """
@@ -52,7 +61,9 @@ class DecoderLM(HybridBlock):
                  rms_norm_eps=1e-6, recompute_experts=False,
                  train_router=True, attention_dtype="bfloat16",
                  expert_activation="silu", expert_gated=True, router=None,
-                 shared_expert_hidden_size=0, mamba=None, **kwargs):
+                 shared_expert_hidden_size=0, mamba=None, conv_kernel=3,
+                 qk_norm_eps=None, dense_ffn_layers=0, tie_embeddings=False,
+                 **kwargs):
         super().__init__(**kwargs)
         rope = rope or {}
         self.vocab_size, self.units = int(vocab_size), int(units)
@@ -64,7 +75,8 @@ class DecoderLM(HybridBlock):
                 units, num_heads, num_kv_heads, head_dim,
                 window=window if kind == "sliding_attention" else None,
                 rope=rope.get(kind, None if kind == "attention" else {}),
-                compute_dtype=attention_dtype, prefix=prefix + "attention_")
+                compute_dtype=attention_dtype, qk_norm_eps=qk_norm_eps,
+                prefix=prefix + "attention_")
 
         def experts(prefix):
             return MoEFFN(
@@ -83,9 +95,14 @@ class DecoderLM(HybridBlock):
             for i, kind in enumerate(layer_types):
                 with self.cells.name_scope():
                     prefix = f"layer{i}_"
-                    if kind in ("sliding_attention", "full_attention"):
-                        blocks = (attention(kind, prefix),
-                                  experts(prefix) if num_experts else
+                    if kind in ("sliding_attention", "full_attention",
+                                "conv"):
+                        sparse = num_experts and i >= dense_ffn_layers
+                        blocks = (ShortConvMixer(units, conv_kernel,
+                                                 prefix=prefix + "conv_")
+                                  if kind == "conv" else
+                                  attention(kind, prefix),
+                                  experts(prefix) if sparse else
                                   GatedFFN(units, hidden_size,
                                            prefix=prefix + "ffn_"))
                     elif kind == "attention":
@@ -102,9 +119,12 @@ class DecoderLM(HybridBlock):
                         prefix=prefix))
             self.final_norm = RMSNorm(units, rms_norm_eps,
                                       prefix="final_norm_")
-            self.lm_head = nn.Dense(vocab_size, in_units=units,
-                                    use_bias=False, flatten=False,
-                                    prefix="lm_head_")
+            # tied: the head's Dense finds the embedding's "weight" in
+            # the dictionary it is handed, the same (vocab, units) leaf
+            self.lm_head = nn.Dense(
+                vocab_size, in_units=units, use_bias=False, flatten=False,
+                **(dict(params=self.word_embed.params) if tie_embeddings
+                   else dict(prefix="lm_head_")))
 
     def hybrid_forward(self, F, tokens):
         x = self.word_embed(tokens)                         # (B, L, C)
@@ -142,6 +162,19 @@ _DECODER_CONFIGS = {
         expert_activation="relu2", expert_gated=False,
         router=dict(scoring="sigmoid", route_scale=2.5),
         shared_expert_hidden_size=3712),
+    # LiquidAI LFM2-24B-A2B, config.json (model_type lfm2_moe): a gated
+    # short convolution or attention (at layers 2, 6, .. 38), then a
+    # dense SwiGLU in the first two layers and experts after
+    "lfm2_24b_a2b": dict(
+        vocab_size=65536, units=2048,
+        layer_types=tuple("full_attention" if i % 4 == 2 else "conv"
+                          for i in range(40)),
+        num_heads=32, num_kv_heads=8, head_dim=64, rms_norm_eps=1e-5,
+        qk_norm_eps=1e-5, rope={"full_attention": dict(theta=1000000.0)},
+        conv_kernel=3, hidden_size=11776, dense_ffn_layers=2,
+        num_experts=64, experts_per_token=4, expert_hidden_size=1536,
+        router=dict(scoring="sigmoid", route_scale=1.0, route_eps=1e-6),
+        tie_embeddings=True),
 }
 
 

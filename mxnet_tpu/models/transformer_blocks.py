@@ -23,7 +23,7 @@ __all__ = ["PositionwiseFFN", "MultiHeadSelfAttention",
            "MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerDecoderLM",
            "RMSNorm", "GatedFFN", "RotaryGroupedAttention", "Mamba2Mixer",
-           "DecoderCell",
+           "ShortConvMixer", "DecoderCell",
            "paged_lm_params", "paged_prefill", "paged_decode_step",
            "paged_verify", "paged_verify_batch"]
 
@@ -277,14 +277,15 @@ class GatedFFN(HybridBlock):
                                   prefix="ffn_2_")
 
     def hybrid_forward(self, F, x):
-        h = self.ffn_1(x)
-        gate = F.slice_axis(h, axis=-1, begin=0, end=self._hidden)
-        up = F.slice_axis(h, axis=-1, begin=self._hidden, end=None)
-        if self._activation == "silu":
-            gate = gate * F.sigmoid(gate)
-        else:
-            gate = F.Activation(gate, act_type=self._activation)
-        return self.ffn_2(gate * up)
+        with jax.named_scope("mx.ffn.dense"):
+            h = self.ffn_1(x)
+            gate = F.slice_axis(h, axis=-1, begin=0, end=self._hidden)
+            up = F.slice_axis(h, axis=-1, begin=self._hidden, end=None)
+            if self._activation == "silu":
+                gate = gate * F.sigmoid(gate)
+            else:
+                gate = F.Activation(gate, act_type=self._activation)
+            return self.ffn_2(gate * up)
 
 
 class RotaryGroupedAttention(HybridBlock):
@@ -301,12 +302,15 @@ class RotaryGroupedAttention(HybridBlock):
     ``kv_proj`` holds [k | v].  ``compute_dtype``: what q, k and v are
     rounded to for the kernels (the MXU's fast path; the default
     matmuls round float32 operands to it too); the output returns to
-    x's type.
+    x's type.  ``qk_norm_eps``: with a value, every head of q and of k
+    passes an RMS norm over its ``head_dim`` channels before the
+    rotation, q's and k's each with a gain (head_dim,) of its own,
+    shared by the heads; None: no such norm and no such gains.
     """
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  window=None, rope=None, compute_dtype="bfloat16",
-                 **kwargs):
+                 qk_norm_eps=None, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"{num_kv_heads} key/value heads do not "
@@ -318,7 +322,13 @@ class RotaryGroupedAttention(HybridBlock):
         self._window = -1 if window is None else int(window)
         self._rope = None if rope is None else dict(rope)
         self._compute_dtype = compute_dtype
+        self._qk_norm_eps = qk_norm_eps
         with self.name_scope():
+            if qk_norm_eps is not None:
+                self.q_norm = RMSNorm(head_dim, qk_norm_eps,
+                                      prefix="q_norm_")
+                self.k_norm = RMSNorm(head_dim, qk_norm_eps,
+                                      prefix="k_norm_")
             self.q_proj = nn.Dense(num_heads * head_dim, in_units=units,
                                    use_bias=False, flatten=False,
                                    prefix="q_proj_")
@@ -337,6 +347,9 @@ class RotaryGroupedAttention(HybridBlock):
         k = F.slice_axis(kv, axis=-1, begin=0, end=Hkv * D)
         v = F.slice_axis(kv, axis=-1, begin=Hkv * D, end=None)
         k = k.reshape((B, L, Hkv, D))
+        if self._qk_norm_eps is not None:
+            with jax.named_scope("mx.attn.qk_norm"):
+                q, k = self.q_norm(q), self.k_norm(k)
         if self._rope is not None:
             q, k = F.rope(q, **self._rope), F.rope(k, **self._rope)
         v = v.reshape((B, L, Hkv, D))
@@ -399,10 +412,39 @@ class Mamba2Mixer(HybridBlock):
             return self.out_proj(y)
 
 
+class ShortConvMixer(HybridBlock):
+    """The gated short convolution over (B, L, C) (the LFM2 family's
+    "conv" operator; ``ops/shortconv.py`` has the equations):
+    ``in_proj`` gives [B | C | u] of ``units`` channels each, ``B * u``
+    passes a causal depthwise convolution of ``kernel`` taps, ``C``
+    gates the result and ``out_proj`` returns it.  No bias, no
+    activation.
+    """
+
+    def __init__(self, units, kernel=3, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.in_proj = nn.Dense(3 * units, in_units=units,
+                                    use_bias=False, flatten=False,
+                                    prefix="in_proj_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(units, int(kernel)))
+            self.out_proj = nn.Dense(units, in_units=units, use_bias=False,
+                                     flatten=False, prefix="out_proj_")
+
+    def hybrid_forward(self, F, x, conv_weight):
+        with jax.named_scope("mx.sconv.in_proj"):
+            h = self.in_proj(x)
+        y = F.gated_short_conv(h, conv_weight)
+        with jax.named_scope("mx.sconv.out_proj"):
+            return self.out_proj(y)
+
+
 class DecoderCell(HybridBlock):
     """The present-day pre-norm decoder block over (B, L, C), RMSNorm
     throughout.  With ``ffn``: ``h = x + mixer(norm(x))``,
-    ``y = h + ffn(norm(h))`` (``mixer`` an attention block).  Without:
+    ``y = h + ffn(norm(h))`` (``mixer`` an attention block or a gated
+    short convolution).  Without:
     one mixer alone under one norm, ``y = x + mixer(norm(x))`` (a hybrid
     model's layer: a state-space mixer, an attention block or an expert
     layer).  What kind of layer this is (window or full attention,

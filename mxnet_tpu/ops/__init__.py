@@ -14,6 +14,7 @@ from . import contrib     # noqa: F401  transformer kernels, roialign, ...
 from . import detection   # noqa: F401  SSD MultiBox prior/target/detection
 from . import moe         # noqa: F401  MoE routing + expert FFN (GShard)
 from . import ssm         # noqa: F401  Mamba-2 conv / selective scan / gated norm
+from . import shortconv   # noqa: F401  LFM2 gated short convolution
 from . import quantization  # noqa: F401  int8 quantize/dequantize/qgemm
 from . import pallas_kernels  # noqa: F401  flash attention (TPU/interpret)
 from .. import random as _random_ops  # noqa: F401  sampling ops

@@ -79,7 +79,7 @@ _ACTIVATIONS = {"relu": jax.nn.relu, "relu2": relu2, "silu": jax.nn.silu,
           num_outputs=2, aliases=["moe_topk_route"])
 def moe_topk_route(x, gate_weight, choice_bias=None, *,
                    experts_per_token: int = 1, scoring: str = "softmax",
-                   scale: float = 1.0):
+                   scale: float = 1.0, route_eps: float = 1e-20):
     """Top-k router.  ``x`` (S, C), ``gate_weight`` (C, E).
 
     Returns (weights (S, k) float32, ids (S, k) int32).  ``scoring``
@@ -89,7 +89,8 @@ def moe_topk_route(x, gate_weight, choice_bias=None, *,
     ``sigmoid(x @ gate_weight)``; the k largest of ``score +
     choice_bias`` ((E,), the load-balancing bias: it chooses and does
     not weigh) are chosen; the weights are the scores at those ids,
-    divided by (their sum + 1e-20), times ``scale``.
+    divided by (their sum + ``route_eps``: 1e-20 in Nemotron-3's
+    published code, 1e-6 in LFM2's), times ``scale``.
     """
     with jax.named_scope("mx.moe.route"):
         logits = jnp.dot(x.astype(jnp.float32),
@@ -107,7 +108,7 @@ def moe_topk_route(x, gate_weight, choice_bias=None, *,
             ids = lax.top_k(choice, k)[1]
             weights = jnp.take_along_axis(scores, ids, axis=-1)
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + route_eps)
         else:
             from ..base import MXNetError
             raise MXNetError(f"moe_topk_route: unsupported scoring "
@@ -213,7 +214,7 @@ def moe_ffn(x, wg, w1, w2, *more, experts_per_token: int = 1,
             first_expert: int = 0, activation: str = "gelu",
             gated: bool = False, recompute: bool = False,
             scoring: str = "softmax", route_scale: float = 1.0,
-            shared_expert: bool = False):
+            route_eps: float = 1e-20, shared_expert: bool = False):
     """The expert layer: route over all experts, compute the held
     experts' part.
 
@@ -253,7 +254,8 @@ def moe_ffn(x, wg, w1, w2, *more, experts_per_token: int = 1,
     xs = x.reshape(-1, x.shape[-1])
     weights, ids = moe_topk_route(xs, wg, choice_bias=choice_bias,
                                   experts_per_token=experts_per_token,
-                                  scoring=scoring, scale=route_scale)
+                                  scoring=scoring, scale=route_scale,
+                                  route_eps=route_eps)
     def part(xs, weights, ids, w1, w2):
         return _experts_part(xs, weights, ids, w1, w2, int(first_expert),
                              activation, bool(gated))

@@ -741,6 +741,9 @@ ORACLES.update({
     "_contrib_ssm_gate_norm": lambda y, z, g, groups=1, eps=1e-5:
         _np_ssm_gate_norm(y, z, g, groups, eps),
     "_contrib_ssm_mixer": lambda *a, **k: _np_ssm_mixer(*a, **k),
+    # the gated short convolution: [B | C | u] in, C * conv(B * u) out,
+    # the K-term sum over a padded row; no bias, no activation
+    "_contrib_gated_short_conv": lambda x, w: _np_gated_short_conv(x, w),
     # decode-path paged attention vs a per-sequence gather + dense
     # softmax (block-table indirection materialized in numpy)
     "_contrib_ragged_paged_attention": lambda q, kp, vp, bt, lens:
@@ -1013,6 +1016,13 @@ def _np_ssm_conv(x, w, b):
     return _np_silu(b + sum(w[:, j] * xp[:, j:j + L] for j in range(K)))
 
 
+def _np_gated_short_conv(x, w):
+    C, K, L = w.shape[0], w.shape[1], x.shape[1]
+    v = x[..., :C] * x[..., 2 * C:]
+    vp = np.concatenate([np.zeros_like(v[:, :K - 1]), v], axis=1)
+    return x[..., C:2 * C] * sum(w[:, j] * vp[:, j:j + L] for j in range(K))
+
+
 def _np_ssm_scan(x, dt, A_log, B, C, D, dt_bias):
     """h_t = exp(delta_t A) h_{t-1} + delta_t x_t B_t^T, y_t = h_t C_t +
     D x_t, one position at a time."""
@@ -1238,6 +1248,9 @@ SPECS = {
     # (b, L, C), taps (C, 4), bias (C,)
     "_contrib_ssm_conv": dict(
         inputs=lambda r: [_f32(r, 2, 6, 5), _f32(r, 5, 4), _f32(r, 5)]),
+    # (b, L, 3C) [B | C | u], taps (C, 3)
+    "_contrib_gated_short_conv": dict(
+        inputs=lambda r: [_f32(r, 2, 7, 15), _f32(r, 5, 3)]),
     # x (b, L, H, P), dt (b, L, H), A_log (H,), B and C (b, L, G, N),
     # D and dt_bias (H,): 4 heads over 2 groups, 7 positions in chunks
     # of 3
