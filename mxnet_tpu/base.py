@@ -275,11 +275,6 @@ declare_env("MXNET_EXEC_BULK_EXEC_TRAIN", "1",
 declare_env("MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN", 15,
             "engine.bulk_size default when bulk-exec is on; bulk backward "
             "runs when bulk_size > 1.")
-declare_env("MXNET_FLASH_BLOCK_Q", None,
-            "Override the flash-attention query block size (default: "
-            "per-seqlen tuned table).")
-declare_env("MXNET_FLASH_BLOCK_K", None,
-            "Override the flash-attention key block size.")
 declare_env("MXNET_CACHED_OP_CACHE_SIZE", 16,
             "Max compiled programs kept per CachedOp (LRU-evicted beyond, "
             "with a churn warning); override per block via "

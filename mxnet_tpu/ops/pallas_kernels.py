@@ -10,12 +10,16 @@ FlashAttention-2 backward (recompute P blockwise from the saved
 logsumexp).
 
 Design notes:
-- grid = (batch*heads, q_blocks, k_blocks), innermost k sequential; the
-  running max / denominator / output accumulator live in VMEM scratch and
-  carry across k iterations (canonical TPU flash pattern).
+- the work follows the mask (``flash_tile_plan``): a kernel's grid is
+  (head, step), the steps walking only the (query block, key block)
+  pairs the causal band shows something of, from tables in the scalar
+  prefetch; the running max / denominator / output accumulator live in
+  VMEM scratch and carry across a query block's steps (canonical TPU
+  flash pattern).  A block on the band's edge is cut into sub-tiles,
+  those the mask hides whole left out and the rest computed a strip at
+  a time; only a strip the edge crosses pays for the iota comparison.
 - per-row key-length masking (padding masks) rides a scalar-prefetch
-  lengths vector; causal masking is an in-kernel iota comparison, and
-  fully-masked k blocks are skipped with ``pl.when``.
+  lengths vector and is compiled in only where lengths are given.
 - matmuls request float32 accumulation (``preferred_element_type``) so
   bf16 inputs hit the MXU without losing the softmax statistics.
 - On CPU backends the kernels run in the Pallas interpreter, so the same
@@ -23,6 +27,7 @@ Design notes:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -34,9 +39,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
 
-__all__ = ["flash_attention", "grouped_matmul", "grouped_matmul_grads",
-           "grouped_tiles", "rows_of_tokens", "tokens_of_rows",
-           "expert_activation", "ssm_scan_chunks", "ssm_scan_tiles",
+__all__ = ["flash_attention", "flash_tile_plan", "grouped_matmul",
+           "grouped_matmul_grads", "grouped_tiles", "rows_of_tokens",
+           "tokens_of_rows", "expert_activation", "ssm_scan_chunks", "ssm_scan_tiles",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_verify", "ragged_paged_verify_reference"]
 
@@ -47,79 +52,303 @@ def _scratch(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _lens_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
+# ---------------------------------------------------------------------------
+# the tile plan: which tiles of the (query, key) square a call computes
+# ---------------------------------------------------------------------------
+_FIRST, _LAST, _KIND_SHIFT = 1, 2, 2     # a walk step's flags: two bits,
+                                         # then the block's kind
 
 
-def _block_mask(s, kv_len, q_start, k_start, causal, block_q, block_k,
-                window=-1):
-    """Mask a (block_q, block_k) score tile: key padding + causal
-    (+ sliding window: key in [q-window+1, q])."""
-    k_idx = k_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = k_idx < kv_len
-    if causal:
-        q_idx = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = jnp.logical_and(mask, k_idx <= q_idx)
+def _cols_of_rows(q_lo, q_hi, n, sk, window):
+    """[first, one past the last) of ``n`` sub-columns of ``sk`` keys,
+    from key 0 on, that queries ``q_lo .. q_hi`` see some key of under
+    the causal band."""
+    end = min(max(q_hi + sk, 0) // sk, n)
+    first = max(q_lo - (window - 1), 0) // sk if window > 0 else 0
+    return first, end
+
+
+def _crossed(q_lo, q_hi, k_lo, k_hi, window):
+    """Whether the band's edge crosses the tile (it hides some pair of
+    it): a tile it does not cross needs no mask arithmetic."""
+    return k_hi > q_lo or (window > 0 and k_lo < q_hi - (window - 1))
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashTilePlan:
+    """What ``flash_attention``'s three kernels compute for one call,
+    from its shapes and flags alone (``flash_tile_plan``).
+
+    The (Lq, Lk) square is cut into ``block_q x block_k`` grid blocks.
+    A kernel's innermost grid axis walks only the blocks the causal band
+    (``k <= q``, and ``k > q - window`` under a window) shows a pair of.
+    What is computed of a block depends on where the band lies in it,
+    which is a matter of ``first query - first key`` alone, so the
+    blocks are of a few ``kinds``: the band hides no pair (the block is
+    one tile, no mask arithmetic), or its edge crosses the block, which
+    is then ``sub_q x sub_k`` sub-tiles, those hidden whole left out,
+    the others computed a strip at a time (``_strips``) and a strip
+    masked only if the edge crosses it.  Every kind is static: a
+    kernel's body holds one straight run of tiles a kind.  A call that
+    is not causal has no band: every block, whole.  Four integers a
+    query head say how far the call is from its mask: ``steps`` (grid
+    steps of the forward or the dQ kernel; the dK/dV kernel makes as
+    many a query head), ``computing_steps`` (those that reach a visible
+    pair: the others only write a block of zeros that nothing else
+    would), ``pairs_computed`` (the computed tiles' area) and
+    ``pairs_visible`` (the mask's)."""
+    Lq: int
+    Lk: int
+    group: int                  # query heads a key/value head
+    causal: bool
+    window: int                 # -1: none
+    check_len: bool             # ``lengths`` given, or Lk padded
+    block_q: int
+    block_k: int
+    sub_q: int
+    sub_k: int
+    steps: int = 0
+    computing_steps: int = 0
+    pairs_computed: int = 0
+    pairs_visible: int = 0
+
+    @property
+    def nq(self):
+        return -(-self.Lq // self.block_q)
+
+    @property
+    def nk(self):
+        return -(-self.Lk // self.block_k)
+
+    def block_tiles(self, i, j):
+        """The tiles computed of grid block (query block i, key block
+        j), each (first query, first key, queries, keys, whether the
+        band's edge crosses it) from the block's corner; none where the
+        band hides the block whole."""
+        return self._tiles_at(i * self.block_q - j * self.block_k)
+
+    @functools.lru_cache(maxsize=None)
+    def _tiles_at(self, d):
+        # d: the block's first query less its first key
+        bq, bk, sq, sk, w = (self.block_q, self.block_k, self.sub_q,
+                             self.sub_k, self.window)
+        if not self.causal or not _crossed(d, d + bq - 1, 0, bk - 1, w):
+            return ((0, 0, bq, bk, False),)
+        return tuple(
+            (a * sq, b * sk, sq, sk, _crossed(
+                d + a * sq, d + a * sq + sq - 1, b * sk, b * sk + sk - 1, w))
+            for a in range(bq // sq)
+            for b in range(*_cols_of_rows(d + a * sq, d + a * sq + sq - 1,
+                                          bk // sk, sk, w)))
+
+    @functools.cached_property
+    def kinds(self):
+        """The distinct ``block_tiles`` of the call; last the empty one,
+        of a step that only writes zeros."""
+        found = dict.fromkeys(self.block_tiles(i, j) for i in range(self.nq)
+                              for j in range(self.nk))
+        return tuple(tiles for tiles in found if tiles) + ((),)
+
+    @functools.lru_cache(maxsize=None)
+    def walk(self, order):
+        """The grid steps of a kernel, in order, as three int32 arrays:
+        the block index of the operand that stays (``order`` "qk": the
+        query block, for the forward and dQ kernels; "kq": the key
+        block, for dK/dV), of the operand that is walked, and the
+        step's flags (``_FIRST`` / ``_LAST`` of its staying block, then
+        its kind).  A staying block the band shows nothing of gets one
+        step all the same, of the empty kind: its output is still to be
+        written."""
+        n_stay, n_walk = ((self.nq, self.nk) if order == "qk"
+                          else (self.nk, self.nq))
+        kind_of = {tiles: n for n, tiles in enumerate(self.kinds)}
+        stay, walked, flags = [], [], []
+        for o in range(n_stay):
+            kinds = [(w, self.block_tiles(*((o, w) if order == "qk"
+                                            else (w, o))))
+                     for w in range(n_walk)]
+            live = [(w, t) for w, t in kinds if t] or [(n_walk - 1, ())]
+            for n, (w, tiles) in enumerate(live):
+                stay.append(o)
+                walked.append(w)
+                flags.append((kind_of[tiles] << _KIND_SHIFT)
+                             | (_FIRST if n == 0 else 0)
+                             | (_LAST if n == len(live) - 1 else 0))
+        return tuple(np.asarray(a, np.int32) for a in (stay, walked, flags))
+
+    def tiles(self):
+        """Every tile a query head's kernels compute, as (first query,
+        first key, queries, keys, whether the band's edge crosses it)."""
+        return [(i * self.block_q + r, j * self.block_k + c, rows, cols, x)
+                for i in range(self.nq) for j in range(self.nk)
+                for r, c, rows, cols, x in self.block_tiles(i, j)]
+
+
+def _ceil_to(x, m):
+    return (x + m - 1) // m * m
+
+
+# Timed on a v5e (PERF.md, PR 40): what a block on the band's edge is
+# walked in.
+_SUB_TILE = 256
+
+
+def _sub_tile(block):
+    """The sub-tile edge for a block edge: ``_SUB_TILE`` where it
+    divides the block; half of an explicit block too small for the chip
+    (the interpreter's tests: 16 gives 8); else the block whole."""
+    if block % _SUB_TILE == 0:
+        return _SUB_TILE
+    return block // 2 if block < 128 and block % 16 == 0 else block
+
+
+@functools.lru_cache(maxsize=None)
+def flash_tile_plan(Lq, Lk, causal=False, window=None, group=1,
+                    lengths=False, block_q=None, block_k=None,
+                    sub_q=None, sub_k=None):
+    """The ``FlashTilePlan`` of a ``flash_attention`` call over Lq
+    queries and Lk keys (of any width: heads of 64 and of 128 want the
+    same tiles), ``group`` query heads a key/value head, ``lengths``
+    saying whether key lengths are given.  Explicit block
+    and sub-tile shapes win; the defaults were timed on a v5e (PERF.md,
+    PR 40).  ``plan.pairs_computed / plan.pairs_visible`` is how far the
+    call is from its mask, for any shape, with no device."""
+    window = -1 if window is None else int(window)
+    if block_q is None or block_k is None:
+        default = 128 if Lk <= 128 else 512 if Lk <= 1024 else 1024
+        block_q, block_k = block_q or default, block_k or default
+    block_q = min(block_q, _ceil_to(Lq, 8))
+    block_k = min(block_k, _ceil_to(Lk, 8))
+    sub_q = sub_q or _sub_tile(block_q)
+    sub_k = sub_k or _sub_tile(block_k)
+    if block_q % sub_q or block_k % sub_k:
+        from ..base import MXNetError
+        raise MXNetError(
+            f"flash_tile_plan: sub-tiles of {sub_q} x {sub_k} do not "
+            f"divide blocks of {block_q} x {block_k}")
+    plan = FlashTilePlan(
+        Lq, Lk, group, bool(causal), window,
+        bool(lengths) or Lk % block_k != 0, block_q, block_k, sub_q, sub_k)
+    tiles = plan.tiles()
+    q = np.arange(Lq, dtype=np.int64)
+    hi = np.minimum(q, Lk - 1) if causal else np.full_like(q, Lk - 1)
+    lo = np.maximum(q - (window - 1), 0) if window > 0 else 0
+    return dataclasses.replace(
+        plan, steps=len(plan.walk("qk")[0]),
+        computing_steps=len({(t[0] // block_q, t[1] // block_k)
+                             for t in tiles}),
+        pairs_computed=sum(t[2] * t[3] for t in tiles),
+        pairs_visible=int(np.maximum(hi - lo + 1, 0).sum()))
+
+
+# ---------------------------------------------------------------------------
+# the kernels' common parts
+# ---------------------------------------------------------------------------
+def _masked(s, q0, k0, kv_len, band, window):
+    """The score tile ``s`` (first query ``q0``, first key ``k0``) with
+    its hidden pairs at -1e30: keys from ``kv_len`` on where a length is
+    given, and where ``band`` the pairs outside the causal band."""
+    if kv_len is None and not band:
+        return s
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mask = None if kv_len is None else col < kv_len - k0
+    if band:
+        ahead = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) - col
+        seen = ahead >= k0 - q0                 # k <= q
         if window > 0:
-            mask = jnp.logical_and(mask, k_idx >= q_idx - (window - 1))
+            seen = jnp.logical_and(seen, ahead <= k0 - q0 + (window - 1))
+        mask = seen if mask is None else jnp.logical_and(mask, seen)
     return jnp.where(mask, s, _NEG_INF)
+
+
+def _strips(tiles, axis):
+    """``tiles`` with every run of neighbours along ``axis`` (0:
+    queries, 1: keys) joined into one tile, masked if any of the run is:
+    what a kernel computes of a block at a time.  A sub-tile by itself
+    costs a quarter of a microsecond more than its share of a whole
+    block (PERF.md, PR 40), and the forward kernel pays for every
+    visit of a row; a strip pays once."""
+    out = []
+    for t in sorted(tiles, key=lambda t: (t[1 - axis], t[axis])):
+        u = out[-1] if out else None
+        if (u and u[1 - axis] == t[1 - axis] and u[3 - axis] == t[3 - axis]
+                and u[axis] + u[2 + axis] == t[axis]):
+            u = list(u)
+            u[2 + axis] += t[2 + axis]
+            u[4] = u[4] or t[4]
+            out[-1] = tuple(u)
+        else:
+            out.append(t)
+    return out
+
+
+def _visit(plan, tile, flags, q0, k0, kv_len, axis):
+    """Call ``tile(rows, cols, first query, first key, band)`` for what
+    the plan computes of the grid block at (q0, k0), ``band`` saying
+    whether the band's edge crosses the tile: one static run of tiles
+    for each kind of block, the step's flags choosing, a kind's
+    sub-tiles joined into strips along ``axis`` (the one the kernel
+    does not accumulate over)."""
+    live = True if kv_len is None else k0 < kv_len
+    for n, tiles in enumerate(plan.kinds):
+        if not tiles:
+            continue
+
+        @pl.when(jnp.logical_and(live, (flags >> _KIND_SHIFT) == n))
+        def _tiles(tiles=_strips(tiles, axis)):
+            for r, c, rows, cols, band in tiles:
+                tile(pl.ds(r, rows), pl.ds(c, cols), q0 + r, k0 + c, band)
+
+
+def _step(plan, lens_ref, q_blk, k_blk, flags_ref, batch):
+    """(the step's flags, first query, first key, key length or None) of
+    a kernel's grid step ``t = program_id(1)``, from the scalar prefetch
+    (lengths, the walk's query blocks, key blocks and flags)."""
+    t = pl.program_id(1)
+    kv_len = lens_ref[batch] if plan.check_len else None
+    return (flags_ref[t], q_blk[t] * plan.block_q, k_blk[t] * plan.block_k,
+            kv_len)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, sm_scale, causal, block_q,
-                block_k, nk, window=-1):
-    b = pl.program_id(0)
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _fwd_kernel(lens_ref, q_blk, k_blk, flags_ref, q_ref, k_ref, v_ref,
+                o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale, plan):
+    flags, q0, k0, kv_len = _step(plan, lens_ref, q_blk, k_blk, flags_ref,
+                                  pl.program_id(0))
 
-    @pl.when(ik == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    kv_len = lens_ref[b]
-    q_start = iq * block_q
-    k_start = ik * block_k
-    # any work in this block? (causal: block fully above the diagonal;
-    # padding: block fully past the key length)
-    needed = k_start < kv_len
-    if causal:
-        needed = jnp.logical_and(needed,
-                                 k_start <= q_start + block_q - 1)
-        if window > 0:
-            needed = jnp.logical_and(
-                needed, k_start + block_k - 1 >= q_start - (window - 1))
-
-    @pl.when(needed)
-    def _step():
+    def tile(rows, cols, r0, c0, band):
         # q/k/v stay in their storage dtype (bf16 on the training path):
         # bf16xbf16->fp32 is the MXU fast path — upcasting inputs first
         # would halve matmul throughput.  Softmax statistics are fp32.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        q = q_ref[0, rows, :]
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
-        s = _block_mask(s, kv_len, q_start, k_start, causal, block_q,
-                        block_k, window)
-        m_prev = m_scr[:]
+            preferred_element_type=jnp.float32) * sm_scale
+        s = _masked(s, r0, c0, kv_len, band, plan.window)
+        m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                              # (bq, bk)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        l_scr[rows, :] = l_scr[rows, :] * corr + jnp.sum(
+            p, axis=1, keepdims=True)
+        acc_scr[rows, :] = acc_scr[rows, :] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        m_scr[rows, :] = m_new
 
-    @pl.when(ik == nk - 1)
+    _visit(plan, tile, flags, q0, k0, kv_len, 1)
+
+    @pl.when((flags & _LAST) != 0)
     def _finish():
         l = l_scr[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -131,105 +360,81 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 # ---------------------------------------------------------------------------
 # backward (FlashAttention-2: dQ pass + dK/dV pass, P recomputed)
 # ---------------------------------------------------------------------------
-def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_scr, *, sm_scale, causal,
-                   block_q, block_k, nk, window=-1):
-    b = pl.program_id(0)
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _bwd_tile(refs, rows, cols, r0, c0, band, kv_len, sm_scale, window):
+    """(q, k, do, P, dS) of one tile, P recomputed from the saved
+    logsumexp."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs
+    q = q_ref[0, rows, :]
+    k = k_ref[0, cols, :]
+    do = do_ref[0, rows, :]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    s = _masked(s, r0, c0, kv_len, band, window)
+    p = jnp.exp(s - lse_ref[0, rows, :])
+    dp = jax.lax.dot_general(
+        do, v_ref[0, cols, :], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0, rows, :]) * sm_scale
+    return q, k, do, p, ds
 
-    @pl.when(ik == 0)
+
+def _bwd_dq_kernel(lens_ref, q_blk, k_blk, flags_ref, q_ref, k_ref, v_ref,
+                   do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, sm_scale,
+                   plan):
+    flags, q0, k0, kv_len = _step(plan, lens_ref, q_blk, k_blk, flags_ref,
+                                  pl.program_id(0))
+
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    kv_len = lens_ref[b]
-    q_start = iq * block_q
-    k_start = ik * block_k
-    needed = k_start < kv_len
-    if causal:
-        needed = jnp.logical_and(needed,
-                                 k_start <= q_start + block_q - 1)
-        if window > 0:
-            needed = jnp.logical_and(
-                needed, k_start + block_k - 1 >= q_start - (window - 1))
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _block_mask(s, kv_len, q_start, k_start, causal, block_q,
-                        block_k, window)
-        p = jnp.exp(s - lse_ref[0])                # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * sm_scale
-        dq_scr[:] += jax.lax.dot_general(
+    def tile(rows, cols, r0, c0, band):
+        _q, k, _do, _p, ds = _bwd_tile(
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rows, cols,
+            r0, c0, band, kv_len, sm_scale, plan.window)
+        dq_scr[rows, :] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ik == nk - 1)
+    _visit(plan, tile, flags, q0, k0, kv_len, 1)
+
+    @pl.when((flags & _LAST) != 0)
     def _finish():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    sm_scale, causal, block_q, block_k, nq, window=-1,
-                    group=1):
-    # one key/value head a grid row; the innermost axis walks the q
-    # blocks of each of the ``group`` query heads that read it, so dK
-    # and dV are summed over the group in the scratch accumulators
-    b = pl.program_id(0)
-    ik = pl.program_id(1)
-    step = pl.program_id(2)
-    iq = step % nq
+def _bwd_dkv_kernel(lens_ref, k_blk, q_blk, flags_ref, q_ref, k_ref, v_ref,
+                    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
+                    dv_scr, *, sm_scale, plan):
+    # one key/value head a grid row; for each of its key blocks the walk
+    # visits the band's query blocks, and the innermost axis the
+    # ``group`` query heads that read it, so dK and dV are summed over
+    # the group in the scratch accumulators
+    head = pl.program_id(2)
+    flags, q0, k0, kv_len = _step(plan, lens_ref, q_blk, k_blk, flags_ref,
+                                  pl.program_id(0) * plan.group)
 
-    @pl.when(step == 0)
+    @pl.when(jnp.logical_and((flags & _FIRST) != 0, head == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    kv_len = lens_ref[b * group]
-    q_start = iq * block_q
-    k_start = ik * block_k
-    needed = k_start < kv_len
-    if causal:
-        needed = jnp.logical_and(needed,
-                                 q_start + block_q - 1 >= k_start)
-        if window > 0:
-            needed = jnp.logical_and(
-                needed, k_start + block_k - 1 >= q_start - (window - 1))
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _block_mask(s, kv_len, q_start, k_start, causal, block_q,
-                        block_k, window)
-        p = jnp.exp(s - lse_ref[0])                # (bq, bk)
-        dv_scr[:] += jax.lax.dot_general(
+    def tile(rows, cols, r0, c0, band):
+        q, _k, do, p, ds = _bwd_tile(
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rows, cols,
+            r0, c0, band, kv_len, sm_scale, plan.window)
+        dv_scr[cols, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, D)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - delta_ref[0]) * sm_scale
-        dk_scr[:] += jax.lax.dot_general(
+            preferred_element_type=jnp.float32)
+        dk_scr[cols, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, D)
+            preferred_element_type=jnp.float32)
 
-    @pl.when(step == group * nq - 1)
+    _visit(plan, tile, flags, q0, k0, kv_len, 0)
+
+    @pl.when(jnp.logical_and((flags & _LAST) != 0,
+                             head == plan.group - 1))
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -238,159 +443,107 @@ def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
-def _specs(block_q, block_k, D, order, group=1, nq=1, nk=1,
-           causal=False, window=-1):
-    """BlockSpecs for (q, k, row statistics) given the grid axis order:
-    'qk' = (query head, iq, ik), 'kq' = (key/value head, ik, walk over
-    the group's query heads and their q blocks).  Query head ``h`` reads
-    key/value head ``h // group``: the index map does the grouping, so
-    K and V are never repeated in HBM.  Under a causal mask the index of
-    the operand that the innermost axis walks is clamped to the blocks
-    the kernel computes on: a skipped step then names the block already
-    in VMEM and fetches nothing."""
-    def k_range(i):             # k blocks a q block needs
-        if not causal:
-            return 0, nk - 1
-        hi = jnp.minimum((i * block_q + block_q - 1) // block_k, nk - 1)
-        lo = jnp.maximum(i * block_q - (window - 1), 0) // block_k \
-            if window > 0 else 0
-        return lo, hi
-
-    def q_range(i):             # q blocks a k block needs
-        if not causal:
-            return 0, nq - 1
-        lo = jnp.minimum((i * block_k) // block_q, nq - 1)
-        hi = jnp.minimum((i * block_k + block_k - 1 + window - 1)
-                         // block_q, nq - 1) if window > 0 else nq - 1
-        return lo, hi
-
+def _specs(plan, D, order):
+    """BlockSpecs for (q, k, row statistics) of a kernel that walks
+    ``plan.walk(order)``: "qk" on the grid (query head, step), "kq" on
+    (key/value head, step, query head of the group).  The step's blocks
+    come from the walk's tables in the scalar prefetch.  Query head
+    ``h`` reads key/value head ``h // group``: the index map does the
+    grouping, so K and V are never repeated in HBM."""
+    group = plan.group
     if order == "qk":
-        qi = lambda b, i, j: (b, i, 0)          # noqa: E731
-        ki = lambda b, i, j: (b // group,       # noqa: E731
-                              jnp.clip(j, *k_range(i)), 0)
+        qi = lambda b, t, lens, stay, walked, flags: (      # noqa: E731
+            b, stay[t], 0)
+        ki = lambda b, t, lens, stay, walked, flags: (      # noqa: E731
+            b // group, walked[t], 0)
     else:
-        qi = lambda b, i, j: (b * group + j // nq,      # noqa: E731
-                              jnp.clip(j % nq, *q_range(i)), 0)
-        ki = lambda b, i, j: (b, i, 0)          # noqa: E731
-    q_spec = pl.BlockSpec((1, block_q, D), qi)
-    k_spec = pl.BlockSpec((1, block_k, D), ki)
-    row_spec = pl.BlockSpec((1, block_q, 1), qi)
-    return q_spec, k_spec, row_spec
+        qi = lambda b, t, h, lens, stay, walked, flags: (   # noqa: E731
+            b * group + h, walked[t], 0)
+        ki = lambda b, t, h, lens, stay, walked, flags: (   # noqa: E731
+            b, stay[t], 0)
+    return (pl.BlockSpec((1, plan.block_q, D), qi),
+            pl.BlockSpec((1, plan.block_k, D), ki),
+            pl.BlockSpec((1, plan.block_q, 1), qi))
 
 
-def _run(kernel, grid, in_specs, out_shape, out_specs, scratch, inputs,
-         interpret):
+def _run(kernel, plan, order, heads, in_specs, out_shape, out_specs, scratch,
+         lens, inputs, interpret):
+    walk = plan.walk(order)
+    grid = (heads, len(walk[0])) + ((plan.group,) if order == "kq" else ())
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
         interpret=interpret,
-    )(*inputs)
+    )(lens, *walk, *inputs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, lens, causal, sm_scale, block_q, block_k, interpret,
-           window):
-    out, _ = _flash_fwd(q, k, v, lens, causal, sm_scale, block_q,
-                        block_k, interpret, window)
-    return out
+def _pad_rows(x, L):
+    return x if x.shape[1] == L else jnp.pad(
+        x, ((0, 0), (0, L - x.shape[1]), (0, 0)))
 
 
-def _flash_fwd(q, k, v, lens, causal, sm_scale, block_q, block_k,
-               interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, lens, plan, sm_scale, interpret):
+    return _flash_fwd(q, k, v, lens, plan, sm_scale, interpret)[0]
+
+
+def _flash_fwd(q, k, v, lens, plan, sm_scale, interpret):
     BH, Lq, D = q.shape
-    Lk = k.shape[1]
-    group = BH // k.shape[0]
-    nq, nk = Lq // block_q, Lk // block_k
-    q_spec, k_spec, row_spec = _specs(block_q, block_k, D, "qk", group,
-                                      nq, nk, causal, window)
-    lens_spec = _lens_spec()
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
-                               causal=causal, block_q=block_q,
-                               block_k=block_k, nk=nk, window=window)
+    q_spec, k_spec, row_spec = _specs(plan, D, "qk")
+    bq = plan.block_q
     out, lse = _run(
-        kernel, (BH, nq, nk),
-        [lens_spec, q_spec, k_spec, k_spec],
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, plan=plan),
+        plan, "qk", BH, [q_spec, k_spec, k_spec],
         (jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
          jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32)),
         (q_spec, row_spec),
-        [_scratch((block_q, 1), jnp.float32),
-         _scratch((block_q, 1), jnp.float32),
-         _scratch((block_q, D), jnp.float32)],
-        (lens, q, k, v), interpret)
+        [_scratch((bq, 1), jnp.float32), _scratch((bq, 1), jnp.float32),
+         _scratch((bq, D), jnp.float32)],
+        lens, (q, k, v), interpret)
     return out, (q, k, v, lens, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
-               res, dout):
+def _flash_dq(plan, sm_scale, interpret, lens, inputs):
+    q = inputs[0]
+    q_spec, k_spec, row_spec = _specs(plan, q.shape[2], "qk")
+    return _run(
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, plan=plan),
+        plan, "qk", q.shape[0],
+        [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        jax.ShapeDtypeStruct(q.shape, q.dtype), q_spec,
+        [_scratch((plan.block_q, q.shape[2]), jnp.float32)],
+        lens, inputs, interpret)
+
+
+def _flash_dkv(plan, sm_scale, interpret, lens, inputs):
+    k, v = inputs[1:3]
+    q_spec, k_spec, row_spec = _specs(plan, k.shape[2], "kq")
+    return _run(
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, plan=plan),
+        plan, "kq", k.shape[0],
+        [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        (jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        (k_spec, k_spec),
+        [_scratch((plan.block_k, k.shape[2]), jnp.float32),
+         _scratch((plan.block_k, k.shape[2]), jnp.float32)],
+        lens, inputs, interpret)
+
+
+def _flash_bwd(plan, sm_scale, interpret, res, dout):
     q, k, v, lens, out, lse = res
-    BH, Lq, D = q.shape
-    BHk, Lk = k.shape[:2]
-    group = BH // BHk
-    nq, nk = Lq // block_q, Lk // block_k
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)                  # (BH, Lq, 1)
-    lens_spec = _lens_spec()
-
-    q_spec, k_spec, row_spec = _specs(block_q, block_k, D, "qk", group,
-                                      nq, nk, causal, window)
-    dq = _run(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q,
-                          block_k=block_k, nk=nk, window=window),
-        (BH, nq, nk),
-        [lens_spec, q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
-        q_spec,
-        [_scratch((block_q, D), jnp.float32)],
-        (lens, q, k, v, dout, lse, delta), interpret)
-
-    q_spec2, k_spec2, row_spec2 = _specs(block_q, block_k, D, "kq",
-                                         group, nq, nk, causal, window)
-    dk, dv = _run(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q,
-                          block_k=block_k, nq=nq, window=window,
-                          group=group),
-        (BHk, nk, group * nq),
-        [lens_spec, q_spec2, k_spec2, k_spec2, q_spec2, row_spec2,
-         row_spec2],
-        (jax.ShapeDtypeStruct((BHk, Lk, D), k.dtype),
-         jax.ShapeDtypeStruct((BHk, Lk, D), v.dtype)),
-        (k_spec2, k_spec2),
-        [_scratch((block_k, D), jnp.float32),
-         _scratch((block_k, D), jnp.float32)],
-        (lens, q, k, v, dout, lse, delta), interpret)
-    dlens = np.zeros(lens.shape, jax.dtypes.float0)
-    return dq, dk, dv, dlens
+    inputs = (q, k, v, dout, lse, delta)
+    dq = _flash_dq(plan, sm_scale, interpret, lens, inputs)
+    dk, dv = _flash_dkv(plan, sm_scale, interpret, lens, inputs)
+    return dq, dk, dv, np.zeros(lens.shape, jax.dtypes.float0)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-def _ceil_to(x, m):
-    return (x + m - 1) // m * m
-
-
-def _default_blocks(Lq, Lk, D):
-    """Block sizes per (seqlen, head-dim), tuned on a v5e chip (see
-    benchmark/opperf.py flash rows).  Bigger k blocks amortize the
-    per-block softmax bookkeeping; VMEM comfortably holds a
-    (256, 512) fp32 score tile at D<=128.  Override with
-    MXNET_FLASH_BLOCK_Q/MXNET_FLASH_BLOCK_K or the explicit args."""
-    from ..base import get_env
-    bq = get_env("MXNET_FLASH_BLOCK_Q", None)
-    bk = get_env("MXNET_FLASH_BLOCK_K", None)
-    if bq or bk:
-        return int(bq or 128), int(bk or 128)
-    if Lk <= 128:
-        return 128, 128
-    if Lk <= 1024:
-        return min(512, _ceil_to(Lq, 8)), min(512, _ceil_to(Lk, 8))
-    return min(1024, _ceil_to(Lq, 8)), min(1024, _ceil_to(Lk, 8))
 
 
 def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
@@ -404,11 +557,14 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     block index maps, dK and dV summed over each group's query heads.
     ``lengths``: optional int32 (B*H,) valid key lengths (padding mask).
     ``window``: optional causal sliding-window width — query q attends
-    keys in [q-window+1, q] (Mistral/Longformer-style local attention);
-    out-of-window blocks are SKIPPED, so compute scales O(L*window)
-    (the splash-style sparsity SURVEY §5.7 asks for).  Requires
-    causal=True.  Returns (B*H, Lq, D) in the query dtype.  Block sizes
-    default to a per-(seqlen, head-dim) tuned table (_default_blocks).
+    keys in [q-window+1, q] (Mistral/Longformer-style local attention).
+    Requires causal=True.  Returns (B*H, Lq, D) in the query dtype.
+
+    What is computed follows the mask (``flash_tile_plan``, which takes
+    the explicit ``block_q`` / ``block_k`` of a test): the grid walks
+    the blocks of the causal band only, so compute scales O(L*window)
+    under a window, and inside a block on the band's edge the sub-tiles
+    the mask hides whole are skipped.
     """
     BH, Lq, D = q.shape
     Lk = k.shape[1]
@@ -417,25 +573,6 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
         raise MXNetError(
             f"flash_attention: {k.shape[0]} key/value heads (k {k.shape}, "
             f"v {v.shape}) do not group {BH} query heads")
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(D))
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    dbq, dbk = _default_blocks(Lq, Lk, D)
-    block_q = block_q or dbq
-    block_k = block_k or dbk
-    block_q = min(block_q, _ceil_to(Lq, 8))
-    block_k = min(block_k, _ceil_to(Lk, 8))
-    Lq_p, Lk_p = _ceil_to(Lq, block_q), _ceil_to(Lk, block_k)
-    if lengths is None:
-        lengths = jnp.full((BH,), Lk, jnp.int32)
-    else:
-        lengths = lengths.astype(jnp.int32)
-    if Lq_p != Lq:
-        q = jnp.pad(q, ((0, 0), (0, Lq_p - Lq), (0, 0)))
-    if Lk_p != Lk:
-        k = jnp.pad(k, ((0, 0), (0, Lk_p - Lk), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, Lk_p - Lk), (0, 0)))
     if window is not None:
         from ..base import MXNetError
         if not causal:
@@ -444,10 +581,19 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
         if int(window) < 1:
             raise MXNetError(
                 f"flash_attention: window must be >= 1, got {window}")
-    out = _flash(q, k, v, lengths, causal, float(sm_scale), block_q,
-                 block_k, bool(interpret),
-                 -1 if window is None else int(window))
-    return out[:, :Lq] if Lq_p != Lq else out
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(D))
+    plan = flash_tile_plan(Lq, Lk, bool(causal),
+                           None if window is None else int(window),
+                           BH // k.shape[0], lengths is not None,
+                           block_q, block_k)
+    lens = (jnp.full((BH,), Lk, jnp.int32) if lengths is None
+            else lengths.astype(jnp.int32))
+    out = _flash(_pad_rows(q, plan.nq * plan.block_q),
+                 _pad_rows(k, plan.nk * plan.block_k),
+                 _pad_rows(v, plan.nk * plan.block_k), lens, plan,
+                 float(sm_scale), _interpret(interpret))
+    return out[:, :Lq]
 
 
 # ---------------------------------------------------------------------------

@@ -46,6 +46,31 @@ def test_flash_attention_lowers_for_tpu(L, mode):
     assert "tpu_custom_call" in _tpu_module_text(fn, a, a, a)
 
 
+# The flash kernels at the benchmark's four shapes, one 8192-token row in
+# bfloat16 with the plan's own tiles (``flash_tile_plan``): Mellum's
+# window and full layers (32 query heads over 4 key/value heads of 128),
+# Nemotron's (32 over 2 of 128), LFM2's (32 over 8 of 64).  The walk's
+# tables ride the scalar prefetch and an edge block's sub-tiles are
+# dynamic slices of its refs: what Mosaic's lowering refuses of either
+# shows here.
+@pytest.mark.parametrize("hkv,d,window", [(4, 128, 1024), (4, 128, None),
+                                          (2, 128, None), (8, 64, None)])
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_flash_kernels_lower_at_the_cells_shapes(hkv, d, window, mode):
+    q, kv = S((32, 8192, d), jnp.bfloat16), S((hkv, 8192, d), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _tpu_module_text(fn, q, kv, kv)
+    assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
+
+
 # the shapes chip_smoke.py's serve leg runs: GPT-2-small heads over a
 # 513-page pool of 16-token pages, 8 slots, 64 pages per sequence; and
 # the wider H=16, D=128 head shape
