@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import numpy as np
 
 from ..base import MXNetError
@@ -59,9 +60,11 @@ class BERTEncoder(HybridBlock):
                        position_weight=None):
         # x: (L, B, C)
         L = x.shape[0]
-        pos = position_weight.slice_axis(axis=0, begin=0, end=L)
-        x = x + pos.expand_dims(1)
-        x = self.dropout_layer(self.layer_norm(x))
+        with jax.named_scope("mx.embed"):
+            pos = position_weight.slice_axis(axis=0, begin=0, end=L)
+            x = x + pos.expand_dims(1)
+        with jax.named_scope("mx.norm"):
+            x = self.dropout_layer(self.layer_norm(x))
         for cell in self.transformer_cells:
             x = cell(x, mask, valid_length)
         return x
@@ -110,10 +113,11 @@ class BERTModel(HybridBlock):
 
     def hybrid_forward(self, F, inputs, token_types=None, valid_length=None):
         B, L = inputs.shape
-        emb = self.word_embed(inputs)
-        if token_types is not None:
-            emb = emb + self.token_type_embed(token_types)
-        x = emb.swapaxes(0, 1)                                  # (L, B, C)
+        with jax.named_scope("mx.embed"):
+            emb = self.word_embed(inputs)
+            if token_types is not None:
+                emb = emb + self.token_type_embed(token_types)
+            x = emb.swapaxes(0, 1)                              # (L, B, C)
         if self._use_flash:
             # padding rides the flash kernel's lengths vector; no O(L^2)
             # mask is ever materialized
@@ -121,13 +125,15 @@ class BERTModel(HybridBlock):
         else:
             mask = None
             if valid_length is not None:
-                mask = self._make_mask(F, valid_length, L)
+                with jax.named_scope("mx.attn.dense"):
+                    mask = self._make_mask(F, valid_length, L)
             out = self.encoder(x, mask)                         # (L, B, C)
-        seq = out.swapaxes(0, 1)                                # (B, L, C)
-        if not self._use_pooler:
-            return seq
-        pooled = self.pooler(seq.slice_axis(axis=1, begin=0, end=1)
-                             .squeeze(axis=1))
+        with jax.named_scope("mx.head"):
+            seq = out.swapaxes(0, 1)                            # (B, L, C)
+            if not self._use_pooler:
+                return seq
+            pooled = self.pooler(seq.slice_axis(axis=1, begin=0, end=1)
+                                 .squeeze(axis=1))
         return seq, pooled
 
 
@@ -151,13 +157,14 @@ class BERTForPretrain(HybridBlock):
     def hybrid_forward(self, F, inputs, token_types, valid_length,
                        masked_positions):
         seq, pooled = self.bert(inputs, token_types, valid_length)
-        # gather the masked positions: (B, M, C)
-        gathered = _gather_positions(F, seq, masked_positions)
-        h = self.mlm_dense(gathered)
-        h = F._contrib_gelu_erf(h)
-        h = self.mlm_norm(h)
-        mlm_scores = self.mlm_decoder(h)          # (B, M, V)
-        nsp_scores = self.nsp_classifier(pooled)  # (B, 2)
+        with jax.named_scope("mx.head"):
+            # gather the masked positions: (B, M, C)
+            gathered = _gather_positions(F, seq, masked_positions)
+            h = self.mlm_dense(gathered)
+            h = F._contrib_gelu_erf(h)
+            h = self.mlm_norm(h)
+            mlm_scores = self.mlm_decoder(h)          # (B, M, V)
+            nsp_scores = self.nsp_classifier(pooled)  # (B, 2)
         return mlm_scores, nsp_scores
 
 

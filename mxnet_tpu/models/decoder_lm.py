@@ -11,6 +11,8 @@ none of this yet: ``TransformerDecoderLM`` stays what is served.
 """
 from __future__ import annotations
 
+import jax
+
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
@@ -127,10 +129,14 @@ class DecoderLM(HybridBlock):
                    else dict(prefix="lm_head_")))
 
     def hybrid_forward(self, F, tokens):
-        x = self.word_embed(tokens)                         # (B, L, C)
+        with jax.named_scope("mx.embed"):
+            x = self.word_embed(tokens)                     # (B, L, C)
         for cell in self.cells:
             x = cell(x)
-        return self.lm_head(self.final_norm(x))
+        with jax.named_scope("mx.norm"):
+            x = self.final_norm(x)
+        with jax.named_scope("mx.head"):
+            return self.lm_head(x)
 
 
 _SLIDING3_FULL1 = ("sliding_attention",) * 3 + ("full_attention",)

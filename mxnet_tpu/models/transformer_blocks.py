@@ -55,14 +55,17 @@ class PositionwiseFFN(HybridBlock):
     def hybrid_forward(self, F, x):
         residual = x
         if self._pre_norm:
-            x = self.layer_norm(x)
-        out = self.ffn_1(x)
-        out = self._act(F, out)
-        out = self.ffn_2(out)
-        out = self.dropout_layer(out)
-        out = out + residual
-        if not self._pre_norm:
-            out = self.layer_norm(out)
+            with jax.named_scope("mx.norm"):
+                x = self.layer_norm(x)
+        with jax.named_scope("mx.ffn.dense"):
+            out = self.ffn_1(x)
+            out = self._act(F, out)
+            out = self.ffn_2(out)
+        with jax.named_scope("mx.norm"):
+            out = self.dropout_layer(out)
+            out = out + residual
+            if not self._pre_norm:
+                out = self.layer_norm(out)
         return out
 
 
@@ -121,7 +124,8 @@ class MultiHeadSelfAttention(HybridBlock):
 
     def hybrid_forward(self, F, x, mask=None, valid_length=None):
         # x: (L, B, C). qkv: (L, B, 3C) interleaved per head [q|k|v]
-        qkv = self.qkv(x)
+        with jax.named_scope("mx.attn.proj"):
+            qkv = self.qkv(x)
         if self._use_flash and mask is None:
             if valid_length is None:
                 out = F.flash_selfatt_nomask(qkv, heads=self._heads,
@@ -132,7 +136,8 @@ class MultiHeadSelfAttention(HybridBlock):
                                       heads=self._heads,
                                       causal=self._causal,
                                       window=self._window)
-            return self.out_proj(self.dropout_layer(out))
+            with jax.named_scope("mx.attn.proj"):
+                return self.out_proj(self.dropout_layer(out))
         if self._window > 0:
             raise MXNetError(
                 "window (sliding-window attention) is only honored on "
@@ -145,15 +150,17 @@ class MultiHeadSelfAttention(HybridBlock):
                 "(use_flash=True, mask=None); the dense path needs an "
                 "explicit additive mask — it would otherwise be silently "
                 "ignored")
-        scores = F._contrib_interleaved_matmul_selfatt_qk(
-            qkv, heads=self._heads)            # (B*H, L, L)
-        if mask is not None:
-            scores = scores + mask
-        att = F.softmax(scores, axis=-1)
-        att = self.dropout_layer(att)
-        out = F._contrib_interleaved_matmul_selfatt_valatt(
-            qkv, att, heads=self._heads)       # (L, B, C)
-        return self.out_proj(out)
+        with jax.named_scope("mx.attn.dense"):
+            scores = F._contrib_interleaved_matmul_selfatt_qk(
+                qkv, heads=self._heads)            # (B*H, L, L)
+            if mask is not None:
+                scores = scores + mask
+            att = F.softmax(scores, axis=-1)
+            att = self.dropout_layer(att)
+            out = F._contrib_interleaved_matmul_selfatt_valatt(
+                qkv, att, heads=self._heads)       # (L, B, C)
+        with jax.named_scope("mx.attn.proj"):
+            return self.out_proj(out)
 
 
 class MultiHeadAttention(HybridBlock):
@@ -208,12 +215,16 @@ class TransformerEncoderCell(HybridBlock):
 
     def hybrid_forward(self, F, x, mask=None, valid_length=None):
         residual = x
-        h = self.attn_norm(x) if self._pre_norm else x
+        h = x
+        if self._pre_norm:
+            with jax.named_scope("mx.norm"):
+                h = self.attn_norm(x)
         h = self.attention(h, mask, valid_length)
-        h = self.dropout_layer(h)
-        h = h + residual
-        if not self._pre_norm:
-            h = self.attn_norm(h)
+        with jax.named_scope("mx.norm"):
+            h = self.dropout_layer(h)
+            h = h + residual
+            if not self._pre_norm:
+                h = self.attn_norm(h)
         return self.ffn(h)
 
 
@@ -342,22 +353,25 @@ class RotaryGroupedAttention(HybridBlock):
     def hybrid_forward(self, F, x):
         B, L, _ = x.shape
         H, Hkv, D = self._heads, self._kv_heads, self._head_dim
-        q = self.q_proj(x).reshape((B, L, H, D))
-        kv = self.kv_proj(x)
-        k = F.slice_axis(kv, axis=-1, begin=0, end=Hkv * D)
-        v = F.slice_axis(kv, axis=-1, begin=Hkv * D, end=None)
-        k = k.reshape((B, L, Hkv, D))
+        with jax.named_scope("mx.attn.proj"):
+            q = self.q_proj(x).reshape((B, L, H, D))
+            kv = self.kv_proj(x)
+            k = F.slice_axis(kv, axis=-1, begin=0, end=Hkv * D)
+            v = F.slice_axis(kv, axis=-1, begin=Hkv * D, end=None)
+            k = k.reshape((B, L, Hkv, D))
         if self._qk_norm_eps is not None:
             with jax.named_scope("mx.attn.qk_norm"):
                 q, k = self.q_norm(q), self.k_norm(k)
         if self._rope is not None:
             q, k = F.rope(q, **self._rope), F.rope(k, **self._rope)
-        v = v.reshape((B, L, Hkv, D))
-        out = F.flash_attention(
-            *(F.cast(a, dtype=self._compute_dtype) for a in (q, k, v)),
-            causal=True, window=self._window)
-        out = F.cast(out, dtype=str(x.dtype)).reshape((B, L, H * D))
-        return self.out_proj(out)
+        with jax.named_scope("mx.attn.proj"):
+            v = v.reshape((B, L, Hkv, D))
+            q, k, v = (F.cast(a, dtype=self._compute_dtype)
+                       for a in (q, k, v))
+        out = F.flash_attention(q, k, v, causal=True, window=self._window)
+        with jax.named_scope("mx.attn.proj"):
+            out = F.cast(out, dtype=str(x.dtype)).reshape((B, L, H * D))
+            return self.out_proj(out)
 
 
 class Mamba2Mixer(HybridBlock):
@@ -467,9 +481,20 @@ class DecoderCell(HybridBlock):
 
     def hybrid_forward(self, F, x):
         if self.ffn is None:
-            return x + self.mixer(self.norm(x))
-        h = x + self.attention(self.attn_norm(x))
-        return h + self.ffn(self.ffn_norm(h))
+            with jax.named_scope("mx.norm"):
+                h = self.norm(x)
+            h = self.mixer(h)
+            with jax.named_scope("mx.norm"):
+                return x + h
+        with jax.named_scope("mx.norm"):
+            h = self.attn_norm(x)
+        h = self.attention(h)
+        with jax.named_scope("mx.norm"):
+            h = x + h
+            y = self.ffn_norm(h)
+        y = self.ffn(y)
+        with jax.named_scope("mx.norm"):
+            return h + y
 
 
 def _sinusoid_table(max_len, units):

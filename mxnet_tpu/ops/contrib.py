@@ -223,12 +223,11 @@ def _gelu_erf_fwd(x):
     # the matmuls that follow, which would split that producer again.
     # The density is written from erfc's own argument, so that it
     # shares the exponential of erfc's expansion.
-    with jax.named_scope("mx.act"):
-        z = -x * _SQRT_HALF
-        cdf = 0.5 * lax.erfc(z)
-        y = x * cdf
-        slope = cdf + x * (jnp.exp(-(z * z)) * _INV_SQRT_2PI)
-        y, rest = lax.optimization_barrier((y, slope - y))
+    z = -x * _SQRT_HALF
+    cdf = 0.5 * lax.erfc(z)
+    y = x * cdf
+    slope = cdf + x * (jnp.exp(-(z * z)) * _INV_SQRT_2PI)
+    y, rest = lax.optimization_barrier((y, slope - y))
     return y, (y, rest)
 
 

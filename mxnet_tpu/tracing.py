@@ -85,6 +85,7 @@ __all__ = [
     "Span", "TraceContext", "Tracer", "TRACER",
     "enable", "disable", "enabled", "reset",
     "trace", "span", "record_span", "tag", "phase", "CLOCK_ANCHOR",
+    "SCOPES", "SCOPE_CONTAINERS",
     "current_span", "current_context",
     "to_chrome_trace", "dump_chrome_trace", "dump_jsonl",
     "flight_record", "record_incident", "incident_paths",
@@ -133,6 +134,61 @@ def phase(name, **tags):
     C++ that costs its construction, and no environment variable is
     read."""
     return jax.profiler.TraceAnnotation("mx." + name, **tags)
+
+
+# The device-side scopes of the training step: the ``jax.named_scope``s
+# that call sites write as literals, by name, with what is inside.  A
+# scope is metadata on the operations traced under it (an operation's
+# jax name reads ``jit(mx_train_step)/jvp(mx.fwd)/<block>/mx.attn.proj/
+# ...``, its backward's ``.../transpose(jvp(mx.fwd))/...``): it rides
+# the device trace's clock and costs nothing when no profiler runs.  A
+# *container* (``SCOPE_CONTAINERS``) holds other scopes; every other
+# name is a *leaf*, and every operation of ``jit_mx_train_step`` that
+# carries a jax name lies under exactly one leaf: a leaf is opened
+# where the work is called, never inside a block that another leaf
+# already wraps (``RMSNorm`` itself opens nothing).  docs/observability.md
+# has the same table with the metric that reads each leaf;
+# tests/test_scope_taxonomy.py holds the program to it.
+SCOPES = {
+    "mx.fwd": "the forward pass (container; the backward pass is its "
+              "transpose)",
+    "mx.loss": "the loss function, from the forward's outputs",
+    "mx.optim": "the optimizer's update that no weight-gradient matmul "
+                "took into its fusion",
+    "mx.collective": "the compressed gradient sync over dp",
+    "mx.embed": "token (BERT: and position and type) embedding lookups; "
+                "backward, the scatter-add",
+    "mx.norm": "the block-level norms and residual adds, a model's final "
+               "norm, BERT's embedding norm",
+    "mx.head": "the vocabulary head (tied or not); BERT's MLM transform "
+               "and decoder, the masked positions' gather, the pooler and "
+               "the NSP classifier",
+    "mx.attn.proj": "an attention block's q, kv (or fused qkv) and out "
+                    "projections",
+    "mx.attn.dense": "the not-flash attention core: scores, mask, "
+                     "softmax, context",
+    "mx.attn.window": "the flash kernels of a sliding-window layer and "
+                      "the transposes around them",
+    "mx.attn.full": "the same of a plain causal (or not causal) layer",
+    "mx.attn.qk_norm": "the per-head RMS norms of q and k",
+    "mx.rope": "the rotary embedding of q or k",
+    "mx.ffn.dense": "a dense feed-forward's two products and the "
+                    "activation between them, gated or not",
+    "mx.moe.route": "the router: product, scores, top-k, weights",
+    "mx.moe.dispatch": "the held pairs sorted and their rows gathered",
+    "mx.moe.experts": "the grouped products and the activation",
+    "mx.moe.combine": "the pairs' rows weighted and summed a token",
+    "mx.moe.shared": "the shared expert's two dense products",
+    "mx.ssm.in_proj": "a Mamba-2 mixer's input projection",
+    "mx.ssm.conv": "its causal depthwise convolution and silu",
+    "mx.ssm.scan": "its chunked selective scan",
+    "mx.ssm.gate_norm": "its gate and group norm",
+    "mx.ssm.out_proj": "its output projection",
+    "mx.sconv.in_proj": "a gated short convolution's input projection",
+    "mx.sconv.conv": "what lies between its projections",
+    "mx.sconv.out_proj": "its output projection",
+}
+SCOPE_CONTAINERS = frozenset({"mx.fwd"})
 
 
 def enable(sample=None):
