@@ -42,6 +42,8 @@ from .registry import register
 __all__ = ["flash_attention", "flash_tile_plan", "grouped_matmul",
            "grouped_matmul_grads", "grouped_tiles", "rows_of_tokens",
            "tokens_of_rows", "expert_activation", "ssm_scan_chunks", "ssm_scan_tiles",
+           "ssm_conv_tiles", "ssm_conv_pass", "ssm_conv_pass_grads",
+           "ssm_norm_tiles", "ssm_norm_pass", "ssm_norm_pass_grads",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_verify", "ragged_paged_verify_reference"]
 
@@ -1626,6 +1628,258 @@ def ssm_scan_chunks(xbc, delta, A, D, N, Q, interpret=None):
                        a.reshape(b, G, R, L),
                        jnp.repeat(D.reshape(1, G * R), P, axis=1), N, Q,
                        _interpret(interpret))
+
+
+# ---------------------------------------------------------------------------
+# The Mamba-2 mixer's two elementwise operators, a pass forward and a
+# pass backward each (ops/ssm.py has the mathematics and the ``jnp``
+# forms that every other shape runs)
+# ---------------------------------------------------------------------------
+# Both read float32 blocks of (rows, 128 k) columns where their
+# neighbours left them: the convolution its columns of the
+# in-projection's one (L, [z | x B C | dt]) result and the group norm
+# its gate's (``lo`` is the first column, a whole number of blocks in),
+# so nothing is sliced out in HBM.  The convolution needs the K - 1
+# positions before a block: walking a row's blocks first to last the
+# forward kernel keeps the last eight rows in VMEM; the backward kernel
+# walks them last to first with the first rows of the later block's
+# pre-activation gradient in VMEM (the transpose reads K - 1 positions
+# AFTER a block) and fetches the eight rows before its block through a
+# second block spec.  The taps', bias's and gain's gradients are summed
+# in an output block that stays in VMEM along the walk.
+
+_SSM_SLAB = 8           # rows before a block: a float32 tile's sublanes
+_SSM_PASS_BYTES = 2 ** 20               # a block of a pass, at most
+
+
+def _pass_rows(L, width):
+    """Rows a block: the largest power of two up to 512 that divides L
+    and keeps a (rows, width) float32 block inside a MiB; 0 where that
+    is under a slab's eight."""
+    rows = 512
+    while rows >= _SSM_SLAB and (L % rows
+                                 or 4 * rows * width > _SSM_PASS_BYTES):
+        rows //= 2
+    return rows if rows >= _SSM_SLAB else 0
+
+
+def _conv_cols(C, lo):
+    for cols in (512, 256, 128):
+        if C % cols == 0 and lo % cols == 0:
+            return cols
+    return 0
+
+
+def ssm_conv_tiles(L, C, K, lo=0):
+    """Whether ``ssm_conv`` over L positions of C channels with K taps,
+    its channels ``lo`` columns into the array it reads, takes the
+    Pallas passes: channels and ``lo`` whole 128-lane blocks, L whole
+    blocks of eight rows or more, and the taps inside one slab."""
+    cols = _conv_cols(C, lo)
+    return bool(cols and _pass_rows(L, cols) and 1 <= K <= _SSM_SLAB + 1)
+
+
+def ssm_norm_tiles(L, C, groups, lo=0):
+    """Whether ``ssm_gate_norm`` over L positions of C channels in
+    ``groups`` groups, the gate ``lo`` columns into its array, takes the
+    Pallas passes: a group a whole number of 128-lane blocks, ``lo`` a
+    whole number of groups, L whole blocks of eight rows or more."""
+    n = C // groups if groups and C % groups == 0 else 0
+    return bool(n and n % _SSM_LANES == 0 and lo % n == 0
+                and _pass_rows(L, n))
+
+
+def _taps(ext_ref, w_ref, rows, ahead=False):
+    """``sum_j w[j] * shifted_j``: tap j reads K - 1 - j rows back in
+    ``ext_ref`` (a slab, then the block), or with ``ahead`` that many
+    rows on (the block, then a slab): the transpose."""
+    K = w_ref.shape[0]
+    first = 0 if ahead else _SSM_SLAB - (K - 1)
+    return sum(w_ref[j:j + 1, :]
+               * ext_ref[pl.ds(first + (K - 1 - j if ahead else j), rows), :]
+               for j in range(K))
+
+
+def _ssm_conv_fwd_kernel(x_ref, w_ref, b_ref, o_ref, ext_ref):
+    rows = x_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ext_ref[0:_SSM_SLAB, :] = jnp.zeros((_SSM_SLAB, ext_ref.shape[1]),
+                                            jnp.float32)
+
+    ext_ref[_SSM_SLAB:, :] = x_ref[...]
+    pre = b_ref[...] + _taps(ext_ref, w_ref, rows)
+    o_ref[...] = pre * jax.nn.sigmoid(pre)
+    ext_ref[0:_SSM_SLAB, :] = ext_ref[rows:, :]
+
+
+def _ssm_conv_bwd_kernel(x_ref, before_ref, g_ref, w_ref, b_ref, dx_ref,
+                         sums_ref, ext_ref, dext_ref):
+    rows, K = x_ref.shape[0], w_ref.shape[0]
+    step, steps = pl.program_id(2), pl.num_programs(2)
+    zeros = jnp.zeros((_SSM_SLAB, ext_ref.shape[1]), jnp.float32)
+
+    @pl.when(step == 0)                 # the row's last block
+    def _():
+        dext_ref[rows:, :] = zeros
+        sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.float32)
+
+    # the row's first block has nothing before it
+    ext_ref[0:_SSM_SLAB, :] = jnp.where(step == steps - 1, zeros,
+                                        before_ref[...])
+    ext_ref[_SSM_SLAB:, :] = x_ref[...]
+    pre = b_ref[...] + _taps(ext_ref, w_ref, rows)
+    s = jax.nn.sigmoid(pre)
+    dpre = g_ref[...] * (s * (1.0 + pre * (1.0 - s)))
+    dext_ref[0:rows, :] = dpre
+    dx_ref[...] = _taps(dext_ref, w_ref, rows, ahead=True)
+    dext_ref[rows:, :] = dext_ref[0:_SSM_SLAB, :]
+    for j in range(K):
+        sums_ref[j:j + 1, :] += jnp.sum(
+            dpre * ext_ref[pl.ds(_SSM_SLAB - (K - 1) + j, rows), :],
+            axis=0, keepdims=True)
+    sums_ref[K:K + 1, :] += jnp.sum(dpre, axis=0, keepdims=True)
+
+
+_SSM_PASS_PARAMS = dict(
+    compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_SSM_VMEM_BYTES))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def ssm_conv_pass(src, taps, bias, lo, interpret):
+    """``silu(bias + causal depthwise conv)`` of columns ``lo`` on of
+    ``src`` (b, L, .): ``taps`` (K, C), ``bias`` (1, C), float32;
+    (b, L, C)."""
+    b, L, _ = src.shape
+    K, C = taps.shape
+    cols = _conv_cols(C, lo)
+    rows = _pass_rows(L, cols)
+    block = pl.BlockSpec((None, rows, cols),
+                         lambda i, j, c: (i, c, lo // cols + j))
+    return pl.pallas_call(
+        _ssm_conv_fwd_kernel, grid=(b, C // cols, L // rows),
+        in_specs=[block, pl.BlockSpec((K, cols), lambda i, j, c: (0, j)),
+                  pl.BlockSpec((1, cols), lambda i, j, c: (0, j))],
+        out_specs=pl.BlockSpec((None, rows, cols),
+                               lambda i, j, c: (i, c, j)),
+        out_shape=jax.ShapeDtypeStruct((b, L, C), jnp.float32),
+        scratch_shapes=[_scratch((_SSM_SLAB + rows, cols), jnp.float32)],
+        interpret=interpret, **_SSM_PASS_PARAMS)(src, taps, bias)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def ssm_conv_pass_grads(src, taps, bias, g, lo, interpret):
+    """``ssm_conv_pass``'s gradients from the cotangent ``g`` (b, L, C):
+    in its C columns of ``src`` (b, L, C), in the taps (K, C) and in the
+    bias (C,)."""
+    b, L, _ = src.shape
+    K, C = taps.shape
+    cols = _conv_cols(C, lo)
+    rows = _pass_rows(L, cols)
+    nc, slabs = L // rows, rows // _SSM_SLAB
+
+    def back(c):                        # a row's blocks last to first
+        return nc - 1 - c
+
+    wide = pl.BlockSpec((None, rows, cols),
+                        lambda i, j, c: (i, back(c), lo // cols + j))
+    before = pl.BlockSpec(
+        (None, _SSM_SLAB, cols),
+        lambda i, j, c: (i, jnp.maximum(back(c) * slabs - 1, 0),
+                         lo // cols + j))
+    own = pl.BlockSpec((None, rows, cols), lambda i, j, c: (i, back(c), j))
+    dx, sums = pl.pallas_call(
+        _ssm_conv_bwd_kernel, grid=(b, C // cols, nc),
+        in_specs=[wide, before, own,
+                  pl.BlockSpec((K, cols), lambda i, j, c: (0, j)),
+                  pl.BlockSpec((1, cols), lambda i, j, c: (0, j))],
+        out_specs=[own, pl.BlockSpec((None, K + 1, cols),
+                                     lambda i, j, c: (i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((b, L, C), jnp.float32),
+                   jax.ShapeDtypeStruct((b, K + 1, C), jnp.float32)],
+        scratch_shapes=[_scratch((_SSM_SLAB + rows, cols), jnp.float32)] * 2,
+        interpret=interpret, **_SSM_PASS_PARAMS)(src, src, g, taps, bias)
+    sums = jnp.sum(sums, axis=0)
+    return dx, sums[:K], sums[K]
+
+
+def _gated_normed(y_ref, z_ref, eps):
+    """v = y silu(z), the reciprocal root of its mean square a row, and
+    what the gate's gradient needs: the block's group is its columns."""
+    y, z = y_ref[...], z_ref[...]
+    s = jax.nn.sigmoid(z)
+    v = y * z * s
+    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+    return y, z, s, v, r
+
+
+def _ssm_norm_fwd_kernel(y_ref, z_ref, gain_ref, o_ref, *, eps):
+    *_, v, r = _gated_normed(y_ref, z_ref, eps)
+    o_ref[...] = v * r * gain_ref[...]
+
+
+def _ssm_norm_bwd_kernel(y_ref, z_ref, gain_ref, g_ref, dy_ref, dz_ref,
+                         dgain_ref, *, eps):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dgain_ref[...] = jnp.zeros(dgain_ref.shape, jnp.float32)
+
+    y, z, s, v, r = _gated_normed(y_ref, z_ref, eps)
+    g = g_ref[...]
+    scaled = g * gain_ref[...]
+    back = jnp.mean(scaled * v, axis=-1, keepdims=True) * (r * r * r)
+    dv = scaled * r - v * back
+    dy_ref[...] = dv * (z * s)
+    dz_ref[...] = dv * y * (s * (1.0 + z * (1.0 - s)))
+    dgain_ref[...] += jnp.sum(g * v * r, axis=0, keepdims=True)
+
+
+def _norm_specs(L, C, groups, lo):
+    n = C // groups
+    rows = _pass_rows(L, n)
+    return dict(
+        rows=rows,
+        own=pl.BlockSpec((None, rows, n), lambda i, j, c: (i, c, j)),
+        gate=pl.BlockSpec((None, rows, n),
+                          lambda i, j, c: (i, c, lo // n + j)),
+        gain=pl.BlockSpec((1, n), lambda i, j, c: (0, j)))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def ssm_norm_pass(y, gate_src, gain, groups, eps, lo, interpret):
+    """``y * silu(gate)`` RMS-normed over each of ``groups`` groups of
+    its C channels, times ``gain`` (1, C); the gate is columns ``lo`` on
+    of ``gate_src`` (b, L, .); float32; (b, L, C)."""
+    b, L, C = y.shape
+    at = _norm_specs(L, C, groups, lo)
+    return pl.pallas_call(
+        functools.partial(_ssm_norm_fwd_kernel, eps=eps),
+        grid=(b, groups, L // at["rows"]),
+        in_specs=[at["own"], at["gate"], at["gain"]], out_specs=at["own"],
+        out_shape=jax.ShapeDtypeStruct((b, L, C), jnp.float32),
+        interpret=interpret, **_SSM_PASS_PARAMS)(y, gate_src, gain)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def ssm_norm_pass_grads(y, gate_src, gain, g, groups, eps, lo, interpret):
+    """``ssm_norm_pass``'s gradients from the cotangent ``g``: in y, in
+    the gate's C columns (b, L, C) and in the gain (C,)."""
+    b, L, C = y.shape
+    at = _norm_specs(L, C, groups, lo)
+    dy, dz, dgain = pl.pallas_call(
+        functools.partial(_ssm_norm_bwd_kernel, eps=eps),
+        grid=(b, groups, L // at["rows"]),
+        in_specs=[at["own"], at["gate"], at["gain"], at["own"]],
+        out_specs=[at["own"], at["own"],
+                   pl.BlockSpec((None, 1, C // groups),
+                                lambda i, j, c: (i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((b, L, C), jnp.float32)] * 2
+        + [jax.ShapeDtypeStruct((b, 1, C), jnp.float32)],
+        interpret=interpret, **_SSM_PASS_PARAMS)(y, gate_src, gain, g)
+    return dy, dz, jnp.sum(dgain, axis=(0, 1))
 
 
 # ---------------------------------------------------------------------------

@@ -19,8 +19,10 @@ from mxnet_tpu.ops.pallas_kernels import (expert_activation,
                                           flash_attention, grouped_matmul,
                                           ragged_paged_attention,
                                           ragged_paged_verify,
-                                          rows_of_tokens, ssm_scan_chunks,
-                                          tokens_of_rows)
+                                          rows_of_tokens, ssm_conv_pass,
+                                          ssm_conv_pass_grads,
+                                          ssm_norm_pass, ssm_norm_pass_grads,
+                                          ssm_scan_chunks, tokens_of_rows)
 
 S = jax.ShapeDtypeStruct
 
@@ -192,3 +194,44 @@ def test_selective_scan_lowers_for_tpu(b, L, G, R, P, N, Q, mode):
         lambda *a: fwd(*a).sum(), argnums=tuple(range(4)))
     text = _tpu_module_text(fn, *avals)
     assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
+
+
+# The mixer's two elementwise operators at Nemotron-3-Nano's widths (one
+# 8192-token row; the convolution's 6144 channels 4096 columns into the
+# in-projection's 10304, four taps; the group norm's 4096 channels in 8
+# groups, the gate at the array's start) and at a small shape with
+# other blocks: a pass forward, a pass backward.  The convolution's
+# taps are sublane-offset reads of a VMEM scratch, its backward fetches
+# the slab before a block through a second block spec.
+@pytest.mark.parametrize("b,L,C,K,lo,wide", [(1, 8192, 6144, 4, 4096, 10304),
+                                             (2, 384, 256, 9, 0, 256)])
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_conv_pass_lowers_for_tpu(b, L, C, K, lo, wide, mode):
+    f32 = jnp.float32
+    avals = (S((b, L, wide), f32), S((K, C), f32), S((1, C), f32))
+    if mode == "fwd":
+        def fn(*a):
+            return ssm_conv_pass(*a, lo, False)
+    else:
+        avals += (S((b, L, C), f32),)
+
+        def fn(*a):
+            return ssm_conv_pass_grads(*a, lo, False)
+    assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("b,L,C,groups,lo,wide", [
+    (1, 8192, 4096, 8, 0, 10304), (2, 384, 256, 1, 256, 640)])
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_norm_pass_lowers_for_tpu(b, L, C, groups, lo, wide, mode):
+    f32 = jnp.float32
+    avals = (S((b, L, C), f32), S((b, L, wide), f32), S((1, C), f32))
+    if mode == "fwd":
+        def fn(*a):
+            return ssm_norm_pass(*a, groups, 1e-5, lo, False)
+    else:
+        avals += (S((b, L, C), f32),)
+
+        def fn(*a):
+            return ssm_norm_pass_grads(*a, groups, 1e-5, lo, False)
+    assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1

@@ -340,6 +340,297 @@ def test_gate_comes_before_the_group_norm():
     assert float(jnp.abs(got - other).max()) > 0.1
 
 
+# ---- ``ssm_conv`` and ``ssm_gate_norm`` are one ``custom_vjp`` each
+# with its backward pass written out (and, where the shapes tile, one
+# Pallas pass each way: in the interpreter here).  The oracle is what
+# the two operators were before: plain expressions under autodiff.
+def conv_oracle(data, weight, bias):
+    K, L = weight.shape[1], data.shape[1]
+    padded = jnp.pad(data, ((0, 0), (K - 1, 0), (0, 0)))
+    out = bias + sum(padded[:, j:j + L] * weight[:, j] for j in range(K))
+    return jax.nn.silu(out).astype(data.dtype)
+
+
+def gate_norm_oracle(data, gate, gamma, groups=1, eps=1e-5):
+    v = data.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    g = v.reshape(v.shape[:-1] + (int(groups), -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return (g.reshape(v.shape) * gamma.astype(jnp.float32)).astype(
+        data.dtype)
+
+
+def _value_and_grads(fn, operands, cotangent):
+    out, vjp = jax.vjp(fn, *operands)
+    return (out,) + vjp(cotangent.astype(out.dtype))
+
+
+def _held_to_the_oracle(op, oracle, operands, dtype, names):
+    """``op`` on ``operands`` rounded to ``dtype`` against ``oracle`` on
+    the same rounded numbers in float32: the value and every gradient,
+    by the norm.  float32 differs by the order of its sums; a bfloat16
+    result is rounded once more, 2^-9 an element."""
+    rounded = [a.astype(dtype) for a in operands]
+    exact = [a.astype(jnp.float32) for a in rounded]
+    w = jax.random.normal(jax.random.PRNGKey(21), rounded[0].shape[:-1]
+                          + (oracle(*exact).shape[-1],))
+    got = _value_and_grads(op, rounded, w)
+    want = _value_and_grads(oracle, exact, w)
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    for name, a, b in zip(("value",) + names, got, want):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        assert _gap(a.astype(jnp.float32), b) < tol, name
+
+
+def _conv_operands(L, C, K, b=2, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k[0], (b, L, C)),
+            jax.random.uniform(k[1], (C, K), minval=-0.5, maxval=0.5),
+            0.1 * jax.random.normal(k[2], (C,)))
+
+
+def _norm_operands(L, C, b=2, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k[0], (b, L, C)),
+            jax.random.normal(k[1], (b, L, C)),
+            1.0 + 0.1 * jax.random.normal(k[2], (C,)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("C", [48, 256])
+@pytest.mark.parametrize("K", [1, 4, 7], ids=["one_tap", "four_taps",
+                                              "seven_taps"])
+@pytest.mark.parametrize("L", [1, 3, 75, 384])
+def test_conv_is_the_autodiff_expression(L, K, C, dtype):
+    """K of 4 and 7 are longer than the rows of 1 and 3; 384 positions
+    of 256 channels take the Pallas passes (three blocks of rows), the
+    other shapes the ``jnp`` form."""
+    assert pallas_kernels.ssm_conv_tiles(L, C, K) is (L == 384 and C == 256)
+    _held_to_the_oracle(ssm_conv, conv_oracle, _conv_operands(L, C, K),
+                        dtype, ("data", "taps", "bias"))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("C,groups", [(48, 1), (48, 3), (48, 8), (256, 1),
+                                      (256, 2), (256, 8)])
+@pytest.mark.parametrize("L", [1, 3, 75, 384])
+def test_gate_norm_is_the_autodiff_expression(L, C, groups, dtype):
+    """384 positions of 256 channels in one or two groups take the
+    Pallas passes; groups of 32 or 6 channels and the other lengths the
+    ``jnp`` form."""
+    assert pallas_kernels.ssm_norm_tiles(L, C, groups) is (
+        L == 384 and C == 256 and groups < 8)
+    _held_to_the_oracle(
+        lambda *a: ssm_gate_norm(*a, groups=groups),
+        lambda *a: gate_norm_oracle(*a, groups=groups),
+        _norm_operands(L, C), dtype, ("y", "z", "gain"))
+
+
+@pytest.mark.parametrize("leaf", ["taps", "bias", "gain"])
+@pytest.mark.parametrize("form", ["jnp", "kernels"])
+def test_conv_and_norm_leaf_gradients_by_finite_differences(leaf, form):
+    """The three small leaves' gradients are sums over every position:
+    the directional derivative along a random direction, central
+    differences of a float64 sum of the operator's float32 result."""
+    L = 75 if form == "jnp" else 128
+    if leaf == "gain":
+        ops, i = _norm_operands(L, 256, seed=5), 2
+        fn = lambda *a: ssm_gate_norm(*a, groups=2)         # noqa: E731
+        assert pallas_kernels.ssm_norm_tiles(L, 256, 2) is (form != "jnp")
+    else:
+        ops, i = _conv_operands(L, 256, 4, seed=5), ("taps", "bias").index(
+            leaf) + 1
+        fn = ssm_conv
+        assert pallas_kernels.ssm_conv_tiles(L, 256, 4) is (form != "jnp")
+    u = jax.random.normal(jax.random.PRNGKey(9), ops[i].shape)
+    w = np.asarray(jax.random.normal(jax.random.PRNGKey(10), ops[0].shape),
+                   np.float64)
+
+    def f(a):
+        out = fn(*ops[:i], a, *ops[i + 1:])
+        return float((np.asarray(out, np.float64) * w).sum())
+
+    auto = float((jax.grad(lambda a: (fn(*ops[:i], a, *ops[i + 1:])
+                                      * w.astype(np.float32)).sum())(ops[i])
+                  * u).sum())
+    eps = 1e-2
+    numeric = (f(ops[i] + eps * u) - f(ops[i] - eps * u)) / (2 * eps)
+    assert abs(auto - numeric) <= 2e-3 * max(abs(numeric), 1.0), (auto,
+                                                                  numeric)
+
+
+def test_conv_and_norm_keep_their_inputs_and_nothing_else():
+    """What each ``custom_vjp`` carries from its forward to its backward
+    pass is its operands, the very arrays: no pre-activation, no gated
+    product, no statistic."""
+    data, taps, bias = _conv_operands(24, 48, 4)
+    out, kept = ssm._conv_silu_fwd(data, taps, bias, 0)
+    assert out.shape == data.shape
+    assert len(kept) == 3 and all(a is b for a, b in zip(
+        kept, (data, taps, bias)))
+    y, z, gain = _norm_operands(24, 48)
+    out, kept = ssm._gate_norm_fwd(y, z, gain, 3, 1e-5, 0)
+    assert out.shape == y.shape
+    assert len(kept) == 3 and all(a is b for a, b in zip(kept, (y, z, gain)))
+    # and what autodiff keeps of a call is what the rule returned
+    for fn, operands in ((ssm_conv, (data, taps, bias)),
+                         (lambda *a: ssm_gate_norm(*a, groups=3),
+                          (y, z, gain))):
+        _, vjp = jax.vjp(fn, *operands)
+        held = [a for a in jax.tree_util.tree_leaves(vjp)
+                if hasattr(a, "shape") and a.size > 1]
+        assert sorted(a.shape for a in held) == sorted(
+            a.shape for a in operands)
+
+
+@pytest.mark.parametrize("L,C,K,lo,takes", [
+    (8192, 6144, 4, 4096, True), (384, 256, 4, 0, True),
+    (16, 128, 9, 128, True), (8, 128, 1, 0, True),
+    (300, 256, 4, 0, False), (4, 256, 4, 0, False), (384, 192, 4, 0, False),
+    (384, 256, 4, 64, False), (384, 256, 10, 0, False)],
+    ids=["cell_widths", "three_blocks", "nine_taps", "one_slab", "ragged_rows",
+         "below_a_slab", "channels_of_192", "gate_64_columns_in",
+         "ten_taps"])
+def test_which_shapes_take_the_conv_passes(L, C, K, lo, takes):
+    assert pallas_kernels.ssm_conv_tiles(L, C, K, lo) is takes
+    src = jax.ShapeDtypeStruct((1, L, lo + C + 64), jnp.float32)
+    taps = jax.ShapeDtypeStruct((C, K), jnp.float32)
+    bias = jax.ShapeDtypeStruct((C,), jnp.float32)
+
+    def fwd(*a):
+        return ssm._conv_silu(*a, lo)
+
+    def bwd(*a):
+        return jax.grad(lambda *b: fwd(*b).sum(), argnums=(0, 1, 2))(*a)
+
+    assert _has_kernels(fwd, src, taps, bias) == int(takes)
+    assert _has_kernels(bwd, src, taps, bias) == 2 * int(takes)
+
+
+@pytest.mark.parametrize("L,C,groups,lo,takes", [
+    (8192, 4096, 8, 0, True), (384, 256, 2, 0, True), (64, 512, 1, 512, True),
+    (8, 4096, 1, 0, True), (300, 256, 2, 0, False), (384, 256, 8, 0, False),
+    (384, 256, 3, 0, False), (384, 256, 2, 64, False)],
+    ids=["cell_widths", "three_blocks", "one_group_behind_another",
+         "one_slab", "ragged_rows", "groups_of_32", "groups_do_not_divide",
+         "gate_64_columns_in"])
+def test_which_shapes_take_the_norm_passes(L, C, groups, lo, takes):
+    assert pallas_kernels.ssm_norm_tiles(L, C, groups, lo) is takes
+    if C % groups:
+        return
+    y = jax.ShapeDtypeStruct((1, L, C), jnp.float32)
+    src = jax.ShapeDtypeStruct((1, L, lo + C + 64), jnp.float32)
+    gain = jax.ShapeDtypeStruct((C,), jnp.float32)
+
+    def fwd(*a):
+        return ssm._gate_norm(*a, groups, 1e-5, lo)
+
+    def bwd(*a):
+        return jax.grad(lambda *b: fwd(*b).sum(), argnums=(0, 1, 2))(*a)
+
+    assert _has_kernels(fwd, y, src, gain) == int(takes)
+    assert _has_kernels(bwd, y, src, gain) == 2 * int(takes)
+
+
+def _jnp_form(fn, *operands):
+    """``fn`` with both operators on their ``jnp`` expressions whatever
+    the shape."""
+    with mock.patch.object(ssm, "ssm_conv_tiles", lambda *a: False), \
+            mock.patch.object(ssm, "ssm_norm_tiles", lambda *a: False):
+        return fn(*operands)
+
+
+@pytest.mark.parametrize("L,C,K,lo", [(384, 256, 4, 0), (1024, 512, 4, 512),
+                                      (64, 128, 9, 128), (16, 128, 1, 0)],
+                         ids=["three_blocks", "columns_behind_others",
+                              "nine_taps", "one_tap"])
+def test_conv_passes_are_the_jnp_form(L, C, K, lo):
+    """The kernels read their columns of a wider array (the mixer's
+    in-projection result), carry the rows before a block forward and the
+    rows after it backward, and give the gradient in the wide array's
+    shape, zeros beside their columns."""
+    _, taps, bias = _conv_operands(L, C, K, seed=3)
+    src = jax.random.normal(jax.random.PRNGKey(4), (2, L, lo + C + 128))
+    w = jax.random.normal(jax.random.PRNGKey(5), (2, L, C))
+
+    def run(*a):
+        return _value_and_grads(lambda *b: ssm._conv_silu(*b, lo), a, w)
+
+    assert pallas_kernels.ssm_conv_tiles(L, C, K, lo)
+    got, want = run(src, taps, bias), _jnp_form(run, src, taps, bias)
+    for name, a, b in zip(("value", "src", "taps", "bias"), got, want):
+        assert a.shape == b.shape, name
+        assert _gap(a, b) < 1e-6, name
+    d_src = np.asarray(got[1])
+    assert not d_src[..., :lo].any() and not d_src[..., lo + C:].any()
+
+
+@pytest.mark.parametrize("L,C,groups,lo", [(384, 256, 2, 0),
+                                           (1024, 1024, 8, 1024),
+                                           (64, 512, 1, 0)],
+                         ids=["three_blocks", "gate_behind_others",
+                              "one_group"])
+def test_norm_passes_are_the_jnp_form(L, C, groups, lo):
+    y, _, gain = _norm_operands(L, C, seed=3)
+    src = jax.random.normal(jax.random.PRNGKey(4), (2, L, lo + C + 128))
+    w = jax.random.normal(jax.random.PRNGKey(5), (2, L, C))
+
+    def run(*a):
+        return _value_and_grads(
+            lambda *b: ssm._gate_norm(*b, groups, 1e-5, lo), a, w)
+
+    assert pallas_kernels.ssm_norm_tiles(L, C, groups, lo)
+    got, want = run(y, src, gain), _jnp_form(run, y, src, gain)
+    for name, a, b in zip(("value", "y", "src", "gain"), got, want):
+        assert a.shape == b.shape, name
+        assert _gap(a, b) < 1e-6, name
+    d_src = np.asarray(got[2])
+    assert not d_src[..., :lo].any() and not d_src[..., lo + C:].any()
+
+
+def test_mixer_hands_both_operators_the_in_projections_result_whole():
+    """At widths all the kernels take (two groups of two heads of 64,
+    state 128, 256 positions), ``ssm_mixer`` is five Pallas calls
+    forward and backward each way of three and slices neither the gate
+    nor x, B, C out of its operand: the only slice left is dt's 4
+    columns; and it gives what the three operators give apart."""
+    H_, P_, G_, N_ = KERNEL["H"], KERNEL["P"], KERNEL["G"], KERNEL["N"]
+    inner, cw = H_ * P_, H_ * P_ + 2 * G_ * N_
+    k = jax.random.split(jax.random.PRNGKey(12), 4)
+    data = jax.random.normal(k[0], (1, 256, inner + cw + H_))
+    leaves = (0.5 * jax.random.normal(k[1], (cw, 4)),
+              0.1 * jax.random.normal(k[2], (cw,)), jnp.zeros((H_,)) - 4.0,
+              jnp.zeros((H_,)), jnp.ones((H_,)),
+              1.0 + 0.1 * jax.random.normal(k[3], (inner,)))
+    kw = dict(num_heads=H_, head_dim=P_, n_groups=G_, state_size=N_)
+
+    def mixer(*a):
+        return ssm_mixer(*a, **kw)
+
+    def by_hand(data, cw_, cb, dtb, A_log, D, gamma):
+        z, xbc, dt = jnp.split(data, (inner, inner + cw), axis=-1)
+        y = ssm._kernel_scan(ssm_conv(xbc, cw_, cb), dt, A_log, D, dtb, H_,
+                             G_, N_, 128)
+        return ssm_gate_norm(y, z, gamma, groups=G_)
+
+    jaxpr = str(jax.make_jaxpr(mixer)(data, *leaves))
+    assert jaxpr.count("pallas_call") == 3
+    sliced = [line.split("=")[0].split(":")[1].strip()
+              for line in jaxpr.splitlines()
+              if " slice[" in line and ":f32[1,256," in line]
+    assert sliced == [f"f32[1,256,{H_}]"]
+
+    def both(fn):
+        return jax.value_and_grad(lambda *a: fn(*a).sum(),
+                                  argnums=(0, 1, 2, 6))(data, *leaves)
+
+    (got, got_g), (want, want_g) = both(mixer), both(by_hand)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for a, b in zip(got_g, want_g):
+        assert _gap(a, b) < 1e-6
+
+
 def test_mixer_op_is_its_three_parts():
     inner, cw = H * P, H * P + 2 * G * N
     k = jax.random.split(jax.random.PRNGKey(4), 4)
