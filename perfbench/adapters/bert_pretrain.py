@@ -1,6 +1,9 @@
 """BERT pretraining through ``models.get_bert_model`` +
 ``BERTForPretrain`` + ``parallel.ShardedTrainer`` (a copy of
-``chip_smoke.py``'s construction, the path PR 21 proved on the chip)."""
+``chip_smoke.py``'s construction, the path PR 21 proved on the chip).
+The mix's ``mesh`` (``{"dp": 4}``; absent: one device) says how the
+cell's devices are used: data-parallel, every leaf replicated, a host
+batch's rows placed over ``dp`` by ``ShardedTrainer.shard_batch``."""
 import gc
 
 import jax
@@ -49,7 +52,7 @@ class Program:
     """One ``ShardedTrainer`` with its state: the object the set-up
     drives through its first steps and the window goes on stepping."""
 
-    def __init__(self, cfg, dims, example_batch, device):
+    def __init__(self, cfg, dims, example_batch, devices, mesh=None):
         import mxnet_tpu as mx
         from mxnet_tpu import models, nd, parallel
         # load_weights overwrites every leaf from the seed, so the
@@ -65,7 +68,14 @@ class Program:
         head.initialize(zeros)
         opt = cfg["optimizer"]
         feats = tuple(nd.array(a) for a in example_batch[:4])
-        mesh = parallel.make_mesh(dp=1, tp=1, sp=1, devices=[device])
+        mesh = mesh or {}
+        if not isinstance(devices, (list, tuple)):
+            devices = [devices]
+        if set(mesh) - {"dp"}:
+            raise ValueError(f"bert_pretrain adapter: the mix asks for the "
+                             f"mesh {mesh}; this adapter builds dp only")
+        mesh = parallel.make_mesh(dp=mesh.get("dp", 1), tp=1, sp=1,
+                                  devices=devices)
         params = cfg["precision"]["params"]
         self.beta1 = opt["beta1"]
         self.heads = dims["num_heads"]
@@ -123,5 +133,8 @@ class Program:
         gc.collect()
 
 
-def build(cfg, dims, example_batch, device):
-    return Program(cfg, dims, example_batch, device)
+def build(cfg, dims, example_batch, devices, mesh=None):
+    """``devices``: every device of the cell (one device alone, as
+    ``tests/test_scope_taxonomy.py`` hands it, is a cell of one);
+    ``mesh``: the mix's, absent = one device."""
+    return Program(cfg, dims, example_batch, devices, mesh)

@@ -61,9 +61,10 @@ class Program(mellum_moe.Program):
     Loading the weights, stepping and freeing are Mellum's adapter's;
     what names this family's reference or its counter is written here."""
 
-    def __init__(self, cfg, dims, example_batch, device):
+    def __init__(self, cfg, dims, example_batch, devices, mesh=None):
         import mxnet_tpu as mx
         from mxnet_tpu import models, nd, parallel
+        device = mellum_moe.one_device("lfm2_moe", devices, mesh)
         if not cfg["use_flash"]:
             raise ValueError("lfm2_moe adapter: the model has no "
                              "attention but the flash kernels'")
@@ -162,5 +163,8 @@ class Program(mellum_moe.Program):
                 f"after the one before, call: ms: {late}")
 
 
-def build(cfg, dims, example_batch, device):
-    return Program(cfg, dims, example_batch, device)
+def build(cfg, dims, example_batch, devices, mesh=None):
+    """``devices``: every device of the cell (one device alone, as
+    ``tests/test_scope_taxonomy.py`` hands it, is a cell of one);
+    ``mesh``: the mix's, absent = one device."""
+    return Program(cfg, dims, example_batch, devices, mesh)

@@ -5,6 +5,7 @@ of the vocabulary as the configuration states it.  Of a batch of the
 one generator it takes the rows of tokens; a row's labels are the row
 shifted by one."""
 import gc
+import math
 import sys
 
 import jax
@@ -57,13 +58,27 @@ def _name_map(lm):
     return {k: p.name for k, p in m.items()}
 
 
+def one_device(adapter, devices, mesh=None):
+    """The one device a decoder adapter builds on: the cell's first (a
+    device alone, as a caller from before PR 43 hands it, is a cell of
+    one).  A mix whose ``mesh`` asks for more is refused by the
+    adapter's name: the expert leaves carry ``ep``, and no adapter
+    places them yet."""
+    if math.prod((mesh or {}).values()) != 1:
+        raise ValueError(
+            f"{adapter} adapter: the mix asks for the mesh {mesh}; this "
+            f"adapter builds on one device until a PR gives it ep")
+    return devices[0] if isinstance(devices, (list, tuple)) else devices
+
+
 class Program:
     """One ``ShardedTrainer`` with its state: the object the set-up
     drives through its first steps and the window goes on stepping."""
 
-    def __init__(self, cfg, dims, example_batch, device):
+    def __init__(self, cfg, dims, example_batch, devices, mesh=None):
         import mxnet_tpu as mx
         from mxnet_tpu import models, nd, parallel
+        device = one_device("mellum_moe", devices, mesh)
         if not cfg["use_flash"]:
             raise ValueError("mellum_moe adapter: the model has no "
                              "attention but the flash kernels'")
@@ -161,5 +176,8 @@ class Program:
         gc.collect()
 
 
-def build(cfg, dims, example_batch, device):
-    return Program(cfg, dims, example_batch, device)
+def build(cfg, dims, example_batch, devices, mesh=None):
+    """``devices``: every device of the cell (one device alone, as
+    ``tests/test_scope_taxonomy.py`` hands it, is a cell of one);
+    ``mesh``: the mix's, absent = one device."""
+    return Program(cfg, dims, example_batch, devices, mesh)

@@ -8,13 +8,18 @@ lower readings), and on the first ``--control-seeds`` of them the
 control: the reference computed in the nearest precision below the one
 the configuration states (bfloat16 for both configurations' float32,
 as their ``precision.control`` says), put in the program's place.
-Training also reads the planted fault "half of the batch left out, the
-mean taken over the rest" in the reference.  One process: the program's
-seeds first, then, with its state freed, the reference's.
+Training also reads the planted fault "rows left out of the gradient,
+the mean taken over the rest" in the reference: half of the batch on
+one device, one device's share of it under a ``mesh`` (the rows of the
+last of ``dp`` devices, as if its gradient never reached the
+all-reduce).  One process: the program's seeds first, on every device
+of the cell as the mix's ``mesh`` says, then, with its state freed, the
+reference's.
 """
 import argparse
 import json
 import sys
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -24,6 +29,7 @@ def say(text):
 
 
 def train(cell, cfg, mix, dims, seeds, n_control, devices):
+    import jax
     import jax.numpy as jnp
     from . import check, harness, traffic
     from .runners import train as runner
@@ -32,31 +38,50 @@ def train(cell, cfg, mix, dims, seeds, n_control, devices):
     cfg = dict(cfg, use_flash=mix.get("use_flash", False))
     batches = {s: traffic.mlm_batches(mix, dims["vocab_size"], s)[:3]
                for s in seeds}
-    program = adapter.build(cfg, dims, batches[seeds[0]][0], devices[0])
+    mesh = mix.get("mesh", {})
+    program = adapter.build(cfg, dims, batches[seeds[0]][0], devices, mesh)
+    shares = max(2, mesh.get("dp", 1))
+    kept_rows = mix["batch"] * (shares - 1) // shares
     got = {}
     for s in seeds:
         got[s] = runner.first_steps(program, ref, dims, s, batches[s])
         say(f"program seed {s}: losses {got[s]['losses']}")
     program.free()
     del program
-    out = []
     sizes = ref.leaf_sizes(dims)
-    for i, s in enumerate(seeds):
-        want = ref.train_steps(dims, cfg["optimizer"], s, batches[s],
-                               mix["reference_rows"])
-        row = {"seed": s, "program": check.train_numbers(got[s], want, sizes)}
-        if i < n_control:
-            low = ref.train_steps(dims, cfg["optimizer"], s, batches[s],
-                                  mix["reference_rows"], dtype=jnp.bfloat16)
-            row["control"] = check.train_numbers(low, want, sizes)
-            half = ref.train_steps(dims, cfg["optimizer"], s, batches[s],
-                                   mix["reference_rows"],
-                                   keep_rows=mix["batch"] // 2)
-            row["fault_half_batch"] = check.train_numbers(half, want, sizes)
+
+    def readings(i):
+        # a seed's reference, control and fault on one device; the seeds
+        # go round robin over the cell's devices, one thread a device and
+        # one seed at a time on it
+        s = seeds[i]
+        with jax.default_device(devices[i % len(devices)]):
+            want = ref.train_steps(dims, cfg["optimizer"], s, batches[s],
+                                   mix["reference_rows"])
+            row = {"seed": s,
+                   "program": check.train_numbers(got[s], want, sizes)}
+            if i < n_control:
+                low = ref.train_steps(dims, cfg["optimizer"], s, batches[s],
+                                      mix["reference_rows"],
+                                      dtype=jnp.bfloat16)
+                row["control"] = check.train_numbers(low, want, sizes)
+                lost = ref.train_steps(dims, cfg["optimizer"], s, batches[s],
+                                       mix["reference_rows"],
+                                       keep_rows=kept_rows)
+                row["fault_rows_left_out"] = check.train_numbers(lost, want,
+                                                                 sizes)
+                row["rows_kept"] = kept_rows
         row["leaves"] = {"program": got[s], "reference": want}
         say("READING " + json.dumps(row))
-        out.append(row)
-    return out
+        return row
+
+    n = len(devices)
+    with ThreadPoolExecutor(n) as pool:
+        lanes = pool.map(
+            lambda d: {i: readings(i) for i in range(d, len(seeds), n)},
+            range(n))
+    rows = {i: row for lane in lanes for i, row in lane.items()}
+    return [rows[i] for i in range(len(seeds))]
 
 
 def _gap_readings(gaps):

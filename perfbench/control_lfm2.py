@@ -53,7 +53,8 @@ def main(argv=None):
     chosen = {}
     if not args.faults_only:
         program = adapter.build(dict(cfg, use_flash=True), dims,
-                                batches[seeds[0]][0], devices[0])
+                                batches[seeds[0]][0], devices,
+                                mix.get("mesh", {}))
         for s in seeds:
             program.load_weights(ref.init_weights(dims, s))
             chosen[s] = program_choices(program, batches[s][0][0])

@@ -75,7 +75,8 @@ def main(argv=None):
     batches = {s: traffic.mlm_batches(mix, dims["vocab_size"], s)[:3]
                for s in seeds}
     program = adapter.build(dict(cfg, use_flash=True), dims,
-                            batches[seeds[0]][0], devices[0])
+                            batches[seeds[0]][0], devices,
+                            mix.get("mesh", {}))
     chosen = {}
     for s in seeds:
         program.load_weights(ref.init_weights(dims, s))

@@ -35,11 +35,11 @@ device's executions, and a step is the mean over all of them.  Imports
 nothing of the program.
 
 ``METRICS`` names the eight metrics the account is read by, with the
-``params`` of each one's file.  The files (``perfbench/metrics/
-<name>.json``) and the entries in ``BENCHMARK.json`` are a ``benchmark``
-PR's to add: ``tests/perfbench_checks/test_{mellum,nemotron,lfm2}_cell.py``
-pin each cell's list of metrics and the tail of ``per_layer``, so any
-new entry fails them until those lists are restated (PERF.md section 7).
+``params`` of each one's file (``perfbench/metrics/<name>.json``, PR 43;
+``tests/perfbench_checks/test_scope_account.py`` holds each file to the
+table).  ``train.ffn_device_ms`` and ``train.attn_dense_device_ms`` list
+the cells whose model has the part; the other six are read in every
+training cell.
 
     python -m perfbench.readers.scope_account <file.xplane.pb>  # the account
 """

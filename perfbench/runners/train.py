@@ -3,7 +3,9 @@ its state), drives it from the seed through its first steps, whose
 losses, first gradient and parameter change are what ``correct``
 compares, and hands the same object to the window.  The window feeds
 host batches round robin, their h2d inside the step, keeps two steps in
-flight, and closes with ``block_until_ready`` on the last."""
+flight, and closes with ``block_until_ready`` on the last.  The adapter
+is handed every device of the cell and the mix's ``mesh`` (absent: one
+device), which says how it uses them."""
 import collections
 import time
 
@@ -62,7 +64,8 @@ def run(ctx):
     adapter = harness.module("adapters", cfg["adapter"])
     batches = traffic.mlm_batches(tr, dims["vocab_size"], args.seed)
     program = adapter.build(dict(cfg, use_flash=tr.get("use_flash", False)),
-                            dims, batches[0], ctx.devices[0])
+                            dims, batches[0], ctx.devices,
+                            tr.get("mesh", {}))
     t_built = time.perf_counter()
     prog = first_steps(program, ref, dims, args.seed, batches)
     t_checked = time.perf_counter()

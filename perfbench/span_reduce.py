@@ -186,26 +186,22 @@ def scope_seconds(trace, names, program, scopes):
     where its root does), over the program's whole executions in the
     window.  Returns (seconds a step, executions, seconds a step of the
     program's operations under none of them and under no scope at all);
-    None where the program did not run."""
-    runs = sorted((s, e) for d, n, s, e in trace.modules
-                  if n.startswith(f"jit_{program}("))
+    None where the program did not run.  On several devices a step is
+    the mean over every device's executions."""
+    runs = trace_reduce.executions(trace, program)
     if not runs:
         return None
     under = re.compile(r"(^|[/(])(%s)([/)]|$)" % "|".join(
         re.escape(s) for s in scopes))
     ours = f"jit({program})/"
     inside = other = 0.0
-    run = 0
-    for _dev, text, s, e in sorted(trace.ops, key=lambda o: o[2]):
-        while run < len(runs) and runs[run][1] < s:
-            run += 1
-        if run == len(runs) or s < runs[run][0] or e > runs[run][1]:
-            continue
-        op = names.get(text, "")
-        if op.startswith(ours) and under.search(op[len(ours):]):
-            inside += e - s
-        else:
-            other += e - s
+    for _dev, _r0, _r1, ops in runs:
+        for _d, text, s, e in ops:
+            op = names.get(text, "")
+            if op.startswith(ours) and under.search(op[len(ours):]):
+                inside += e - s
+            else:
+                other += e - s
     return inside / len(runs), len(runs), other / len(runs)
 
 

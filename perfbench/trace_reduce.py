@@ -123,6 +123,31 @@ def busy_seconds(trace):
     return total / trace.n_devices
 
 
+def executions(trace, program):
+    """[(device, start, end, its operations that lie whole inside)] of
+    the whole executions of ``jit_<program>`` in the window, device by
+    device: on several devices the executions overlap in time, and an
+    operation belongs to an execution of its own device.  Swept once a
+    trace and program."""
+    cache = trace.__dict__.setdefault("_executions", {})
+    if program not in cache:
+        runs = sorted((d, s, e) for d, n, s, e in trace.modules
+                      if n.startswith(f"jit_{program}("))
+        ops = sorted(trace.ops, key=lambda o: (o[0], o[2]))
+        out, at = [], 0
+        for dev, r0, r1 in runs:
+            while at < len(ops) and (ops[at][0], ops[at][2]) < (dev, r0):
+                at += 1
+            inside = []
+            while at < len(ops) and ops[at][0] == dev and ops[at][2] < r1:
+                if ops[at][3] <= r1:
+                    inside.append(ops[at])
+                at += 1
+            out.append((dev, r0, r1, inside))
+        cache[program] = out
+    return cache[program]
+
+
 def op_seconds(trace, pattern):
     """Summed device time and count of the operations whose HLO text
     matches ``pattern`` (per device: averaged over planes)."""

@@ -4,7 +4,7 @@ functions of ``flops.py`` and ``kernels.py``.  Configurations and
 metric files name these functions as ``module:function``."""
 import importlib
 
-from . import flops, kernels
+from . import flops, harness, kernels
 
 
 ITEMSIZE = {"float32": 4, "bfloat16": 2}
@@ -19,6 +19,23 @@ def bert_pretrain_flops(ctx):
     tr = ctx.facts["traffic"]
     return ctx.facts["steps"] * flops.bert_pretrain_step_flops(
         ctx.dims, tr["batch"], tr["seqlen"], tr["masked"])
+
+
+def allreduce_bytes(elements, itemsize, n_devices):
+    """Bytes ONE chip sends in an all-reduce of ``elements`` numbers
+    over ``n_devices``: 2 (n - 1) / n of the array, the least any
+    algorithm sends (a ring's reduce-scatter, then its all-gather)."""
+    return 2 * (n_devices - 1) * elements * itemsize / n_devices
+
+
+def grad_allreduce(ctx):
+    """(operations, bytes one chip sends) of the window's gradient
+    all-reduces under data parallelism: every trained leaf once a
+    step, in float32, as the configuration's reference shapes them."""
+    ref = harness.module("reference", ctx.cfg["reference"])
+    elements = sum(ref.leaf_sizes(ctx.dims).values())
+    return 0, ctx.facts["steps"] * allreduce_bytes(
+        elements, ITEMSIZE["float32"], len(ctx.devices))
 
 
 def _window_tokens(ctx):

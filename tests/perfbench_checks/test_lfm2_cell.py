@@ -35,7 +35,7 @@ def _program(attention="float32", dims=TOY):
     cfg = dict(CFG, use_flash=True,
                precision=dict(CFG["precision"], attention=attention))
     batches = traffic.mlm_batches(MIX["toy"], dims["vocab_size"], SEED)
-    program = adapter.build(cfg, dims, batches[0], jax.devices()[0])
+    program = adapter.build(cfg, dims, batches[0], jax.devices()[:1], {})
     program.load_weights(ref.init_weights(dims, SEED))
     return program, batches
 
@@ -446,10 +446,15 @@ def test_cell_reads_the_trainers_metrics_and_its_own():
             "flash_attn_d64_train_roofline"}
     names = {m["name"] for m in harness.cell_metrics(CELL,
                                                      runner.END_TO_END)}
-    assert names == mine | {
+    # at least these: a later PR may add a metric every training cell reads
+    assert names >= mine | {
         "train.step_mfu_pct", "train.device_idle_pct", "train.dispatch_ms",
         "train.h2d_ms", "train.compiles_in_window", "train.optim_device_ms",
-        "train.fwd_bwd_device_ms"}
+        "train.fwd_bwd_device_ms", "train.step_device_ms",
+        "train.attn_proj_device_ms", "train.head_loss_device_ms",
+        "train.norm_embed_device_ms", "train.ffn_device_ms",
+        "train.unnamed_device_ms", "train.unscoped_device_ms"}
+    assert "train.attn_dense_device_ms" not in names    # no dense core here
     for other in ("bert-large.pretrain_b32_l128",
                   "mellum2-12b-a2.5b.causal_b1_l8192",
                   "nemotron-3-nano-30b-a3b.causal_b1_l8192"):
@@ -463,9 +468,10 @@ def test_cell_reads_the_trainers_metrics_and_its_own():
                       "mx.attn.full", "mx.attn.qk_norm", "mx.rope"}
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["configs"][-1]["name"] == CFG["name"]
-    assert bench["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in bench["per_layer"][-7:]] == [
+    # by name, not by place: later PRs append entries
+    assert CFG["name"] in [c["name"] for c in bench["configs"]]
+    assert CELL in [w["name"] for w in bench["workloads"]]
+    assert [m["name"] for m in bench["per_layer"] if m["name"] in mine] == [
         "train.sconv_device_ms", "sconv_gate_roofline",
         "train.dense_ffn_device_ms", "train.moe_top4_device_ms",
         "moe_swiglu1536_experts_roofline", "train.attn_d64_device_ms",

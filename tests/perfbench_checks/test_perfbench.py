@@ -34,57 +34,99 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 WORKLOAD_FILES = sorted(f[:-5] for f in os.listdir(os.path.join(PB, "workloads"))
                         if f.endswith(".json"))
 TRAIN, SERVE = "bert-large.pretrain_b32_l128", "gpt2-medium.chat_closed16"
+DP4 = "bert-large.pretrain_dp4_b128_l128"       # the cell on four chips
 METRIC_FILES = sorted(f for f in os.listdir(os.path.join(PB, "metrics"))
                       if f.endswith(".json"))
 
 
-def _child(code, timeout=600):
+def _child(code, timeout=600, devices=1):
+    """``code`` in a child process on ``devices`` forced CPU devices."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
+    if devices > 1:
+        env["XLA_FLAGS"] = \
+            f"--xla_force_host_platform_device_count={devices}"
     return subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                           capture_output=True, text=True, timeout=timeout)
 
 
 # ------------------------------------------------------------ the data files
-def test_names_units_and_sources():
+# Each check takes the benchmark it checks: ``BENCHMARK.json`` as loaded
+# and the directory of ``perfbench``'s data files.  They run over the
+# repo's own, and (``test_an_appended_entry_passes_every_data_check``)
+# over a copy with one made-up configuration, cell and metric appended:
+# nothing here may name a place in a list or the length of one.
+def _cells(bench):
+    return [w["name"] for w in bench["workloads"]]
+
+
+def _listed(pb, folder):
+    return sorted(f[:-5] for f in os.listdir(os.path.join(pb, folder))
+                  if f.endswith(".json"))
+
+
+def _loaders(pb):
+    """``harness``'s two finders, reading the data files under ``pb``."""
+    from perfbench import harness
+
+    def load_cell(name):
+        cell = _json(pb, "workloads", name + ".json")
+        return (cell, _json(pb, "configs", cell["config"] + ".json"),
+                _json(pb, "mixes", cell["traffic"] + ".json"))
+
+    def cell_metrics(cell_name, end_to_end):
+        real, harness.ROOT = harness.ROOT, pb
+        try:
+            return harness.cell_metrics(cell_name, end_to_end)
+        finally:
+            harness.ROOT = real
+
+    return load_cell, cell_metrics
+
+
+def check_names_units_and_sources(bench, pb):
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        names = [e["name"] for e in BENCH[group]]
+        names = [e["name"] for e in bench[group]]
         assert len(names) == len(set(names)), group
-        for e in BENCH[group]:
+        for e in bench[group]:
             assert NAME.match(e["name"]), e["name"]
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+    for m in bench["end_to_end"] + bench["per_layer"]:
         assert UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher"), m
         assert m["source"] in SOURCES, m
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace"), m
         assert 0 < m["bound"] <= 0.1, m
-    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
-    for w in BENCH["workloads"]:
+    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
+    for w in bench["workloads"]:
         assert NAME.match(w["traffic"]) and w["chips"] in (1, 4), w
         assert 0 < len(w["why"]) <= 200 and "\n" not in w["why"], w
-    for root, _dirs, files in os.walk(PB):
+    # of a benchmark's cells at most a quarter, and always one, on four
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 4)
+    for root, _dirs, files in os.walk(pb):
         if "__pycache__" in root:
             continue
         for f in files:
             assert re.match(r"^[A-Za-z0-9_.\-]+$", f), (root, f)
 
 
-@pytest.mark.parametrize("cell", WORKLOAD_FILES)
-def test_cell_files(cell):
+def check_cell_files(bench, pb, cell):
     """A workload file is a cell of ``BENCHMARK.json``, letter for
     letter, or it is parked and says why (and then ``perfbench.run``
-    gives no result for it)."""
-    work = _json(PB, "workloads", cell + ".json")
+    gives no result for it).  A cell's ``chips`` is the product of its
+    mix's ``mesh`` (absent: one device)."""
+    import math
+    work = _json(pb, "workloads", cell + ".json")
     assert work["name"] == cell and NAME.match(cell)
-    assert (cell in CELLS) != ("parked" in work)
-    cfg = _json(PB, "configs", work["config"] + ".json")
-    if cell in CELLS:
-        w = next(w for w in BENCH["workloads"] if w["name"] == cell)
+    assert (cell in _cells(bench)) != ("parked" in work)
+    cfg = _json(pb, "configs", work["config"] + ".json")
+    if cell in _cells(bench):
+        w = next(w for w in bench["workloads"] if w["name"] == cell)
         assert (work["config"], work["traffic"], work["chips"],
                 work["why"]) == (w["config"], w["traffic"], w["chips"],
                                  w["why"])
-        entry = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+        entry = next(c for c in bench["configs"] if c["name"] == w["config"])
         assert entry["file"] == f"perfbench/configs/{cfg['name']}.json"
         assert cfg["source"] == entry["source"]
         assert cfg["reduced"] == entry["reduced"]
@@ -93,53 +135,141 @@ def test_cell_files(cell):
     assert cfg["name"] == work["config"]
     assert cfg["kind"] in ("train", "serve")
     assert "bfloat16" in cfg["precision"]["control"]
-    mix = _json(PB, "mixes", work["traffic"] + ".json")
+    mix = _json(pb, "mixes", work["traffic"] + ".json")
     assert mix["name"] == work["traffic"] and "toy" in mix
+    for sizes in (mix, mix["toy"]):
+        assert math.prod(sizes.get("mesh", {}).values()) == work["chips"]
+        if "mesh" in sizes:     # rows divide over dp, blocks over rows
+            assert sizes["batch"] % sizes["mesh"].get("dp", 1) == 0
     assert work["limits"] and set(work["limits"]) == set(work["toy_limits"])
     for folder, key in (("adapters", "adapter"), ("reference", "reference")):
         assert os.path.exists(os.path.join(PB, folder, cfg[key] + ".py"))
 
 
-@pytest.mark.parametrize("fn", METRIC_FILES)
-def test_metric_files(fn):
+def check_metric_files(bench, pb, name):
     from perfbench import harness
-    m = _json(PB, "metrics", fn)
-    assert fn == m["name"] + ".json"
-    entry = next(e for e in BENCH["per_layer"] if e["name"] == m["name"])
+    load_cell, cell_metrics = _loaders(pb)
+    m = _json(pb, "metrics", name + ".json")
+    assert name == m["name"]
+    entry = next(e for e in bench["per_layer"] if e["name"] == m["name"])
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == m[key], key
     assert entry.get("workloads") == m.get("workloads")
     assert os.path.exists(os.path.join(PB, "readers", m["reader"] + ".py"))
-    moved = next(e for e in BENCH["end_to_end"] if e["name"] == m["moves"])
-    reporting = moved.get("workloads", CELLS)
+    moved = next(e for e in bench["end_to_end"] if e["name"] == m["moves"])
+    reporting = moved.get("workloads", _cells(bench))
     for cell in m.get("workloads", reporting):
         assert cell in reporting, (m["name"], cell)
-        work, cfg, _mix = harness.load_cell(cell)
+        _work, cfg, _mix = load_cell(cell)
         runner = harness.module("runners", cfg["kind"])
         assert m["moves"] in runner.END_TO_END
-        assert m in harness.cell_metrics(cell, runner.END_TO_END)
+        assert m in cell_metrics(cell, runner.END_TO_END)
     if "roofline" in m["name"]:
         assert m["name"].endswith("_roofline") and m["unit"] == "%"
     if "mfu" in re.split(r"[._]", m["name"]):
         assert m["unit"] == "%"
 
 
-def test_every_cell_reports_a_per_layer_metric():
+def check_every_cell_reports_a_per_layer_metric(bench, pb):
     from perfbench import harness
-    assert len(BENCH["per_layer"]) == len(METRIC_FILES)
-    for cell in CELLS:
-        _work, cfg, _mix = harness.load_cell(cell)
+    load_cell, cell_metrics = _loaders(pb)
+    assert sorted(e["name"] for e in bench["per_layer"]) \
+        == _listed(pb, "metrics")
+    for cell in _cells(bench):
+        _work, cfg, _mix = load_cell(cell)
         runner = harness.module("runners", cfg["kind"])
-        assert harness.cell_metrics(cell, runner.END_TO_END), cell
+        assert cell_metrics(cell, runner.END_TO_END), cell
         for name in runner.END_TO_END:
-            e = next(e for e in BENCH["end_to_end"] if e["name"] == name)
-            assert cell in e.get("workloads", CELLS)
+            e = next(e for e in bench["end_to_end"] if e["name"] == name)
+            assert cell in e.get("workloads", _cells(bench))
+
+
+def check_all(bench, pb):
+    check_names_units_and_sources(bench, pb)
+    for cell in _listed(pb, "workloads"):
+        check_cell_files(bench, pb, cell)
+    for name in _listed(pb, "metrics"):
+        check_metric_files(bench, pb, name)
+    check_every_cell_reports_a_per_layer_metric(bench, pb)
+
+
+def test_names_units_and_sources():
+    check_names_units_and_sources(BENCH, PB)
+
+
+@pytest.mark.parametrize("cell", WORKLOAD_FILES)
+def test_cell_files(cell):
+    check_cell_files(BENCH, PB, cell)
+
+
+@pytest.mark.parametrize("fn", METRIC_FILES)
+def test_metric_files(fn):
+    check_metric_files(BENCH, PB, fn[:-5])
+
+
+def test_every_cell_reports_a_per_layer_metric():
+    check_every_cell_reports_a_per_layer_metric(BENCH, PB)
+
+
+@pytest.mark.parametrize("where", ["end", "front"])
+def test_an_appended_entry_passes_every_data_check(tmp_path, where):
+    """What the next ``model_config``, ``perf_opt`` or ``tracing`` PR
+    does: one made-up configuration, cell and metric, as new files and
+    one entry each, go into a copy of the benchmark, and every data
+    check above passes over the copy, wherever in its list an entry
+    lands (PR 39 pinned the lists' last entries, and no PR after it
+    could add one until a ``benchmark`` PR restated the asserts)."""
+    import copy
+    import shutil
+    pb = tmp_path / "perfbench"
+    for folder in ("configs", "workloads", "mixes", "metrics"):
+        shutil.copytree(os.path.join(PB, folder), pb / folder)
+    bench = copy.deepcopy(BENCH)
+
+    def put(folder, name, data):
+        with open(pb / folder / (name + ".json"), "w") as f:
+            json.dump(data, f)
+
+    cfg = dict(_json(PB, "configs", "bert-large.json"), name="made-up-1b",
+               source="https://example.org/made-up-1b/config.json")
+    mix = dict(_json(PB, "mixes", "pretrain_b32_l128.json"),
+               name="pretrain_b16_l128", batch=16)
+    cell = dict(_json(PB, "workloads", TRAIN + ".json"),
+                name="made-up-1b.pretrain_b16_l128", config="made-up-1b",
+                traffic="pretrain_b16_l128", why="a made-up cell")
+    metric = dict(_json(PB, "metrics", "train.optim_device_ms.json"),
+                  name="train.made_up_device_ms", workloads=[cell["name"]])
+    put("configs", cfg["name"], cfg)
+    put("mixes", mix["name"], mix)
+    put("workloads", cell["name"], cell)
+    put("metrics", metric["name"], metric)
+    at = {"end": len, "front": lambda entries: 0}[where]
+    for group, entry in (
+            ("configs", {"name": cfg["name"], "source": cfg["source"],
+                         "file": "perfbench/configs/made-up-1b.json",
+                         "reduced": cfg["reduced"], "why": "made up"}),
+            ("workloads", {k: cell[k] for k in
+                           ("name", "config", "traffic", "chips", "why")}),
+            ("per_layer", {k: metric[k] for k in
+                           ("name", "unit", "better", "source", "layer",
+                            "moves", "workloads")})):
+        bench[group].insert(at(bench[group]), entry)
+    for m in bench["end_to_end"]:
+        if m["name"] == "train_tokens_per_s":
+            m["workloads"].insert(at(m["workloads"]), cell["name"])
+    check_all(bench, str(pb))
+    # and the check is no empty one: the made-up metric without its file
+    os.remove(pb / "metrics" / (metric["name"] + ".json"))
+    with pytest.raises(AssertionError):
+        check_every_cell_reports_a_per_layer_metric(bench, str(pb))
 
 
 def test_peak_table_refuses_an_unknown_device():
     from perfbench import peaks
     assert peaks.peak("TPU v5 lite")["flops_per_s"] == 197e12
     assert peaks.peak("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    # 1,600 Gbit/s of inter-chip links a chip, the sum of its links
+    assert peaks.peak("TPU v5 lite")["ici_bytes_per_s"] == 200e9
     with pytest.raises(KeyError):
         peaks.peak("cpu")
 
@@ -236,7 +366,7 @@ def _run(cell, seed, trace=0, rehearsal=True, patch=""):
         f"sys.exit(run.main(['--workload', {cell!r}, '--seed', '{seed}', "
         f"'--seconds', '0.5', '--trace', '{trace}'"
         + (", '--rehearsal'" if rehearsal else "") + "]))\n")
-    return _child(code)
+    return _child(code, devices=_json(PB, "workloads", cell + ".json")["chips"])
 
 
 def _verdict(stderr):
@@ -246,6 +376,7 @@ def _verdict(stderr):
 
 
 @pytest.mark.parametrize("cell,reason", [(TRAIN, "needs 1 TPU chip"),
+                                         (DP4, "needs 4 TPU chip"),
                                          (SERVE, "is parked")])
 def test_run_gives_no_result(cell, reason):
     """Without a chip; and, chip or none, for a parked cell."""
@@ -296,7 +427,7 @@ def test_run_outside_a_checkout_prints_no_result(tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("cell", [TRAIN, SERVE])
+@pytest.mark.parametrize("cell", [TRAIN, SERVE, DP4])
 def test_rehearsal_is_correct_and_prints_no_result(cell):
     r = _run(cell, 2 ** 31 + 77, trace=1)
     assert r.returncode == 0, r.stderr[-2000:]
@@ -329,6 +460,19 @@ def step(self, batch):
     return orig(self, tuple(np.concatenate([x[:h], x[:h]]) for x in batch))
 a.Program.step = step
 """
+# what every chip would hold with the exchange between chips left out:
+# the step of its own rows alone.  Device 0's leaves are what the norms
+# read, so the batch is the first chip's rows on every chip.
+EXCHANGE_LEFT_OUT = """
+import numpy as np
+from perfbench.adapters import bert_pretrain as a
+orig = a.Program.step
+def step(self, batch):
+    own = len(batch[0]) // self.trainer.mesh.shape["dp"]
+    return orig(self, tuple(np.concatenate([x[:own]] * (len(x) // own))
+                            for x in batch))
+a.Program.step = step
+"""
 TOKEN_ALTERED = """
 import numpy as np
 from perfbench.adapters import decoder_lm as a
@@ -351,7 +495,11 @@ a.Program.generate = generate
     (STATE_UNCHANGED, TRAIN, ("change_gap", "grad_gap")),
     (HALF_BATCH, TRAIN, ("grad_gap", "change_gap")),
     (TOKEN_ALTERED, SERVE, ("token_gap",)),
-], ids=["state_unchanged", "half_batch", "token_altered"])
+    (STATE_UNCHANGED, DP4, ("change_gap", "grad_gap")),
+    (HALF_BATCH, DP4, ("grad_gap", "change_gap")),
+    (EXCHANGE_LEFT_OUT, DP4, ("grad_gap", "change_gap")),
+], ids=["state_unchanged", "half_batch", "token_altered",
+        "dp4_state_unchanged", "dp4_half_batch", "dp4_exchange_left_out"])
 def test_a_broken_timed_path_is_not_correct(fault, cell, caught_by):
     r = _run(cell, 2 ** 31 + 78, patch=fault)
     assert r.returncode == 1, r.stderr[-2000:]
@@ -368,7 +516,8 @@ def _control_rows(cell):
         "import sys\nfrom perfbench import control\n"
         f"sys.exit(control.main(['--workload', {cell!r}, '--seeds', '3', "
         "'--control-seeds', '3', '--seconds', '1', '--rehearsal', "
-        "'--first-seed', '2200000001']))\n")
+        "'--first-seed', '2200000001']))\n",
+        devices=_json(PB, "workloads", cell + ".json")["chips"])
     assert r.returncode == 0, r.stderr[-2000:]
     line = [ln for ln in r.stdout.splitlines()
             if ln.startswith("READINGS ")][-1]
@@ -379,18 +528,21 @@ def _control_rows(cell):
 
 
 @pytest.mark.slow
-def test_the_training_control_is_not_correct():
+@pytest.mark.parametrize("cell,rows_kept", [(TRAIN, 2), (DP4, 6)])
+def test_the_training_control_is_not_correct(cell, rows_kept):
     """The reference in bfloat16 (the precision below the
     configuration's float32), put in the program's place, fails a limit
-    that the program keeps, and so does half of the batch left out (toy
-    sizes and toy limits; the chip's readings at the cell's size are in
-    PERF.md)."""
+    that the program keeps, and so do rows left out of the gradient:
+    half of the toy's four on one device, one chip's two of the toy's
+    eight over four (toy sizes and toy limits; the chip's readings at
+    the cell's size are in PERF.md)."""
     from perfbench import check
-    rows, limits = _control_rows(TRAIN)
+    rows, limits = _control_rows(cell)
     for row in rows:
+        assert row["rows_kept"] == rows_kept
         assert check.verdict(row["program"][0], limits)[0]
         assert not check.verdict(row["control"][0], limits)[0]
-        assert not check.verdict(row["fault_half_batch"][0], limits)[0]
+        assert not check.verdict(row["fault_rows_left_out"][0], limits)[0]
 
 
 @pytest.mark.slow

@@ -8,8 +8,7 @@ of each, under the closed taxonomy.  ``fixtures/train_slice.xspace.txt``
 and no leaf under the container, which is also what a stale executable
 from the compile cache looks like.  The expected values are worked out
 again here from the files' text.  The eight metrics are read through
-``scope_account.METRICS``: their files are a ``benchmark`` PR's to
-add."""
+``scope_account.METRICS``, and their files (PR 43) are held to it."""
 import json
 import os
 import re
@@ -87,21 +86,16 @@ def _read(name, ctx):
 @pytest.mark.parametrize("name", NEW)
 def test_the_readers_table_names_the_metric(name):
     """``scope_account.METRICS`` holds the ``params`` of the eight
-    metrics' files, which a ``benchmark`` PR adds (the cells' own tests
-    pin their lists of metrics and the tail of ``per_layer``): where a
-    file is there, it says what the table says."""
+    metrics' files: each file is there and says what the table says."""
     from perfbench.readers import scope_account
     params = scope_account.METRICS[name]
     assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}", name)
     assert params.get("scopes") == SCOPED.get(name)
     assert params["what"] == ("scopes" if name in SCOPED
                               else name.split(".")[1].split("_")[0])
-    path = os.path.join(PB, "metrics", name + ".json")
-    if os.path.exists(path):
-        with open(path) as f:
-            m = json.load(f)
-        assert m["reader"] == "scope_account"
-        assert m["params"] == {"program": scope_account.PROGRAM, **params}
+    m = _file(name)
+    assert m["reader"] == "scope_account"
+    assert m["params"] == {"program": scope_account.PROGRAM, **params}
 
 
 def test_the_fixture_is_small_and_carries_the_taxonomy():
