@@ -41,7 +41,8 @@ class MoEFFN(HybridBlock):
     (its published update rule, from the experts' load, is a training
     loop's, not this layer's).  ``shared_hidden_size`` > 0 adds one
     shared expert of that width and of the routed experts' kind, which
-    every token passes.
+    every token passes; ``shared_gate`` scales its output by
+    ``sigmoid(x @ shared_gate_weight)`` (units, 1) a token.
 
     ``rows_routed`` (n_held,) is a cumulative count, kept on the device
     as float32 (exact to 2**24 a expert; read differences), of the
@@ -61,7 +62,7 @@ class MoEFFN(HybridBlock):
                  experts_per_token=1, experts_held=None, first_expert=0,
                  activation="gelu", gated=False, recompute=False, train_router=True,
                  scoring="softmax", route_scale=1.0, route_eps=1e-20,
-                 shared_hidden_size=0,
+                 shared_hidden_size=0, shared_gate=False,
                  weight_initializer=None, **kwargs):
         super().__init__(**kwargs)
         held = num_experts if experts_held is None else int(experts_held)
@@ -87,7 +88,8 @@ class MoEFFN(HybridBlock):
                         recompute=bool(recompute), scoring=scoring,
                         route_scale=float(route_scale),
                         route_eps=float(route_eps),
-                        shared_expert=bool(shared_hidden_size))
+                        shared_expert=bool(shared_hidden_size),
+                        shared_gate=bool(shared_hidden_size and shared_gate))
         fan = 2 if gated else 1
         with self.name_scope():
             self.gate_weight = self.params.get(
@@ -115,12 +117,16 @@ class MoEFFN(HybridBlock):
                 self.shared_w2 = self.params.get(
                     "shared_w2", shape=(shared_hidden_size, units),
                     init=weight_initializer)
+                if shared_gate:
+                    self.shared_gate_weight = self.params.get(
+                        "shared_gate_weight", shape=(units, 1),
+                        init=weight_initializer)
 
     def hybrid_forward(self, F, x, gate_weight, expert_w1, expert_w2,
                        rows_routed, route_bias=None, shared_w1=None,
-                       shared_w2=None):
-        more = [a for a in (route_bias, shared_w1, shared_w2)
-                if a is not None]
+                       shared_w2=None, shared_gate_weight=None):
+        more = [a for a in (route_bias, shared_w1, shared_w2,
+                            shared_gate_weight) if a is not None]
         out, rows = F.moe_ffn(x, gate_weight, expert_w1, expert_w2, *more,
                               **self._kw)
         update_aux_state(self.rows_routed, rows_routed + rows)

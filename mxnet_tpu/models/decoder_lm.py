@@ -1,9 +1,10 @@
 """Present-day decoder-only language models for training: RMSNorm,
 rotary positions, grouped key/value heads, window and full causal
-layers through the flash kernels or gated short convolutions, each
-before a dense or sparse feed-forward, and hybrid models whose layers
-are one mixer each (Mamba-2, attention without rotary, an expert layer
-with a shared expert).
+layers through the flash kernels (with an output gate and partial
+rotary where the model has them), gated short convolutions or Gated
+DeltaNet linear attention, each before a dense or sparse feed-forward,
+and hybrid models whose layers are one mixer each (Mamba-2, attention
+without rotary, an expert layer with a shared expert).
 One ``DecoderCell`` class; ``layer_types`` says what each layer is.
 
 The serving side (the paged forwards of ``transformer_blocks``) has
@@ -17,9 +18,9 @@ from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.contrib.moe import MoEFFN
-from .transformer_blocks import (DecoderCell, GatedFFN, Mamba2Mixer,
-                                 RMSNorm, RotaryGroupedAttention,
-                                 ShortConvMixer)
+from .transformer_blocks import (DecoderCell, GatedDeltaNetMixer, GatedFFN,
+                                 Mamba2Mixer, RMSNorm,
+                                 RotaryGroupedAttention, ShortConvMixer)
 
 __all__ = ["DecoderLM", "get_decoder_lm"]
 
@@ -28,10 +29,13 @@ class DecoderLM(HybridBlock):
     """``lm(tokens (B, L)) -> logits (B, L, vocab_size)``.
 
     ``layer_types``, a layer each.  "sliding_attention" /
-    "full_attention" / "conv": attention, or a gated short convolution
-    of ``conv_kernel`` taps (``ShortConvMixer``), then a feed-forward,
+    "full_attention" / "conv" / "linear_attention": attention, a gated
+    short convolution of ``conv_kernel`` taps (``ShortConvMixer``) or a
+    Gated DeltaNet mixer of the sizes in ``gdn``
+    (``GatedDeltaNetMixer``'s keyword arguments), then a feed-forward,
     each under its norm; ``rope``: the ``rope`` op's keyword arguments
-    for each of the two attentions; ``qk_norm_eps``:
+    for each of the two attentions; ``qk_norm_eps``, ``rotary_dim`` and
+    ``attention_out_gate`` (its ``out_gate``):
     ``RotaryGroupedAttention``'s.  "mamba2" / "attention" / "moe": ONE
     mixer under one norm, a
     hybrid model's layer: a Mamba-2 mixer of the sizes in ``mamba``
@@ -47,7 +51,8 @@ class DecoderLM(HybridBlock):
     from ``first_expert`` on (all by default; one chip's share under
     expert parallelism); ``router``: ``MoEFFN``'s ``scoring``,
     ``route_scale`` and ``route_eps``; ``shared_expert_hidden_size`` > 0
-    gives each expert layer a shared expert.  ``vocab_size`` is the
+    gives each expert layer a shared expert, ``shared_expert_gate`` a
+    sigmoid gate on it.  ``vocab_size`` is the
     number of rows of the embedding and the head held here;
     ``tie_embeddings``: the head reads the embedding's rows, one
     parameter whose gradient is the sum of both uses.
@@ -65,7 +70,8 @@ class DecoderLM(HybridBlock):
                  expert_activation="silu", expert_gated=True, router=None,
                  shared_expert_hidden_size=0, mamba=None, conv_kernel=3,
                  qk_norm_eps=None, dense_ffn_layers=0, tie_embeddings=False,
-                 **kwargs):
+                 gdn=None, rotary_dim=0, attention_out_gate=False,
+                 shared_expert_gate=False, **kwargs):
         super().__init__(**kwargs)
         rope = rope or {}
         self.vocab_size, self.units = int(vocab_size), int(units)
@@ -78,6 +84,7 @@ class DecoderLM(HybridBlock):
                 window=window if kind == "sliding_attention" else None,
                 rope=rope.get(kind, None if kind == "attention" else {}),
                 compute_dtype=attention_dtype, qk_norm_eps=qk_norm_eps,
+                out_gate=attention_out_gate, rotary_dim=rotary_dim,
                 prefix=prefix + "attention_")
 
         def experts(prefix):
@@ -88,6 +95,7 @@ class DecoderLM(HybridBlock):
                 activation=expert_activation, gated=expert_gated,
                 recompute=recompute_experts, train_router=train_router,
                 shared_hidden_size=shared_expert_hidden_size,
+                shared_gate=shared_expert_gate,
                 prefix=prefix + "moe_", **(router or {}))
 
         with self.name_scope():
@@ -98,11 +106,14 @@ class DecoderLM(HybridBlock):
                 with self.cells.name_scope():
                     prefix = f"layer{i}_"
                     if kind in ("sliding_attention", "full_attention",
-                                "conv"):
+                                "conv", "linear_attention"):
                         sparse = num_experts and i >= dense_ffn_layers
                         blocks = (ShortConvMixer(units, conv_kernel,
                                                  prefix=prefix + "conv_")
                                   if kind == "conv" else
+                                  GatedDeltaNetMixer(
+                                      units, prefix=prefix + "gdn_", **gdn)
+                                  if kind == "linear_attention" else
                                   attention(kind, prefix),
                                   experts(prefix) if sparse else
                                   GatedFFN(units, hidden_size,
@@ -181,6 +192,21 @@ _DECODER_CONFIGS = {
         num_experts=64, experts_per_token=4, expert_hidden_size=1536,
         router=dict(scoring="sigmoid", route_scale=1.0, route_eps=1e-6),
         tie_embeddings=True),
+    # Qwen3-Next-80B-A3B-Instruct, config.json (model_type qwen3_next):
+    # three Gated DeltaNet layers, then one gated attention layer (heads
+    # of 256, rotary on the first 64 channels), each before 512 SwiGLU
+    # experts of 512 (10 a token) and a gated shared expert.  Its norms
+    # are x / rms(x) * (1 + w): the gains here are the stored 1 + w
+    "qwen3_next_80b_a3b": dict(
+        vocab_size=151936, units=2048,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 12,
+        num_heads=16, num_kv_heads=2, head_dim=256, rms_norm_eps=1e-6,
+        qk_norm_eps=1e-6, rope={"full_attention": dict(theta=10000000.0)},
+        rotary_dim=64, attention_out_gate=True,
+        gdn=dict(key_heads=16, value_heads=32, key_dim=128, value_dim=128,
+                 conv_kernel=4, chunk=64, norm_eps=1e-6),
+        num_experts=512, experts_per_token=10, expert_hidden_size=512,
+        shared_expert_hidden_size=512, shared_expert_gate=True),
 }
 
 

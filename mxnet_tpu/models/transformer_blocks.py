@@ -23,7 +23,7 @@ __all__ = ["PositionwiseFFN", "MultiHeadSelfAttention",
            "MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerDecoderLM",
            "RMSNorm", "GatedFFN", "RotaryGroupedAttention", "Mamba2Mixer",
-           "ShortConvMixer", "DecoderCell",
+           "ShortConvMixer", "GatedDeltaNetMixer", "DecoderCell",
            "paged_lm_params", "paged_prefill", "paged_decode_step",
            "paged_verify", "paged_verify_batch"]
 
@@ -317,11 +317,15 @@ class RotaryGroupedAttention(HybridBlock):
     passes an RMS norm over its ``head_dim`` channels before the
     rotation, q's and k's each with a gain (head_dim,) of its own,
     shared by the heads; None: no such norm and no such gains.
+    ``out_gate``: each query head's projection carries [q | gate] and
+    the attention's output is multiplied by ``sigmoid(gate)`` before
+    ``out_proj``.  ``rotary_dim``: rotary over the first channels of a
+    head only (the ``rope`` op's ``rotary_dim``).
     """
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  window=None, rope=None, compute_dtype="bfloat16",
-                 qk_norm_eps=None, **kwargs):
+                 qk_norm_eps=None, out_gate=False, rotary_dim=0, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"{num_kv_heads} key/value heads do not "
@@ -332,15 +336,19 @@ class RotaryGroupedAttention(HybridBlock):
         self._head_dim = head_dim
         self._window = -1 if window is None else int(window)
         self._rope = None if rope is None else dict(rope)
+        if self._rope is not None and rotary_dim:
+            self._rope["rotary_dim"] = int(rotary_dim)
         self._compute_dtype = compute_dtype
         self._qk_norm_eps = qk_norm_eps
+        self._out_gate = bool(out_gate)
         with self.name_scope():
             if qk_norm_eps is not None:
                 self.q_norm = RMSNorm(head_dim, qk_norm_eps,
                                       prefix="q_norm_")
                 self.k_norm = RMSNorm(head_dim, qk_norm_eps,
                                       prefix="k_norm_")
-            self.q_proj = nn.Dense(num_heads * head_dim, in_units=units,
+            self.q_proj = nn.Dense((1 + self._out_gate) * num_heads
+                                   * head_dim, in_units=units,
                                    use_bias=False, flatten=False,
                                    prefix="q_proj_")
             self.kv_proj = nn.Dense(2 * num_kv_heads * head_dim,
@@ -354,7 +362,12 @@ class RotaryGroupedAttention(HybridBlock):
         B, L, _ = x.shape
         H, Hkv, D = self._heads, self._kv_heads, self._head_dim
         with jax.named_scope("mx.attn.proj"):
-            q = self.q_proj(x).reshape((B, L, H, D))
+            q = self.q_proj(x)
+            if self._out_gate:
+                q = q.reshape((B, L, H, 2 * D))
+                gate = F.slice_axis(q, axis=-1, begin=D, end=None)
+                q = F.slice_axis(q, axis=-1, begin=0, end=D)
+            q = q.reshape((B, L, H, D))
             kv = self.kv_proj(x)
             k = F.slice_axis(kv, axis=-1, begin=0, end=Hkv * D)
             v = F.slice_axis(kv, axis=-1, begin=Hkv * D, end=None)
@@ -370,8 +383,12 @@ class RotaryGroupedAttention(HybridBlock):
                        for a in (q, k, v))
         out = F.flash_attention(q, k, v, causal=True, window=self._window)
         with jax.named_scope("mx.attn.proj"):
-            out = F.cast(out, dtype=str(x.dtype)).reshape((B, L, H * D))
-            return self.out_proj(out)
+            out = F.cast(out, dtype=str(x.dtype))
+        if self._out_gate:
+            with jax.named_scope("mx.attn.out_gate"):
+                out = out * F.sigmoid(gate)
+        with jax.named_scope("mx.attn.proj"):
+            return self.out_proj(out.reshape((B, L, H * D)))
 
 
 class Mamba2Mixer(HybridBlock):
@@ -451,6 +468,56 @@ class ShortConvMixer(HybridBlock):
             h = self.in_proj(x)
         y = F.gated_short_conv(h, conv_weight)
         with jax.named_scope("mx.sconv.out_proj"):
+            return self.out_proj(y)
+
+
+class GatedDeltaNetMixer(HybridBlock):
+    """The Gated DeltaNet mixer over (B, L, C) (Qwen3-Next's
+    ``linear_attention``; ``ops/deltanet.py`` has the equations):
+    ``in_proj`` gives [q | k | v | z] of widths ``key_heads * key_dim``
+    twice and ``value_heads * value_dim`` twice, ``ba_proj`` [b | a] of
+    ``value_heads`` each; q, k and v pass a causal depthwise convolution
+    of ``conv_kernel`` taps without a bias and silu, the gated delta rule
+    runs in chunks of ``chunk``, each value head's output is RMS-normed
+    (one gain of ``value_dim`` shared by the heads) and gated by
+    ``silu(z)``, and ``out_proj`` returns to C.  No bias.
+    """
+
+    def __init__(self, units, key_heads, value_heads, key_dim, value_dim,
+                 conv_kernel=4, chunk=64, norm_eps=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        if value_heads % key_heads:
+            raise MXNetError(f"{key_heads} key heads do not divide "
+                             f"{value_heads} value heads")
+        conv_dim = 2 * key_heads * key_dim + value_heads * value_dim
+        self._sizes = dict(key_heads=key_heads, value_heads=value_heads,
+                           key_dim=key_dim, value_dim=value_dim,
+                           chunk=int(chunk), eps=norm_eps)
+        with self.name_scope():
+            self.in_proj = nn.Dense(conv_dim + value_heads * value_dim,
+                                    in_units=units, use_bias=False,
+                                    flatten=False, prefix="in_proj_")
+            self.ba_proj = nn.Dense(2 * value_heads, in_units=units,
+                                    use_bias=False, flatten=False,
+                                    prefix="ba_proj_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(conv_dim, conv_kernel))
+            self.dt_bias = self.params.get(
+                "dt_bias", shape=(value_heads,), init="ones")
+            self.A_log = self.params.get(
+                "A_log", shape=(value_heads,), init="zeros")
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(value_dim,), init="ones")
+            self.out_proj = nn.Dense(units, in_units=value_heads * value_dim,
+                                     use_bias=False, flatten=False,
+                                     prefix="out_proj_")
+
+    def hybrid_forward(self, F, x, conv_weight, dt_bias, A_log, norm_gamma):
+        with jax.named_scope("mx.gdn.in_proj"):
+            qkvz, ba = self.in_proj(x), self.ba_proj(x)
+        y = F.gdn_mixer(qkvz, ba, conv_weight, A_log, dt_bias, norm_gamma,
+                        **self._sizes)
+        with jax.named_scope("mx.gdn.out_proj"):
             return self.out_proj(y)
 
 

@@ -304,13 +304,17 @@ def rope_inv_freq(head_dim, theta, yarn_factor=0.0, yarn_original_max=0,
 @register("_contrib_rope", num_inputs=1, aliases=["rope"])
 def rope(data, *, theta: float = 10000.0, yarn_factor: float = 0.0,
          yarn_original_max: int = 0, yarn_beta_fast: float = 32.0,
-         yarn_beta_slow: float = 1.0, attention_factor: float = 1.0):
-    """Rotary positions over the whole head dimension of (B, L, H, D):
-    position ``t`` of axis 1 rotates the pairs (i, i + D/2) by
-    ``t * inv_freq_i`` (the half-split layout of ``transformers``);
-    ``attention_factor`` scales cos and sin (YaRN)."""
+         yarn_beta_slow: float = 1.0, attention_factor: float = 1.0,
+         rotary_dim: int = 0):
+    """Rotary positions over the head dimension of (B, L, H, D): position
+    ``t`` of axis 1 rotates the pairs (i, i + D/2) by ``t * inv_freq_i``
+    (the half-split layout of ``transformers``); ``attention_factor``
+    scales cos and sin (YaRN).  ``rotary_dim`` (0: all of D) rotates the
+    first ``rotary_dim`` channels alone, as a head of that width (pairs
+    (i, i + rotary_dim/2), its frequencies), and passes the rest through
+    (a partial rotary factor)."""
     with jax.named_scope("mx.rope"):
-        D = data.shape[-1]
+        D = int(rotary_dim) or data.shape[-1]
         inv = jnp.asarray(rope_inv_freq(
             D, theta, yarn_factor, yarn_original_max, yarn_beta_fast,
             yarn_beta_slow), jnp.float32)
@@ -318,6 +322,9 @@ def rope(data, *, theta: float = 10000.0, yarn_factor: float = 0.0,
         cos = (jnp.cos(angle) * attention_factor)[None, :, None, :]
         sin = (jnp.sin(angle) * attention_factor)[None, :, None, :]
         x = data.astype(jnp.float32)
-        a, b = x[..., :D // 2], x[..., D // 2:]
-        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+        a, b = x[..., :D // 2], x[..., D // 2:D]
+        turned = [a * cos - b * sin, b * cos + a * sin]
+        if D < data.shape[-1]:
+            turned.append(x[..., D:])
+        out = jnp.concatenate(turned, -1)
         return out.astype(data.dtype)

@@ -23,8 +23,9 @@ experts, and computes its own experts' part of the result:
   in float32 (``pallas_kernels.tokens_of_rows``, the token-side mover).
   A token none of whose experts is held gets zero;
 - ``mx.moe.shared``: a shared expert, where the layer has one: the same
-  feed-forward for every token, dense, added after combine (every chip
-  computes it alike; a sum over the shares counts it once).
+  feed-forward for every token, dense, scaled by a sigmoid gate a token
+  where the layer has one, added after combine (every chip computes it
+  alike; a sum over the shares counts it once).
 
 No capacity and no dropped token: the pair buffers have S*k rows, the
 worst case, and **every pass visits only the rows below the last held
@@ -205,7 +206,8 @@ _experts_part.defvjp(_forward, _backward)
 
 def _n_inputs(kw):
     return (4 + (kw.get("scoring") == "sigmoid")
-            + 2 * bool(kw.get("shared_expert")))
+            + 2 * bool(kw.get("shared_expert"))
+            + bool(kw.get("shared_gate")))
 
 
 @register("_contrib_moe_ffn", num_inputs=_n_inputs, num_outputs=2,
@@ -214,7 +216,8 @@ def moe_ffn(x, wg, w1, w2, *more, experts_per_token: int = 1,
             first_expert: int = 0, activation: str = "gelu",
             gated: bool = False, recompute: bool = False,
             scoring: str = "softmax", route_scale: float = 1.0,
-            route_eps: float = 1e-20, shared_expert: bool = False):
+            route_eps: float = 1e-20, shared_expert: bool = False,
+            shared_gate: bool = False):
     """The expert layer: route over all experts, compute the held
     experts' part.
 
@@ -227,6 +230,8 @@ def moe_ffn(x, wg, w1, w2, *more, experts_per_token: int = 1,
     With ``shared_expert`` the last two inputs are its matrices (C, Hs)
     (or (C, 2Hs), [gate | up]) and (Hs, C): one more expert of the same
     kind that every token passes, added to the held experts' part.
+    With ``shared_gate`` one more input (C, 1) follows: the shared
+    expert's output is scaled by ``sigmoid(x @ it)`` a token.
     Returns (out with x's shape, rows (n_held,) float32: the (token,
     expert) pairs this call routed to each held expert; over S*k, the
     share of the pair buffers that the layer's passes visit).  Where
@@ -264,12 +269,16 @@ def moe_ffn(x, wg, w1, w2, *more, experts_per_token: int = 1,
     out, rows = part(xs, weights.astype(xs.dtype), ids, w1, w2)
     if shared_expert:
         with jax.named_scope("mx.moe.shared"):
-            s1, s2 = more
+            s1, s2 = more[:2]
             h = jnp.dot(xs, s1.astype(xs.dtype))
             if gated:
                 gate, up = jnp.split(h, 2, axis=-1)
                 h = _ACTIVATIONS[activation](gate) * up
             else:
                 h = _ACTIVATIONS[activation](h)
-            out = out + jnp.dot(h, s2.astype(xs.dtype))
+            shared = jnp.dot(h, s2.astype(xs.dtype))
+            if shared_gate:
+                shared = shared * jax.nn.sigmoid(
+                    jnp.dot(xs, more[2].astype(xs.dtype)))
+            out = out + shared
     return out.reshape(x.shape), rows

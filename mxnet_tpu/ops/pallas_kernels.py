@@ -1806,34 +1806,43 @@ def ssm_conv_pass_grads(src, taps, bias, g, lo, interpret):
     return dx, sums[:K], sums[K]
 
 
-def _gated_normed(y_ref, z_ref, eps):
-    """v = y silu(z), the reciprocal root of its mean square a row, and
-    what the gate's gradient needs: the block's group is its columns."""
+def _gated_normed(y_ref, z_ref, eps, before):
+    """v = y silu(z), the reciprocal root of the mean square a row of v
+    (of y where the norm comes ``before`` the gate), and what the gate's
+    gradient needs: the block's group is its columns."""
     y, z = y_ref[...], z_ref[...]
     s = jax.nn.sigmoid(z)
     v = y * z * s
-    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+    normed = y if before else v
+    r = jax.lax.rsqrt(jnp.mean(normed * normed, axis=-1, keepdims=True)
+                      + eps)
     return y, z, s, v, r
 
 
-def _ssm_norm_fwd_kernel(y_ref, z_ref, gain_ref, o_ref, *, eps):
-    *_, v, r = _gated_normed(y_ref, z_ref, eps)
+def _ssm_norm_fwd_kernel(y_ref, z_ref, gain_ref, o_ref, *, eps, before):
+    *_, v, r = _gated_normed(y_ref, z_ref, eps, before)
     o_ref[...] = v * r * gain_ref[...]
 
 
 def _ssm_norm_bwd_kernel(y_ref, z_ref, gain_ref, g_ref, dy_ref, dz_ref,
-                         dgain_ref, *, eps):
+                         dgain_ref, *, eps, before):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dgain_ref[...] = jnp.zeros(dgain_ref.shape, jnp.float32)
 
-    y, z, s, v, r = _gated_normed(y_ref, z_ref, eps)
+    y, z, s, v, r = _gated_normed(y_ref, z_ref, eps, before)
     g = g_ref[...]
     scaled = g * gain_ref[...]
-    back = jnp.mean(scaled * v, axis=-1, keepdims=True) * (r * r * r)
-    dv = scaled * r - v * back
-    dy_ref[...] = dv * (z * s)
-    dz_ref[...] = dv * y * (s * (1.0 + z * (1.0 - s)))
+    if before:
+        a = scaled * (z * s)
+        back = jnp.mean(a * y, axis=-1, keepdims=True) * (r * r * r)
+        dy_ref[...] = a * r - y * back
+        dz_ref[...] = scaled * y * r * (s * (1.0 + z * (1.0 - s)))
+    else:
+        back = jnp.mean(scaled * v, axis=-1, keepdims=True) * (r * r * r)
+        dv = scaled * r - v * back
+        dy_ref[...] = dv * (z * s)
+        dz_ref[...] = dv * y * (s * (1.0 + z * (1.0 - s)))
     dgain_ref[...] += jnp.sum(g * v * r, axis=0, keepdims=True)
 
 
@@ -1848,29 +1857,32 @@ def _norm_specs(L, C, groups, lo):
         gain=pl.BlockSpec((1, n), lambda i, j, c: (0, j)))
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def ssm_norm_pass(y, gate_src, gain, groups, eps, lo, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def ssm_norm_pass(y, gate_src, gain, groups, eps, lo, interpret,
+                  before=False):
     """``y * silu(gate)`` RMS-normed over each of ``groups`` groups of
-    its C channels, times ``gain`` (1, C); the gate is columns ``lo`` on
-    of ``gate_src`` (b, L, .); float32; (b, L, C)."""
+    its C channels, times ``gain`` (1, C); ``before``: y normed, then
+    times the gain and ``silu(gate)``.  The gate is columns ``lo`` on of
+    ``gate_src`` (b, L, .); float32; (b, L, C)."""
     b, L, C = y.shape
     at = _norm_specs(L, C, groups, lo)
     return pl.pallas_call(
-        functools.partial(_ssm_norm_fwd_kernel, eps=eps),
+        functools.partial(_ssm_norm_fwd_kernel, eps=eps, before=before),
         grid=(b, groups, L // at["rows"]),
         in_specs=[at["own"], at["gate"], at["gain"]], out_specs=at["own"],
         out_shape=jax.ShapeDtypeStruct((b, L, C), jnp.float32),
         interpret=interpret, **_SSM_PASS_PARAMS)(y, gate_src, gain)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def ssm_norm_pass_grads(y, gate_src, gain, g, groups, eps, lo, interpret):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def ssm_norm_pass_grads(y, gate_src, gain, g, groups, eps, lo, interpret,
+                        before=False):
     """``ssm_norm_pass``'s gradients from the cotangent ``g``: in y, in
     the gate's C columns (b, L, C) and in the gain (C,)."""
     b, L, C = y.shape
     at = _norm_specs(L, C, groups, lo)
     dy, dz, dgain = pl.pallas_call(
-        functools.partial(_ssm_norm_bwd_kernel, eps=eps),
+        functools.partial(_ssm_norm_bwd_kernel, eps=eps, before=before),
         grid=(b, groups, L // at["rows"]),
         in_specs=[at["own"], at["gate"], at["gain"], at["own"]],
         out_specs=[at["own"], at["own"],

@@ -28,6 +28,16 @@ out, the taps', bias's and gain's sums in float32:
     dv = r * (g * gain) - v * r^3 * mean_group(g * gain * v)
     dy = dv * silu(z)    dz = dv * y * silu'(z)    d_gain = sum_rows g * v * r
 
+Two variants serve Qwen3-Next's Gated DeltaNet (``ops/deltanet.py``),
+which calls the two ``custom_vjp``s itself under its own scopes: the
+convolution without a bias (``bias`` None), and the norm BEFORE the gate,
+``out = norm(y) * gain * silu(z)`` (``before``), whose backward is the
+same with ``a = g * gain * silu(z)`` in the place of ``g * gain`` and y
+in the place of v:
+
+    r = rsqrt(mean_group(y^2) + eps)
+    dy = r * a - y * r^3 * mean_group(a * y)    dz = g * gain * y * r * silu'(z)
+
 Both take their operand as columns ``lo`` on of a wider array: the mixer
 hands them the in-projection's one [z | x B C | dt] result and no slice
 of it (a slice handed to an operator is written out in HBM; the
@@ -126,19 +136,27 @@ def _conv_kernels(src, weight, lo):
     return src.ndim == 3 and ssm_conv_tiles(src.shape[1], *weight.shape, lo)
 
 
+def _bias_or_zeros(bias, C):
+    """The bias in float32, zeros where the convolution has none."""
+    return (jnp.zeros((C,), jnp.float32) if bias is None
+            else bias.astype(jnp.float32))
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _conv_silu(src, weight, bias, lo):
     """``silu(bias + conv)`` of the ``weight.shape[0]`` channels that
-    start at column ``lo`` of ``src``."""
+    start at column ``lo`` of ``src``; ``bias`` None: no bias."""
     f32 = jnp.float32
     C = weight.shape[0]
     if _conv_kernels(src, weight, lo):
         # mxlint: disable=recompile-churn (a column and a bool)
         out = ssm_conv_pass(src.astype(f32), weight.astype(f32).T,
-                            bias.astype(f32)[None], lo, _interpret(None))
+                            _bias_or_zeros(bias, C)[None], lo,
+                            _interpret(None))
     else:
-        pre = bias.astype(f32) + _taps(_columns(src, lo, C).astype(f32),
-                                       weight.astype(f32))
+        pre = _taps(_columns(src, lo, C).astype(f32), weight.astype(f32))
+        if bias is not None:
+            pre = bias.astype(f32) + pre
         out = jax.nn.silu(pre)
     return out.astype(src.dtype)
 
@@ -155,12 +173,14 @@ def _conv_silu_bwd(lo, res, g):
     if _conv_kernels(src, weight, lo):
         # mxlint: disable=recompile-churn (a column and a bool)
         d_x, d_weight, d_bias = ssm_conv_pass_grads(
-            src.astype(f32), w.T, bias.astype(f32)[None], g, lo,
+            src.astype(f32), w.T, _bias_or_zeros(bias, C)[None], g, lo,
             _interpret(None))
         d_weight = d_weight.T
     else:
         x = _columns(src, lo, C).astype(f32)
-        pre = bias.astype(f32) + _taps(x, w)
+        pre = _taps(x, w)
+        if bias is not None:
+            pre = bias.astype(f32) + pre
         s = jax.nn.sigmoid(pre)
         dpre = g * (s * (1.0 + pre * (1.0 - s)))
         # the convolution's transpose: tap j of position t + (K - 1 - j)
@@ -170,7 +190,7 @@ def _conv_silu_bwd(lo, res, g):
              for j in range(K)], axis=1)
         d_bias = jnp.sum(dpre, axis=(0, 1))
     return (_in_columns(d_x, src, lo), d_weight.astype(weight.dtype),
-            d_bias.astype(bias.dtype))
+            None if bias is None else d_bias.astype(bias.dtype))
 
 
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
@@ -311,45 +331,48 @@ def _each_channel(stats, channels):
     return jnp.broadcast_to(out, stats[0].shape[:-1] + (channels,))
 
 
-def _gated(y, gate, groups, eps):
+def _gated(y, gate, groups, eps, before=False):
     """The gate in float32, its sigmoid, ``v = y * silu(gate)`` and the
-    reciprocal root of v's mean square a group (a list, a group each)."""
+    reciprocal root of the mean square a group (a list, a group each) of
+    v, or of y itself where the norm comes ``before`` the gate."""
     z = gate.astype(jnp.float32)
     s = jax.nn.sigmoid(z)
     v = y * z * s
     n = v.shape[-1] // groups
+    normed = y if before else v
     return z, s, v, [lax.rsqrt(a / n + eps)
-                     for a in _group_sums(v * v, groups)]
+                     for a in _group_sums(normed * normed, groups)]
 
 
 def _norm_kernels(data, groups, lo):
     return data.ndim == 3 and ssm_norm_tiles(*data.shape[1:], groups, lo)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _gate_norm(data, gate_src, gamma, groups, eps, lo):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gate_norm(data, gate_src, gamma, groups, eps, lo, before=False):
     """The gate is the ``data.shape[-1]`` channels that start at column
-    ``lo`` of ``gate_src``."""
+    ``lo`` of ``gate_src``; ``before``: the norm comes before the gate,
+    ``norm(data) * gamma * silu(gate)``."""
     f32 = jnp.float32
     C = data.shape[-1]
     if _norm_kernels(data, groups, lo):
         # mxlint: disable=recompile-churn (sizes and a bool)
         out = ssm_norm_pass(data.astype(f32), gate_src.astype(f32),
                             gamma.astype(f32)[None], groups, eps, lo,
-                            _interpret(None))
+                            _interpret(None), before)
     else:
         _, _, v, r = _gated(data.astype(f32), _columns(gate_src, lo, C),
-                            groups, eps)
+                            groups, eps, before)
         out = v * _each_channel(r, C) * gamma.astype(f32)
     return out.astype(data.dtype)
 
 
-def _gate_norm_fwd(data, gate_src, gamma, groups, eps, lo):
-    return (_gate_norm(data, gate_src, gamma, groups, eps, lo),
+def _gate_norm_fwd(data, gate_src, gamma, groups, eps, lo, before=False):
+    return (_gate_norm(data, gate_src, gamma, groups, eps, lo, before),
             (data, gate_src, gamma))
 
 
-def _gate_norm_bwd(groups, eps, lo, res, g):
+def _gate_norm_bwd(groups, eps, lo, before, res, g):
     data, gate_src, gamma = res
     f32 = jnp.float32
     C = data.shape[-1]
@@ -358,17 +381,27 @@ def _gate_norm_bwd(groups, eps, lo, res, g):
         # mxlint: disable=recompile-churn (sizes and a bool)
         dy, dz, d_gamma = ssm_norm_pass_grads(
             y, gate_src.astype(f32), gamma.astype(f32)[None], g, groups, eps,
-            lo, _interpret(None))
+            lo, _interpret(None), before)
     else:
-        z, s, v, r = _gated(y, _columns(gate_src, lo, C), groups, eps)
+        z, s, v, r = _gated(y, _columns(gate_src, lo, C), groups, eps,
+                            before)
         n = C // groups
         scaled = g * gamma.astype(f32)
-        back = [a / n * ri * ri * ri
-                for a, ri in zip(_group_sums(scaled * v, groups), r)]
-        r = _each_channel(r, C)
-        dv = scaled * r - v * _each_channel(back, C)
-        dy = dv * (z * s)
-        dz = dv * y * (s * (1.0 + z * (1.0 - s)))
+        if before:
+            # out = y r gamma silu(z): the norm's cotangent is scaled silu(z)
+            a = scaled * (z * s)
+            back = [t / n * ri * ri * ri
+                    for t, ri in zip(_group_sums(a * y, groups), r)]
+            r = _each_channel(r, C)
+            dy = a * r - y * _each_channel(back, C)
+            dz = scaled * y * r * (s * (1.0 + z * (1.0 - s)))
+        else:
+            back = [t / n * ri * ri * ri
+                    for t, ri in zip(_group_sums(scaled * v, groups), r)]
+            r = _each_channel(r, C)
+            dv = scaled * r - v * _each_channel(back, C)
+            dy = dv * (z * s)
+            dz = dv * y * (s * (1.0 + z * (1.0 - s)))
         d_gamma = jnp.sum(g * v * r, axis=tuple(range(g.ndim - 1)))
     return (dy.astype(data.dtype), _in_columns(dz, gate_src, lo),
             d_gamma.astype(gamma.dtype))

@@ -171,6 +171,8 @@ SCOPES = {
                       "the transposes around them",
     "mx.attn.full": "the same of a plain causal (or not causal) layer",
     "mx.attn.qk_norm": "the per-head RMS norms of q and k",
+    "mx.attn.out_gate": "the sigmoid of a gated attention's gate and "
+                        "its product with the attention's output",
     "mx.rope": "the rotary embedding of q or k",
     "mx.ffn.dense": "a dense feed-forward's two products and the "
                     "activation between them, gated or not",
@@ -187,6 +189,12 @@ SCOPES = {
     "mx.sconv.in_proj": "a gated short convolution's input projection",
     "mx.sconv.conv": "what lies between its projections",
     "mx.sconv.out_proj": "its output projection",
+    "mx.gdn.in_proj": "a Gated DeltaNet mixer's two input projections",
+    "mx.gdn.conv": "its causal depthwise convolution and silu",
+    "mx.gdn.core": "its L2 norms, beta, decays and the chunked gated "
+                   "delta rule",
+    "mx.gdn.gate_norm": "its per-head norm, gain and silu gate",
+    "mx.gdn.out_proj": "its output projection",
 }
 SCOPE_CONTAINERS = frozenset({"mx.fwd"})
 

@@ -744,6 +744,12 @@ ORACLES.update({
     # the gated short convolution: [B | C | u] in, C * conv(B * u) out,
     # the K-term sum over a padded row; no bias, no activation
     "_contrib_gated_short_conv": lambda x, w: _np_gated_short_conv(x, w),
+    # the gated delta rule one position at a time, and the Gated DeltaNet
+    # mixer around it: convolution without a bias, L2 norms, the rule,
+    # the norm BEFORE the gate
+    "_contrib_gated_delta_rule": lambda *a, chunk=64:
+        _np_gated_delta_rule(*a),
+    "_contrib_gdn_mixer": lambda *a, **k: _np_gdn_mixer(*a, **k),
     # decode-path paged attention vs a per-sequence gather + dense
     # softmax (block-table indirection materialized in numpy)
     "_contrib_ragged_paged_attention": lambda q, kp, vp, bt, lens:
@@ -1047,6 +1053,41 @@ def _np_ssm_gate_norm(y, z, g, groups=1, eps=1e-5):
     return v.reshape(y.shape) * g
 
 
+def _np_gated_delta_rule(q, k, v, g, beta):
+    H = v.shape[2]
+    q, k = (np.repeat(a, H // a.shape[2], axis=2) for a in (q, k))
+    S = np.zeros(q.shape[:1] + (H, q.shape[-1], v.shape[-1]))
+    out = np.zeros(v.shape)
+    for t in range(q.shape[1]):
+        S = np.exp(g[:, t])[..., None, None] * S
+        pred = np.einsum("bhkv,bhk->bhv", S, k[:, t])
+        S = S + np.einsum("bhk,bhv->bhkv", k[:, t],
+                          beta[:, t, :, None] * (v[:, t] - pred))
+        out[:, t] = np.einsum("bhkv,bhk->bhv", S, q[:, t])
+    return out
+
+
+def _np_gdn_mixer(qkvz, ba, cw, A_log, dtb, gamma, key_heads, value_heads,
+                  key_dim, value_dim, chunk=64, eps=1e-6):
+    Hk, H, dk, dv = key_heads, value_heads, key_dim, value_dim
+    b, L, _ = qkvz.shape
+    width = 2 * Hk * dk + H * dv
+    qkv = _np_ssm_conv(qkvz[..., :width], cw, np.zeros(width))
+
+    def l2(x):
+        return x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    q = l2(qkv[..., :Hk * dk].reshape(b, L, Hk, dk)) / np.sqrt(dk)
+    k = l2(qkv[..., Hk * dk:2 * Hk * dk].reshape(b, L, Hk, dk))
+    v = qkv[..., 2 * Hk * dk:].reshape(b, L, H, dv)
+    beta = 1 / (1 + np.exp(-ba[..., :H]))
+    g = -np.exp(A_log) * np.log1p(np.exp(ba[..., H:] + dtb))
+    o = _np_gated_delta_rule(q, k, v, g, beta)
+    o = o / np.sqrt((o * o).mean(-1, keepdims=True) + eps) * gamma
+    z = qkvz[..., width:].reshape(b, L, H, dv)
+    return (o * _np_silu(z)).reshape(b, L, H * dv)
+
+
 def _np_ssm_mixer(data, cw, cb, dtb, A_log, D, g, num_heads, head_dim,
                   n_groups, state_size, chunk=128, eps=1e-5):
     H, P, G, N = num_heads, head_dim, n_groups, state_size
@@ -1268,6 +1309,21 @@ SPECS = {
         inputs=lambda r: [_f32(r, 1, 7, 22), _f32(r, 14, 4), _f32(r, 14),
                           _f32(r, 2), _f32(r, 2), _f32(r, 2), _pos(r, 6)],
         kwargs=dict(num_heads=2, head_dim=3, n_groups=1, state_size=4,
+                    chunk=3)),
+    # q, k (b, L, Hk, dk), v (b, L, H, dv), g (<= 0) and beta (b, L, H):
+    # 4 value heads over 2 key heads, 7 positions in chunks of 3
+    "_contrib_gated_delta_rule": dict(
+        inputs=lambda r: [0.5 * _f32(r, 1, 7, 2, 3), 0.5 * _f32(r, 1, 7, 2, 3),
+                          _f32(r, 1, 7, 4, 2), -0.3 * _pos(r, 1, 7, 4),
+                          0.3 * _pos(r, 1, 7, 4)],
+        kwargs=dict(chunk=3)),
+    # [q | k | v | z] of widths 3 | 3 | 4 | 4 (a key head of 3, two value
+    # heads of 2), [b | a], taps (10, 4), A_log, dt_bias, the gain (2,)
+    "_contrib_gdn_mixer": dict(
+        inputs=lambda r: [_f32(r, 1, 7, 14), _f32(r, 1, 7, 4),
+                          0.5 * _f32(r, 10, 4), 0.3 * _f32(r, 2),
+                          _f32(r, 2), _pos(r, 2)],
+        kwargs=dict(key_heads=1, value_heads=2, key_dim=3, value_dim=2,
                     chunk=3)),
     "_contrib_rms_norm": dict(inputs=lambda r: [_f32(r, 2, 3, 8),
                                                 _pos(r, 8)]),

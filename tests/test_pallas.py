@@ -65,13 +65,15 @@ def test_flash_nondivisible_seq_padding():
 # 2.5 sub-tiles crosses blocks and sub-tiles off their edges; 1, 4 and 16
 # query heads a key/value head; with lengths (one a key/value head, as
 # the ops hand them over) every row keeps a key in its window (a row
-# with none is garbage in, garbage out, as before).
+# with none is garbage in, garbage out, as before).  Heads of 256 at 8
+# query heads a key/value head are Qwen3-Next's gated attention's.
 @pytest.mark.parametrize("causal,window,group,D,lens,L,block", [
     (True, None, 1, 16, (48, 20, 48, 9), 48, 16),
     (True, None, 1, 64, None, 96, 32),
     (True, None, 4, 128, (89, 96), 96, 32),
     (True, None, 16, 64, (73,), 80, 32),
     (True, None, 16, 128, None, 96, 32),
+    (True, None, 8, 256, None, 96, 32),
     (True, 40, 1, 128, None, 96, 32),
     (True, 40, 4, 64, (89, 96), 96, 32),
     (True, 40, 16, 128, None, 80, 32),

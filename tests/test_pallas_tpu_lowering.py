@@ -73,6 +73,23 @@ def test_flash_kernels_lower_at_the_cells_shapes(hkv, d, window, mode):
     assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
 
 
+# Qwen3-Next's gated attention: 16 query heads of 256 over 2 key/value
+# heads, 8 a group
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_flash_kernels_lower_at_heads_of_256(mode):
+    q, kv = S((16, 8192, 256), jnp.bfloat16), S((2, 8192, 256), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _tpu_module_text(fn, q, kv, kv)
+    assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
+
+
 # the shapes chip_smoke.py's serve leg runs: GPT-2-small heads over a
 # 513-page pool of 16-token pages, 8 slots, 64 pages per sequence; and
 # the wider H=16, D=128 head shape
@@ -202,9 +219,13 @@ def test_selective_scan_lowers_for_tpu(b, L, G, R, P, N, Q, mode):
 # groups, the gate at the array's start) and at a small shape with
 # other blocks: a pass forward, a pass backward.  The convolution's
 # taps are sublane-offset reads of a VMEM scratch, its backward fetches
-# the slab before a block through a second block spec.
+# the slab before a block through a second block spec.  Qwen3-Next's
+# Gated DeltaNet: the convolution's 8192 channels at the start of the
+# in-projection's 12288, no bias; the norm before the gate over 32
+# groups of 128, the gate 8192 columns in.
 @pytest.mark.parametrize("b,L,C,K,lo,wide", [(1, 8192, 6144, 4, 4096, 10304),
-                                             (2, 384, 256, 9, 0, 256)])
+                                             (2, 384, 256, 9, 0, 256),
+                                             (1, 8192, 8192, 4, 0, 12288)])
 @pytest.mark.parametrize("mode", ["fwd", "bwd"])
 def test_conv_pass_lowers_for_tpu(b, L, C, K, lo, wide, mode):
     f32 = jnp.float32
@@ -220,18 +241,19 @@ def test_conv_pass_lowers_for_tpu(b, L, C, K, lo, wide, mode):
     assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("b,L,C,groups,lo,wide", [
-    (1, 8192, 4096, 8, 0, 10304), (2, 384, 256, 1, 256, 640)])
+@pytest.mark.parametrize("b,L,C,groups,lo,wide,before", [
+    (1, 8192, 4096, 8, 0, 10304, False), (2, 384, 256, 1, 256, 640, False),
+    (1, 8192, 4096, 32, 8192, 12288, True)])
 @pytest.mark.parametrize("mode", ["fwd", "bwd"])
-def test_norm_pass_lowers_for_tpu(b, L, C, groups, lo, wide, mode):
+def test_norm_pass_lowers_for_tpu(b, L, C, groups, lo, wide, before, mode):
     f32 = jnp.float32
     avals = (S((b, L, C), f32), S((b, L, wide), f32), S((1, C), f32))
     if mode == "fwd":
         def fn(*a):
-            return ssm_norm_pass(*a, groups, 1e-5, lo, False)
+            return ssm_norm_pass(*a, groups, 1e-5, lo, False, before)
     else:
         avals += (S((b, L, C), f32),)
 
         def fn(*a):
-            return ssm_norm_pass_grads(*a, groups, 1e-5, lo, False)
+            return ssm_norm_pass_grads(*a, groups, 1e-5, lo, False, before)
     assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1

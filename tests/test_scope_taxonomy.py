@@ -22,7 +22,8 @@ sys.path.insert(0, REPO)
 CELLS = ["bert-large.pretrain_b32_l128",
          "mellum2-12b-a2.5b.causal_b1_l8192",
          "nemotron-3-nano-30b-a3b.causal_b1_l8192",
-         "lfm2-24b-a2b.causal_b1_l8192"]
+         "lfm2-24b-a2b.causal_b1_l8192",
+         "qwen3-next-80b-a3b.causal_b1_l8192"]
 # what each family's toy step must carry besides mx.loss and mx.optim
 FAMILY = {
     CELLS[0]: {"mx.embed", "mx.norm", "mx.head", "mx.attn.proj",
@@ -38,6 +39,12 @@ FAMILY = {
                "mx.attn.full", "mx.attn.qk_norm", "mx.rope", "mx.ffn.dense",
                "mx.moe.route", "mx.moe.experts", "mx.sconv.in_proj",
                "mx.sconv.conv", "mx.sconv.out_proj"},
+    CELLS[4]: {"mx.embed", "mx.norm", "mx.head", "mx.attn.proj",
+               "mx.attn.full", "mx.attn.qk_norm", "mx.rope",
+               "mx.attn.out_gate", "mx.moe.route", "mx.moe.dispatch",
+               "mx.moe.experts", "mx.moe.combine", "mx.moe.shared",
+               "mx.gdn.in_proj", "mx.gdn.conv", "mx.gdn.core",
+               "mx.gdn.gate_norm", "mx.gdn.out_proj"},
 }
 LEAVES = set(tracing.SCOPES) - tracing.SCOPE_CONTAINERS
 SCOPE = re.compile(r"(?:^|[/(])(mx\.[\w.]+)(?=[/):]|$)")
@@ -65,7 +72,48 @@ def lowered(request):
         r'^#(loc\d+) = loc\("((?:[^"\\]|\\.)*)"', text, re.M)
         if v.startswith("jit(mx_train_step)")}
     dots = re.findall(r"stablehlo\.dot_general .* loc\(#(loc\d+)\)", text)
-    return request.param, names, dots
+    return request.param, {**_loop_names(text, names), **names}, dots
+
+
+def _loop_names(text, names):
+    """{location id: the name of what its operation lies in} for the
+    operations whose own name is relative: those in a loop's body (the
+    ``while``, printed after its regions as ``} loc(#locN)``, carries the
+    whole name) and those in a private function (a scan's outlined body:
+    its call carries the name, or in turn lies in a loop).  The compiled
+    program's metadata composes the two (``.../mx.gdn.core/while/body/
+    closed_call/...``), which is what the device trace shows."""
+    opened, calls, depth = [], {}, 0
+    every = []
+    for line in text.splitlines():
+        func = re.match(r"\s*func\.func private @([\w.$-]+)\(", line)
+        if func or "stablehlo.while" in line:
+            box = {"depth": depth, "ids": [], "func": func and func.group(1),
+                   "outer": opened[-1] if opened else None, "anchor": None}
+            opened.append(box)
+            every.append(box)
+        depth += line.count("{") - line.count("}")
+        ids = re.findall(r"loc\(#(loc\d+)\)", line)
+        call = re.search(r"call @([\w.$-]+)\(", line)
+        if call and ids:
+            calls.setdefault(call.group(1), (ids[-1], opened[-1] if opened
+                                             else None))
+        if opened and depth == opened[-1]["depth"] and line.strip() \
+                .startswith("}"):
+            box = opened.pop()
+            box["anchor"] = (ids[-1] if ids else None, box["outer"])
+        elif opened:
+            opened[-1]["ids"].extend(ids)
+
+    def named(loc, box):
+        return names.get(loc) or (box_name(box) if box else "")
+
+    def box_name(box):
+        where = calls.get(box["func"]) if box["func"] else box["anchor"]
+        return named(*where) if where and where[0] else ""
+
+    return {loc: box_name(box) for box in every for loc in box["ids"]
+            if loc not in names}
 
 
 def test_every_component_is_in_the_table(lowered):
