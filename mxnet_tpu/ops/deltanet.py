@@ -30,20 +30,51 @@ and the chunks are walked in order with the state carried.  Decays are
 exponentials, which underflows); decays, state and sums are float32, the
 other products take the backend's default precision.
 
-``gated_delta_rule`` is one ``custom_vjp`` that keeps its five operands
-between the passes and nothing else: the backward pass computes the
-chunks again (behind an ``optimization_barrier``, so that XLA does not
-merge them with the forward's and keep the forward's states alive) and
-differentiates that.  So one layer's per-chunk states exist only inside
-its own backward pass.
+The rule is one ``custom_vjp`` (``_rule``) over x (b, L, [q | k | v]),
+g and beta that keeps those three operands between the passes and
+nothing else.  Its backward, a chunk at a time from the last, with ``S``
+the state entering the chunk, ``dS'`` the gradient of the one leaving
+it, ``f = e^{gamma_Q - gamma}``, ``P = Q K^T * D``, ``T = (I + A)^-1``,
+``W = T diag(beta e^gamma) K``, ``Un = T diag(beta) V`` and ``U = Un -
+W S``:
+
+    dU = P^T dO + diag(f) K dS'
+    dS = e^{gamma_Q} dS' + (diag(e^gamma) Q)^T dO - W^T dU
+    dT = -dU S^T (diag(beta e^gamma) K)^T + dU (diag(beta) V)^T
+    dA = -T^T dT T^T on the strictly lower part (one product pair)
+    dQ = diag(e^gamma) dO S^T + (dO U^T * D) K
+    dK = (dO U^T * D)^T Q + diag(f) U dS'^T
+         + diag(beta e^gamma) T^T dW + (dM + dM^T) K,  dW = -dU S^T,
+         dM = diag(beta) dA * D_<  (D_< the strictly lower decays)
+    dV = diag(beta) T^T dU
+
+and beta's and gamma's gradients the row (and, for gamma_j, column)
+sums of the same elementwise products; g's is gamma's reverse running
+sum inside the chunk.
+
+Shapes that ``deltanet_kernels.gdn_chunk_tiles`` takes (chunks a multiple
+of 8, keys and values a multiple of 128 wide: Qwen3-Next's chunks of 64
+over 16 key heads of 128 and two value heads of 128 each) run that
+backward as Pallas kernels, and the forward too, the state in VMEM from
+chunk to chunk and every (Q, Q) matrix made and used there; the
+inverse there is a bfloat16 product form refined by two Newton steps
+to float32 accuracy, and q and k are read as x's raw columns and
+L2-normed in the kernels.  Every other shape (the tests' models, chunks
+of 16 and heads of 8) runs ``_chunked`` in ``jnp`` inside the same
+``custom_vjp``: the backward pass computes the chunks again (behind an
+``optimization_barrier``, so that XLA does not merge them with the
+forward's and keep the forward's states alive) and differentiates that.
+Either way one layer's per-chunk states exist only inside its own
+backward pass.
 
 ``gdn_mixer`` is the mixer between its projections, from the
 in-projection's [q | k | v | z] result and the (b | a) one: the causal
 depthwise convolution without a bias and its silu over [q | k | v]
-(``ssm._conv_silu``, reading its columns in place), the L2 norms, beta
-and g, the rule, then the norm of each value head's output times one
-gain shared by the heads, times ``silu(z)`` (``ssm._gate_norm`` with the
-norm BEFORE the gate, reading z in place).
+(``ssm._conv_silu``, reading its columns in place), beta and g, the
+rule over the convolution's result as it lies (q and k L2-normed inside
+it), then the norm of each value head's output times one gain shared by
+the heads, times ``silu(z)`` (``ssm._gate_norm`` with the norm BEFORE
+the gate, reading z in place).
 
 Ops:
   ``gated_delta_rule`` — q, k (b, L, Hk, dk), v (b, L, H, dv), g, beta
@@ -58,6 +89,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .deltanet_kernels import (L2_EPS, gdn_chunk_tiles, gdn_chunks,
+                               gdn_chunks_grads)
 from .registry import register
 from .ssm import _conv_silu, _gate_norm, _whole_chunks
 
@@ -127,21 +160,64 @@ def _chunked(q, k, v, g, beta, Q):
     return jnp.moveaxis(o, (0, 4), (1, 2)).reshape(b, L, G, R, dv)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule(q, k, v, g, beta, Q):
-    return _chunked(q, k, v, g, beta, Q)
+def _split(x, g, layout, normed):
+    """q, k (b, L, Hk, dk) and v (b, L, Hk, R, dv) from x (b, L, [q | k
+    | v]); with ``normed`` q and k L2-normed a head, q over sqrt(dk)."""
+    Hk, dk, dv = layout
+    b, L, _ = x.shape
+    q = x[..., :Hk * dk].reshape(b, L, Hk, dk)
+    k = x[..., Hk * dk:2 * Hk * dk].reshape(b, L, Hk, dk)
+    v = x[..., 2 * Hk * dk:].reshape(b, L, Hk, g.shape[-1], dv)
+    if normed:
+        q, k = _l2_normed(q) * dk ** -0.5, _l2_normed(k)
+    return q, k, v
 
 
-def _rule_fwd(q, k, v, g, beta, Q):
-    return _chunked(q, k, v, g, beta, Q), (q, k, v, g, beta)
+def _kernels(g, Q, layout):
+    """Whether the Pallas kernels take the rule in chunks of Q, g
+    (b, L, Hk, R), ``layout`` (Hk, dk, dv)."""
+    Hk, dk, dv = layout
+    return gdn_chunk_tiles(Q, dk, dv, g.shape[-1], Hk)
 
 
-def _rule_bwd(Q, res, do):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rule(x, g, beta, Q, layout, normed):
+    return _rule_fwd(x, g, beta, Q, layout, normed)[0]
+
+
+def _rule_fwd(x, g, beta, Q, layout, normed):
+    if _kernels(g, Q, layout):
+        o = gdn_chunks(x, g, beta, Q, layout, normed)
+    else:
+        o = _chunked(*_split(x, g, layout, normed), g, beta, Q)
+    return o, (x, g, beta)
+
+
+def _rule_bwd(Q, layout, normed, res, do):
+    if _kernels(res[1], Q, layout):
+        # the backward's operations carry the scope's name too (a
+        # transposed custom_vjp opens none)
+        with jax.named_scope("mx.gdn.core"):
+            return gdn_chunks_grads(*res, do, Q, layout, normed)
     res = lax.optimization_barrier(res)
-    return jax.vjp(lambda *a: _chunked(*a, Q), *res)[1](do)
+    return jax.vjp(lambda x, g, beta: _chunked(
+        *_split(x, g, layout, normed), g, beta, Q), *res)[1](do)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _run(x, g, beta, layout, chunk, normed):
+    """The rule over x (b, L, [q | k | v]), g and beta (b, L, H), in
+    float32 in chunks of ``chunk``, the last one filled with positions
+    that neither decay nor write.  Returns o (b, L, H dv) float32."""
+    Hk, _, dv = layout
+    b, L, H = g.shape
+    Q = int(chunk)
+    o = _rule(*(_whole_chunks(a.astype(jnp.float32), Q) for a in (
+        x, g.reshape(b, L, Hk, H // Hk), beta.reshape(b, L, Hk, H // Hk))),
+        Q, layout, normed)
+    return o[:, :L].reshape(b, L, H * dv)
 
 
 @register("_contrib_gated_delta_rule", num_inputs=5,
@@ -159,15 +235,14 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
         from ..base import MXNetError
         raise MXNetError(f"gated_delta_rule: {Hk} key heads do not divide "
                          f"{H} value heads")
-    R, Q = H // Hk, int(chunk)
     f32 = jnp.float32
-    o = _rule(*(_whole_chunks(a.astype(f32), Q) for a in (
-        q, k, v.reshape(b, L, Hk, R, dv), g.reshape(b, L, Hk, R),
-        beta.reshape(b, L, Hk, R))), Q)
-    return o[:, :L].reshape(b, L, H, dv).astype(v.dtype)
+    x = jnp.concatenate([a.astype(f32).reshape(b, L, -1) for a in (q, k, v)],
+                        axis=2)
+    o = _run(x, g, beta, (Hk, dk, dv), chunk, False)
+    return o.reshape(b, L, H, dv).astype(v.dtype)
 
 
-def _l2_normed(x, eps=1e-6):
+def _l2_normed(x, eps=L2_EPS):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
@@ -192,15 +267,13 @@ def gdn_mixer(qkvz, ba, conv_weight, A_log, dt_bias, gamma, *,
     with jax.named_scope("mx.gdn.conv"):
         qkv = _conv_silu(qkvz, conv_weight, None, 0)
     with jax.named_scope("mx.gdn.core"):
-        q = _l2_normed(qkv[..., :Hk * dk].astype(f32).reshape(b, L, Hk, dk))
-        k = _l2_normed(qkv[..., Hk * dk:2 * Hk * dk].astype(f32)
-                       .reshape(b, L, Hk, dk))
-        v = qkv[..., 2 * Hk * dk:].reshape(b, L, H, dv)
         ba = ba.astype(f32)
         beta = jax.nn.sigmoid(ba[..., :H])
         g = -jnp.exp(A_log.astype(f32)) * jax.nn.softplus(
             ba[..., H:] + dt_bias.astype(f32))
-        o = gated_delta_rule(q * dk ** -0.5, k, v, g, beta, chunk=chunk)
+        # q, k and v are read where the convolution left them, q and k
+        # L2-normed inside the rule
+        o = _run(qkv, g, beta, (Hk, dk, dv), chunk, True).astype(qkv.dtype)
     with jax.named_scope("mx.gdn.gate_norm"):
-        return _gate_norm(o.reshape(b, L, H * dv), qkvz,
+        return _gate_norm(o, qkvz,
                           jnp.tile(gamma, H), H, float(eps), width, True)

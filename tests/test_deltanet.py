@@ -2,10 +2,13 @@
 and the pieces Qwen3-Next added to shared operators, on the CPU at small
 sizes: the chunked rule against the recurrence it stands for, forward
 and every gradient, at two chunk sizes and a length that is no multiple
-of either; the mixer against plain expressions; the gate-norm's norm
-before the gate and the convolution without a bias, in ``jnp`` and in
-the Pallas passes (the interpreter); partial rotary; the attention's
-output gate; the shared expert's gate."""
+of either; the Pallas kernels (the interpreter) at a shape they tile,
+forward, sweep and backward against ``_chunked`` and its autodiff, and
+their inverses against a float64 solve; the mixer against plain
+expressions; the gate-norm's norm before the gate and the convolution
+without a bias, in ``jnp`` and in the Pallas passes (the interpreter);
+partial rotary; the attention's output gate; the shared expert's
+gate."""
 from unittest import mock
 
 import numpy as np
@@ -14,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.ops import deltanet, pallas_kernels, ssm
+from mxnet_tpu.ops import deltanet, deltanet_kernels, pallas_kernels, ssm
 from mxnet_tpu.ops.contrib import rope
 from mxnet_tpu.ops.deltanet import gated_delta_rule, gdn_mixer
 
@@ -95,16 +98,19 @@ def test_two_chunk_sizes_agree_and_the_decay_and_the_step_matter():
 
 def test_the_rule_keeps_its_operands_and_nothing_else():
     """What the ``custom_vjp`` carries from its forward to its backward
-    pass is its five operands: no state of any chunk."""
-    ops = [a.reshape(a.shape[:2] + (HK, -1) + a.shape[3:])
-           if i in (2, 3, 4) else a for i, a in enumerate(_operands(64))]
-    _, kept = deltanet._rule_fwd(*ops, 16)
-    assert len(kept) == 5 and all(a is b for a, b in zip(kept, ops))
+    pass is its three operands, x = [q | k | v], g and beta: no state of
+    any chunk."""
+    q, k, v, g, beta = _operands(64)
+    b, L = q.shape[:2]
+    ops = (jnp.concatenate([a.reshape(b, L, -1) for a in (q, k, v)], 2),
+           g.reshape(b, L, HK, -1), beta.reshape(b, L, HK, -1))
+    _, kept = deltanet._rule_fwd(*ops, 16, (HK, DK, DV), False)
+    assert len(kept) == 3 and all(a is b for a, b in zip(kept, ops))
     _, vjp = jax.vjp(lambda *a: gated_delta_rule(*a, chunk=16),
                      *_operands(64))
     held = sorted(a.size for a in jax.tree_util.tree_leaves(vjp)
                   if hasattr(a, "shape") and a.size > 1)
-    assert max(held) <= 2 * 64 * HV * DV
+    assert max(held) <= 2 * 64 * (2 * HK * DK + HV * DV)      # x
 
 
 def test_the_inverse_is_the_triangular_solve():
@@ -115,6 +121,159 @@ def test_the_inverse_is_the_triangular_solve():
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(np.asarray((eye + A) @ inv),
                                    np.asarray(eye), atol=1e-5)
+
+
+# ------------------------------------------- the Pallas kernels (interpreter)
+KH, KR, KD, KQ, KL = 2, 2, 128, 64, 256     # a shape the kernels tile
+KERNEL_LAYOUT = (KH, KD, KD)
+
+
+def _kernel_operands(seed, g_scale=0.5, beta_shift=0.0):
+    """x = [q | k | v] (1, KL, .) with q and k raw (the kernels norm
+    them), g and beta (1, KL, KH, KR)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.normal(ks[0], (1, KL, (2 * KH + KH * KR) * KD))
+    g = -g_scale * jnp.abs(jax.random.normal(ks[1], (1, KL, KH, KR)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[2], (1, KL, KH, KR))
+                          + beta_shift)
+    w = jax.random.normal(ks[3], (1, KL, KH, KR, KD))
+    return x, g, beta, w
+
+
+def _chunked_form(normed):
+    return lambda x, g, beta: deltanet._chunked(
+        *deltanet._split(x, g, KERNEL_LAYOUT, normed), g, beta, KQ)
+
+
+def _float32_products():
+    """The kernels with every product at float32 accuracy: their
+    mathematics, apart from the bfloat16 operands XLA's default gives
+    the ``jnp`` form on the chip."""
+    kernels = deltanet_kernels
+    return mock.patch.object(kernels, "_default", kernels._exact)
+
+
+@pytest.mark.parametrize("normed,g_scale,beta_shift", [
+    (False, 0.5, 0.0), (True, 1e-3, 0.0), (True, 8.0, 0.0),
+    (True, 0.5, -6.0), (True, 0.5, 6.0)],
+    ids=["given_q_k", "g_near_0", "g_strongly_negative", "beta_near_0",
+         "beta_near_1"])
+def test_kernels_are_the_chunked_rule(normed, g_scale, beta_shift):
+    """o and the gradients in x, g and beta: the forward kernel, the
+    sweep and the backward walk against autodiff of ``_chunked``."""
+    x, g, beta, w = _kernel_operands(1, g_scale, beta_shift)
+    if not normed:              # q and k given as unit rows
+        x = jnp.concatenate([a.reshape(1, KL, -1) for a in deltanet._split(
+            x, g, KERNEL_LAYOUT, True)], axis=2)
+    assert deltanet._kernels(g, KQ, KERNEL_LAYOUT)
+    kernels = deltanet_kernels
+    with _float32_products():
+        o = kernels.gdn_chunks(x, g, beta, KQ, KERNEL_LAYOUT, normed)
+        grads = kernels.gdn_chunks_grads(x, g, beta, w, KQ, KERNEL_LAYOUT,
+                                         normed)
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(_chunked_form(normed), x, g, beta)
+        want_grads = vjp(w)
+    assert o.shape == want.shape and _gap(o, want) < 1e-5
+    for name, a, b in zip(("x", "g", "beta"), grads, want_grads):
+        assert a.shape == b.shape, name
+        assert _gap(a, b) < 1e-5, name
+
+
+def test_kernels_with_bfloat16_operands_stay_near_the_rule():
+    """As the chip runs them: a product's operands rounded to bfloat16
+    (the inverse's and its gradient's excepted)."""
+    x, g, beta, w = _kernel_operands(2)
+    kernels = deltanet_kernels
+    o = kernels.gdn_chunks(x, g, beta, KQ, KERNEL_LAYOUT, True)
+    grads = kernels.gdn_chunks_grads(x, g, beta, w, KQ, KERNEL_LAYOUT, True)
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(_chunked_form(True), x, g, beta)
+        want_grads = vjp(w)
+    assert 1e-5 < _gap(o, want) < 1e-2
+    for name, a, b in zip(("x", "g", "beta"), grads, want_grads):
+        assert _gap(a, b) < 1e-2, name
+
+
+def test_the_sweep_carries_the_states_and_the_chunks_inverses():
+    """The states entering the chunks are the recurrence's at the
+    chunks' starts; each chunk's T is ``(I + A)^-1`` to float32
+    accuracy with the products the chip takes (a first guess in
+    bfloat16, two Newton steps)."""
+    x, g, beta, _ = _kernel_operands(3)
+    kernels = deltanet_kernels
+    cols, rows = kernels._vectors(g, beta, KQ)
+    run = kernels._fwd(x, cols, rows, KQ, KERNEL_LAYOUT, True, True, True)
+    with _float32_products():
+        states, *_ = kernels._fwd(x, cols, rows, KQ, KERNEL_LAYOUT, True, True,
+                                 True)
+    _, inverses, transposed = run
+    q, k, v = deltanet._split(x, g, KERNEL_LAYOUT, True)
+    H = KH * KR
+    with jax.default_matmul_precision("highest"):
+        q, k = (jnp.repeat(a, KR, axis=2) for a in (q, k))
+
+        def step(S, at):
+            kt, vt, gt, bt = at
+            S = jnp.exp(gt)[..., None, None] * S
+            S = S + jnp.einsum("bhk,bhv->bhkv", kt, bt[..., None] * (
+                vt - jnp.einsum("bhkv,bhk->bhv", S, kt)))
+            return S, S
+
+        _, after = jax.lax.scan(step, jnp.zeros((1, H, KD, KD)), (
+            jnp.moveaxis(k, 1, 0),
+            jnp.moveaxis(v.reshape(1, KL, H, KD), 1, 0),
+            *(jnp.moveaxis(a.reshape(1, KL, H), 1, 0) for a in (g, beta))))
+    entering = jnp.concatenate([jnp.zeros_like(after[:1]),
+                                after[KQ - 1:-1:KQ]])       # (nc, 1, H, ..)
+    want = jnp.moveaxis(entering, 0, 1).reshape(1, KL // KQ, KH, KR, KD, KD)
+    assert _gap(states, jnp.swapaxes(want, 1, 2)) < 1e-5
+    # A from the kernel's own operands: K K^T of k rounded to bfloat16
+    kb = np.asarray(k[:, :, ::KR].astype(jnp.bfloat16).astype(jnp.float32),
+                    np.float64).reshape(KL // KQ, KQ, KH, KD)
+    gam = np.asarray(rows, np.float64)[0]                   # (KH, nc, KR, Q)
+    bt = np.asarray(beta, np.float64)[0].reshape(KL // KQ, KQ, KH, KR)
+    below = np.tri(KQ, k=-1, dtype=bool)
+    T = np.asarray(inverses, np.float64)[0]                 # (KH, nc, KR, ..)
+    worst = 0.0
+    for h in range(KH):
+        for c in range(KL // KQ):
+            kk = kb[c, :, h] @ kb[c, :, h].T
+            for r in range(KR):
+                diff = gam[h, c, r][:, None] - gam[h, c, r][None, :]
+                A = bt[c, :, h, r][:, None] * kk * np.where(
+                    below, np.exp(np.where(below, diff, 0.0)), 0.0)
+                want = np.linalg.inv(np.eye(KQ) + A)
+                worst = max(worst, np.linalg.norm(T[h, c, r] - want)
+                            / np.linalg.norm(want))
+    assert worst < 1e-6
+
+
+def test_the_predicate_takes_the_cells_shape_and_not_the_tests_models():
+    tiles = deltanet_kernels.gdn_chunk_tiles
+    assert tiles(64, 128, 128, 2, 16)       # Qwen3-Next: chunks of 64
+    assert tiles(KQ, KD, KD, KR, KH)
+    assert not tiles(16, 8, 8, 2, 2)        # the tests' model
+    assert not tiles(16, DK, DV, HV // HK, HK)
+    assert not tiles(60, 128, 128, 2, 16)   # chunks no multiple of 8
+    assert not tiles(64, 128, 96, 2, 16)    # values no multiple of 128
+    ops = _operands(64)
+    calls = []
+    kernels = deltanet_kernels
+    with mock.patch.object(deltanet, "gdn_chunks",
+                           lambda *a: calls.append(a) or kernels.gdn_chunks(
+                               *a)):
+        gated_delta_rule(*ops, chunk=16)
+        assert not calls                    # the tests' shapes: jnp
+        x, g, beta, _ = _kernel_operands(4)
+        q, k, v = deltanet._split(x, g, KERNEL_LAYOUT, True)
+        o = gated_delta_rule(q, k, v.reshape(1, KL, KH * KR, KD),
+                             g.reshape(1, KL, -1), beta.reshape(1, KL, -1),
+                             chunk=KQ)
+        assert len(calls) == 1
+    with jax.default_matmul_precision("highest"):
+        want = _chunked_form(True)(x, g, beta)
+    assert _gap(o, want.reshape(o.shape)) < 1e-2
 
 
 # ----------------------------------------------------------------- the mixer
