@@ -1,8 +1,9 @@
 """Present-day decoder-only language models for training: RMSNorm,
 rotary positions, grouped key/value heads, window and full causal
 layers through the flash kernels (with an output gate and partial
-rotary where the model has them), gated short convolutions or Gated
-DeltaNet linear attention, each before a dense or sparse feed-forward,
+rotary where the model has them), latent attention, gated short
+convolutions, Gated DeltaNet or Kimi Delta Attention linear attention,
+each before a dense or sparse feed-forward,
 and hybrid models whose layers are one mixer each (Mamba-2, attention
 without rotary, an expert layer with a shared expert).
 One ``DecoderCell`` class; ``layer_types`` says what each layer is.
@@ -19,6 +20,7 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.contrib.moe import MoEFFN
 from .transformer_blocks import (DecoderCell, GatedDeltaNetMixer, GatedFFN,
+                                 KimiDeltaMixer, LatentAttention,
                                  Mamba2Mixer, RMSNorm,
                                  RotaryGroupedAttention, ShortConvMixer)
 
@@ -29,12 +31,15 @@ class DecoderLM(HybridBlock):
     """``lm(tokens (B, L)) -> logits (B, L, vocab_size)``.
 
     ``layer_types``, a layer each.  "sliding_attention" /
-    "full_attention" / "conv" / "linear_attention": attention, a gated
-    short convolution of ``conv_kernel`` taps (``ShortConvMixer``) or a
-    Gated DeltaNet mixer of the sizes in ``gdn``
-    (``GatedDeltaNetMixer``'s keyword arguments), then a feed-forward,
-    each under its norm; ``rope``: the ``rope`` op's keyword arguments
-    for each of the two attentions; ``qk_norm_eps``, ``rotary_dim`` and
+    "full_attention" / "conv" / "linear_attention" / "kda" / "mla":
+    attention, a gated short convolution of ``conv_kernel`` taps
+    (``ShortConvMixer``), a Gated DeltaNet mixer of the sizes in ``gdn``
+    (``GatedDeltaNetMixer``'s keyword arguments), a Kimi Delta
+    Attention mixer of the sizes in ``kda`` (``KimiDeltaMixer``'s) or
+    latent attention of ``num_heads`` heads and the sizes in ``mla``
+    (``LatentAttention``'s), then a feed-forward, each under its norm;
+    ``rope``: the ``rope`` op's keyword arguments for each of the two
+    attentions; ``qk_norm_eps``, ``rotary_dim`` and
     ``attention_out_gate`` (its ``out_gate``):
     ``RotaryGroupedAttention``'s.  "mamba2" / "attention" / "moe": ONE
     mixer under one norm, a
@@ -71,7 +76,7 @@ class DecoderLM(HybridBlock):
                  shared_expert_hidden_size=0, mamba=None, conv_kernel=3,
                  qk_norm_eps=None, dense_ffn_layers=0, tie_embeddings=False,
                  gdn=None, rotary_dim=0, attention_out_gate=False,
-                 shared_expert_gate=False, **kwargs):
+                 shared_expert_gate=False, kda=None, mla=None, **kwargs):
         super().__init__(**kwargs)
         rope = rope or {}
         self.vocab_size, self.units = int(vocab_size), int(units)
@@ -106,7 +111,7 @@ class DecoderLM(HybridBlock):
                 with self.cells.name_scope():
                     prefix = f"layer{i}_"
                     if kind in ("sliding_attention", "full_attention",
-                                "conv", "linear_attention"):
+                                "conv", "linear_attention", "kda", "mla"):
                         sparse = num_experts and i >= dense_ffn_layers
                         blocks = (ShortConvMixer(units, conv_kernel,
                                                  prefix=prefix + "conv_")
@@ -114,6 +119,14 @@ class DecoderLM(HybridBlock):
                                   GatedDeltaNetMixer(
                                       units, prefix=prefix + "gdn_", **gdn)
                                   if kind == "linear_attention" else
+                                  KimiDeltaMixer(
+                                      units, prefix=prefix + "kda_", **kda)
+                                  if kind == "kda" else
+                                  LatentAttention(
+                                      units, num_heads,
+                                      compute_dtype=attention_dtype,
+                                      prefix=prefix + "mla_", **mla)
+                                  if kind == "mla" else
                                   attention(kind, prefix),
                                   experts(prefix) if sparse else
                                   GatedFFN(units, hidden_size,
@@ -207,6 +220,24 @@ _DECODER_CONFIGS = {
                  conv_kernel=4, chunk=64, norm_eps=1e-6),
         num_experts=512, experts_per_token=10, expert_hidden_size=512,
         shared_expert_hidden_size=512, shared_expert_gate=True),
+    # moonshotai Kimi-Linear-48B-A3B-Instruct, config.json (model_type
+    # kimi_linear): Kimi Delta Attention, and latent attention without
+    # rotary at layers 4, 8, .. 24 and 27 (counted from 1), each before
+    # a dense SwiGLU in the first layer and 256 SwiGLU experts of 1024
+    # (8 a token by sigmoid score, times 2.446) and a shared one after
+    "kimi_linear_48b_a3b": dict(
+        vocab_size=163840, units=2304,
+        layer_types=tuple("mla" if i + 1 in (4, 8, 12, 16, 20, 24, 27)
+                          else "kda" for i in range(27)),
+        num_heads=32, num_kv_heads=32, head_dim=128, rms_norm_eps=1e-5,
+        kda=dict(heads=32, head_dim=128, conv_kernel=4, chunk=64,
+                 norm_eps=1e-5),
+        mla=dict(qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+                 kv_lora_rank=512, latent_eps=1e-5),
+        hidden_size=9216, dense_ffn_layers=1,
+        num_experts=256, experts_per_token=8, expert_hidden_size=1024,
+        router=dict(scoring="sigmoid", route_scale=2.446),
+        shared_expert_hidden_size=1024),
 }
 
 

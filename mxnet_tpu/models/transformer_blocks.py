@@ -23,7 +23,8 @@ __all__ = ["PositionwiseFFN", "MultiHeadSelfAttention",
            "MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerDecoderLM",
            "RMSNorm", "GatedFFN", "RotaryGroupedAttention", "Mamba2Mixer",
-           "ShortConvMixer", "GatedDeltaNetMixer", "DecoderCell",
+           "ShortConvMixer", "GatedDeltaNetMixer", "KimiDeltaMixer",
+           "LatentAttention", "DecoderCell",
            "paged_lm_params", "paged_prefill", "paged_decode_step",
            "paged_verify", "paged_verify_batch"]
 
@@ -519,6 +520,119 @@ class GatedDeltaNetMixer(HybridBlock):
                         **self._sizes)
         with jax.named_scope("mx.gdn.out_proj"):
             return self.out_proj(y)
+
+
+class KimiDeltaMixer(HybridBlock):
+    """Kimi Delta Attention over (B, L, C) (Kimi Linear's linear
+    attention; ``ops/deltanet.py`` has the equations): ``qkv_proj``
+    gives [q | k | v] of ``heads * head_dim`` each, which pass a causal
+    depthwise convolution of ``conv_kernel`` taps without a bias and
+    silu; the decay a key channel comes from ``f_b_proj(f_a_proj(x))``
+    (rank ``head_dim``), beta from ``b_proj``, and the delta rule runs
+    in chunks of ``chunk``; each head's output is RMS-normed (one gain
+    of ``head_dim`` shared by the heads) and gated by ``sigmoid`` of
+    ``g_b_proj(g_a_proj(x))``, and ``out_proj`` returns to C.  No bias.
+    The projections' weights are ``(out, in)`` leaves of this block
+    (``Dense``'s layout and names): the ``kda_mixer`` op takes them, so
+    that it can compute the whole mixer again in the backward pass.
+    """
+
+    _PROJECTIONS = ("qkv_proj", "f_a_proj", "f_b_proj", "b_proj",
+                    "g_a_proj", "g_b_proj")
+
+    def __init__(self, units, heads, head_dim, conv_kernel=4, chunk=64,
+                 norm_eps=1e-5, **kwargs):
+        super().__init__(**kwargs)
+        width = heads * head_dim
+        self._sizes = dict(heads=heads, head_dim=head_dim, chunk=int(chunk),
+                           eps=norm_eps)
+        shapes = dict(zip(self._PROJECTIONS, (
+            (3 * width, units), (head_dim, units), (width, head_dim),
+            (heads, units), (head_dim, units), (width, head_dim))))
+        with self.name_scope():
+            for name, shape in shapes.items():
+                setattr(self, name, self.params.get(name + "_weight",
+                                                    shape=shape))
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(3 * width, conv_kernel))
+            self.A_log = self.params.get("A_log", shape=(heads,),
+                                         init="zeros")
+            self.dt_bias = self.params.get("dt_bias", shape=(width,),
+                                           init="zeros")
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(head_dim,), init="ones")
+            self.out_proj = self.params.get("out_proj_weight",
+                                            shape=(units, width))
+
+    def hybrid_forward(self, F, x, qkv_proj, f_a_proj, f_b_proj, b_proj,
+                       g_a_proj, g_b_proj, conv_weight, A_log, dt_bias,
+                       norm_gamma, out_proj):
+        return F.kda_mixer(x, qkv_proj, f_a_proj, f_b_proj, b_proj,
+                           g_a_proj, g_b_proj, conv_weight, A_log, dt_bias,
+                           norm_gamma, out_proj, **self._sizes)
+
+
+class LatentAttention(HybridBlock):
+    """Multi-head latent attention over (B, L, C) without rotary (Kimi
+    Linear's global layers; DeepSeek-V2's MLA with ``q_lora_rank`` none
+    and no position): ``q_proj`` gives each of ``num_heads`` heads
+    [q_nope | q_pe] of ``qk_nope_dim + qk_rope_dim``; ``kv_a_proj`` the
+    latent c (``kv_lora_rank``) and one ``k_pe`` (``qk_rope_dim``)
+    shared by the heads; ``kv_b_proj`` of ``RMSNorm(c)`` gives each head
+    [k_nope | v] of ``qk_nope_dim + v_head_dim``; head h attends with
+    ``k_h = [k_nope_h | k_pe]`` causally, scale ``(qk_nope_dim +
+    qk_rope_dim)^-1/2``, through the flash kernels at a v width that
+    differs from q's and k's; ``out_proj`` returns to C.  No bias.
+    ``compute_dtype``: what q, k and v are rounded to for the kernels.
+    """
+
+    def __init__(self, units, num_heads, qk_nope_dim, qk_rope_dim,
+                 v_head_dim, kv_lora_rank, latent_eps=1e-5,
+                 compute_dtype="bfloat16", **kwargs):
+        super().__init__(**kwargs)
+        self._sizes = (num_heads, qk_nope_dim, qk_rope_dim, v_head_dim,
+                       kv_lora_rank)
+        self._compute_dtype = compute_dtype
+
+        def dense(n, fan_in, prefix):
+            return nn.Dense(n, in_units=fan_in, use_bias=False,
+                            flatten=False, prefix=prefix)
+
+        with self.name_scope():
+            self.q_proj = dense(num_heads * (qk_nope_dim + qk_rope_dim),
+                                units, "q_proj_")
+            self.kv_a_proj = dense(kv_lora_rank + qk_rope_dim, units,
+                                   "kv_a_proj_")
+            self.kv_norm = RMSNorm(kv_lora_rank, latent_eps,
+                                   prefix="kv_norm_")
+            self.kv_b_proj = dense(num_heads * (qk_nope_dim + v_head_dim),
+                                   kv_lora_rank, "kv_b_proj_")
+            self.out_proj = dense(units, num_heads * v_head_dim,
+                                  "out_proj_")
+
+    def hybrid_forward(self, F, x):
+        B, L, _ = x.shape
+        H, nope, rope, dv, rank = self._sizes
+        cast = self._compute_dtype
+        with jax.named_scope("mx.attn.proj"):
+            q = self.q_proj(x).reshape((B, L, H, nope + rope))
+            ckv = self.kv_a_proj(x)
+        with jax.named_scope("mx.mla.latent"):
+            c = self.kv_norm(F.slice_axis(ckv, axis=-1, begin=0, end=rank))
+            k_pe = F.slice_axis(ckv, axis=-1, begin=rank, end=None)
+        with jax.named_scope("mx.attn.proj"):
+            kv = self.kv_b_proj(c).reshape((B, L, H, nope + dv))
+        with jax.named_scope("mx.mla.latent"):
+            v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
+            k = F.concat(
+                F.slice_axis(kv, axis=-1, begin=0, end=nope),
+                F.broadcast_to(k_pe.reshape((B, L, 1, rope)),
+                               shape=(B, L, H, rope)), dim=-1)
+            q, k, v = (F.cast(a, dtype=cast) for a in (q, k, v))
+        out = F.flash_attention(q, k, v, causal=True)
+        with jax.named_scope("mx.attn.proj"):
+            out = F.cast(out, dtype=str(x.dtype))
+            return self.out_proj(out.reshape((B, L, H * dv)))
 
 
 class DecoderCell(HybridBlock):

@@ -67,6 +67,39 @@ forward's and keep the forward's states alive) and differentiates that.
 Either way one layer's per-chunk states exist only inside its own
 backward pass.
 
+**A decay per key channel** (Kimi Delta Attention, Kimi Linear report,
+arXiv:2510.26692): ``g_t`` is a vector over the dk key channels and the
+state forgets row by row,
+
+    S_t = (I - beta_t k_t k_t^T) Diag(e^{g_t}) S_{t-1} + beta_t k_t v_t^T
+
+which is the rule above with the scalar ``e^{g_t}`` replaced by
+``Diag(e^{g_t})``.  The decay no longer comes out of a chunk's products
+as ``e^{gamma_t - gamma_j}``: ``A[t, j] = beta_t sum_d k_td k_jd
+e^{Gamma_td - Gamma_jd}`` is a contraction with an exponential inside
+it, and ``e^{-Gamma_j}`` over a whole chunk overflows float32 once its
+decays pass about 88.  So a chunk is cut into sub-chunks of ``_SUB``
+positions with a reference position r before each: where t's sub-chunk
+follows j's, ``(k_t e^{Gamma_t - Gamma_r}) . (k_j e^{Gamma_r -
+Gamma_j})`` is a product of factors of at most 1 (r is the position
+before t's sub-chunk, so Gamma_t <= Gamma_r <= Gamma_j); inside a
+sub-chunk the pair's sum is taken elementwise with ``e^{Gamma_t -
+Gamma_j}`` exact.  The rest is the scalar rule's with the same
+replacement: ``W = T diag(beta) (K * e^Gamma)``, ``O = (Q * e^Gamma)
+S_0 + P U``, ``S_Q = Diag(e^{Gamma_Q}) S_0 + (K * e^{Gamma_Q -
+Gamma})^T U``, every exponent at most 0.
+
+That form runs in ``jnp`` (``_channel_span``) behind its own
+``custom_vjp`` (``_rule_channels``), which keeps x, g and beta and
+nothing else.  The chunks are taken ``_SPAN`` at a time, the state
+carried from chunk to chunk by a written-out sequence (no ``while``:
+a loop's event would count its body twice in the device account), so
+no (chunks, Q, Q, dk) array exists over a whole row.  Its backward sweeps
+the states entering the spans, then walks the spans last to first,
+each one's forward computed again and differentiated by ``jax.vjp``
+with the state's gradient carried: one span's per-chunk arrays exist at
+a time.  A scalar g takes the path above, unchanged.
+
 ``gdn_mixer`` is the mixer between its projections, from the
 in-projection's [q | k | v | z] result and the (b | a) one: the causal
 depthwise convolution without a bias and its silu over [q | k | v]
@@ -76,13 +109,23 @@ it), then the norm of each value head's output times one gain shared by
 the heads, times ``silu(z)`` (``ssm._gate_norm`` with the norm BEFORE
 the gate, reading z in place).
 
+``kda_mixer`` is Kimi Linear's delta-rule mixer with its projections:
+the convolution and silu over [q | k | v] as ``gdn_mixer``'s, the decay
+``g = -exp(A_log) softplus(f + dt_bias)`` a key channel, the rule with
+q and k L2-normed inside it, and ``rmsnorm(o) * gain * sigmoid(z)`` a
+head (``ssm._gate_norm`` with the norm before a sigmoid gate); it keeps
+its input alone and computes the rest again in the backward pass.
+
 Ops:
-  ``gated_delta_rule`` — q, k (b, L, Hk, dk), v (b, L, H, dv), g, beta
-                         (b, L, H) -> o (b, L, H, dv)
+  ``gated_delta_rule`` — q, k (b, L, Hk, dk), v (b, L, H, dv), beta
+                         (b, L, H), g (b, L, H) or (b, L, H, dk)
+                         -> o (b, L, H, dv)
   ``gdn_mixer``        — the mixer between its projections
+  ``kda_mixer``        — Kimi Linear's, with its projections
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -94,9 +137,11 @@ from .deltanet_kernels import (L2_EPS, gdn_chunk_tiles, gdn_chunks,
 from .registry import register
 from .ssm import _conv_silu, _gate_norm, _whole_chunks
 
-__all__ = ["gated_delta_rule", "gdn_mixer"]
+__all__ = ["gated_delta_rule", "gdn_mixer", "kda_mixer"]
 
 _HIGHEST = lax.Precision.HIGHEST
+_SUB = 16       # positions of a sub-chunk of the per-channel rule
+_SPAN = 16      # chunks of the per-channel rule whose arrays exist at once
 
 
 def _inverse(A):
@@ -207,13 +252,161 @@ def _rule_bwd(Q, layout, normed, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def _run(x, g, beta, layout, chunk, normed):
-    """The rule over x (b, L, [q | k | v]), g and beta (b, L, H), in
-    float32 in chunks of ``chunk``, the last one filled with positions
-    that neither decay nor write.  Returns o (b, L, H dv) float32."""
+def _channel_span(q, k, v, g, beta, S, sub):
+    """The per-channel rule over a span of whole chunks, float32, with
+    the state S (b, H, dk, dv) entering it.  q, k, g (b, n, H, Q, dk);
+    v (b, n, H, Q, dv); beta (b, n, H, Q).  Returns (o, v's shape; the
+    state leaving the span)."""
+    Q, dk = q.shape[-2:]
+    m = Q // sub
+    cum = jnp.cumsum(g, axis=-2)                       # Gamma, (.., Q, dk)
+    # the reference of each sub-chunk: Gamma before its first position
+    ref = jnp.concatenate([jnp.zeros_like(cum[..., :1, :]),
+                           cum[..., sub - 1:Q - 1:sub, :]], axis=-2)
+    left = jnp.exp(cum - jnp.repeat(ref, sub, axis=-2))           # <= 1
+    before = (jnp.arange(Q)[None, :]
+              < sub * jnp.arange(m)[:, None])[..., None]          # (m, Q, 1)
+    # k_j e^{Gamma_r - Gamma_j} for j before sub-chunk a's start:
+    # (.., m, Q, dk)
+    right = k[..., None, :, :] * jnp.exp(jnp.where(
+        before, ref[..., :, None, :] - cum[..., None, :, :], -jnp.inf))
+
+    def off(x):
+        # sub-chunk a's rows against every earlier sub-chunk's columns
+        rows = (x * left).reshape(x.shape[:-2] + (m, sub, dk))
+        return jnp.einsum("...asd,...ajd->...asj", rows, right).reshape(
+            x.shape[:-2] + (Q, Q))
+
+    # inside a sub-chunk, elementwise: (.., m, sub, sub, dk)
+    cs = cum.reshape(cum.shape[:-2] + (m, sub, dk))
+    seen = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    inner = jnp.exp(jnp.where(seen, cs[..., :, None, :] - cs[..., None, :, :],
+                              -jnp.inf))
+    ks = k.reshape(cs.shape)
+
+    def diagonal(x):
+        xs = x.reshape(cs.shape)
+        d = jnp.sum(xs[..., :, None, :] * ks[..., None, :, :] * inner, -1)
+        # (.., m, sub, sub) placed on the block diagonal of (.., Q, Q)
+        eye = jnp.eye(m, dtype=d.dtype)[:, None, :, None]
+        return (d[..., :, :, None, :] * eye).reshape(d.shape[:-3] + (Q, Q))
+
+    below = jnp.tril(jnp.ones((Q, Q), bool), -1)
+    A = beta[..., :, None] * jnp.where(below, off(k) + diagonal(k), 0.0)
+    T = _inverse(A)                                    # (b, n, H, Q, Q)
+    P = off(q) + diagonal(q)
+    decay = jnp.exp(cum)
+    W = T @ (beta[..., None] * k * decay)              # (b, n, H, Q, dk)
+    Un = T @ (beta[..., None] * v)                     # (b, n, H, Q, dv)
+    kd = k * jnp.exp(cum[..., -1:, :] - cum)
+    whole = jnp.exp(cum[..., -1, :])                   # (b, n, H, dk)
+    entering, writes = [], []
+    for c in range(q.shape[1]):
+        entering.append(S)
+        U = Un[:, c] - W[:, c] @ S
+        writes.append(U)
+        S = whole[:, c, ..., None] * S + jnp.swapaxes(kd[:, c], -1, -2) @ U
+    S0, U = jnp.stack(entering, 1), jnp.stack(writes, 1)
+    return (q * decay) @ S0 + P @ U, S
+
+
+def _pieces(a, Q, span):
+    """``a`` (b, L, H, ...) as the spans' pieces, heads before
+    positions: a list of (b, n, H, Q, ...), n chunks of Q a piece and
+    ``span`` at most."""
+    b, L = a.shape[:2]
+    nc = L // Q
+    return [jnp.moveaxis(a[:, lo * Q:(lo + n) * Q].reshape(
+        (b, n, Q) + a.shape[2:]), 2, 3)
+        for lo, n in ((lo, min(span, nc - lo)) for lo in range(0, nc, span))]
+
+
+def _joined(pieces):
+    """``_pieces``' inverse."""
+    return jnp.concatenate([jnp.moveaxis(p, 3, 2).reshape(
+        (p.shape[0], -1) + p.shape[2:3] + p.shape[4:]) for p in pieces], 1)
+
+
+def _channel_operands(x, g, layout, normed):
+    """q, k (b, L, H, dk), one key head a value head, and v (b, L, H,
+    dv) of x (b, L, [q | k | v]); g (b, L, H, dk)."""
+    Hk, dk, dv = layout
+    b, L, H, _ = g.shape
+    q, k, v = _split(x, g[..., 0].reshape(b, L, Hk, H // Hk), layout, normed)
+    q, k = (jnp.broadcast_to(a[:, :, :, None], (b, L, Hk, H // Hk, dk))
+            .reshape(b, L, H, dk) for a in (q, k))
+    return q, k, v.reshape(b, L, H, dv)
+
+
+def _span_operands(x, g, beta, Q, layout, normed, span):
+    """The spans' operands: a list of (q, k, v, g, beta) pieces."""
+    ops = _channel_operands(x, g, layout, normed) + (g, beta)
+    return list(zip(*(_pieces(a, Q, span) for a in ops)))
+
+
+def _channels(x, g, beta, Q, layout, normed, sub, span):
+    """o (b, L, H, dv) of the per-channel rule, span after span."""
+    _, dk, dv = layout
+    b, _, H = beta.shape
+    S = jnp.zeros((b, H, dk, dv), jnp.float32)
+    out = []
+    for part in _span_operands(x, g, beta, Q, layout, normed, span):
+        o, S = _channel_span(*part, S, sub)
+        out.append(o)
+    return _joined(out)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _rule_channels(x, g, beta, Q, layout, normed, sub, span):
+    return _channels(x, g, beta, Q, layout, normed, sub, span)
+
+
+def _rule_channels_fwd(x, g, beta, Q, layout, normed, sub, span):
+    return (_channels(x, g, beta, Q, layout, normed, sub, span),
+            (x, g, beta))
+
+
+def _rule_channels_bwd(Q, layout, normed, sub, span, res, do):
+    x, g, beta = res
+    _, dk, dv = layout
+    b, _, H = beta.shape
+    (q, k, v), operands = jax.vjp(
+        lambda x: _channel_operands(x, g, layout, normed), x)
+    parts = list(zip(*(_pieces(a, Q, span) for a in (q, k, v, g, beta))))
+    # the states entering the spans (the sweep's outputs are not used)
+    states = [jnp.zeros((b, H, dk, dv), jnp.float32)]
+    for part in parts[:-1]:
+        states.append(_channel_span(*part, states[-1], sub)[1])
+    dS = jnp.zeros_like(states[0])
+    grads = []
+    for part, S, d_o in reversed(list(zip(parts, states,
+                                          _pieces(do, Q, span)))):
+        # a barrier, so that XLA does not merge this span's forward with
+        # the sweep's and keep the sweep's arrays alive until here
+        part, S = lax.optimization_barrier((part, S))
+        _, back = jax.vjp(partial(_channel_span, sub=sub), *part, S)
+        *grad, dS = back((d_o, dS))
+        grads.append(grad)
+    dq, dk_, dv_, dg, dbeta = (_joined(p[::-1]) for p in zip(*grads))
+    return operands((dq, dk_, dv_))[0], dg, dbeta
+
+
+_rule_channels.defvjp(_rule_channels_fwd, _rule_channels_bwd)
+
+
+def _run(x, g, beta, layout, chunk, normed, sub=_SUB, span=_SPAN):
+    """The rule over x (b, L, [q | k | v]), beta (b, L, H) and g
+    (b, L, H), or (b, L, H, dk) for a decay a key channel, in float32 in
+    chunks of ``chunk``, the last one filled with positions that neither
+    decay nor write.  Returns o (b, L, H dv) float32."""
     Hk, _, dv = layout
-    b, L, H = g.shape
+    b, L, H = beta.shape
     Q = int(chunk)
+    if g.ndim == 4:
+        o = _rule_channels(*(_whole_chunks(a.astype(jnp.float32), Q)
+                             for a in (x, g, beta)),
+                           Q, layout, normed, math.gcd(Q, sub), int(span))
+        return o[:, :L].reshape(b, L, H * dv)
     o = _rule(*(_whole_chunks(a.astype(jnp.float32), Q) for a in (
         x, g.reshape(b, L, Hk, H // Hk), beta.reshape(b, L, Hk, H // Hk))),
         Q, layout, normed)
@@ -277,3 +470,55 @@ def gdn_mixer(qkvz, ba, conv_weight, A_log, dt_bias, gamma, *,
     with jax.named_scope("mx.gdn.gate_norm"):
         return _gate_norm(o, qkvz,
                           jnp.tile(gamma, H), H, float(eps), width, True)
+
+
+@register("_contrib_kda_mixer", num_inputs=12, aliases=["kda_mixer"])
+def kda_mixer(x, qkv_weight, f_a_weight, f_b_weight, b_weight, g_a_weight,
+              g_b_weight, conv_weight, A_log, dt_bias, gamma, out_weight, *,
+              heads: int, head_dim: int, chunk: int = 64,
+              eps: float = 1e-5):
+    """Kimi Delta Attention over x (b, L, C), its projections included
+    (weights (out, in), as ``Dense`` holds them), ``heads`` H of
+    ``head_dim`` d.  ``[q | k | v] = silu(conv(x W_qkv))``, the causal
+    depthwise convolution (``conv_weight`` (3 H d, K), no bias); q and k
+    L2-normed a head (q then over sqrt(d)); ``beta = sigmoid(x W_b)``
+    a head; ``g = -exp(A_log) softplus((x W_fa) W_fb + dt_bias)`` a key
+    channel (``A_log`` (H,), ``dt_bias`` (H d,)); the rule;
+    ``rmsnorm(o) * gamma * sigmoid((x W_ga) W_gb)`` a head (``gamma``
+    (d,), the gain every head's norm shares); ``W_out``.  Returns
+    (b, L, C).
+
+    The mixer keeps its input alone between the passes: every array in
+    it, the projections' results among them, is computed again in the
+    backward pass (``jax.checkpoint``).  Kept, a layer's would hold 1.4
+    GB at Kimi Linear's widths over 8192 positions, more than the chip
+    has room for beside four such layers."""
+    H, d = int(heads), int(head_dim)
+    bs, L, _ = x.shape
+    f32 = jnp.float32
+
+    def mixer(x, qkv_weight, f_a_weight, f_b_weight, b_weight, g_a_weight,
+              g_b_weight, conv_weight, A_log, dt_bias, gamma, out_weight):
+        with jax.named_scope("mx.kda.in_proj"):
+            qkv = jnp.matmul(x, qkv_weight.T)
+            f = jnp.matmul(jnp.matmul(x, f_a_weight.T), f_b_weight.T)
+            b = jnp.matmul(x, b_weight.T)
+            z = jnp.matmul(jnp.matmul(x, g_a_weight.T), g_b_weight.T)
+        with jax.named_scope("mx.kda.conv"):
+            u = _conv_silu(qkv, conv_weight, None, 0)
+        with jax.named_scope("mx.kda.core"):
+            beta = jax.nn.sigmoid(b.astype(f32))
+            g = -jnp.exp(jnp.repeat(A_log.astype(f32), d)) * jax.nn.softplus(
+                f.astype(f32) + dt_bias.astype(f32))
+            o = _run(u, g.reshape(bs, L, H, d), beta, (H, d, d), chunk,
+                     True).astype(u.dtype)
+        with jax.named_scope("mx.kda.gate_norm"):
+            y = _gate_norm(o, z, jnp.tile(gamma, H), H, float(eps), 0, True,
+                           "sigmoid")
+        with jax.named_scope("mx.kda.out_proj"):
+            return jnp.matmul(y, out_weight.T)
+
+    return jax.checkpoint(
+        mixer, policy=jax.checkpoint_policies.nothing_saveable)(
+        x, qkv_weight, f_a_weight, f_b_weight, b_weight, g_a_weight,
+        g_b_weight, conv_weight, A_log, dt_bias, gamma, out_weight)

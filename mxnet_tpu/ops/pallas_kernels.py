@@ -445,13 +445,15 @@ def _bwd_dkv_kernel(lens_ref, k_blk, q_blk, flags_ref, q_ref, k_ref, v_ref,
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
-def _specs(plan, D, order):
-    """BlockSpecs for (q, k, row statistics) of a kernel that walks
+def _specs(plan, D, order, Dv):
+    """BlockSpecs for (q, k, row statistics, a query head's rows of v's
+    width Dv: the output and its cotangent, v) of a kernel that walks
     ``plan.walk(order)``: "qk" on the grid (query head, step), "kq" on
     (key/value head, step, query head of the group).  The step's blocks
     come from the walk's tables in the scalar prefetch.  Query head
     ``h`` reads key/value head ``h // group``: the index map does the
-    grouping, so K and V are never repeated in HBM."""
+    grouping, so K and V are never repeated in HBM.  Where Dv is D the
+    last two are the first two."""
     group = plan.group
     if order == "qk":
         qi = lambda b, t, lens, stay, walked, flags: (      # noqa: E731
@@ -465,7 +467,9 @@ def _specs(plan, D, order):
             b, stay[t], 0)
     return (pl.BlockSpec((1, plan.block_q, D), qi),
             pl.BlockSpec((1, plan.block_k, D), ki),
-            pl.BlockSpec((1, plan.block_q, 1), qi))
+            pl.BlockSpec((1, plan.block_q, 1), qi),
+            pl.BlockSpec((1, plan.block_q, Dv), qi),
+            pl.BlockSpec((1, plan.block_k, Dv), ki))
 
 
 def _run(kernel, plan, order, heads, in_specs, out_shape, out_specs, scratch,
@@ -494,27 +498,29 @@ def _flash(q, k, v, lens, plan, sm_scale, interpret):
 
 def _flash_fwd(q, k, v, lens, plan, sm_scale, interpret):
     BH, Lq, D = q.shape
-    q_spec, k_spec, row_spec = _specs(plan, D, "qk")
+    Dv = v.shape[2]
+    q_spec, k_spec, row_spec, o_spec, v_spec = _specs(plan, D, "qk", Dv)
     bq = plan.block_q
     out, lse = _run(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, plan=plan),
-        plan, "qk", BH, [q_spec, k_spec, k_spec],
-        (jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+        plan, "qk", BH, [q_spec, k_spec, v_spec],
+        (jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
          jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32)),
-        (q_spec, row_spec),
+        (o_spec, row_spec),
         [_scratch((bq, 1), jnp.float32), _scratch((bq, 1), jnp.float32),
-         _scratch((bq, D), jnp.float32)],
+         _scratch((bq, Dv), jnp.float32)],
         lens, (q, k, v), interpret)
     return out, (q, k, v, lens, out, lse)
 
 
 def _flash_dq(plan, sm_scale, interpret, lens, inputs):
-    q = inputs[0]
-    q_spec, k_spec, row_spec = _specs(plan, q.shape[2], "qk")
+    q, v = inputs[0], inputs[2]
+    q_spec, k_spec, row_spec, o_spec, v_spec = _specs(
+        plan, q.shape[2], "qk", v.shape[2])
     return _run(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, plan=plan),
         plan, "qk", q.shape[0],
-        [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
         jax.ShapeDtypeStruct(q.shape, q.dtype), q_spec,
         [_scratch((plan.block_q, q.shape[2]), jnp.float32)],
         lens, inputs, interpret)
@@ -522,16 +528,17 @@ def _flash_dq(plan, sm_scale, interpret, lens, inputs):
 
 def _flash_dkv(plan, sm_scale, interpret, lens, inputs):
     k, v = inputs[1:3]
-    q_spec, k_spec, row_spec = _specs(plan, k.shape[2], "kq")
+    q_spec, k_spec, row_spec, o_spec, v_spec = _specs(
+        plan, k.shape[2], "kq", v.shape[2])
     return _run(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, plan=plan),
         plan, "kq", k.shape[0],
-        [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
         (jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        (k_spec, k_spec),
+        (k_spec, v_spec),
         [_scratch((plan.block_k, k.shape[2]), jnp.float32),
-         _scratch((plan.block_k, k.shape[2]), jnp.float32)],
+         _scratch((plan.block_k, v.shape[2]), jnp.float32)],
         lens, inputs, interpret)
 
 
@@ -557,10 +564,13 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     dividing H: query head ``h`` then reads key/value head
     ``h // (H // Hkv)`` (grouped-query attention) through the kernels'
     block index maps, dK and dV summed over each group's query heads.
+    ``v`` may be of another width than q and k, (B*Hkv, L, Dv) (latent
+    attention's heads of 192 over values of 128): the output, its
+    cotangent and dV are Dv wide, and ``sm_scale`` defaults to D's.
     ``lengths``: optional int32 (B*H,) valid key lengths (padding mask).
     ``window``: optional causal sliding-window width — query q attends
     keys in [q-window+1, q] (Mistral/Longformer-style local attention).
-    Requires causal=True.  Returns (B*H, Lq, D) in the query dtype.
+    Requires causal=True.  Returns (B*H, Lq, Dv) in the query dtype.
 
     What is computed follows the mask (``flash_tile_plan``, which takes
     the explicit ``block_q`` / ``block_k`` of a test): the grid walks
@@ -570,7 +580,7 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     """
     BH, Lq, D = q.shape
     Lk = k.shape[1]
-    if BH % k.shape[0] or v.shape != k.shape:
+    if BH % k.shape[0] or v.shape[:2] != k.shape[:2]:
         from ..base import MXNetError
         raise MXNetError(
             f"flash_attention: {k.shape[0]} key/value heads (k {k.shape}, "
@@ -1806,43 +1816,54 @@ def ssm_conv_pass_grads(src, taps, bias, g, lo, interpret):
     return dx, sums[:K], sums[K]
 
 
-def _gated_normed(y_ref, z_ref, eps, before):
-    """v = y silu(z), the reciprocal root of the mean square a row of v
+def _gate_value(z, s, gate):
+    """The gate at z, whose sigmoid is s: ``silu`` or ``sigmoid``."""
+    return z * s if gate == "silu" else s
+
+
+def _gate_slope(z, s, gate):
+    """The gate's derivative at z."""
+    return s * (1.0 + z * (1.0 - s)) if gate == "silu" else s * (1.0 - s)
+
+
+def _gated_normed(y_ref, z_ref, eps, before, gate):
+    """v = y act(z), the reciprocal root of the mean square a row of v
     (of y where the norm comes ``before`` the gate), and what the gate's
     gradient needs: the block's group is its columns."""
     y, z = y_ref[...], z_ref[...]
     s = jax.nn.sigmoid(z)
-    v = y * z * s
+    v = y * z * s if gate == "silu" else y * s
     normed = y if before else v
     r = jax.lax.rsqrt(jnp.mean(normed * normed, axis=-1, keepdims=True)
                       + eps)
     return y, z, s, v, r
 
 
-def _ssm_norm_fwd_kernel(y_ref, z_ref, gain_ref, o_ref, *, eps, before):
-    *_, v, r = _gated_normed(y_ref, z_ref, eps, before)
+def _ssm_norm_fwd_kernel(y_ref, z_ref, gain_ref, o_ref, *, eps, before,
+                         gate):
+    *_, v, r = _gated_normed(y_ref, z_ref, eps, before, gate)
     o_ref[...] = v * r * gain_ref[...]
 
 
 def _ssm_norm_bwd_kernel(y_ref, z_ref, gain_ref, g_ref, dy_ref, dz_ref,
-                         dgain_ref, *, eps, before):
+                         dgain_ref, *, eps, before, gate):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dgain_ref[...] = jnp.zeros(dgain_ref.shape, jnp.float32)
 
-    y, z, s, v, r = _gated_normed(y_ref, z_ref, eps, before)
+    y, z, s, v, r = _gated_normed(y_ref, z_ref, eps, before, gate)
     g = g_ref[...]
     scaled = g * gain_ref[...]
     if before:
-        a = scaled * (z * s)
+        a = scaled * _gate_value(z, s, gate)
         back = jnp.mean(a * y, axis=-1, keepdims=True) * (r * r * r)
         dy_ref[...] = a * r - y * back
-        dz_ref[...] = scaled * y * r * (s * (1.0 + z * (1.0 - s)))
+        dz_ref[...] = scaled * y * r * _gate_slope(z, s, gate)
     else:
         back = jnp.mean(scaled * v, axis=-1, keepdims=True) * (r * r * r)
         dv = scaled * r - v * back
-        dy_ref[...] = dv * (z * s)
-        dz_ref[...] = dv * y * (s * (1.0 + z * (1.0 - s)))
+        dy_ref[...] = dv * _gate_value(z, s, gate)
+        dz_ref[...] = dv * y * _gate_slope(z, s, gate)
     dgain_ref[...] += jnp.sum(g * v * r, axis=0, keepdims=True)
 
 
@@ -1857,32 +1878,35 @@ def _norm_specs(L, C, groups, lo):
         gain=pl.BlockSpec((1, n), lambda i, j, c: (0, j)))
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def ssm_norm_pass(y, gate_src, gain, groups, eps, lo, interpret,
-                  before=False):
+                  before=False, gate="silu"):
     """``y * silu(gate)`` RMS-normed over each of ``groups`` groups of
     its C channels, times ``gain`` (1, C); ``before``: y normed, then
-    times the gain and ``silu(gate)``.  The gate is columns ``lo`` on of
+    times the gain and ``silu(gate)``; ``gate`` "sigmoid": ``sigmoid``
+    in the place of ``silu``.  The gate is columns ``lo`` on of
     ``gate_src`` (b, L, .); float32; (b, L, C)."""
     b, L, C = y.shape
     at = _norm_specs(L, C, groups, lo)
     return pl.pallas_call(
-        functools.partial(_ssm_norm_fwd_kernel, eps=eps, before=before),
+        functools.partial(_ssm_norm_fwd_kernel, eps=eps, before=before,
+                          gate=gate),
         grid=(b, groups, L // at["rows"]),
         in_specs=[at["own"], at["gate"], at["gain"]], out_specs=at["own"],
         out_shape=jax.ShapeDtypeStruct((b, L, C), jnp.float32),
         interpret=interpret, **_SSM_PASS_PARAMS)(y, gate_src, gain)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def ssm_norm_pass_grads(y, gate_src, gain, g, groups, eps, lo, interpret,
-                        before=False):
+                        before=False, gate="silu"):
     """``ssm_norm_pass``'s gradients from the cotangent ``g``: in y, in
     the gate's C columns (b, L, C) and in the gain (C,)."""
     b, L, C = y.shape
     at = _norm_specs(L, C, groups, lo)
     dy, dz, dgain = pl.pallas_call(
-        functools.partial(_ssm_norm_bwd_kernel, eps=eps, before=before),
+        functools.partial(_ssm_norm_bwd_kernel, eps=eps, before=before,
+                          gate=gate),
         grid=(b, groups, L // at["rows"]),
         in_specs=[at["own"], at["gate"], at["gain"], at["own"]],
         out_specs=[at["own"], at["own"],
@@ -1923,13 +1947,14 @@ def flash_selfatt(queries_keys_values, valid_length, *, heads: int = 1,
           aliases=["flash_attention"])
 def flash_attention_heads(q, k, v, *, causal: bool = False,
                           window: int = -1):
-    """Flash attention over separate projections: q (B, L, H, D), k and
-    v (B, L, Hkv, D) with Hkv dividing H (query head ``h`` reads
-    key/value head ``h // (H // Hkv)``).  ``window > 0``: causal
-    sliding window of that width.  Returns (B, L, H, D) in q's dtype.
+    """Flash attention over separate projections: q (B, L, H, D), k
+    (B, L, Hkv, D) and v (B, L, Hkv, Dv) with Hkv dividing H (query head
+    ``h`` reads key/value head ``h // (H // Hkv)``; Dv is D but in
+    latent attention).  ``window > 0``: causal sliding window of that
+    width.  Returns (B, L, H, Dv) in q's dtype.
     """
     B, L, H, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = v.shape[2:]
     # the scope the device metrics find the kernels by, forward and
     # (as transpose(jvp(...))) backward
     with jax.named_scope("mx.attn.window" if window > 0
@@ -1937,9 +1962,9 @@ def flash_attention_heads(q, k, v, *, causal: bool = False,
         out = flash_attention(
             q.transpose(0, 2, 1, 3).reshape(B * H, L, D),
             k.transpose(0, 2, 1, 3).reshape(B * Hkv, k.shape[1], D),
-            v.transpose(0, 2, 1, 3).reshape(B * Hkv, v.shape[1], D),
+            v.transpose(0, 2, 1, 3).reshape(B * Hkv, v.shape[1], Dv),
             causal=causal, window=None if window <= 0 else window)
-        return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+        return out.reshape(B, H, L, Dv).transpose(0, 2, 1, 3)
 
 
 @register("_contrib_flash_selfatt_nomask", num_inputs=1,

@@ -38,6 +38,10 @@ in the place of v:
     r = rsqrt(mean_group(y^2) + eps)
     dy = r * a - y * r^3 * mean_group(a * y)    dz = g * gain * y * r * silu'(z)
 
+Kimi Linear's delta-rule mixer gates its normed output by ``sigmoid(z)``
+(``gate="sigmoid"``): the same passes with ``sigmoid`` and ``sigmoid' =
+s (1 - s)`` in the place of ``silu`` and ``silu'``.
+
 Both take their operand as columns ``lo`` on of a wider array: the mixer
 hands them the in-projection's one [z | x B C | dt] result and no slice
 of it (a slice handed to an operator is written out in HBM; the
@@ -104,9 +108,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from .pallas_kernels import (
-    _interpret, ssm_conv_pass, ssm_conv_pass_grads, ssm_conv_tiles,
-    ssm_norm_pass, ssm_norm_pass_grads, ssm_norm_tiles, ssm_scan_chunks,
-    ssm_scan_tiles)
+    _gate_slope, _gate_value, _interpret, ssm_conv_pass, ssm_conv_pass_grads,
+    ssm_conv_tiles, ssm_norm_pass, ssm_norm_pass_grads, ssm_norm_tiles,
+    ssm_scan_chunks, ssm_scan_tiles)
 from .registry import register
 from .shortconv import _shifted
 
@@ -331,13 +335,13 @@ def _each_channel(stats, channels):
     return jnp.broadcast_to(out, stats[0].shape[:-1] + (channels,))
 
 
-def _gated(y, gate, groups, eps, before=False):
-    """The gate in float32, its sigmoid, ``v = y * silu(gate)`` and the
+def _gated(y, gate, groups, eps, before=False, act="silu"):
+    """The gate in float32, its sigmoid, ``v = y * act(gate)`` and the
     reciprocal root of the mean square a group (a list, a group each) of
     v, or of y itself where the norm comes ``before`` the gate."""
     z = gate.astype(jnp.float32)
     s = jax.nn.sigmoid(z)
-    v = y * z * s
+    v = y * z * s if act == "silu" else y * s
     n = v.shape[-1] // groups
     normed = y if before else v
     return z, s, v, [lax.rsqrt(a / n + eps)
@@ -348,60 +352,63 @@ def _norm_kernels(data, groups, lo):
     return data.ndim == 3 and ssm_norm_tiles(*data.shape[1:], groups, lo)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _gate_norm(data, gate_src, gamma, groups, eps, lo, before=False):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _gate_norm(data, gate_src, gamma, groups, eps, lo, before=False,
+               gate="silu"):
     """The gate is the ``data.shape[-1]`` channels that start at column
     ``lo`` of ``gate_src``; ``before``: the norm comes before the gate,
-    ``norm(data) * gamma * silu(gate)``."""
+    ``norm(data) * gamma * act(gate)``; ``gate``: ``act``, "silu" or
+    "sigmoid"."""
     f32 = jnp.float32
     C = data.shape[-1]
     if _norm_kernels(data, groups, lo):
-        # mxlint: disable=recompile-churn (sizes and a bool)
+        # mxlint: disable=recompile-churn (sizes, a bool and a name)
         out = ssm_norm_pass(data.astype(f32), gate_src.astype(f32),
                             gamma.astype(f32)[None], groups, eps, lo,
-                            _interpret(None), before)
+                            _interpret(None), before, gate)
     else:
         _, _, v, r = _gated(data.astype(f32), _columns(gate_src, lo, C),
-                            groups, eps, before)
+                            groups, eps, before, gate)
         out = v * _each_channel(r, C) * gamma.astype(f32)
     return out.astype(data.dtype)
 
 
-def _gate_norm_fwd(data, gate_src, gamma, groups, eps, lo, before=False):
-    return (_gate_norm(data, gate_src, gamma, groups, eps, lo, before),
+def _gate_norm_fwd(data, gate_src, gamma, groups, eps, lo, before=False,
+                   gate="silu"):
+    return (_gate_norm(data, gate_src, gamma, groups, eps, lo, before, gate),
             (data, gate_src, gamma))
 
 
-def _gate_norm_bwd(groups, eps, lo, before, res, g):
+def _gate_norm_bwd(groups, eps, lo, before, gate, res, g):
     data, gate_src, gamma = res
     f32 = jnp.float32
     C = data.shape[-1]
     y, g = data.astype(f32), g.astype(f32)
     if _norm_kernels(data, groups, lo):
-        # mxlint: disable=recompile-churn (sizes and a bool)
+        # mxlint: disable=recompile-churn (sizes, a bool and a name)
         dy, dz, d_gamma = ssm_norm_pass_grads(
             y, gate_src.astype(f32), gamma.astype(f32)[None], g, groups, eps,
-            lo, _interpret(None), before)
+            lo, _interpret(None), before, gate)
     else:
         z, s, v, r = _gated(y, _columns(gate_src, lo, C), groups, eps,
-                            before)
+                            before, gate)
         n = C // groups
         scaled = g * gamma.astype(f32)
         if before:
-            # out = y r gamma silu(z): the norm's cotangent is scaled silu(z)
-            a = scaled * (z * s)
+            # out = y r gamma act(z): the norm's cotangent is scaled act(z)
+            a = scaled * _gate_value(z, s, gate)
             back = [t / n * ri * ri * ri
                     for t, ri in zip(_group_sums(a * y, groups), r)]
             r = _each_channel(r, C)
             dy = a * r - y * _each_channel(back, C)
-            dz = scaled * y * r * (s * (1.0 + z * (1.0 - s)))
+            dz = scaled * y * r * _gate_slope(z, s, gate)
         else:
             back = [t / n * ri * ri * ri
                     for t, ri in zip(_group_sums(scaled * v, groups), r)]
             r = _each_channel(r, C)
             dv = scaled * r - v * _each_channel(back, C)
-            dy = dv * (z * s)
-            dz = dv * y * (s * (1.0 + z * (1.0 - s)))
+            dy = dv * _gate_value(z, s, gate)
+            dz = dv * y * _gate_slope(z, s, gate)
         d_gamma = jnp.sum(g * v * r, axis=tuple(range(g.ndim - 1)))
     return (dy.astype(data.dtype), _in_columns(dz, gate_src, lo),
             d_gamma.astype(gamma.dtype))

@@ -195,6 +195,16 @@ SCOPES = {
                    "delta rule",
     "mx.gdn.gate_norm": "its per-head norm, gain and silu gate",
     "mx.gdn.out_proj": "its output projection",
+    "mx.kda.in_proj": "a Kimi Delta Attention mixer's input projections, "
+                      "the decay's and the gate's low-rank pairs among "
+                      "them",
+    "mx.kda.conv": "its causal depthwise convolution and silu",
+    "mx.kda.core": "its L2 norms, beta, per-channel decays and the "
+                   "chunked delta rule",
+    "mx.kda.gate_norm": "its per-head norm, gain and sigmoid gate",
+    "mx.kda.out_proj": "its output projection",
+    "mx.mla.latent": "latent attention's latent norm and the assembly of "
+                     "its per-head K and V",
 }
 SCOPE_CONTAINERS = frozenset({"mx.fwd"})
 

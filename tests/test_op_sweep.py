@@ -750,6 +750,7 @@ ORACLES.update({
     "_contrib_gated_delta_rule": lambda *a, chunk=64:
         _np_gated_delta_rule(*a),
     "_contrib_gdn_mixer": lambda *a, **k: _np_gdn_mixer(*a, **k),
+    "_contrib_kda_mixer": lambda *a, **k: _np_kda_mixer(*a, **k),
     # decode-path paged attention vs a per-sequence gather + dense
     # softmax (block-table indirection materialized in numpy)
     "_contrib_ragged_paged_attention": lambda q, kp, vp, bt, lens:
@@ -1054,12 +1055,14 @@ def _np_ssm_gate_norm(y, z, g, groups=1, eps=1e-5):
 
 
 def _np_gated_delta_rule(q, k, v, g, beta):
+    """g (b, L, H), or (b, L, H, dk): a decay a key channel."""
     H = v.shape[2]
     q, k = (np.repeat(a, H // a.shape[2], axis=2) for a in (q, k))
     S = np.zeros(q.shape[:1] + (H, q.shape[-1], v.shape[-1]))
     out = np.zeros(v.shape)
     for t in range(q.shape[1]):
-        S = np.exp(g[:, t])[..., None, None] * S
+        decay = np.exp(g[:, t])
+        S = (decay[..., None] if g.ndim == 4 else decay[..., None, None]) * S
         pred = np.einsum("bhkv,bhk->bhv", S, k[:, t])
         S = S + np.einsum("bhk,bhv->bhkv", k[:, t],
                           beta[:, t, :, None] * (v[:, t] - pred))
@@ -1086,6 +1089,27 @@ def _np_gdn_mixer(qkvz, ba, cw, A_log, dtb, gamma, key_heads, value_heads,
     o = o / np.sqrt((o * o).mean(-1, keepdims=True) + eps) * gamma
     z = qkvz[..., width:].reshape(b, L, H, dv)
     return (o * _np_silu(z)).reshape(b, L, H * dv)
+
+
+def _np_kda_mixer(x, w_qkv, w_fa, w_fb, w_b, w_ga, w_gb, cw, A_log, dtb,
+                  gamma, w_out, heads, head_dim, chunk=64, eps=1e-5):
+    H, d = heads, head_dim
+    b, L, _ = x.shape
+    qkv = _np_ssm_conv(x @ w_qkv.T, cw, np.zeros(3 * H * d))
+
+    def l2(a):
+        return a / np.sqrt((a * a).sum(-1, keepdims=True) + 1e-6)
+
+    q = l2(qkv[..., :H * d].reshape(b, L, H, d)) / np.sqrt(d)
+    k = l2(qkv[..., H * d:2 * H * d].reshape(b, L, H, d))
+    v = qkv[..., 2 * H * d:].reshape(b, L, H, d)
+    f = (x @ w_fa.T) @ w_fb.T + dtb
+    g = -np.exp(A_log)[:, None] * np.log1p(np.exp(f.reshape(b, L, H, d)))
+    beta = 1.0 / (1.0 + np.exp(-(x @ w_b.T)))
+    o = _np_gated_delta_rule(q, k, v, g, beta)
+    o = o / np.sqrt((o * o).mean(-1, keepdims=True) + eps) * gamma
+    z = ((x @ w_ga.T) @ w_gb.T).reshape(b, L, H, d)
+    return (o / (1.0 + np.exp(-z))).reshape(b, L, H * d) @ w_out.T
 
 
 def _np_ssm_mixer(data, cw, cb, dtb, A_log, D, g, num_heads, head_dim,
@@ -1325,6 +1349,18 @@ SPECS = {
                           _f32(r, 2), _pos(r, 2)],
         kwargs=dict(key_heads=1, value_heads=2, key_dim=3, value_dim=2,
                     chunk=3)),
+    # x (1, 7, 6); two heads of 2: [q | k | v] 12 wide, the decay's and
+    # the gate's low-rank pairs through 2, beta, taps (12, 4), A_log (2,),
+    # dt_bias (4,), the gain (2,), out (6, 4); chunks of 3, the last
+    # ragged
+    "_contrib_kda_mixer": dict(
+        inputs=lambda r: [_f32(r, 1, 7, 6), 0.5 * _f32(r, 12, 6),
+                          0.5 * _f32(r, 2, 6), 0.5 * _f32(r, 4, 2),
+                          0.5 * _f32(r, 2, 6), 0.5 * _f32(r, 2, 6),
+                          0.5 * _f32(r, 4, 2), 0.5 * _f32(r, 12, 4),
+                          0.3 * _f32(r, 2), 0.3 * _f32(r, 4), _pos(r, 2),
+                          0.5 * _f32(r, 6, 4)],
+        kwargs=dict(heads=2, head_dim=2, chunk=3)),
     "_contrib_rms_norm": dict(inputs=lambda r: [_f32(r, 2, 3, 8),
                                                 _pos(r, 8)]),
     "_contrib_rope": dict(

@@ -90,6 +90,24 @@ def test_flash_kernels_lower_at_heads_of_256(mode):
     assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
 
 
+# Kimi Linear's latent attention: 32 heads of q and k 192 wide over 32
+# heads of v 128 wide, the output, its cotangent, dV and the scratch at
+# v's width
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_flash_kernels_lower_at_q_192_and_v_128(mode):
+    qk, v = S((32, 8192, 192), jnp.bfloat16), S((32, 8192, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _tpu_module_text(fn, qk, qk, v)
+    assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
+
+
 # the shapes chip_smoke.py's serve leg runs: GPT-2-small heads over a
 # 513-page pool of 16-token pages, 8 slots, 64 pages per sequence; and
 # the wider H=16, D=128 head shape
@@ -256,4 +274,23 @@ def test_norm_pass_lowers_for_tpu(b, L, C, groups, lo, wide, before, mode):
 
         def fn(*a):
             return ssm_norm_pass_grads(*a, groups, 1e-5, lo, False, before)
+    assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1
+
+
+# Kimi Delta Attention's gate: the norm before a sigmoid gate, 32 heads of
+# 128 over the out-projection's input, z an array of its own
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_norm_pass_with_a_sigmoid_gate_lowers_for_tpu(mode):
+    f32 = jnp.float32
+    b, L, C, groups = 1, 8192, 4096, 32
+    avals = (S((b, L, C), f32), S((b, L, C), f32), S((1, C), f32))
+    if mode == "fwd":
+        def fn(*a):
+            return ssm_norm_pass(*a, groups, 1e-5, 0, False, True, "sigmoid")
+    else:
+        avals += (S((b, L, C), f32),)
+
+        def fn(*a):
+            return ssm_norm_pass_grads(*a, groups, 1e-5, 0, False, True,
+                                       "sigmoid")
     assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1

@@ -4,7 +4,7 @@ of ``jit_mx_train_step`` that carries a jax name lies under exactly one
 leaf, and every leaf is read by a metric: a file of the benchmark's,
 or one of the account's eight (``perfbench.readers.scope_account.
 METRICS``, whose files a ``benchmark`` PR adds).  The toy
-models are the four families' own, through the adapters and ``toy``
+models are the families' own, through the adapters and ``toy``
 sizes the benchmark's rehearsals use."""
 import json
 import os
@@ -23,7 +23,8 @@ CELLS = ["bert-large.pretrain_b32_l128",
          "mellum2-12b-a2.5b.causal_b1_l8192",
          "nemotron-3-nano-30b-a3b.causal_b1_l8192",
          "lfm2-24b-a2b.causal_b1_l8192",
-         "qwen3-next-80b-a3b.causal_b1_l8192"]
+         "qwen3-next-80b-a3b.causal_b1_l8192",
+         "kimi-linear-48b-a3b.causal_b1_l8192"]
 # what each family's toy step must carry besides mx.loss and mx.optim
 FAMILY = {
     CELLS[0]: {"mx.embed", "mx.norm", "mx.head", "mx.attn.proj",
@@ -45,6 +46,12 @@ FAMILY = {
                "mx.moe.experts", "mx.moe.combine", "mx.moe.shared",
                "mx.gdn.in_proj", "mx.gdn.conv", "mx.gdn.core",
                "mx.gdn.gate_norm", "mx.gdn.out_proj"},
+    CELLS[5]: {"mx.embed", "mx.norm", "mx.head", "mx.attn.proj",
+               "mx.attn.full", "mx.mla.latent", "mx.ffn.dense",
+               "mx.moe.route", "mx.moe.dispatch", "mx.moe.experts",
+               "mx.moe.combine", "mx.moe.shared", "mx.kda.in_proj",
+               "mx.kda.conv", "mx.kda.core", "mx.kda.gate_norm",
+               "mx.kda.out_proj"},
 }
 LEAVES = set(tracing.SCOPES) - tracing.SCOPE_CONTAINERS
 SCOPE = re.compile(r"(?:^|[/(])(mx\.[\w.]+)(?=[/):]|$)")
