@@ -89,16 +89,24 @@ replacement: ``W = T diag(beta) (K * e^Gamma)``, ``O = (Q * e^Gamma)
 S_0 + P U``, ``S_Q = Diag(e^{Gamma_Q}) S_0 + (K * e^{Gamma_Q -
 Gamma})^T U``, every exponent at most 0.
 
-That form runs in ``jnp`` (``_channel_span``) behind its own
-``custom_vjp`` (``_rule_channels``), which keeps x, g and beta and
-nothing else.  The chunks are taken ``_SPAN`` at a time, the state
-carried from chunk to chunk by a written-out sequence (no ``while``:
-a loop's event would count its body twice in the device account), so
-no (chunks, Q, Q, dk) array exists over a whole row.  Its backward sweeps
-the states entering the spans, then walks the spans last to first,
-each one's forward computed again and differentiated by ``jax.vjp``
-with the state's gradient carried: one span's per-chunk arrays exist at
-a time.  A scalar g takes the path above, unchanged.
+That form sits behind its own ``custom_vjp`` (``_rule_channels``),
+which keeps x, g and beta and nothing else.  Shapes that
+``kda_kernels.kda_chunk_tiles`` takes (one key head a value head,
+chunks a multiple of 8 and of the sub-chunk, keys and values a multiple
+of 128 wide: Kimi Linear's 32 heads of 128 in chunks of 64) run it as
+Pallas kernels in the scalar rule's three-call form, one forward and a
+sweep and a walk backward, each head's state, the chunk's decays and
+its (Q, Q) matrices in VMEM, the diagonal blocks summed elementwise
+there.  Every other shape (the tests' models, heads of 8 in chunks of
+16) runs it in ``jnp`` (``_channel_span``): the chunks taken ``_SPAN``
+at a time, the state carried from chunk to chunk by a written-out
+sequence (no ``while``: a loop's event would count its body twice in
+the device account), so no (chunks, Q, Q, dk) array exists over a whole
+row; its backward sweeps the states entering the spans, then walks the
+spans last to first, each one's forward computed again and
+differentiated by ``jax.vjp`` with the state's gradient carried: one
+span's per-chunk arrays exist at a time.  A scalar g takes the path
+above, unchanged.
 
 ``gdn_mixer`` is the mixer between its projections, from the
 in-projection's [q | k | v | z] result and the (b | a) one: the causal
@@ -134,6 +142,7 @@ from jax import lax
 
 from .deltanet_kernels import (L2_EPS, gdn_chunk_tiles, gdn_chunks,
                                gdn_chunks_grads)
+from .kda_kernels import kda_chunk_tiles, kda_chunks, kda_chunks_grads
 from .registry import register
 from .ssm import _conv_silu, _gate_norm, _whole_chunks
 
@@ -356,18 +365,34 @@ def _channels(x, g, beta, Q, layout, normed, sub, span):
     return _joined(out)
 
 
+def _channel_kernels(g, Q, layout, sub):
+    """Whether the Pallas kernels take the per-channel rule in chunks of
+    Q and sub-chunks of ``sub``, g (b, L, H, dk): a key head a value
+    head and a shape ``kda_chunk_tiles`` takes."""
+    Hk, dk, dv = layout
+    return g.shape[2] == Hk and kda_chunk_tiles(Q, sub, dk, dv, Hk)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _rule_channels(x, g, beta, Q, layout, normed, sub, span):
-    return _channels(x, g, beta, Q, layout, normed, sub, span)
+    return _rule_channels_fwd(x, g, beta, Q, layout, normed, sub, span)[0]
 
 
 def _rule_channels_fwd(x, g, beta, Q, layout, normed, sub, span):
-    return (_channels(x, g, beta, Q, layout, normed, sub, span),
-            (x, g, beta))
+    if _channel_kernels(g, Q, layout, sub):
+        o = kda_chunks(x, g, beta, Q, sub, layout, normed)
+    else:
+        o = _channels(x, g, beta, Q, layout, normed, sub, span)
+    return o, (x, g, beta)
 
 
 def _rule_channels_bwd(Q, layout, normed, sub, span, res, do):
     x, g, beta = res
+    if _channel_kernels(g, Q, layout, sub):
+        # the backward's operations carry the scope's name too (a
+        # transposed custom_vjp opens none)
+        with jax.named_scope("mx.kda.core"):
+            return kda_chunks_grads(x, g, beta, do, Q, sub, layout, normed)
     _, dk, dv = layout
     b, _, H = beta.shape
     (q, k, v), operands = jax.vjp(

@@ -15,6 +15,7 @@ relative; the gradients sum that over the positions, so 1e-5 relative.
 Decays of -20 a step leave a state that forgets nearly all at once: the
 values are then near the last write alone and the recurrence's and the
 chunks' roundings differ a little more, 1e-4."""
+import re
 from unittest import mock
 
 import numpy as np
@@ -23,7 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.ops import deltanet, pallas_kernels, ssm
+from mxnet_tpu.ops import deltanet, kda_kernels, pallas_kernels, ssm
 from mxnet_tpu.ops.deltanet import gated_delta_rule, kda_mixer
 
 H, DK, DV = 3, 8, 6
@@ -290,19 +291,27 @@ def test_silu_stays_the_default_and_its_program():
     assert default == named
 
 
+def _mixer_operands(Hh, d, C, L):
+    ks = jax.random.split(jax.random.PRNGKey(2), 9)
+    shapes = [(1, L, C), (3 * Hh * d, C), (d, C), (Hh * d, d), (Hh, C),
+              (d, C), (Hh * d, d)]
+    return [0.3 * jax.random.normal(k, s) for k, s in zip(ks, shapes)] + [
+        jax.random.uniform(ks[7], (3 * Hh * d, 4), minval=-0.5, maxval=0.5),
+        jnp.log(jnp.array([1.0, 8.0])), jnp.zeros((Hh * d,)),
+        jnp.ones((d,)), 0.3 * jax.random.normal(ks[8], (C, Hh * d))]
+
+
 def test_kda_mixer_runs_its_pieces_under_their_scopes():
     """The projections, the convolution, the rule with its decays, the
     gated norm and the out-projection each under the leaf the device
     metrics read, forward and in the backward's recomputation, which
-    keeps the mixer's input and nothing of its inside."""
+    keeps the mixer's input and nothing of its inside.  At a shape the
+    rule's kernels tile, lowered for the TPU: the four Pallas calls of
+    the rule (forward, forward again in the recomputation, the sweep and
+    the walk of the backward) each under ``mx.kda.core``, and no loop in
+    the step."""
     Hh, d, C, L = 2, 8, 12, 32
-    ks = jax.random.split(jax.random.PRNGKey(2), 9)
-    shapes = [(1, L, C), (3 * Hh * d, C), (d, C), (Hh * d, d), (Hh, C),
-              (d, C), (Hh * d, d)]
-    ops = [0.3 * jax.random.normal(k, s) for k, s in zip(ks, shapes)] + [
-        jax.random.uniform(ks[7], (3 * Hh * d, 4), minval=-0.5, maxval=0.5),
-        jnp.log(jnp.array([1.0, 8.0])), jnp.zeros((Hh * d,)),
-        jnp.ones((d,)), 0.3 * jax.random.normal(ks[8], (C, Hh * d))]
+    ops = _mixer_operands(Hh, d, C, L)
 
     def fn(*a):
         return kda_mixer(*a, heads=Hh, head_dim=d, chunk=16)
@@ -317,3 +326,26 @@ def test_kda_mixer_runs_its_pieces_under_their_scopes():
     held = [a.shape for a in jax.tree_util.tree_leaves(vjp)
             if hasattr(a, "shape") and a.ndim == 3]
     assert held == [(1, L, C)], held
+
+    Hh, d, L = 2, 128, 64
+    assert deltanet._channel_kernels(jnp.zeros((1, L, Hh, d)), 64,
+                                     (Hh, d, d), 16)
+    ops = _mixer_operands(Hh, d, 16, L)
+
+    def compiled(interpret=None):
+        return False
+
+    with mock.patch.object(kda_kernels, "_interpret", compiled), \
+            mock.patch.object(ssm, "_interpret", compiled):
+        text = jax.jit(jax.value_and_grad(
+            lambda *a: kda_mixer(*a, heads=Hh, head_dim=d, chunk=64).sum(),
+            argnums=range(12))).trace(*ops).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "stablehlo.while" not in text
+    locations = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+    calls = [locations[re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)]
+             for line in text.splitlines() if "@tpu_custom_call" in line]
+    rule = sorted(re.search(r"/(kda_\w+)/pallas_call", c).group(1)
+                  for c in calls if "/kda_" in c)
+    assert rule == ["kda_fwd", "kda_fwd", "kda_sweep", "kda_walk"], calls
+    assert all("mx.kda.core" in c for c in calls if "/kda_" in c), calls

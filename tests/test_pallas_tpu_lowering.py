@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from mxnet_tpu.ops.kda_kernels import kda_chunks, kda_chunks_grads
 from mxnet_tpu.ops.pallas_kernels import (expert_activation,
                                           flash_attention, grouped_matmul,
                                           ragged_paged_attention,
@@ -294,3 +295,28 @@ def test_norm_pass_with_a_sigmoid_gate_lowers_for_tpu(mode):
             return ssm_norm_pass_grads(*a, groups, 1e-5, 0, False, True,
                                        "sigmoid")
     assert _tpu_module_text(fn, *avals).count("tpu_custom_call") == 1
+
+
+# Kimi Delta Attention's rule with a decay a key channel at the cell's
+# shape (one 8192-token row, 32 heads of 128, chunks of 64 in sub-chunks
+# of 16): the forward kernel; with the gradients also the sweep of the
+# entering states and the backward walk.  (That Mosaic compiles them
+# shows at its compile: PERF.md section 7, recipe 2.)
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_kda_kernels_lower_at_the_cells_shape(mode):
+    f32 = jnp.float32
+    b, L, H, d, Q, sub = 1, 8192, 32, 128, 64, 16
+    layout = (H, d, d)
+    avals = (S((b, L, 3 * H * d), f32), S((b, L, H, d), f32),
+             S((b, L, H), f32))
+
+    def fwd(x, g, beta):
+        return kda_chunks(x, g, beta, Q, sub, layout, True, interpret=False)
+
+    def fwd_bwd(x, g, beta):
+        o = fwd(x, g, beta)
+        return o, kda_chunks_grads(x, g, beta, o, Q, sub, layout, True,
+                                   interpret=False)
+
+    text = _tpu_module_text(fwd if mode == "fwd" else fwd_bwd, *avals)
+    assert text.count("tpu_custom_call") == (1 if mode == "fwd" else 3)
